@@ -8,30 +8,13 @@ Reference analog of this module: ``deepspeed/__init__.py`` —
 
 from .version import __version__
 
-from .utils.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
-from . import comm  # noqa: F401, E402
+from . import comm  # noqa: F401
 from .platform import get_platform  # noqa: F401
 from .runtime.config import HDSConfig, load_config  # noqa: F401
 from .runtime.engine import HDSEngine
 from .runtime.hybrid_engine import HybridEngine  # noqa: F401
+from .utils.compile_cache import ensure_compile_cache
 from .utils.logging import log_dist, logger  # noqa: F401
-
-
-def default_compile_cache_dir():
-    """Shared location for the persistent XLA compilation cache used by
-    the measurement tools (bench.py, hds_serve_bench, hds_decode_diag):
-    ``HDS_COMPILE_CACHE_DIR`` if set, else ``.jax_cache`` next to the
-    package (the repo root in a checkout). One helper so the three
-    entry points cannot drift to different directories."""
-    import os
-    env = os.environ.get("HDS_COMPILE_CACHE_DIR")
-    if env:
-        return env
-    return os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
 
 
 def initialize(args=None,
@@ -67,17 +50,7 @@ def initialize(args=None,
     """
     assert model is not None, "deepspeed.initialize requires a model"
     cfg = load_config(config if config is not None else config_params)
-    # persistent compilation cache (the AOT half of DeepCompile):
-    # compiled executables are keyed by HLO+flags and reused across
-    # process restarts. Set unconditionally from THIS config so a later
-    # initialize() without cache_dir doesn't keep writing to a previous
-    # engine's cache directory.
-    import jax as _jax
-    _jax.config.update("jax_compilation_cache_dir",
-                       cfg.compile.cache_dir or None)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                       cfg.compile.cache_min_compile_time_secs)
-    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    ensure_compile_cache()
     comm.init_distributed()
     # apply an EXPLICIT comms_logger config block to the global logger
     # (reference: comms_config.py wired through deepspeed.initialize);

@@ -56,9 +56,7 @@ def aot_estimate(jitted, *args, peak_flops: float = 0.0,
             + getattr(mem, "argument_size_in_bytes", 0)
             + getattr(mem, "output_size_in_bytes", 0)
             - getattr(mem, "alias_size_in_bytes", 0))
-    cost = (compiled.cost_analysis() or {})
-    if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0) or 0.0)
     bytes_accessed = float(cost.get("bytes accessed", 0.0) or 0.0)
     t_flops = flops / peak_flops if peak_flops else 0.0

@@ -190,21 +190,18 @@ def fused_vs_unfused_bench(payloads=((512, 256), (1024, 512),
     "fused_fallbacks", "backend", "devices"}``. ``maxdiff`` is the
     fused-vs-unfused output divergence (chunked-K sum: value-equal,
     not bitwise — the bitwise contract belongs to the reference twin,
-    gated elsewhere). The two fallback dicts snapshot
-    ``ops.quantized_matmul.fallback_debug_info()`` and
-    ``ops.fused_collective_matmul.fused_fallback_debug_info()`` AFTER
-    the runs — on CPU they record the deliberate reference dispatch,
-    on chip an unexpectedly non-empty fused dict means the Pallas
-    kernel bailed and the row is timing the fallback."""
+    gated elsewhere). The two fallback dicts are those ops' entries in
+    ``ops.fallback_report()`` AFTER the runs: on chip a non-empty one
+    means the Pallas kernel bailed and the row is timing the
+    reference."""
     from functools import partial
 
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.fused_collective_matmul import (
-        fused_fallback_debug_info, streamed_fused_gather_matmul)
-    from ..ops.quantized_matmul import (
-        fallback_debug_info, quantize_for_matmul, quantized_matmul)
+    from ..ops import fallback_report
+    from ..ops.fused_collective_matmul import streamed_fused_gather_matmul
+    from ..ops.quantized_matmul import quantize_for_matmul, quantized_matmul
 
     if mesh is None:
         devs = jax.devices()
@@ -265,8 +262,9 @@ def fused_vs_unfused_bench(payloads=((512, 256), (1024, 512),
     return {"rows": rows,
             "fused_le_unfused_largest":
                 bool(largest["fused_ms"] <= largest["unfused_ms"]),
-            "qmm_fallbacks": fallback_debug_info(),
-            "fused_fallbacks": fused_fallback_debug_info(),
+            "qmm_fallbacks": fallback_report().get("quantized_matmul", {}),
+            "fused_fallbacks":
+                fallback_report().get("fused_gather_matmul", {}),
             "backend": jax.default_backend(), "devices": n}
 
 

@@ -85,6 +85,10 @@ def init_distributed(dist_backend=None,
     Reference: ``comm/comm.py:636 init_distributed`` (+ mpi/AML/SageMaker env
     discovery :705-808). Here rendezvous is only needed across *hosts*;
     single-host (even 256-chip single-slice via one controller) needs nothing.
+    Multi-host is asked for by name — a coordinator address or a process
+    count above one, as arguments or through the launcher's ``HDS_*``
+    variables — never inferred from what a TPU host's environment happens
+    to carry, and a rendezvous that was asked for and fails raises.
     Safe to call multiple times.
     """
     global _initialized
@@ -111,23 +115,9 @@ def init_distributed(dist_backend=None,
             # built-in one. The reference's analog is the CCL backend
             # for CPU runs (SURVEY §2.2). Must be set before backends
             # initialise.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception as e:   # older jax spelling
-                logger.warning(f"cpu collectives unavailable: {e}")
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         logger.info(f"jax.distributed.initialize({kwargs})")
         jax.distributed.initialize(**kwargs)
-    else:
-        # Cloud TPU pod slices auto-discover through the metadata server;
-        # initialize() is then arg-free. Probe the env FIRST — touching
-        # jax.process_count() would initialise the backend and make
-        # jax.distributed.initialize() impossible.
-        if _looks_like_pod():
-            try:
-                jax.distributed.initialize()
-            except Exception as e:  # already initialised or not a pod
-                logger.warning(f"jax.distributed.initialize() skipped: {e}")
     _initialized = True
 
 
@@ -148,11 +138,6 @@ def _platform_is_cpu():
     platforms = cfg or os.environ.get("JAX_PLATFORMS", "")
     first = platforms.split(",")[0].strip().lower()
     return first in ("", "cpu")
-
-
-def _looks_like_pod():
-    return any(k in os.environ for k in
-               ("TPU_WORKER_HOSTNAMES", "MEGASCALE_COORDINATOR_ADDRESS"))
 
 
 def is_initialized():

@@ -29,7 +29,8 @@ def collect_report():
         "process_count": plat.process_count(),
         "supports_pallas": plat.supports_pallas(),
         "supports_host_offload": plat.supports_host_offload(),
-        "peak_bf16_tflops": plat.peak_tflops("bfloat16"),
+        "peak_bf16_tflops": None if plat.name == "cpu"
+        else plat.peak_tflops("bfloat16"),
         "op_table": ops_pkg.op_report(),
     }
     return report

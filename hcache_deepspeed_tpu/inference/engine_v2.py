@@ -24,6 +24,7 @@ import numpy as np
 from ..platform import get_platform
 from ..resilience.faults import get_injector
 from ..telemetry.tracer import get_tracer
+from ..utils.compile_cache import ensure_compile_cache
 from ..utils.logging import log_dist
 from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
@@ -112,6 +113,7 @@ class InferenceEngineV2:
         tensor-parallel serving — sharded heads/KV blocks, per-layer
         allreduce (reference: TP sharding throughout the v2 model
         implementations, llama_v2/model.py:160,169)."""
+        ensure_compile_cache()
         self.config = config or RaggedInferenceEngineConfig()
         self.topology = topology
         sm_cfg = self.config.state_manager
@@ -192,6 +194,7 @@ class InferenceEngineV2:
             restore_chunk_bytes=self.config.hcache.restore_chunk_bytes,
             latent_dtype=self.config.hcache.latent_dtype,
             topology=topology, quantization=self.config.quantization)
+        self._check_paged_attention_fits(model_config)
         self.cache = BlockedKVCache(
             model_config.n_layer, num_blocks, self.block_size,
             model_config.n_kv_head, model_config.head_dim,
@@ -211,6 +214,23 @@ class InferenceEngineV2:
                  f"{self.block_size} tokens, max_context="
                  f"{self.max_context}", ranks=[0])
 
+    def _check_paged_attention_fits(self, model_config):
+        """Refuse at construction a head layout / block size the paged
+        kernel cannot tile (``PagedAttentionBudgetError`` carries the
+        rows, bytes and limit) — otherwise the first dispatch dies
+        inside the Mosaic compiler. Query rows are tiled, so the
+        dispatch length (``max_ragged_batch_size``/``prefill_chunk``)
+        does not enter; only where the kernel is what will run."""
+        from ..ops import get_op_impl
+        from ..ops.paged_attention import pick_tiles
+        if not get_op_impl("paged_attention").compatible():
+            return
+        kv_local = model_config.n_kv_head // self.model.tp
+        rows = self.config.state_manager.max_ragged_batch_size * \
+            (model_config.n_head // model_config.n_kv_head)
+        pick_tiles(kv_local, rows, model_config.head_dim, self.block_size,
+                   jnp.dtype(model_config.compute_dtype).itemsize)
+
     @staticmethod
     def _size_cache_blocks(model_config, kv_cfg) -> int:
         """'reserve' allocation mode: size the pool from free device memory
@@ -219,9 +239,15 @@ class InferenceEngineV2:
         per_token = BlockedKVCache.token_bytes(
             model_config.n_layer, model_config.n_kv_head,
             model_config.head_dim, kv_cfg.cache_dtype)
-        free = get_platform().available_memory()
-        if free <= 0:          # unknown limit (e.g. CPU test platform)
-            free = 1 << 30
+        platform = get_platform()
+        free = platform.available_memory()
+        if free <= 0:
+            if platform.name != "cpu":
+                raise RuntimeError(
+                    f"cannot size the KV pool: the {platform.name} backend "
+                    f"reports no free device memory "
+                    f"({platform.memory_stats()}); set kv_cache.num_blocks")
+            free = 1 << 30     # CPU test platform reports no limit
         blocks = int(free * kv_cfg.memory_fraction /
                      (per_token * kv_cfg.block_size))
         return max(blocks, 16)

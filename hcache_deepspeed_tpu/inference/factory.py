@@ -193,10 +193,11 @@ MODEL_FAMILIES = {
 
 def build_engine(model=None, config=None, *, model_config=None, params=None,
                  engine_config: Optional[RaggedInferenceEngineConfig] = None,
-                 **kw) -> InferenceEngineV2:
+                 topology=None) -> InferenceEngineV2:
     """``hcache_deepspeed_tpu.init_inference`` backend. Accepts either a
     ready ``(model_config, params)`` pair or an HF-style config dict via
-    ``model``."""
+    ``model``. ``topology``: a MeshTopology whose ``tensor`` axis shards
+    the engine (see :class:`InferenceEngineV2`)."""
     if engine_config is None and isinstance(config, dict):
         engine_config = RaggedInferenceEngineConfig(**config)
     if model_config is None:
@@ -223,10 +224,10 @@ def build_engine(model=None, config=None, *, model_config=None, params=None,
         raise ValueError("build_engine requires params (a trained "
                          "LlamaForCausalLM param tree)")
     return InferenceEngineV2(model_config, params,
-                             config=engine_config)
+                             config=engine_config, topology=topology)
 
 
 def build_hf_engine(hf_config: Dict[str, Any], params,
-                    engine_config=None) -> InferenceEngineV2:
+                    engine_config=None, topology=None) -> InferenceEngineV2:
     return build_engine(model=hf_config, params=params,
-                        engine_config=engine_config)
+                        engine_config=engine_config, topology=topology)

@@ -423,13 +423,15 @@ class PagedInferenceModel:
         cache_spec = P(None, TENSOR_AXIS, None, None)  # [L, KV, P, D]
         rep = P()
 
+        # every mesh axis manual (no ``axis_names``): Mosaic refuses a
+        # kernel while any axis, even of size one, is left automatic
         fwd_m = jax.shard_map(
-            fwd, mesh=mesh, axis_names={TENSOR_AXIS},
+            fwd, mesh=mesh,
             in_specs=(pspecs, cache_spec, cache_spec, rep, rep, rep, rep),
             out_specs=(cache_spec, cache_spec, rep, rep),
             check_vma=False)
         restore_m = jax.shard_map(
-            restore, mesh=mesh, axis_names={TENSOR_AXIS},
+            restore, mesh=mesh,
             in_specs=(pspecs, cache_spec, cache_spec, rep, rep, rep, rep,
                       rep),
             out_specs=(cache_spec, cache_spec),
@@ -678,7 +680,6 @@ class PagedInferenceModel:
                 rep = P()
                 fwd_tail = jax.shard_map(
                     fwd_tail, mesh=self.topology.mesh,
-                    axis_names={TENSOR_AXIS},
                     in_specs=(self._param_spec_tree(), cache_spec,
                               cache_spec, rep, rep, rep, rep),
                     out_specs=(cache_spec, cache_spec, rep),
@@ -713,7 +714,6 @@ class PagedInferenceModel:
                 rep = P()
                 fwd_tail = jax.shard_map(
                     fwd_tail, mesh=self.topology.mesh,
-                    axis_names={TENSOR_AXIS},
                     in_specs=(self._param_spec_tree(), cache_spec,
                               cache_spec, rep, rep, rep, rep),
                     out_specs=(cache_spec, cache_spec, rep, rep),
@@ -921,7 +921,6 @@ class PagedInferenceModel:
                 rep = P()
                 fwd_tail = jax.shard_map(
                     fwd_tail, mesh=self.topology.mesh,
-                    axis_names={TENSOR_AXIS},
                     in_specs=(self._param_spec_tree(), cache_spec,
                               cache_spec, rep, rep, rep, rep),
                     out_specs=(cache_spec, cache_spec, rep),

@@ -11,6 +11,13 @@ the analog of the reference's torch fallbacks) and may have a Pallas
 implementation used when the platform supports it. ``get_op(name)`` returns
 the best available callable; ``HDS_DISABLE_PALLAS=1`` forces references
 (the analog of ``DS_BUILD_OPS=0``).
+
+A dispatcher that gives way to its reference on a platform that HAS the
+kernel (a shape the tiles cannot cover, ``HDS_DISABLE_PALLAS``) calls
+:func:`note_fallback` first: the substitution is counted per
+``(op, reason)``, warned once per pair, and read back through
+:func:`fallback_report` — a measurement that ran the reference must be
+able to say so.
 """
 
 import os
@@ -18,6 +25,37 @@ import os
 from ..utils.logging import logger
 
 _REGISTRY = {}
+#: (op, reason) -> times a dispatcher returned the reference instead of
+#: the kernel. Dispatchers run while JAX traces, so this counts traced
+#: call sites, not executions of a compiled program.
+_FALLBACKS = {}
+
+
+def note_fallback(op, reason, detail=""):
+    """Record that ``op`` is about to return its jnp reference for
+    ``reason``; warns the first time each ``(op, reason)`` is seen."""
+    key = (op, reason)
+    seen = _FALLBACKS.get(key, 0)
+    _FALLBACKS[key] = seen + 1
+    if not seen:
+        logger.warning(
+            "%s: running the jnp reference instead of the Pallas kernel "
+            "(%s%s). Counted in ops.fallback_report(); further "
+            "occurrences of this pair are not logged.", op, reason,
+            f"; {detail}" if detail else "")
+
+
+def fallback_report():
+    """``{op: {reason: count}}`` of reference substitutions so far;
+    empty when every dispatched op ran its kernel."""
+    out = {}
+    for (op, reason), n in sorted(_FALLBACKS.items()):
+        out.setdefault(op, {})[reason] = n
+    return out
+
+
+def reset_fallback_report():
+    _FALLBACKS.clear()
 
 
 class OpImpl:
@@ -27,20 +65,27 @@ class OpImpl:
         self.pallas_fn = pallas_fn
         self._is_compatible = is_compatible
 
-    def compatible(self):
-        """Can the pallas path run natively here? (reference:
-        OpBuilder.is_compatible)"""
+    def _native(self):
+        """Does the platform have a Pallas path for this op at all?"""
         if self.pallas_fn is None:
-            return False
-        if os.environ.get("HDS_DISABLE_PALLAS") == "1":
             return False
         if self._is_compatible is not None and not self._is_compatible():
             return False
         from ..platform import get_platform
         return get_platform().supports_pallas()
 
+    def compatible(self):
+        """Can the pallas path run natively here? (reference:
+        OpBuilder.is_compatible)"""
+        return self._native() and \
+            os.environ.get("HDS_DISABLE_PALLAS") != "1"
+
     def best(self):
-        return self.pallas_fn if self.compatible() else self.reference_fn
+        if self.compatible():
+            return self.pallas_fn
+        if self._native():
+            note_fallback(self.name, "HDS_DISABLE_PALLAS=1")
+        return self.reference_fn
 
 
 def register_op(name, reference_fn, pallas_fn=None, is_compatible=None):
@@ -86,4 +131,5 @@ def _ensure_loaded():
                    rms_norm, rope)
 
 
-__all__ = ["register_op", "get_op", "get_op_impl", "op_report"]
+__all__ = ["register_op", "get_op", "get_op_impl", "op_report",
+           "note_fallback", "fallback_report", "reset_fallback_report"]

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import register_op
+from . import note_fallback, register_op
+from .partitioning import BATCH, HEADS, per_shard
 
 _NEG_INF = -1e30
 
@@ -316,20 +317,39 @@ def _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g):
 # ------------------------------------------------------------------ #
 # custom_vjp wrapper
 # ------------------------------------------------------------------ #
+# batch elements and heads are independent: q/k/v/out [B, T, H, D] and
+# lse [B, H, T, 1] keep their batch split and (query and KV heads
+# together, GQA groups staying whole) their head split; T and D are
+# whole in every shard
+_BTHD = (BATCH, None, HEADS, None)
+_BHT1 = (BATCH, HEADS, None, None)
+
+
+def _fwd_placed(q, k, v, scale, causal, block_q, block_k, interpret):
+    return per_shard(
+        lambda q, k, v: _fwd_pallas(q, k, v, scale, causal, block_q,
+                                    block_k, interpret),
+        (q, k, v), in_roles=(_BTHD,) * 3, out_roles=(_BTHD, _BHT1))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret)
+    out, _ = _fwd_placed(q, k, v, scale, causal, block_q, block_k, interpret)
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out_bhtd, lse = _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
+    out_bhtd, lse = _fwd_placed(q, k, v, scale, causal, block_q, block_k,
                                 interpret)
     return out_bhtd, (q, k, v, out_bhtd, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
-    return _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g)
+    return per_shard(
+        lambda *a: _bwd_pallas(scale, causal, block_q, block_k, interpret,
+                               a[:5], a[5]),
+        (*res, g), in_roles=(_BTHD,) * 4 + (_BHT1, _BTHD),
+        out_roles=(_BTHD,) * 3)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -346,16 +366,21 @@ def pallas_attention(q, k, v, causal=True, scale=None, block_q=512,
         from ..platform import get_platform
         interpret = not get_platform().supports_pallas()
     block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
+    reason = None
     if block_q < 128 or block_k < 128 or T % block_q or T % block_k:
-        return reference_attention(q, k, v, causal=causal, scale=scale)
-    if not interpret and (block_q % 8 or block_k % 128):
+        reason = "seq_not_block_multiple"
+    elif not interpret and (block_q % 8 or block_k % 128):
         # Mosaic tiling: the s=[block_q, block_k] tile needs a (8,128)-
-        # aligned layout on real hardware; unaligned shapes fall back
-        return reference_attention(q, k, v, causal=causal, scale=scale)
-    if not interpret and D % 128 and D != 64:
+        # aligned layout on real hardware
+        reason = "tile_misaligned"
+    elif not interpret and D % 128 and D != 64:
         # lane (last-dim) tiling: D must be 128-aligned (64 is the one
         # sublane-packable exception Mosaic handles well); e.g. D=96
         # crashes the compiler
+        reason = "head_dim_unsupported"
+    if reason:
+        note_fallback("flash_attention", reason,
+                      f"T={T} D={D} block_q={block_q} block_k={block_k}")
         return reference_attention(q, k, v, causal=causal, scale=scale)
     return _flash(q, k, v, scale, causal, block_q, block_k, interpret)
 

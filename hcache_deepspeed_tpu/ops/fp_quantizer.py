@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from . import register_op
+from . import note_fallback, register_op
+from .partitioning import per_shard
 from .quantizer import _pack_groups, reference_dequantize
 
 _FP8_MAX = {"e4m3": 448.0, "e5m2": 57344.0}
@@ -64,8 +65,10 @@ def pallas_quantize_fp8(x, group_size=2048, fmt="e4m3", interpret=None,
     G = groups.shape[0]
     block_groups = min(block_groups, G)
     if G % block_groups:
+        note_fallback("quantize_fp8", "groups_not_block_multiple",
+                      f"groups={G} block_groups={block_groups}")
         return reference_quantize_fp8(x, group_size, fmt)
-    q, scale = pl.pallas_call(
+    q, scale = per_shard(pl.pallas_call(
         functools.partial(_fp8_kernel, fmt=fmt),
         grid=(G // block_groups,),
         in_specs=[pl.BlockSpec((block_groups, group_size),
@@ -79,7 +82,7 @@ def pallas_quantize_fp8(x, group_size=2048, fmt="e4m3", interpret=None,
             jax.ShapeDtypeStruct((G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(groups)
+    ), (groups,))
     return q, scale, x.shape, n
 
 

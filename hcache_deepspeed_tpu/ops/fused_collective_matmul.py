@@ -35,9 +35,7 @@ Three execution tiers, one contract:
    in-kernel form: double-buffered VMEM chunk slots, per-step
    ``make_async_remote_copy`` to the ring neighbor overlapping the
    MXU dots on the resident slot. Shapes the kernel cannot tile fall
-   back to the reference twin, recorded in
-   :func:`fused_fallback_debug_info` and warned once (the
-   ``quantized_matmul`` fallback convention).
+   back to the reference twin, recorded in ``ops.fallback_report()``.
 
 Every in-kernel permute step attributes its bytes through the comms
 logger with ``op_kind="fused_permute"`` (never ``collective_permute``
@@ -56,7 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import register_op
+from . import note_fallback, register_op
 from .quantized_matmul import quantized_matmul, reference_quantized_matmul
 
 #: comms-logger op names of the fused wires (matched ``fused_*`` rows)
@@ -175,37 +173,10 @@ def streamed_fused_gather_matmul(x, q_shard, s_shard, group_k=256, *,
 # Pallas kernels
 # ------------------------------------------------------------------ #
 
-#: fallback observability, same convention as
-#: ``quantized_matmul._FALLBACK_DEBUG``: a perf run that thinks it
-#: measured the fused kernel but ran the gather-then-matmul twin
-#: reports numbers for the wrong code. Warn once, count always.
-_FUSED_FALLBACK = {"count": 0, "by_reason": {}, "last": None,
-                   "warned": False}
-
-
-def fused_fallback_debug_info():
-    """Copy of the fused-kernel fallback record:
-    ``{count, by_reason: {reason: n}, last: (reason, M, K_sh, N)}``."""
-    out = dict(_FUSED_FALLBACK)
-    out["by_reason"] = dict(out["by_reason"])
-    return out
-
-
 def _fused_fallback(reason, x, q_shard, s_shard, group_k, **kw):
-    d = _FUSED_FALLBACK
-    d["count"] += 1
-    d["by_reason"][reason] = d["by_reason"].get(reason, 0) + 1
-    d["last"] = (reason, x.shape[0], q_shard.shape[0], q_shard.shape[1])
-    if not d["warned"]:
-        d["warned"] = True
-        from ..utils.logging import logger
-        logger.warning(
-            "fused_gather_matmul: falling back to the reference "
-            "gather-then-matmul twin (%s; M=%d K_sh=%d N=%d). "
-            "Subsequent fallbacks are silent — check "
-            "fused_fallback_debug_info() before trusting a perf "
-            "number.", reason, x.shape[0], q_shard.shape[0],
-            q_shard.shape[1])
+    note_fallback("fused_gather_matmul", reason,
+                  f"M={x.shape[0]} K_sh={q_shard.shape[0]} "
+                  f"N={q_shard.shape[1]}")
     return reference_fused_gather_matmul(x, q_shard, s_shard, group_k,
                                          **kw)
 
@@ -341,7 +312,7 @@ def pallas_fused_gather_matmul(x, q_shard, s_shard, group_k=256, *,
     whose members each hold one K-dim shard). Tiling guards mirror
     ``pallas_quantized_matmul``: shapes the whole-shard blocking cannot
     cover fall back to the reference twin (bitwise-safe), recorded in
-    :func:`fused_fallback_debug_info`."""
+    ``ops.fallback_report()``."""
     kw = dict(axis_name=axis_name, shard_dim=shard_dim,
               axis_index_groups=axis_index_groups)
     if interpret is None:

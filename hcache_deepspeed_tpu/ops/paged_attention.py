@@ -37,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import register_op
+from . import note_fallback, register_op
 
 _NEG_INF = -1e30
 
@@ -75,12 +75,12 @@ def reference_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
 # Pallas kernel
 # ------------------------------------------------------------------ #
 def _kernel(tables_ref, kvlen_ref, start_ref,    # scalar prefetch
-            q_ref, k_ref, v_ref,                 # [1,KVT,TGp,D], [KVT,1,BS,D]
-            o_ref,                               # [1,KVT,TGp,D]
+            q_ref, k_ref, v_ref,                 # [1,KVT,TQ,D], [KVT,1,BS,D]
+            o_ref,                               # [1,KVT,TQ,D]
             acc, m_s, l_s,                       # VMEM scratch
-            *, scale, G, BS, TGp, KVT):
-    b, nb = pl.program_id(0), pl.program_id(2)
-    nblocks = pl.num_programs(2)
+            *, scale, G, BS, TQ):
+    b, qt, nb = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    nblocks = pl.num_programs(3)
 
     @pl.when(nb == 0)
     def _init():
@@ -90,22 +90,25 @@ def _kernel(tables_ref, kvlen_ref, start_ref,    # scalar prefetch
 
     kvlen = kvlen_ref[b]
     start = start_ref[b]
-    run = nb * BS < kvlen
+    # a cache block runs for this row tile when it holds valid tokens
+    # at or before the tile's last query position (causal frontier)
+    last_pos = start + (qt * TQ + TQ - 1) // G
+    run = (nb * BS < kvlen) & (nb * BS <= last_pos)
 
     @pl.when(run)
     def _body():
         # KVT kv heads per grid step: one batched MXU call and one
         # [KVT*BS, D]-sized DMA instead of KVT tiny steps — the grid
         # count (not FLOPs) is what dominates decode-shape cost
-        q = q_ref[0]                                         # [KVT,TGp,D]
+        q = q_ref[0]                                         # [KVT,TQ,D]
         k = k_ref[:, 0].astype(q.dtype)                      # [KVT,BS,D]
         # matmuls stay in the input dtype (bf16 MXU rate) with fp32
         # accumulation — an fp32 upcast here runs at ~1/8 peak
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale      # [KVT,TGp,BS]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (TGp, BS), 0)
-        cols = nb * BS + jax.lax.broadcasted_iota(jnp.int32, (TGp, BS), 1)
+            preferred_element_type=jnp.float32) * scale      # [KVT,TQ,BS]
+        rows = qt * TQ + jax.lax.broadcasted_iota(jnp.int32, (TQ, BS), 0)
+        cols = nb * BS + jax.lax.broadcasted_iota(jnp.int32, (TQ, BS), 1)
         row_pos = start + rows // G
         ok = (cols <= row_pos) & (cols < kvlen)
         s = jnp.where(ok[None], s, _NEG_INF)
@@ -128,16 +131,51 @@ def _kernel(tables_ref, kvlen_ref, start_ref,    # scalar prefetch
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
 
 
-def _pick_head_tile(KV, TGp, D, BS, itemsize, budget=6 * 2**20):
-    """Largest divisor of KV whose per-step VMEM footprint (q/o tiles,
-    double-buffered k/v tiles, fp32 scratch) stays under ``budget``."""
-    per_head = (2 * TGp * D * itemsize          # q + o
-                + 2 * 2 * BS * D * itemsize     # k, v double-buffered
-                + TGp * D * 4                   # acc
-                + 2 * TGp * 128 * 4)            # m, l
-    cap = max(budget // per_head, 1)
-    return max(kvt for kvt in range(1, KV + 1)
-               if KV % kvt == 0 and kvt <= cap)
+class PagedAttentionBudgetError(ValueError):
+    """No (row tile, head tile) of the paged kernel fits its VMEM
+    budget for this cache layout."""
+
+
+#: query rows (tokens x group) per grid step: a prefill dispatch of any
+#: length is walked in row tiles of at most this many, so the kernel's
+#: VMEM footprint does not grow with the dispatch
+_MAX_ROW_TILE = 512
+#: per-step VMEM the tiles may claim. The v5e compiler's scoped limit is
+#: 16 MiB; the rest is left for Mosaic's own temporaries.
+_VMEM_BUDGET = 10 * 2**20
+
+
+def _step_bytes(rows, D, BS, itemsize):
+    """VMEM bytes one kv head claims in one grid step at ``rows`` query
+    rows: q/o and k/v blocks (double-buffered by the pipeline), the
+    fp32 accumulator and m/l scratch, and the fp32 score/prob tiles."""
+    return (2 * 2 * rows * D * itemsize       # q + o
+            + 2 * 2 * BS * D * itemsize       # k + v
+            + rows * D * 4                    # acc
+            + 2 * rows * 128 * 4              # m, l
+            + 2 * rows * BS * 4)              # s, p
+
+
+def pick_tiles(KV, TG, D, BS, itemsize):
+    """``(row tile, padded rows, head tile)`` for ``TG`` query rows per
+    kv head: rows are tiled at ``_MAX_ROW_TILE`` (8-aligned), then the
+    largest divisor of ``KV`` that keeps the step under ``_VMEM_BUDGET``.
+    Raises :class:`PagedAttentionBudgetError` when one head at the row
+    tile is already over it (block_size x head_dim too large)."""
+    TQ = min(-(-TG // 8) * 8, _MAX_ROW_TILE)     # Mosaic sublane alignment
+    TGp = -(-TG // TQ) * TQ
+    per_head = _step_bytes(TQ, D, BS, itemsize)
+    if per_head > _VMEM_BUDGET:
+        raise PagedAttentionBudgetError(
+            f"paged attention cannot tile this cache layout: one kv head "
+            f"at {TQ} query rows, block_size={BS}, head_dim={D} needs "
+            f"{per_head} bytes of VMEM per grid step, over the "
+            f"{_VMEM_BUDGET}-byte budget; use a smaller "
+            f"kv_cache.block_size")
+    cap = _VMEM_BUDGET // per_head
+    KVT = max(kvt for kvt in range(1, KV + 1)
+              if KV % kvt == 0 and kvt <= cap)
+    return TQ, TGp, KVT
 
 
 def pallas_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
@@ -156,11 +194,10 @@ def pallas_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
     qg = q.reshape(B, T, KV, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, T * G, D)
     TG = T * G
-    TGp = max(8, -(-TG // 8) * 8)  # Mosaic sublane alignment
+    TQ, TGp, KVT = pick_tiles(KV, TG, D, BS, q.dtype.itemsize)
     if TGp != TG:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, TGp - TG), (0, 0)))
-
-    KVT = head_tile or _pick_head_tile(KV, TGp, D, BS, q.dtype.itemsize)
+    KVT = head_tile or KVT
     if KV % KVT:
         # a non-divisor tile would floor-divide the grid and silently
         # leave the uncovered heads' output blocks unwritten
@@ -172,31 +209,32 @@ def pallas_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
     kv_len = jnp.asarray(kv_len, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
 
-    def page_index(b, kh, nb, tables_ref, kvlen_ref, start_ref):
+    def page_index(b, kh, qt, nb, tables_ref, kvlen_ref, start_ref):
         # clamp out-of-range slots to the last valid block: repeated block
         # index ⇒ Pallas skips the DMA, so dead slots cost nothing
         last = jnp.maximum(kvlen_ref[b] - 1, 0) // BS
         return (kh, tables_ref[b, jnp.minimum(nb, last)], 0, 0)
 
+    def row_index(b, kh, qt, nb, *refs):
+        return (b, kh, qt, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV // KVT, NB),
+        grid=(B, KV // KVT, TGp // TQ, NB),
         in_specs=[
-            pl.BlockSpec((1, KVT, TGp, D),
-                         lambda b, kh, nb, *refs: (b, kh, 0, 0)),
+            pl.BlockSpec((1, KVT, TQ, D), row_index),
             pl.BlockSpec((KVT, 1, BS, D), page_index),
             pl.BlockSpec((KVT, 1, BS, D), page_index),
         ],
-        out_specs=pl.BlockSpec((1, KVT, TGp, D),
-                               lambda b, kh, nb, *refs: (b, kh, 0, 0)),
+        out_specs=pl.BlockSpec((1, KVT, TQ, D), row_index),
         scratch_shapes=[
-            pltpu.VMEM((KVT, TGp, D), jnp.float32),
-            pltpu.VMEM((KVT, TGp, 128), jnp.float32),
-            pltpu.VMEM((KVT, TGp, 128), jnp.float32),
+            pltpu.VMEM((KVT, TQ, D), jnp.float32),
+            pltpu.VMEM((KVT, TQ, 128), jnp.float32),
+            pltpu.VMEM((KVT, TQ, 128), jnp.float32),
         ],
     )
     kern = functools.partial(_kernel, scale=1.0 / np.sqrt(D), G=G, BS=BS,
-                             TGp=TGp, KVT=KVT)
+                             TQ=TQ)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -216,6 +254,8 @@ def _dispatch_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
             f"query heads ({Hq}) must be a multiple of kv heads ({KV})")
     # alignment guards: the kernel needs whole, sublane-aligned blocks
     if k_pool.shape[1] % block_size or block_size % 8:
+        note_fallback("paged_attention", "block_misaligned",
+                      f"pool={k_pool.shape[1]} block_size={block_size}")
         return reference_paged_attention(q, k_pool, v_pool, tables, start,
                                          kv_len, block_size)
     return pallas_paged_attention(q, k_pool, v_pool, tables, start, kv_len,

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import register_op
+from . import note_fallback, register_op
+from .partitioning import per_shard
 
 
 def quantize_for_matmul(w, group_k=256, num_bits=8):
@@ -220,37 +221,10 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc, *, group_k, gpb,
         o_ref[0] = acc[:].astype(o_ref.dtype)
 
 
-#: observability for the silent-until-now reference-path fallbacks: a
-#: perf run that thinks it measured the Pallas kernel but actually ran
-#: the dequantize-then-matmul reference path reports numbers for the
-#: wrong code. Counters per fallback reason + the last shape, exposed
-#: via :func:`fallback_debug_info`; the first fallback also warns.
-_FALLBACK_DEBUG = {"count": 0, "by_reason": {}, "last": None,
-                   "warned": False}
-
-
-def fallback_debug_info():
-    """Copy of the reference-path fallback record:
-    ``{count, by_reason: {reason: n}, last: (reason, M, K, N, block)}``."""
-    out = dict(_FALLBACK_DEBUG)
-    out["by_reason"] = dict(out["by_reason"])
-    return out
-
-
 def _reference_fallback(reason, x, q, scale, group_k, block=None):
-    d = _FALLBACK_DEBUG
-    d["count"] += 1
-    d["by_reason"][reason] = d["by_reason"].get(reason, 0) + 1
-    d["last"] = (reason, x.shape[0], x.shape[1], q.shape[1], block)
-    if not d["warned"]:
-        d["warned"] = True
-        from ..utils.logging import logger
-        logger.warning(
-            "quantized_matmul: falling back to the reference "
-            "dequantize-then-matmul path (%s; M=%d K=%d N=%d "
-            "block=%s). Subsequent fallbacks are silent — check "
-            "fallback_debug_info() before trusting a perf number.",
-            reason, x.shape[0], x.shape[1], q.shape[1], block)
+    note_fallback("quantized_matmul", reason,
+                  f"M={x.shape[0]} K={x.shape[1]} N={q.shape[1]} "
+                  f"block={block}")
     return reference_quantized_matmul(x, q, scale, group_k=group_k)
 
 
@@ -263,7 +237,7 @@ def pallas_quantized_matmul(x, q, scale, group_k=256, block_m=None,
     override (tests exercise fixed blockings). ``block_k`` must be a
     whole number of scale groups. Shapes the tiles cannot cover fall
     back to the reference path — recorded in
-    :func:`fallback_debug_info` and warned once."""
+    ``ops.fallback_report()``."""
     M, K = x.shape
     K2, N = q.shape
     assert K == K2
@@ -310,7 +284,7 @@ def pallas_quantized_matmul(x, q, scale, group_k=256, block_m=None,
                               lambda mi, ni, ki: (0, 0, ni))
     kern = functools.partial(_qmm_kernel, group_k=group_k, gpb=gpb,
                              sliced_scale=sliced_scale)
-    return pl.pallas_call(
+    return per_shard(pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -325,7 +299,7 @@ def pallas_quantized_matmul(x, q, scale, group_k=256, block_m=None,
         out_shape=jax.ShapeDtypeStruct((1, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x[None], q[None], scale[None])[0]
+    ), (x[None], q[None], scale[None]))[0]
 
 
 def quantized_matmul(x, q, scale, group_k=256):

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import register_op
+from . import note_fallback, register_op
+from .partitioning import per_shard
 
 
 def _pack_groups(x, group_size):
@@ -61,8 +62,10 @@ def pallas_quantize(x, group_size=256, num_bits=8, interpret=None,
     G = groups.shape[0]
     block_groups = min(block_groups, G)
     if G % block_groups:
+        note_fallback("quantize", "groups_not_block_multiple",
+                      f"groups={G} block_groups={block_groups}")
         return reference_quantize(x, group_size, num_bits)
-    q, scale = pl.pallas_call(
+    q, scale = per_shard(pl.pallas_call(
         functools.partial(_quant_kernel, qmax=qmax),
         grid=(G // block_groups,),
         in_specs=[pl.BlockSpec((block_groups, group_size), lambda i: (i, 0))],
@@ -75,7 +78,7 @@ def pallas_quantize(x, group_size=256, num_bits=8, interpret=None,
             jax.ShapeDtypeStruct((G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(groups)
+    ), (groups,))
     return q, scale, x.shape, n
 
 

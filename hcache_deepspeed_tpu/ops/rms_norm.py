@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import register_op
+from . import note_fallback, register_op
+from .partitioning import BATCH, SEQ, per_shard
 
 
 def reference_rms_norm(x, weight, eps=1e-6):
@@ -30,13 +31,15 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
                 w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _rms_fwd_pallas(x, weight, eps, interpret, block_rows=256):
+def _rms_fwd_pallas(x, weight, eps, interpret):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
-    block_rows = min(block_rows, n)
+    block_rows = min(256, n)
     if n % block_rows:
+        note_fallback("rms_norm", "rows_not_block_multiple",
+                      f"rows={n} block_rows={block_rows}")
         return reference_rms_norm(x, weight, eps)
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
@@ -52,13 +55,23 @@ def _rms_fwd_pallas(x, weight, eps, interpret, block_rows=256):
     return out.reshape(orig_shape)
 
 
+def _rms_fwd_placed(x, weight, eps, interpret):
+    # rows are independent: [batch, seq, d] activations keep their
+    # batch/sequence split, the feature dim and the scale are whole
+    rows = (BATCH, SEQ, None) if x.ndim == 3 else \
+        (BATCH,) + (None,) * (x.ndim - 1)
+    return per_shard(
+        lambda x, w: _rms_fwd_pallas(x, w, eps, interpret), (x, weight),
+        in_roles=(rows, (None,)), out_roles=rows)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _rms(x, weight, eps, interpret):
-    return _rms_fwd_pallas(x, weight, eps, interpret)
+    return _rms_fwd_placed(x, weight, eps, interpret)
 
 
 def _rms_fwd(x, weight, eps, interpret):
-    return _rms_fwd_pallas(x, weight, eps, interpret), (x, weight)
+    return _rms_fwd_placed(x, weight, eps, interpret), (x, weight)
 
 
 def _rms_bwd(eps, interpret, res, g):

@@ -1,12 +1,12 @@
 """Performance observatory: artifact registry + regression sentinel.
 
 The repo root's committed perf evidence (bench JSON, phase-stream
-JSONLs, chip logs) becomes machine-readable here:
+JSONLs) becomes machine-readable here:
 
 * :mod:`.schemas` — one declared family + parser per artifact kind;
 * :mod:`.registry` — walks/classifies/indexes into the committed
-  ``PERF_TRAJECTORY.json`` (per-metric series with producer-PR,
-  phase, and freshness tags) and lints source for artifact names
+  ``PERF_TRAJECTORY.json`` (per-metric series with producer-PR and
+  phase tags) and lints source for artifact names
   without a schema;
 * :mod:`.check` — the regression gate (`perf check`): fresh points vs
   the committed headline values, with per-metric tolerances, plus the
@@ -18,8 +18,7 @@ See ``docs/observability.md``.
 
 from .check import (TOLERANCES, Tolerance, Verdict,  # noqa: F401
                     check_artifact, check_headline, check_points,
-                    freshness_alarm, regressions, self_check_rows,
-                    self_test)
+                    regressions, self_check_rows, self_test)
 from .registry import (INDEX_NAME, build_index, lint_sources,  # noqa: F401
                        load_allowlist, load_index, repo_root,
                        write_index)
@@ -32,5 +31,5 @@ __all__ = [
     "write_index", "load_index", "load_allowlist", "lint_sources",
     "repo_root", "TOLERANCES", "Tolerance", "Verdict", "check_points",
     "check_artifact", "check_headline", "regressions",
-    "self_check_rows", "self_test", "freshness_alarm",
+    "self_check_rows", "self_test",
 ]

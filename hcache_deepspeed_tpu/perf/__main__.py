@@ -12,12 +12,9 @@ Subcommands:
   regressions (tier-1 runs this; exit 6 on failure).
 * ``lint [--root DIR]`` — fail (exit 7) if any source file writes an
   artifact-style filename the registry has no schema for.
-* ``freshness [--max-age-days N]`` — print the wedged-relay gauge
-  (exit 0 always; the relay being down is not a code regression).
 """
 
 import argparse
-import json
 import os
 import sys
 
@@ -30,17 +27,12 @@ def _cmd_index(args) -> int:
     print(f"indexed {len(index['artifacts'])} artifacts -> "
           f"{len(index['series'])} series / {n_pts} points; "
           f"unindexed={index['unindexed']}")
-    fresh = index["freshness"]
-    print(f"freshness: last chip measurement "
-          f"{fresh['last_chip_measurement_utc']} "
-          f"({fresh['staleness_days']} days old, "
-          f"stale={fresh['stale']})")
     return 0
 
 
 def _cmd_check(args) -> int:
-    from .check import (check_artifact, check_headline,
-                        freshness_alarm, regressions, self_test)
+    from .check import (check_artifact, check_headline, regressions,
+                        self_test)
     from .registry import build_index, load_index, repo_root
     if args.self_test:
         return 0 if self_test(verbose=True) else 6
@@ -77,9 +69,6 @@ def _cmd_check(args) -> int:
                 print(f"REGRESSION {v.metric}: {v.detail}")
             elif args.verbose:
                 print(f"{v.metric}: {v.status} ({v.new_value})")
-    alarm = freshness_alarm(baseline, args.max_age_days)
-    if alarm:
-        print(f"freshness: WARNING {alarm}")
     if failed:
         print("perf check: FAILED")
         return 5
@@ -99,17 +88,6 @@ def _cmd_lint(args) -> int:
     return 0
 
 
-def _cmd_freshness(args) -> int:
-    from .check import freshness_alarm
-    from .registry import load_index
-    index = load_index(path=args.against, root=args.root)
-    print(json.dumps(index["freshness"]))
-    alarm = freshness_alarm(index, args.max_age_days)
-    if alarm:
-        print(f"WARNING: {alarm}")
-    return 0
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         "python -m hcache_deepspeed_tpu.perf",
@@ -117,7 +95,7 @@ def main(argv=None) -> int:
     p.add_argument("--root", default=None,
                    help="repo root (default: auto-detect)")
     p.add_argument("--now", type=float, default=None,
-                   help="freshness reference time (UTC epoch "
+                   help="generated_utc reference time (UTC epoch "
                         "seconds); injects the ONE sanctioned wall-"
                         "clock default in registry.build_index, "
                         "making index/check runs reproducible")
@@ -137,7 +115,6 @@ def main(argv=None) -> int:
     pc.add_argument("--self-test", action="store_true",
                     help="prove the gate trips on synthetic "
                          "regressions (no repo state needed)")
-    pc.add_argument("--max-age-days", type=float, default=2.0)
     pc.add_argument("--verbose", action="store_true")
     pc.add_argument("files", nargs="*",
                     help="artifacts to gate (default: all indexable "
@@ -148,11 +125,6 @@ def main(argv=None) -> int:
                         help="no source-written artifact without a "
                              "schema")
     pl.set_defaults(fn=_cmd_lint)
-
-    pf = sub.add_parser("freshness", help="wedged-relay gauge")
-    pf.add_argument("--against", default=None)
-    pf.add_argument("--max-age-days", type=float, default=2.0)
-    pf.set_defaults(fn=_cmd_freshness)
 
     args = p.parse_args(argv)
     return args.fn(args)

@@ -1,18 +1,15 @@
 """Perf-artifact registry: walk, classify, index.
 
 Builds the committed ``PERF_TRAJECTORY.json`` — the machine-readable
-trajectory the repo root's ~50 perf artifacts previously only implied:
+trajectory the repo root's perf artifacts previously only implied:
 
-* every root ``*.json`` / ``*.jsonl`` (plus the chip/relay ``*.log``
-  files and ``.bench_last_measured.json``) is classified into a family
-  (``perf.schemas``) and parsed into metric points;
+* every root ``*.json`` / ``*.jsonl`` / ``*.log`` is classified into a
+  family (``perf.schemas``) and parsed into metric points;
 * points are grouped into per-metric **series** (tok/s/chip, MFU,
   overlap ratios, wire fraction, serve-loop TTFT/TPOT percentiles,
   chaos invariants, ...), each point tagged with its producing file,
-  bench phase, producer PR (first git commit that added the file, when
-  git is available) and **freshness** — age in days since the
-  measurement timestamp, reusing bench.py's dead-relay ``stale``
-  convention;
+  bench phase and producer PR (first git commit that added the file,
+  when git is available);
 * a **headline** block carries, per regression-gated metric
   (``perf.check.TOLERANCES``), the best committed value — the number
   ``perf check`` refuses to regress.
@@ -20,7 +17,8 @@ trajectory the repo root's ~50 perf artifacts previously only implied:
 The golden-schema tier-1 test re-walks the root and fails on any
 artifact the registry can't classify that is not allowlisted in
 ``perf/KNOWN_UNINDEXED`` (shipped empty — the allowlist is a debt
-ledger, not a dumping ground).
+ledger, not a dumping ground). The driver's own records
+(``PERF_LEDGER.jsonl``, ``BENCHMARK.json``) are not this registry's.
 """
 
 import json
@@ -30,15 +28,16 @@ import subprocess
 import time
 from typing import Dict, List, Optional
 
-from .schemas import (FAMILIES, ParsedArtifact, classify,
-                      parse_artifact, parse_utc, staleness_days)
+from .schemas import FAMILIES, ParsedArtifact, classify, parse_artifact
 
 INDEX_NAME = "PERF_TRAJECTORY.json"
 ALLOWLIST_NAME = "KNOWN_UNINDEXED"
 UTC_FMT = "%Y-%m-%dT%H:%M:%SZ"
 
-#: root files that are code/config/docs, never perf artifacts
-_NON_ARTIFACTS = {"pyproject.toml", INDEX_NAME}
+#: root files that are code/config/docs or the driver's own records,
+#: never this registry's artifacts
+_NON_ARTIFACTS = {"pyproject.toml", INDEX_NAME, "PERF_LEDGER.jsonl",
+                  "BENCHMARK.json"}
 
 
 def repo_root(start: Optional[str] = None) -> str:
@@ -81,17 +80,14 @@ def load_allowlist() -> Dict[str, str]:
 
 def iter_artifact_names(root: str) -> List[str]:
     """Committed root-level perf artifacts, sorted: every ``*.json`` /
-    ``*.jsonl`` plus chip/relay logs and the hidden last-measured
-    record."""
+    ``*.jsonl`` / ``*.log``."""
     names = []
     for name in sorted(os.listdir(root)):
         if name in _NON_ARTIFACTS:
             continue
         if not os.path.isfile(os.path.join(root, name)):
             continue
-        if name.endswith((".json", ".jsonl")) or \
-                (name.endswith(".log")) or \
-                name == ".bench_last_measured.json":
+        if name.endswith((".json", ".jsonl", ".log")):
             names.append(name)
     return names
 
@@ -121,9 +117,9 @@ def build_index(root: Optional[str] = None, now: Optional[float] = None,
     from .check import TOLERANCES
     root = root or repo_root()
     # the ONE sanctioned wall-clock site in the deterministic-given-
-    # (tree, now) index build: the freshness default when the CLI did
-    # not inject --now; every other consumer threads now= through
-    # hds: allow(HDS-P001) sanctioned freshness default, CLI --now injects
+    # (tree, now) index build: the generated_utc stamp when the CLI
+    # did not inject --now
+    # hds: allow(HDS-P001) sanctioned generated_utc default, CLI --now injects
     now = time.time() if now is None else now
     artifacts: List[Dict] = []
     series: Dict[str, List[Dict]] = {}
@@ -155,9 +151,6 @@ def build_index(root: Optional[str] = None, now: Optional[float] = None,
         artifacts.append(row)
         for p in parsed.points:
             rec = p.to_json()
-            age = staleness_days(p.utc, now)
-            if age is not None:
-                rec["staleness_days"] = round(age, 2)
             if with_git and "producer_pr" in row:
                 rec["producer_pr"] = row["producer_pr"]
             series.setdefault(p.metric, []).append(rec)
@@ -175,13 +168,11 @@ def build_index(root: Optional[str] = None, now: Optional[float] = None,
         headline[metric] = {
             "value": pick["value"], "file": pick["file"],
             "utc": pick.get("utc"),
-            "stale": bool(pick.get("stale")),
             "tags": pick.get("tags", {}),
             "direction": tol.direction,
             "rel_tolerance": tol.rel,
             "abs_tolerance": tol.abs,
         }
-    freshness = _freshness_block(series, now)
     return {
         "version": 1,
         "generated_utc": time.strftime(UTC_FMT, time.gmtime(now)),
@@ -189,32 +180,9 @@ def build_index(root: Optional[str] = None, now: Optional[float] = None,
         "artifacts": artifacts,
         "series": {k: series[k] for k in sorted(series)},
         "headline": headline,
-        "freshness": freshness,
         "unindexed": sorted(unindexed),
         "allowlisted": allow,
     }
-
-
-def _freshness_block(series: Dict, now: float) -> Dict:
-    """The wedged-relay condition as a queryable gauge (ROADMAP item
-    5): age of the last real chip measurement, from the chip-truth
-    series' timestamps."""
-    best_utc = None
-    for metric in ("chip.last_tokens_per_sec",
-                   "train.tokens_per_sec_per_chip"):
-        for rec in series.get(metric, []):
-            u = rec.get("utc")
-            if u and (best_utc is None or
-                      (parse_utc(u) or 0) > (parse_utc(best_utc) or 0)):
-                best_utc = u
-    out = {"last_chip_measurement_utc": best_utc}
-    age = staleness_days(best_utc, now)
-    out["staleness_days"] = round(age, 2) if age is not None else None
-    # the bench dead-relay convention: stale once a round reports with
-    # no fresh measurement; numerically: any positive age counts, 2+
-    # days is the wedged-relay alarm threshold used in ROADMAP item 5
-    out["stale"] = bool(age is not None and age > 1.0)
-    return out
 
 
 def write_index(path: Optional[str] = None, root: Optional[str] = None,
@@ -268,7 +236,7 @@ def lint_sources(root: Optional[str] = None) -> List[str]:
             continue
         for m in _ARTIFACT_LITERAL_RE.finditer(text):
             name = m.group(1)
-            if classify(name) is None:
+            if name not in _NON_ARTIFACTS and classify(name) is None:
                 line = text.count("\n", 0, m.start()) + 1
                 violations.append(
                     f"{os.path.relpath(src, root)}:{line}: artifact "

@@ -8,7 +8,7 @@ the PJRT plugin system already did the probing.
 
 import os
 
-from .abstract import Platform
+from .abstract import Platform, UnknownPeakError
 from .tpu import CPUPlatform, TPUPlatform
 
 _PLATFORMS = {
@@ -31,10 +31,35 @@ def get_platform() -> Platform:
         else:
             import jax
             backend = jax.default_backend()
-            # Any non-CPU PJRT backend (tpu, or a tunnelled TPU plugin) gets
-            # the TPU platform; CPU gets the host platform.
+            # the CPU backend gets the host platform (what tier-1 runs
+            # on); any accelerator backend gets the TPU platform
             _platform = CPUPlatform() if backend == "cpu" else TPUPlatform()
     return _platform
+
+
+def device_row():
+    """The device as JAX reports it — the three fields every printed
+    measurement row carries, so a number can never be read without the
+    device it came from."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
+def require_chip(tool):
+    """For entry points that exist to measure: the device row, or exit
+    non-zero saying why when JAX has no TPU. A measurement path never
+    falls back to the CPU (the library itself does, for tests: see
+    :func:`get_platform`)."""
+    row = device_row()
+    if row["platform"] != "tpu":
+        raise SystemExit(
+            f"{tool}: JAX found no TPU (platform {row['platform']!r}, "
+            f"{row['device_count']} device); this tool measures the chip "
+            "and a CPU run is never recorded under a device metric's name")
+    return row
 
 
 def set_platform(name_or_platform):
@@ -47,4 +72,5 @@ def set_platform(name_or_platform):
     return _platform
 
 
-__all__ = ["Platform", "TPUPlatform", "CPUPlatform", "get_platform", "set_platform"]
+__all__ = ["Platform", "TPUPlatform", "CPUPlatform", "UnknownPeakError",
+           "get_platform", "set_platform", "device_row", "require_chip"]

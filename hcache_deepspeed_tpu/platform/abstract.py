@@ -12,6 +12,12 @@ shaped in the reference dissolves into XLA.
 from abc import ABC, abstractmethod
 
 
+class UnknownPeakError(LookupError):
+    """The platform has no published peak for what was asked (an
+    unknown ``device_kind``, a dtype with no published figure, or the
+    host CPU). A missing peak is an error, never a default of 0."""
+
+
 class Platform(ABC):
     """A hardware platform seen by the framework."""
 
@@ -76,9 +82,15 @@ class Platform(ABC):
     # ------------------------------------------------------------------ #
     # Hardware peak numbers (used by the flops profiler / MFU reporting)
     # ------------------------------------------------------------------ #
+    @abstractmethod
     def peak_tflops(self, dtype="bfloat16"):
-        """Peak matmul TFLOP/s per device for ``dtype``; 0 if unknown."""
-        return 0.0
+        """Published peak matmul TFLOP/s per device for ``dtype``;
+        raises :class:`UnknownPeakError` when there is none."""
+
+    @abstractmethod
+    def peak_hbm_gbps(self):
+        """Published peak HBM GB/s per device; raises
+        :class:`UnknownPeakError` when there is none."""
 
     # ------------------------------------------------------------------ #
     # Profiler (reference: range_push/pop NVTX + torch profiler hooks)

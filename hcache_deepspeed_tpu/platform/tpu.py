@@ -11,22 +11,26 @@ import contextlib
 
 import jax
 
-from .abstract import Platform
+from .abstract import Platform, UnknownPeakError
 
-# Peak dense-matmul bf16 TFLOP/s per *jax device*, by TPU generation
-# (public specs). v2/v3 expose one TensorCore per device (half a chip);
-# v4 onward expose the whole chip (megacore / single core), so the
-# per-device peak is the full chip figure: v4 275, v5e 197, v5p 459,
-# v6e 918.
-_PEAK_BF16_TFLOPS = {
-    "v2": 22.5,
-    "v3": 61.5,
-    "v4": 275.0,
-    "v5 lite": 197.0,
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6 lite": 918.0,
-    "v6e": 918.0,
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports (one
+# JAX device is one chip from v4 on; jax/_src/pallas/mosaic/tpu_info.py
+# lists the kind strings). Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v4", "TPU v5e",
+# "TPU v5p", "TPU v6e"): dense-matmul TFLOP/s (TOP/s for int8) by dtype,
+# and HBM GB/s. Only published figures: TPUs have no published fp32
+# matmul peak, so asking for one raises rather than guessing.
+_V5E = ({"bfloat16": 197.0, "int8": 393.0}, 819.0)
+_V5P = ({"bfloat16": 459.0, "int8": 918.0}, 2765.0)
+_V6E = ({"bfloat16": 918.0, "int8": 1836.0}, 1640.0)
+_PEAKS = {
+    "TPU v4": ({"bfloat16": 275.0, "int8": 275.0}, 1200.0),
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+    "TPU v5": _V5P,
+    "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E,
+    "TPU v6e": _V6E,
 }
 
 
@@ -58,14 +62,25 @@ class TPUPlatform(Platform):
         devs = jax.devices()
         return devs[0].device_kind if devs else "unknown"
 
+    def _peaks(self):
+        kind = self.device_kind()
+        if kind not in _PEAKS:
+            raise UnknownPeakError(
+                f"no published peak for device_kind {kind!r}; known: "
+                f"{sorted(_PEAKS)} (add it to platform/tpu.py with its "
+                f"source)")
+        return _PEAKS[kind]
+
     def peak_tflops(self, dtype="bfloat16"):
-        kind = self.device_kind().lower()
-        for key, tflops in _PEAK_BF16_TFLOPS.items():
-            if key in kind:
-                if dtype in ("float32", "fp32"):
-                    return tflops / 2
-                return tflops
-        return 0.0
+        by_dtype, _ = self._peaks()
+        if dtype not in by_dtype:
+            raise UnknownPeakError(
+                f"no published {dtype} matmul peak for "
+                f"{self.device_kind()!r}; have {sorted(by_dtype)}")
+        return by_dtype[dtype]
+
+    def peak_hbm_gbps(self):
+        return self._peaks()[1]
 
     def memory_stats(self, device=None):
         device = device or jax.local_devices()[0]
@@ -103,8 +118,10 @@ class CPUPlatform(TPUPlatform):
     def supports_pallas(self):
         return False  # interpret mode only
 
-    def peak_tflops(self, dtype="bfloat16"):
-        return 0.0
+    def _peaks(self):
+        raise UnknownPeakError(
+            "the host CPU platform has no published peak; MFU and "
+            "roofline shares are device metrics and need a TPU")
 
     def memory_stats(self, device=None):
         try:

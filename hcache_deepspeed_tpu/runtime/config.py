@@ -561,16 +561,12 @@ class LoRATrainingConfig(HDSConfigModel):
 class CompileConfig(HDSConfigModel):
     """Reference: DeepCompile (runtime/config.py compile block). On TPU the
     compiler is XLA; these knobs steer jit: donation, remat, combining.
-    ``cache_dir`` enables JAX's persistent compilation cache — executables
-    survive process restarts, which removes the tens-of-seconds first
-    compile on every relaunch (the AOT half of DeepCompile's value)."""
+    The persistent compilation cache (the AOT half of DeepCompile's
+    value) is not a knob here: ``utils/compile_cache.py`` places it."""
     enabled: bool = True
     donate_params: bool = True
     remat_policy: Optional[str] = None
     collective_combining_mb: int = 0  # 0 = XLA default
-    cache_dir: str = ""
-    #: skip caching tiny programs (seconds saved would not cover disk IO)
-    cache_min_compile_time_secs: float = 1.0
 
 
 # ------------------------------------------------------------------ #

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..ops.partitioning import kernel_mesh
 from ..parallel.topology import (MeshTopology, TopologySpec,
                                  initialize_topology)
 from ..platform import get_platform
@@ -80,6 +81,8 @@ class ModelAdapter:
     def __init__(self, model, loss_fn: Optional[Callable] = None):
         self.loss_fn = loss_fn
         self.module = None
+        #: the engine's mesh, set by the engine once it has a topology
+        self.mesh = None
         if hasattr(model, "apply") and hasattr(model, "init"):
             self.module = model
             self._takes_train = self._call_takes_train(model)
@@ -131,18 +134,23 @@ class ModelAdapter:
         if self.module is None:
             raise ValueError("param init requires a flax Module or explicit "
                              "init_params")
-        if self._takes_train:
-            variables = self.module.init(rng, example_batch, train=False)
-        else:
-            variables = self.module.init(rng, example_batch)
+        with kernel_mesh(self.mesh):
+            if self._takes_train:
+                variables = self.module.init(rng, example_batch,
+                                             train=False)
+            else:
+                variables = self.module.init(rng, example_batch)
         return variables["params"]
 
     def loss(self, params, batch, rng, train=True, pld_theta=None):
-        if self.module is not None:
-            out = self.apply_fn(params, batch, rng, train,
-                                pld_theta=pld_theta)
-        else:  # bare apply_fn callables have the 4-arg contract
-            out = self.apply_fn(params, batch, rng, train)
+        # the engine's mesh in scope: the model's Pallas ops place
+        # themselves per shard (ops/partitioning.py)
+        with kernel_mesh(self.mesh):
+            if self.module is not None:
+                out = self.apply_fn(params, batch, rng, train,
+                                    pld_theta=pld_theta)
+            else:  # bare apply_fn callables have the 4-arg contract
+                out = self.apply_fn(params, batch, rng, train)
         if self.loss_fn is not None:
             out = self.loss_fn(out, batch)
         if isinstance(out, tuple):
@@ -204,6 +212,7 @@ class HDSEngine:
                 topology._engine_owned = True
         self.topology = topology
         self.mesh = topology.mesh
+        self.adapter.mesh = self.mesh
 
         # ---- batch trinity ----
         config.resolve_batch_sizes(topology.dp_world_size())
@@ -413,8 +422,10 @@ class HDSEngine:
                        for x in jax.tree.leaves(self.state["params"]))
         self.step_metrics = StepMetrics(
             monitor=self.monitor,
-            peak_tflops=self.platform.peak_tflops("bfloat16") *
-            self.mesh.size,       # tokens are global -> global peak
+            # tokens are global -> global peak; the host CPU has no
+            # published peak, so MFU is not emitted there
+            peak_tflops=None if self.platform.name == "cpu" else
+            self.platform.peak_tflops("bfloat16") * self.mesh.size,
             flops_per_token=6.0 * n_params)
 
         # ---- dataloader ----

@@ -47,7 +47,8 @@ from .router import (FleetRouter, ReplicaSnapshot,  # noqa: F401
                      RouterConfig)
 from .scheduler import (ContinuousBatchingScheduler,  # noqa: F401
                         StepReport)
-from .server import ServerConfig, ServingServer  # noqa: F401
+from .server import (RequestTimeout, ServerConfig,  # noqa: F401
+                     ServingServer)
 from .sim import SimulatedEngine  # noqa: F401
 from .spec import (SLODegradation, SLOModeConfig,  # noqa: F401
                    SpeculationConfig, lookup_draft,

@@ -20,6 +20,8 @@ immediately with a distinct reason (the caller can shed load upstream),
 while schedulable-but-not-yet requests queue normally.
 """
 
+import collections
+import contextlib
 import threading
 from dataclasses import dataclass
 from typing import List, Optional
@@ -32,6 +34,11 @@ from .clock import MonotonicClock, VirtualClock
 from .metrics import ServingMetrics
 from .request import Request, RequestState
 from .scheduler import ContinuousBatchingScheduler
+
+
+#: thread mode: how long the loop stands back between two steps when a
+#: caller is blocked on the server lock
+_HANDOFF_S = 5e-4
 
 
 @dataclass
@@ -84,6 +91,17 @@ class ServerConfig:
     spec_draft_token_s: float = 5e-5
 
 
+class RequestTimeout(TimeoutError):
+    """``ServingServer.wait`` gave up on a request that is still live;
+    ``request`` is that request (``state``, ``tokens_out`` so far)."""
+
+    def __init__(self, request: Request, timeout: float):
+        self.request = request
+        super().__init__(
+            f"request {request.uid} still {request.state.name} after "
+            f"{timeout:g}s ({len(request.tokens_out)} tokens out)")
+
+
 class ServingServer:
 
     def __init__(self, engine, config: ServerConfig = None, clock=None,
@@ -113,6 +131,13 @@ class ServingServer:
         self.monitor = monitor
         self.emit_every_steps = emit_every_steps
         self._lock = make_lock("ServingServer._lock")
+        #: one marker per caller thread blocked on ``_lock`` (deque
+        #: append/pop are atomic). ``threading.Lock`` is not fair: the
+        #: loop thread releases it after a step and retakes it within
+        #: microseconds, so a ``submit()`` would otherwise wait until
+        #: the scheduler ran out of work — the loop reads this between
+        #: steps and stands back when a caller is waiting.
+        self._lock_waiters = collections.deque()
         self._ingress: List[Request] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -140,6 +165,16 @@ class ServingServer:
     def _usable_blocks(self) -> int:
         return self.scheduler.engine.state.allocator.num_blocks - 1
 
+    @contextlib.contextmanager
+    def _handoff(self):
+        """Announce a caller thread about to block on ``_lock`` (see
+        ``_lock_waiters``)."""
+        self._lock_waiters.append(None)
+        try:
+            yield
+        finally:
+            self._lock_waiters.pop()
+
     def submit(self, prompt=None, request: Request = None,
                **kw) -> Request:
         """Enqueue a request (or build one from ``prompt`` + kwargs).
@@ -148,7 +183,7 @@ class ServingServer:
         ``REJECTED`` state with ``reject_reason`` set ("queue_full" or
         "kv_overload") — the caller is expected to check.
         """
-        with self._lock:
+        with self._handoff(), self._lock:
             if request is None:
                 request = Request(uid=self._next_uid, prompt=list(prompt),
                                   arrival_time=self.clock.now(), **kw)
@@ -189,7 +224,7 @@ class ServingServer:
             return request
 
     def cancel(self, uid: int) -> None:
-        with self._lock:
+        with self._handoff(), self._lock:
             for req in self._ingress:
                 if req.uid == uid:
                     req.cancelled = True
@@ -402,6 +437,10 @@ class ServingServer:
                 report = self.step()
                 if not report.work_done:
                     self._stop.wait(self.config.idle_sleep_s)
+                elif self._lock_waiters:
+                    # long enough for the woken caller to take the lock
+                    # this thread has just released
+                    self._stop.wait(_HANDOFF_S)
         except BaseException as exc:          # noqa: BLE001
             self._on_loop_error(exc)
 
@@ -459,12 +498,15 @@ class ServingServer:
 
     def wait(self, req: Request, timeout: float = 60.0) -> Request:
         """Block until ``req`` finishes (thread mode helper). Raises
-        the captured loop error if the server died while waiting."""
+        the captured loop error if the server died while waiting, and
+        :class:`RequestTimeout` when ``timeout`` seconds pass first — a
+        cold compile of one prefill bucket can outlast the default, so
+        size it for the first request of a fresh engine."""
         deadline = self.clock.now() + timeout
-        while not req.finished and self.clock.now() < deadline:
+        while not req.finished:
             if self.error is not None:
                 raise self.error
+            if self.clock.now() >= deadline:
+                raise RequestTimeout(req, timeout)
             self.clock.sleep(self.config.idle_sleep_s)
-        if not req.finished and self.error is not None:
-            raise self.error
         return req

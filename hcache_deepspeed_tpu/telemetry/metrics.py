@@ -37,16 +37,22 @@ class StepMetrics:
 
     ``flops_per_token`` is the portable 6N estimate by default (set it
     to an exact per-token cost when one is known, e.g. from the flops
-    profiler's XLA cost analysis); ``peak_tflops`` comes from
-    ``platform.peak_tflops`` and gates MFU emission (0 = unknown peak,
-    MFU omitted rather than emitted as garbage).
+    profiler's XLA cost analysis); ``peak_tflops`` is the published
+    peak from ``platform.peak_tflops`` (times the mesh size). ``None``
+    means the platform has no peak (the host CPU) and MFU is not a
+    defined metric there; a peak of 0 is refused, it is not a spelling
+    of "unknown".
     """
 
-    def __init__(self, monitor=None, peak_tflops: float = 0.0,
+    def __init__(self, monitor=None, peak_tflops: Optional[float] = None,
                  flops_per_token: float = 0.0, prefix: str = "Train",
                  registry=None):
         self.monitor = monitor
-        self.peak_tflops = float(peak_tflops)
+        if peak_tflops is not None and not peak_tflops > 0:
+            raise ValueError(
+                f"peak_tflops={peak_tflops!r}: pass the published peak, "
+                "or None on a platform that has none")
+        self.peak_tflops = peak_tflops
         self.flops_per_token = float(flops_per_token)
         self.prefix = prefix
         #: optional ``telemetry.prometheus.MetricRegistry``: every
@@ -65,7 +71,8 @@ class StepMetrics:
             if samples:
                 out.append((f"{p}/samples_per_sec", samples / wall_s,
                             step))
-            if tokens and self.flops_per_token and self.peak_tflops:
+            if tokens and self.flops_per_token and \
+                    self.peak_tflops is not None:
                 achieved = tokens * self.flops_per_token / wall_s / 1e12
                 out.append((f"{p}/mfu", achieved / self.peak_tflops,
                             step))

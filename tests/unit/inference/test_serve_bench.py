@@ -86,8 +86,8 @@ def test_serve_bench_sweep_fused():
 
 def test_bench_model_sizes_trace():
     """The 1b/7b bench configs must build and trace (eval_shape — no
-    weights materialized) with sane parameter counts, so a live-relay
-    7B session can't die on a config bug."""
+    weights materialized) with sane parameter counts, so a 7B chip
+    run can't die on a config bug."""
     import jax
     from hcache_deepspeed_tpu.models.llama import (LlamaConfig,
                                                    LlamaForCausalLM)
@@ -123,7 +123,7 @@ def test_serve_bench_restore_mode():
 
 def test_serve_bench_restore_marginal_mode():
     """Marginal decomposition: device replay cost vs link ship cost
-    (chained dispatches, one sync — the high-latency-relay method)."""
+    (chained dispatches, one sync)."""
     from hcache_deepspeed_tpu.inference.benchmark import \
         run_restore_marginal
     rows = run_restore_marginal(model_size="tiny", max_context=128,

@@ -12,7 +12,7 @@ The contract under test is the transport-swap twin discipline:
   oracle — it runs the ring kernel's exact compute schedule with the
   transport swapped for HBM chunks;
 - layout guards fall back to the reference twin LOUDLY
-  (``fused_fallback_debug_info``);
+  (``ops.fallback_report``);
 - ``fused_qrs_exchange`` is bitwise-equal to the native ``all_to_all``
   it replaces, and the fused quant+EF epilogue matches the host twin
   under jit (the engine always runs jitted).
@@ -26,9 +26,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from hcache_deepspeed_tpu.ops import fallback_report, reset_fallback_report
 from hcache_deepspeed_tpu.ops.fused_collective_matmul import (
-    ShardedQuantizedTensor, fused_fallback_debug_info,
-    fused_qrs_exchange, pallas_fused_gather_matmul,
+    ShardedQuantizedTensor, fused_qrs_exchange, pallas_fused_gather_matmul,
     pallas_fused_gather_matmul_resident, reference_fused_gather_matmul,
     streamed_fused_gather_matmul)
 from hcache_deepspeed_tpu.ops.quantized_matmul import (
@@ -120,7 +120,7 @@ class TestFallbacks:
         """N-sharded (shard_dim=1) rides the reference twin — counted,
         reason recorded, result still bitwise vs the unfused pipeline."""
         x, q, s = _mk(seed=4)
-        before = fused_fallback_debug_info()["count"]
+        reset_fallback_report()
 
         def fused(q_sh, s_sh):
             return pallas_fused_gather_matmul(
@@ -134,11 +134,8 @@ class TestFallbacks:
         a = _shmap(fused, specs, P())(q, s)
         b = _shmap(unfused, specs, P())(q, s)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        info = fused_fallback_debug_info()
-        assert info["count"] > before
-        assert info["by_reason"].get("unsupported_layout", 0) >= 1
-        assert info["warned"] is True
-        assert info["last"][0] == "unsupported_layout"
+        assert fallback_report()["fused_gather_matmul"][
+            "unsupported_layout"] >= 1
 
 
 class TestShardedQuantizedTensor:

@@ -38,6 +38,12 @@ class TestPagedAttentionParity:
         # start > 0: continuation chunk attends to earlier cache blocks
         _case(1, 16, 4, 2, 32, 8, 32, 8, starts=[24], lens=[40])
 
+    def test_prefill_walks_row_tiles(self):
+        # T*G = 1152 rows per kv head: three 512-row tiles (the last one
+        # padded), continuation chunk so the causal frontier differs
+        # per tile
+        _case(1, 288, 8, 2, 32, 16, 40, 24, starts=[70], lens=[358])
+
     def test_mha_no_gqa(self):
         _case(2, 1, 4, 4, 128, 16, 32, 4, starts=[7, 0], lens=[8, 1])
 
@@ -107,19 +113,33 @@ class TestHeadTiling:
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                    atol=3e-5)
 
-    def test_pick_head_tile(self):
-        from hcache_deepspeed_tpu.ops.paged_attention import \
-            _pick_head_tile
-        # decode shapes fit every head in one step
-        assert _pick_head_tile(32, 8, 64, 64, 2) == 32
-        # must divide KV
-        assert 24 % _pick_head_tile(24, 8, 64, 64, 2) == 0
-        # large prefill tiles shrink under the budget but stay >= 1
-        kvt = _pick_head_tile(32, 512, 128, 64, 2)
-        assert 1 <= kvt <= 32 and 32 % kvt == 0
-        per_head = (2 * 512 * 128 * 2 + 2 * 2 * 64 * 128 * 2
-                    + 512 * 128 * 4 + 2 * 512 * 128 * 4)
-        assert kvt * per_head <= 6 * 2**20
+    def test_pick_tiles(self):
+        from hcache_deepspeed_tpu.ops.paged_attention import (
+            _MAX_ROW_TILE, _VMEM_BUDGET, _step_bytes, pick_tiles)
+        # decode shapes: one 8-row tile, every head in one step
+        assert pick_tiles(32, 1, 64, 64, 2) == (8, 8, 32)
+        # the head tile must divide KV
+        assert 24 % pick_tiles(24, 8, 64, 64, 2)[2] == 0
+        # a 2048-token GQA 32/8 x 128 prefill: rows walk in tiles, the
+        # footprint is that of one tile however long the dispatch
+        tq, tgp, kvt = pick_tiles(8, 2048 * 4, 128, 64, 2)
+        assert tq == _MAX_ROW_TILE and tgp == 2048 * 4 and 8 % kvt == 0
+        assert kvt * _step_bytes(tq, 128, 64, 2) <= _VMEM_BUDGET
+        # ragged row counts pad up to whole tiles
+        assert pick_tiles(2, 515, 64, 16, 4)[:2] == (512, 1024)
+
+    def test_over_budget_layout_is_a_typed_error(self):
+        """One head at the row tile already over the VMEM budget is
+        refused by name with the numbers, never handed to the compiler
+        (the old picker clamped it to one head and Mosaic died with
+        RESOURCE_EXHAUSTED)."""
+        from hcache_deepspeed_tpu.ops.paged_attention import (
+            PagedAttentionBudgetError, pick_tiles)
+        with pytest.raises(PagedAttentionBudgetError) as exc:
+            pick_tiles(8, 2048 * 4, 256, 2048, 2)
+        msg = str(exc.value)
+        assert "512 query rows" in msg and "block_size=2048" in msg
+        assert "bytes" in msg and "budget" in msg
 
     def test_non_divisor_head_tile_rejected(self):
         rng = np.random.default_rng(6)

@@ -161,39 +161,17 @@ def test_choose_tiles_scale_with_activation_bytes():
     assert vmem(bn2, gpb2, 4) > budget
 
 
-def test_reference_fallback_recorded_and_warned_once():
-    """The silent reference-path fallback must leave a trail: counters
-    by reason + the last shape in fallback_debug_info(), and ONE
-    warning for the first fallback (a perf run can then check it
-    measured the kernel, not the dequant path). The repo logger does
-    not propagate, so warn-once is asserted via the debug record's
-    ``warned`` latch rather than captured records."""
+def test_reference_fallback_still_computes_the_reference():
+    """A shape the tiles cannot cover returns the reference result (the
+    ledger side is pinned in test_fallback_report.py)."""
     from hcache_deepspeed_tpu.ops import quantized_matmul as qmm
     x, w, q, scale = _mk(M=32, K=192, N=256, group_k=64, seed=3)
-    saved = dict(qmm._FALLBACK_DEBUG)
-    saved["by_reason"] = dict(saved["by_reason"])
-    try:
-        qmm._FALLBACK_DEBUG.update(count=0, by_reason={}, last=None,
-                                   warned=False)
-        # ragged M against an explicit block_m: 17 % 8 != 0
-        out = qmm.pallas_quantized_matmul(
-            x[:17], q, scale, group_k=64, block_m=8, interpret=True)
-        assert qmm._FALLBACK_DEBUG["warned"]      # first fallback warns
-        out2 = qmm.pallas_quantized_matmul(
-            x[:17], q, scale, group_k=64, block_m=8, interpret=True)
-        ref = qmm.reference_quantized_matmul(x[:17], q, scale,
-                                             group_k=64)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-4)
-        np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
-                                   atol=1e-4)
-        info = qmm.fallback_debug_info()
-        assert info["count"] == 2
-        assert info["by_reason"] == {"tile_misaligned": 2}
-        reason, M, K, N, block = info["last"]
-        assert (reason, M, K, N) == ("tile_misaligned", 17, 192, 256)
-    finally:
-        qmm._FALLBACK_DEBUG.update(saved)
+    # ragged M against an explicit block_m: 17 % 8 != 0
+    out = qmm.pallas_quantized_matmul(
+        x[:17], q, scale, group_k=64, block_m=8, interpret=True)
+    ref = qmm.reference_quantized_matmul(x[:17], q, scale, group_k=64)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-4)
 
 
 class TestFusedConsumption:

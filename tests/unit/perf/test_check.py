@@ -7,9 +7,8 @@ import os
 
 from hcache_deepspeed_tpu.perf import (MetricPoint, check_artifact,
                                        check_headline, check_points,
-                                       freshness_alarm, load_index,
-                                       regressions, self_check_rows,
-                                       self_test)
+                                       load_index, regressions,
+                                       self_check_rows, self_test)
 from hcache_deepspeed_tpu.perf.registry import build_index
 
 ROOT = os.path.abspath(
@@ -127,28 +126,6 @@ def test_self_check_rows_roundtrip():
     assert out.get("ok") is False
     assert any(r["metric"] == "chaos.deterministic"
                for r in out["regressions"])
-
-
-def test_freshness_gauge_is_queryable():
-    """ROADMAP item 5's wedged-relay condition as a gauge: the
-    committed index always carries a timestamped chip measurement and
-    its age; the alarm fires on a synthetic stale index and stays
-    quiet on a fresh one (no dependence on the relay's current
-    state)."""
-    index = _committed_index()
-    fr = index["freshness"]
-    assert fr["last_chip_measurement_utc"]
-    assert fr["staleness_days"] is not None and \
-        fr["staleness_days"] >= 0.0
-    stale = {"freshness": {"last_chip_measurement_utc":
-                           "2026-08-01T00:00:00Z",
-                           "staleness_days": 3.4, "stale": True}}
-    assert freshness_alarm(stale, max_age_days=2.0)
-    fresh = {"freshness": {"last_chip_measurement_utc":
-                           "2026-08-04T00:00:00Z",
-                           "staleness_days": 0.1, "stale": False}}
-    assert freshness_alarm(fresh, max_age_days=2.0) is None
-    assert freshness_alarm({}, max_age_days=2.0)   # nothing indexed
 
 
 def test_cli_check_self_test_and_lint():

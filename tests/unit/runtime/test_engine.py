@@ -269,10 +269,11 @@ class TestGradNorm:
 
 class TestCompilationCache:
     def test_cache_reused_across_processes(self, tmp_path):
-        # compile.cache_dir turns on JAX's persistent compilation cache:
-        # a first process writes executables, a SECOND process reuses
-        # them (measured as a large drop in init+first-step wall time —
-        # in-process jit caching cannot explain a cross-process speedup)
+        # a cache directory placed through JAX_COMPILATION_CACHE_DIR is
+        # left alone by hds.initialize: a first process writes
+        # executables there, a SECOND process reuses them (measured as a
+        # large drop in init+first-step wall time — in-process jit
+        # caching cannot explain a cross-process speedup)
         import os
         import subprocess
         import sys
@@ -288,8 +289,6 @@ engine, _, _, _ = hds.initialize(
     model=GPT2LMHeadModel(gpt2_tiny()), example_batch=batch,
     config={{"train_batch_size": 8,
             "optimizer": {{"type": "Adam", "params": {{"lr": 1e-3}}}},
-            "compile": {{"cache_dir": {cache!r},
-                        "cache_min_compile_time_secs": 0.0}},
             "steps_per_print": 10**9}})
 float(engine.train_batch(batch=batch))
 print("ELAPSED", time.time() - t0)
@@ -299,6 +298,8 @@ print("ELAPSED", time.time() - t0)
                        os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))),
                    JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=cache,
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
                    XLA_FLAGS="--xla_force_host_platform_device_count=8")
         times = []
         for _ in range(2):

@@ -1,7 +1,10 @@
 """Server frontend: ingress admission control, thread mode, and the
 metrics -> MonitorMaster event-path wiring."""
 
+import time
+
 import numpy as np
+import pytest
 
 from hcache_deepspeed_tpu.inference import RaggedInferenceEngineConfig
 from hcache_deepspeed_tpu.serving import (Request, ServerConfig,
@@ -97,6 +100,51 @@ def test_thread_mode_serves_submissions():
     ref.run_trace(ref_reqs)
     assert [r.tokens_out for r in rs] == \
         [r.tokens_out for r in ref_reqs]
+
+
+def test_a_blocked_submit_announces_itself_to_the_loop():
+    """``threading.Lock`` is not fair: the loop thread releases the
+    server lock after a step and retakes it microseconds later, so a
+    ``submit()`` from another thread used to wait until the scheduler
+    ran out of work (with a real engine under load: for the whole
+    backlog). A caller blocked on the lock now leaves a marker the loop
+    reads between steps to stand back. The end-to-end effect — a request
+    submitted beside decoding residents preempts one of them — is what
+    ``chip_smoke.py``'s serve phase depends on
+    (test_chip_entry_points.py); here the marker's lifetime."""
+    import threading
+    srv = ServingServer(sim_engine(num_blocks=20),
+                        config=ServerConfig(kv_demand_fraction=1e9))
+    done = []
+    srv._lock.acquire()                   # the loop, mid-step
+    try:
+        caller = threading.Thread(target=lambda: done.append(
+            srv.submit(prompt=list(range(10)), max_new_tokens=4)))
+        caller.start()
+        deadline = time.monotonic() + 5.0
+        while not srv._lock_waiters:
+            assert time.monotonic() < deadline
+        assert len(srv._lock_waiters) == 1 and not done
+    finally:
+        srv._lock.release()
+    caller.join(timeout=5.0)
+    assert not caller.is_alive() and len(done) == 1
+    assert not srv._lock_waiters
+
+
+def test_wait_raises_typed_timeout_with_the_live_request():
+    """A request still live when the time is up is an error carrying
+    its state, never a quiet return of the unfinished request."""
+    from hcache_deepspeed_tpu.serving import RequestTimeout
+    srv = ServingServer(sim_engine(num_blocks=20),
+                        config=ServerConfig(kv_demand_fraction=1e9))
+    # never started: nothing drains the ingress queue
+    r = srv.submit(prompt=list(range(10)), max_new_tokens=4)
+    with pytest.raises(RequestTimeout) as exc:
+        srv.wait(r, timeout=0.05)
+    assert isinstance(exc.value, TimeoutError)
+    assert exc.value.request is r and not r.finished
+    assert r.state.name in str(exc.value)
 
 
 def test_serving_metrics_histograms():

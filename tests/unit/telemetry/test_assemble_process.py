@@ -20,7 +20,7 @@ def _parent_events():
 
 
 def _worker_streams():
-    # worker 0 relays uid 7 out at local ts 1.0 (offset +100 -> 101);
+    # worker 0 forwards uid 7 out at local ts 1.0 (offset +100 -> 101);
     # worker 1 lands it at local ts 2.0 (offset +200 -> 202)
     return {
         0: {"events": [

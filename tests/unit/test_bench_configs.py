@@ -1,86 +1,75 @@
-"""Every bench.py config must build its model and trace its train step
-abstractly (jax.eval_shape — no compile, no device) so a broken config
-is caught here instead of burning a live-relay vetting window.
+"""bench.py's training path: one fixed configuration, measured in
+process. The configuration must build and trace abstractly
+(jax.eval_shape — no compile, no device) so a broken shape is caught
+here and not on chip time, and the tiny CPU path must run the same code
+end to end and name the device it ran on. (That the real configuration
+refuses to run without a chip is pinned in test_chip_entry_points.py.)
 """
 
+import json
 import os
+import subprocess
 import sys
 
 import jax
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, _REPO)
 import bench  # noqa: E402
 
 
-@pytest.mark.parametrize("name", sorted(bench.CONFIGS))
-def test_config_traces(name):
-    # bench.build_model is the SAME builder run_config measures with —
-    # a private copy here once drifted (hardcoded n_layer=24) and
-    # silently traced the wrong model for tiny-cpu-guard
-    model, cfg, batch_size, seq = bench.build_model(name)
-    batch = {"input_ids": jax.ShapeDtypeStruct((batch_size, seq),
-                                               np.int32)}
+@pytest.mark.parametrize("spec", [bench.CONFIG, bench.TINY_CONFIG],
+                         ids=lambda s: s["name"])
+def test_config_traces(spec):
+    # bench.build_model is the SAME builder run_training measures with
+    model, cfg = bench.build_model(spec)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (spec["batch"], spec["seq"]), np.int32)}
 
     def init_and_loss(rng, batch):
         variables = model.init(rng, batch, train=False)
-        loss = model.apply(variables, batch, train=True,
+        return model.apply(variables, batch, train=True,
                            rngs={"dropout": rng})
-        return loss
 
     out = jax.eval_shape(init_and_loss, jax.random.PRNGKey(0), batch)
     assert out.shape == ()
 
 
-def test_candidates_are_configs():
-    assert set(bench.CANDIDATES) <= set(bench.CONFIGS)
-    # the last candidate must be the server-cache-proven one (round-2
-    # workflow contract; see bench.py module docstring)
-    assert bench.CANDIDATES[-1] == "350m-b8"
+def test_the_one_configuration_is_the_350m_hd128_shape():
+    assert bench.CONFIG["name"] == "350m-hd128-b8"
+    _, cfg = bench.build_model(bench.CONFIG)
+    assert (cfg.n_layer, cfg.n_embd, cfg.n_head) == (24, 1024, 8)
+    assert cfg.vocab_size % 128 == 0
 
 
-def test_stale_payload_carries_last_measurement(tmp_path, monkeypatch):
-    """Dead-relay payloads must NOT promote the historical best to the
-    top-level ``value`` (the driver scoreboard records it verbatim, so
-    a zero-fresh-measurement round would masquerade as a best-ever run
-    and mask regressions — ADVICE r5 high). The history rides under
-    ``extra.last_measured`` with a top-level ``stale`` marker, and the
-    exit code stays non-zero and distinct (3 = stale history exists,
-    2 = nothing at all)."""
-    state = {"best": {"value": 123.4, "mfu": 0.61, "vs_baseline": 1.13,
-                      "config": "x", "utc": "2026-08-01T00:00:00Z"},
-             "last": {"value": 100.0, "mfu": 0.50, "vs_baseline": 0.93,
-                      "config": "y", "utc": "2026-08-02T00:00:00Z"}}
-    p = tmp_path / "last.json"
-    p.write_text(__import__("json").dumps(state))
-    monkeypatch.setattr(bench, "_LAST_MEASURED_PATH", str(p))
-    payload = bench._error_payload("relay down")
-    assert payload["stale"] is True
-    assert payload["value"] == 0.0            # never the stale best
-    assert payload["vs_baseline"] == 0.0
-    assert payload["stale_utc"] == "2026-08-01T00:00:00Z"
-    assert payload["error"] == "relay down"
-    assert payload["extra"]["last_measured"]["best"]["value"] == 123.4
-    assert payload["extra"]["last_measured"]["last"]["value"] == 100.0
-    assert bench._error_exit_code(payload) == 3
-    # fresh payloads never set the key, so absence == fresh; and with
-    # no history at all the exit code distinguishes that too
-    monkeypatch.setattr(bench, "_LAST_MEASURED_PATH",
-                        str(tmp_path / "missing.json"))
-    payload = bench._error_payload("relay down")
-    assert "stale" not in payload and payload["value"] == 0.0
-    assert bench._error_exit_code(payload) == 2
+def test_training_path_starts_no_subprocess():
+    """One process for the chip: the parent that probed and ran
+    candidates in children is gone, and so are its knobs."""
+    assert not hasattr(bench, "subprocess")
+    assert not hasattr(bench, "threading")
+    src = open(os.path.join(_REPO, "bench.py")).read()
+    for gone in ("HDS_BENCH_CHILD", "HDS_BENCH_CAND_SECS",
+                 "HDS_BENCH_PROBE_SECS", "HDS_BENCH_WATCHDOG_SECS",
+                 "_record_last_measured", "CANDIDATES"):
+        assert gone not in src, gone
 
 
-def test_stale_payload_never_from_smoke(tmp_path, monkeypatch):
-    """HDS_BENCH_TINY smoke runs must not transmit chip numbers."""
-    state = {"best": {"value": 123.4, "mfu": 0.61, "vs_baseline": 1.13,
-                      "config": "x", "utc": "u"}}
-    p = tmp_path / "last.json"
-    p.write_text(__import__("json").dumps(state))
-    monkeypatch.setattr(bench, "_LAST_MEASURED_PATH", str(p))
-    monkeypatch.setenv("HDS_BENCH_TINY", "1")
-    payload = bench._error_payload("relay down")
-    assert "stale" not in payload and payload["value"] == 0.0
+def test_tiny_path_runs_in_process_and_names_its_device():
+    env = dict(os.environ, HDS_BENCH_TINY="1", PYTHONPATH=_REPO,
+               XLA_FLAGS="--xla_force_host_platform_device_count=1")
+    out = subprocess.run([sys.executable, os.path.join(_REPO, "bench.py")],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    row = json.loads(out.stdout.strip().splitlines()[-1])
+    assert row["device"] == {"platform": "cpu", "device_kind": "cpu",
+                             "device_count": row["device"]["device_count"]}
+    assert "SMOKE" in row["metric"] and row["value"] > 0
+    # no device metric from a CPU run: the host has no published peak
+    assert row["vs_baseline"] is None
+    assert row["extra"]["mfu"] is None
+    assert row["extra"]["peak_tflops"] is None
+    assert row["extra"]["fallbacks"] == {}
