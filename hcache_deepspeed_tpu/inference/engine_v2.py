@@ -21,29 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..platform import get_platform
 from ..resilience.faults import get_injector
-from ..telemetry.tracer import get_tracer
+from ..telemetry.tracer import get_tracer, traced
 from ..utils.compile_cache import ensure_compile_cache
 from ..utils.logging import log_dist
 from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
 from .ragged.kv_cache import BlockedKVCache, StateManager
 from .scheduling import SchedulingError, SchedulingResult
-
-
-def _annotated(name):
-    """Trace-annotate a serving entry point (reference:
-    instrument_w_nvtx on the v2 engine's hot methods). ``get_platform``
-    is called per invocation (cheap singleton) so test platform
-    overrides are respected."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with get_platform().annotate(name):
-                return fn(*args, **kwargs)
-        return wrapper
-    return deco
 
 
 @dataclass
@@ -71,6 +56,17 @@ def _bucket(n: int, minimum: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _token_count(seqs) -> int:
+    """Tokens in the sequences ``seqs``: a span attribute, so only
+    computed while the tracer is on."""
+    return int(sum(len(t) for t in seqs))
+
+
+def _nbytes(*arrays) -> int:
+    """Bytes of the arrays that are not ``None`` (a span attribute)."""
+    return int(sum(a.nbytes for a in arrays if a is not None))
 
 
 def _logsumexp_rows(logits):
@@ -301,7 +297,6 @@ class InferenceEngineV2:
     # -------------------------------------------------------------- #
     # put (reference: engine_v2.py:131)
     # -------------------------------------------------------------- #
-    @_annotated("hds.serve.put")
     def put(self, batch_uids: Iterable[int],
             batch_tokens: Iterable, do_checks: bool = True,
             defer_fetch: bool = False):
@@ -322,31 +317,41 @@ class InferenceEngineV2:
         batch_tokens = [np.asarray(t, np.int32).reshape(-1)
                         for t in batch_tokens]
         tracer = get_tracer()
-        if tracer.enabled:
-            tracer.instant("serve.put", n_seqs=len(batch_uids),
-                           tokens=int(sum(len(t) for t in batch_tokens)))
-        if do_checks:
-            # NOTE: with prefix caching the block budget is conservative
-            # (checked before any prefix attaches reduce the real need)
-            result = self.can_schedule(batch_uids,
-                                       [len(t) for t in batch_tokens])
-            if result != SchedulingResult.Success:
-                raise SchedulingError(result)
-        self._reject_suspended(batch_uids)
-        _inj = get_injector()
-        if _inj.enabled and batch_uids:
-            # resilience fault site: before any state mutation, so a
-            # faulted dispatch is retryable / its batch quarantinable
-            _inj.fire("engine.prefill"
-                      if any(len(t) > 1 for t in batch_tokens)
-                      else "engine.decode",
-                      uid=batch_uids[-1], uids=tuple(batch_uids))
-        if defer_fetch and (self.prefix_caching or
-                            self.config.hcache.enable_latents or
-                            self.config.state_manager.prefill_chunk):
-            raise ValueError(
-                "defer_fetch supports only the plain put() path (no "
-                "prefix caching, latent capture, or chunked prefill)")
+        with tracer.span("hds.serve.put", n_seqs=len(batch_uids),
+                         tokens=_token_count(batch_tokens)
+                         if tracer.enabled else 0):
+            return self._put(batch_uids, batch_tokens, do_checks,
+                             defer_fetch)
+
+    def _put(self, batch_uids, batch_tokens, do_checks, defer_fetch):
+        """The body of :meth:`put`, in leaf spans: admit, then per
+        dispatch build / enqueue / wait / fetch / scatter."""
+        tracer = get_tracer()
+        with tracer.span("serve.put.admit"):
+            if do_checks:
+                # NOTE: with prefix caching the block budget is
+                # conservative (checked before any prefix attaches
+                # reduce the real need)
+                result = self.can_schedule(
+                    batch_uids, [len(t) for t in batch_tokens])
+                if result != SchedulingResult.Success:
+                    raise SchedulingError(result)
+            self._reject_suspended(batch_uids)
+            _inj = get_injector()
+            if _inj.enabled and batch_uids:
+                # resilience fault site: before any state mutation, so
+                # a faulted dispatch is retryable / its batch
+                # quarantinable
+                _inj.fire("engine.prefill"
+                          if any(len(t) > 1 for t in batch_tokens)
+                          else "engine.decode",
+                          uid=batch_uids[-1], uids=tuple(batch_uids))
+            if defer_fetch and (self.prefix_caching or
+                                self.config.hcache.enable_latents or
+                                self.config.state_manager.prefill_chunk):
+                raise ValueError(
+                    "defer_fetch supports only the plain put() path (no "
+                    "prefix caching, latent capture, or chunked prefill)")
         if self.prefix_caching:
             # two-wave in-batch dedup: a new prompt that could share a
             # prefix with an EARLIER new prompt in this same call defers
@@ -395,33 +400,48 @@ class InferenceEngineV2:
                 if not long_idx:
                     break
                 heads: List = [None] * len(batch_tokens)
-                for i in long_idx:
-                    heads[i] = batch_tokens[i][:chunk]
-                    seq = self.state.get_or_create_sequence(batch_uids[i])
-                    self.state.maybe_allocate_kv(seq, chunk)
-                    seq.pre_forward(chunk)
+                with tracer.span("serve.put.admit"):
+                    for i in long_idx:
+                        heads[i] = batch_tokens[i][:chunk]
+                        seq = self.state.get_or_create_sequence(
+                            batch_uids[i])
+                        self.state.maybe_allocate_kv(seq, chunk)
+                        seq.pre_forward(chunk)
                 part_l: List = [None] * len(batch_tokens)
                 part_t: List = [None] * len(batch_tokens)
                 self._run_prefill(batch_uids, heads, long_idx,
                                   _bucket(chunk), part_l, part_t)
-                for i in long_idx:
-                    self.state.get_sequence(batch_uids[i]).post_forward()
-                    if self.config.hcache.enable_latents:
-                        lead_latents.setdefault(i, []).append(part_t[i])
-                    batch_tokens[i] = batch_tokens[i][chunk:]
+                with tracer.span("serve.scatter"):
+                    for i in long_idx:
+                        self.state.get_sequence(
+                            batch_uids[i]).post_forward()
+                        if self.config.hcache.enable_latents:
+                            lead_latents.setdefault(i, []).append(
+                                part_t[i])
+                        batch_tokens[i] = batch_tokens[i][chunk:]
 
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            seq = self.state.get_or_create_sequence(uid)
-            self.state.maybe_allocate_kv(seq, len(tokens))
-            seq.pre_forward(len(tokens))
+        with tracer.span("serve.put.admit"):
+            for uid, tokens in zip(batch_uids, batch_tokens):
+                seq = self.state.get_or_create_sequence(uid)
+                self.state.maybe_allocate_kv(seq, len(tokens))
+                seq.pre_forward(len(tokens))
 
-        # route: single-token continuations -> one batched decode;
-        # everything else -> per-sequence bucketed prefill
-        decode_idx = [i for i, (u, t) in enumerate(
-            zip(batch_uids, batch_tokens))
-            if len(t) == 1 and self.state.get_sequence(u).seen_tokens > 0]
-        prefill_idx = [i for i in range(len(batch_uids))
-                       if i not in decode_idx]
+            # route: single-token continuations -> one batched decode;
+            # everything else -> per-sequence bucketed prefill
+            decode_idx = [i for i, (u, t) in enumerate(
+                zip(batch_uids, batch_tokens))
+                if len(t) == 1 and
+                self.state.get_sequence(u).seen_tokens > 0]
+            prefill_idx = [i for i in range(len(batch_uids))
+                           if i not in decode_idx]
+            # prefills batch per length bucket: one dispatch per (B, T)
+            # bucket instead of one jit call per sequence (round-1
+            # latency hygiene finding; reference batches prefills in
+            # one ragged pass)
+            groups: Dict[int, List[int]] = {}
+            for i in prefill_idx:
+                groups.setdefault(_bucket(len(batch_tokens[i])),
+                                  []).append(i)
 
         n = len(batch_uids)
         logits_out: List = [None] * n
@@ -430,34 +450,29 @@ class InferenceEngineV2:
         if decode_idx:
             self._run_decode(batch_uids, batch_tokens, decode_idx,
                              logits_out, latents_out, defer=defer_fetch)
-        # prefills batch per length bucket: one dispatch per (B, T)
-        # bucket instead of one jit call per sequence (round-1 latency
-        # hygiene finding; reference batches prefills in one ragged pass)
-        groups: Dict[int, List[int]] = {}
-        for i in prefill_idx:
-            groups.setdefault(_bucket(len(batch_tokens[i])), []).append(i)
         for T, idx in sorted(groups.items()):
             self._run_prefill(batch_uids, batch_tokens, idx, T,
                               logits_out, latents_out, defer=defer_fetch)
 
-        for uid in batch_uids:
-            self.state.get_sequence(uid).post_forward()
+        with tracer.span("serve.scatter"):
+            for uid in batch_uids:
+                self.state.get_sequence(uid).post_forward()
 
-        if self.prefix_caching:
-            for uid, toks in zip(batch_uids, processed):
-                seq = self.state.get_sequence(uid)
-                seq.history.extend(int(t) for t in toks)
-                self._register_full_blocks(seq)
+            if self.prefix_caching:
+                for uid, toks in zip(batch_uids, processed):
+                    seq = self.state.get_sequence(uid)
+                    seq.history.extend(int(t) for t in toks)
+                    self._register_full_blocks(seq)
 
-        if lead_latents:   # chunked prefill: stitch per-chunk latents
-            for i, parts in lead_latents.items():
-                tail = [latents_out[i]] if latents_out[i] is not None \
-                    else []
-                latents_out[i] = np.concatenate(parts + tail, axis=1)
+            if lead_latents:   # chunked prefill: stitch per-chunk latents
+                for i, parts in lead_latents.items():
+                    tail = [latents_out[i]] if latents_out[i] is not None \
+                        else []
+                    latents_out[i] = np.concatenate(parts + tail, axis=1)
 
-        if defer_fetch:
-            return logits_out, latents_out
-        return np.stack(logits_out), latents_out
+            if defer_fetch:
+                return logits_out, latents_out
+            return np.stack(logits_out), latents_out
 
     def _tables(self, idx, uids):
         return np.stack([
@@ -477,59 +492,78 @@ class InferenceEngineV2:
 
     def _run_decode(self, uids, tokens, idx, logits_out, latents_out,
                     defer=False):
+        tracer = get_tracer()
         B = _bucket(len(idx))
-        tok, start, t_len, tables = self._blank_lanes(B)
-        tables[:len(idx)] = self._tables(idx, uids)
-        for j, i in enumerate(idx):
-            tok[j, 0] = tokens[i][0]
-            start[j] = self.state.get_sequence(uids[i]).seen_tokens
-            t_len[j] = 1
-        with get_tracer().span("serve.decode_dispatch",
-                               lanes=len(idx), bucket=B):
+        with tracer.span("serve.batch_build", bucket=B):
+            tok, start, t_len, tables = self._blank_lanes(B)
+            tables[:len(idx)] = self._tables(idx, uids)
+            for j, i in enumerate(idx):
+                tok[j, 0] = tokens[i][0]
+                start[j] = self.state.get_sequence(uids[i]).seen_tokens
+                t_len[j] = 1
+        with tracer.span("serve.decode_dispatch",
+                         lanes=len(idx), bucket=B):
             logits, latents = self.model.forward_chunk(
                 self.cache, tok, start, tables, t_len)
         if defer:   # keep the device array whole (row slicing here would
             for j, i in enumerate(idx):   # dispatch an op per lane) —
                 logits_out[i] = (logits, j)   # every uid gets its lane
             return
-        logits = np.asarray(logits)
-        if self.config.hcache.enable_latents:
-            latents = np.asarray(latents)      # [L, B, 1, H] -> host
-        for j, i in enumerate(idx):
-            logits_out[i] = logits[j]
-            if self.config.hcache.enable_latents:
-                latents_out[i] = latents[:, j]
+        logits, latents = self._fetch(logits, latents)
+        with tracer.span("serve.scatter"):
+            for j, i in enumerate(idx):
+                logits_out[i] = logits[j]
+                if latents is not None:
+                    latents_out[i] = latents[:, j]     # [L, B, 1, H]
 
     def _run_prefill(self, uids, tokens, idx, T, logits_out, latents_out,
                      defer=False):
         """One batched dispatch for all prefills in a length bucket;
         padded rows (t_len=0) write to the scratch block like padded
         decode lanes."""
+        tracer = get_tracer()
         B = _bucket(len(idx), minimum=1)
-        tok, start, t_len, tables = self._blank_lanes(B, T)
-        tables[:len(idx)] = self._tables(idx, uids)
-        for j, i in enumerate(idx):
-            seq = self.state.get_sequence(uids[i])
-            tok[j, :len(tokens[i])] = tokens[i]
-            start[j] = seq.seen_tokens
-            t_len[j] = len(tokens[i])
-        with get_tracer().span("serve.prefill_dispatch",
-                               lanes=len(idx), bucket=B, bucket_T=T,
-                               tokens=int(sum(len(tokens[i])
-                                              for i in idx))):
+        with tracer.span("serve.batch_build", bucket=B):
+            tok, start, t_len, tables = self._blank_lanes(B, T)
+            tables[:len(idx)] = self._tables(idx, uids)
+            for j, i in enumerate(idx):
+                seq = self.state.get_sequence(uids[i])
+                tok[j, :len(tokens[i])] = tokens[i]
+                start[j] = seq.seen_tokens
+                t_len[j] = len(tokens[i])
+        with tracer.span("serve.prefill_dispatch",
+                         lanes=len(idx), bucket=B, bucket_T=T,
+                         tokens=_token_count(tokens[i] for i in idx)
+                         if tracer.enabled else 0):
             logits, latents = self.model.forward_chunk(
                 self.cache, tok, start, tables, t_len)
         if defer:
             for j, i in enumerate(idx):
                 logits_out[i] = (logits, j)
             return
-        logits = np.asarray(logits)
-        if self.config.hcache.enable_latents:
-            latents = np.asarray(latents)      # [L, B, T, H]
-        for j, i in enumerate(idx):
-            logits_out[i] = logits[j]
-            if self.config.hcache.enable_latents:
-                latents_out[i] = latents[:, j, :len(tokens[i])]
+        logits, latents = self._fetch(logits, latents)
+        with tracer.span("serve.scatter"):
+            for j, i in enumerate(idx):
+                logits_out[i] = logits[j]
+                if latents is not None:            # [L, B, T, H]
+                    latents_out[i] = latents[:, j, :len(tokens[i])]
+
+    def _fetch(self, logits, latents):
+        """A dispatch's results on the host: wait for the device
+        (``serve.device_wait``), then copy (``serve.fetch``). Latents
+        come back ``None`` when HCache capture is off."""
+        tracer = get_tracer()
+        if not self.config.hcache.enable_latents:
+            latents = None
+        with tracer.span("serve.device_wait"):
+            jax.block_until_ready((logits, latents))
+        with tracer.span("serve.fetch",
+                         bytes=_nbytes(logits, latents)
+                         if tracer.enabled else 0):
+            logits = np.asarray(logits)
+            if latents is not None:
+                latents = np.asarray(latents)
+        return logits, latents
 
     # -------------------------------------------------------------- #
     # Serving loop (reference: the generate() surface the v1 engine
@@ -659,7 +693,7 @@ class InferenceEngineV2:
     # compiles the whole decode stretch, reference has no analog because
     # its engine must rebuild the ragged batch host-side each step)
     # -------------------------------------------------------------- #
-    @_annotated("hds.serve.generate_fused")
+    @traced("hds.serve.generate_fused")
     def generate_fused(self, prompts, max_new_tokens: int = 32,
                        eos_token_id: int = None, temperature: float = 0.0,
                        top_k: int = 0, top_p: float = 1.0, seed: int = 0,
@@ -1032,7 +1066,7 @@ class InferenceEngineV2:
     #: latent preemption as well as in exact-KV suspension mode
     spec_latent_capture = True
 
-    @_annotated("hds.serve.put_spec")
+    @traced("hds.serve.put_spec")
     def put_spec(self, batch_uids: Iterable[int], batch_feeds,
                  do_checks: bool = True):
         """One fused speculative verify step over tracked decode
@@ -1092,9 +1126,10 @@ class InferenceEngineV2:
             start[j] = starts[j]
             t_len[j] = len(feed)
         tables[:n] = self._tables(list(range(n)), batch_uids)
-        with get_tracer().span("serve.spec_dispatch", lanes=n,
-                               tokens=int(sum(len(f)
-                                              for f in batch_feeds))):
+        tracer = get_tracer()
+        with tracer.span("serve.spec_dispatch", lanes=n,
+                         tokens=_token_count(batch_feeds)
+                         if tracer.enabled else 0):
             if capture:
                 tail_logits, lat = self.model.forward_chunk_tail_lat(
                     self.cache, tok, start, tables, t_len, T)
@@ -1128,7 +1163,7 @@ class InferenceEngineV2:
     # -------------------------------------------------------------- #
     # HCache restore (fork: engine_v2.py:108)
     # -------------------------------------------------------------- #
-    @_annotated("hds.serve.restore_kv")
+    @traced("hds.serve.restore_kv")
     def restore_kv(self, batch_uids: Iterable[int], batch_tokens: Iterable,
                    batch_latents: Iterable) -> None:
         """Rebuild the blocked KV cache for ``batch_uids`` from saved
@@ -1209,10 +1244,13 @@ class InferenceEngineV2:
         # the umbrella span covers STAGING (state ops + slab build +
         # first ships); the replay chunks get their own
         # serve.restore.stage spans as advance_restores issues them
-        with get_tracer().span(
+        tracer = get_tracer()
+        with tracer.span(
                 "serve.restore_kv", sequences=len(items),
-                tokens=int(sum(len(it[1]) for it in items)),
-                latent_bytes=int(sum(it[2].nbytes for it in items))):
+                tokens=_token_count(it[1] for it in items)
+                if tracer.enabled else 0,
+                latent_bytes=_nbytes(*(it[2] for it in items))
+                if tracer.enabled else 0):
             for T, group in sorted(groups.items()):
                 lat, start, t_len, tables, seqs = \
                     self._stage_restore_group(group, T)

@@ -44,6 +44,7 @@ from ..ops.paged_attention import paged_attention
 from ..ops.rms_norm import rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.topology import TENSOR_AXIS
+from ..telemetry.tracer import get_tracer
 
 
 def join_path(path):
@@ -1242,7 +1243,9 @@ class RestorePipeline:
         # the lane slab is layer-major contiguous (built by
         # _stage_restore_group / HostLatentStore), so this is a
         # straight block copy, not a gather
-        return jax.device_put(np.ascontiguousarray(sl), self._dev)
+        with get_tracer().span("restore.ship", layer0=l0,
+                               layers=sl.shape[0], bytes=sl.nbytes):
+            return jax.device_put(np.ascontiguousarray(sl), self._dev)
 
     def prefetch(self) -> int:
         """Ship ahead: issue H2D for the next unshipped chunks up to
@@ -1264,7 +1267,6 @@ class RestorePipeline:
         remaining), shipping the following chunk ahead of each replay.
         Async end to end — returns the number of replays issued."""
         from ..resilience.faults import get_injector
-        from ..telemetry.tracer import get_tracer
         tracer = get_tracer()
         _inj = get_injector()
         issued = 0
@@ -1290,11 +1292,14 @@ class RestorePipeline:
                 if cur is None:
                     cur = self._ship(i)
                 self._next_replay = i + 1
-                ck, cv = self.model._restore(
-                    self.model.params, self.cache.k, self.cache.v,
-                    jnp.int32(l0), cur, self._start, self._tables,
-                    self._t_len)
-                self.cache.replace(ck, cv)
+                with tracer.span("restore.replay", layer0=l0,
+                                 layers=min(self.chunk_layers, L - l0),
+                                 bytes=nbytes):
+                    ck, cv = self.model._restore(
+                        self.model.params, self.cache.k, self.cache.v,
+                        jnp.int32(l0), cur, self._start, self._tables,
+                        self._t_len)
+                    self.cache.replace(ck, cv)
                 # dual-lane: the NEXT chunks' H2D ships issue right
                 # behind this (async) replay dispatch and ride the link
                 # under it. Ordered after the replay so a faulted ship

@@ -31,6 +31,15 @@ _REGISTRY = {}
 _FALLBACKS = {}
 
 
+def kernel_name(kernel):
+    """``name=`` and ``metadata=`` of a ``pl.pallas_call``: the custom
+    call is then ``%hds_<kernel>.N`` in the compiled program and its
+    HLO text, which a device trace names the operation by, carries
+    ``kernel_metadata={"hds_kernel":"<kernel>"}``: a trace reader finds
+    the kernel whatever its operands' shapes."""
+    return {"name": f"hds_{kernel}", "metadata": {"hds_kernel": kernel}}
+
+
 def note_fallback(op, reason, detail=""):
     """Record that ``op`` is about to return its jnp reference for
     ``reason``; warns the first time each ``(op, reason)`` is seen."""

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import note_fallback, register_op
+from . import kernel_name, note_fallback, register_op
 from .partitioning import BATCH, HEADS, per_shard
 
 _NEG_INF = -1e30
@@ -155,6 +155,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        **kernel_name("flash_attention_fwd"),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -285,6 +286,7 @@ def _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g):
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        **kernel_name("flash_attention_bwd_dq"),
     )(qt, kt, vt, dot, lse, delta)
 
     # dkv grid: (B, H, nk, nq) — note swapped roles of the index maps
@@ -308,6 +310,7 @@ def _bwd_pallas(scale, causal, block_q, block_k, interpret, res, g):
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        **kernel_name("flash_attention_bwd_dkv"),
     )(qt, kt, vt, dot, lse, delta)
 
     to_bthd = lambda x: x.transpose(0, 2, 1, 3)

@@ -37,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import note_fallback, register_op
+from . import kernel_name, note_fallback, register_op
 
 _NEG_INF = -1e30
 
@@ -240,6 +240,7 @@ def pallas_paged_attention(q, k_pool, v_pool, tables, start, kv_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, TGp, D), q.dtype),
         interpret=interpret,
+        **kernel_name("paged_attention"),
     )(tables, kv_len, start, qg, kp, vp)
     out = out[:, :, :TG].reshape(B, KV, T, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, Hq, D)

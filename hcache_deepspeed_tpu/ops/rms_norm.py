@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import note_fallback, register_op
+from . import kernel_name, note_fallback, register_op
 from .partitioning import BATCH, SEQ, per_shard
 
 
@@ -51,6 +51,7 @@ def _rms_fwd_pallas(x, weight, eps, interpret):
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
+        **kernel_name("rms_norm"),
     )(x2, weight)
     return out.reshape(orig_shape)
 

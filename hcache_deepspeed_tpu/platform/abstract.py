@@ -103,8 +103,9 @@ class Platform(ABC):
     def profiler_stop(self):
         ...
 
-    def annotate(self, name):
-        """Context manager adding a named range to profiler traces."""
+    def annotate(self, name, **attrs):
+        """Context manager adding a named range, with ``attrs`` as its
+        arguments, to profiler traces."""
         import contextlib
         return contextlib.nullcontext()
 

@@ -97,8 +97,8 @@ class TPUPlatform(Platform):
     def profiler_stop(self):
         jax.profiler.stop_trace()
 
-    def annotate(self, name):
-        return jax.profiler.TraceAnnotation(name)
+    def annotate(self, name, **attrs):
+        return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 class CPUPlatform(TPUPlatform):

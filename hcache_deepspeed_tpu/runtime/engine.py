@@ -1175,19 +1175,18 @@ class HDSEngine:
             if self._structured is not None:
                 extra_kw["comp_step"] = jnp.asarray(self.global_steps,
                                                     jnp.int32)
-            with self.platform.annotate("hds.fwd_bwd"):
-                if getattr(self, "_qrs_error_feedback", False):
-                    loss, new_acc, new_werr = self._micro_fwd_bwd(
-                        self.state["params"], self.state["grad_acc"],
-                        self.state["loss_scale"], batch,
-                        self._next_rng(), True, None,
-                        self.state["wire_error"])
-                    self.state["wire_error"] = new_werr
-                else:
-                    loss, new_acc = self._micro_fwd_bwd(
-                        self.state["params"], self.state["grad_acc"],
-                        self.state["loss_scale"], batch,
-                        self._next_rng(), True, **extra_kw)
+            if getattr(self, "_qrs_error_feedback", False):
+                loss, new_acc, new_werr = self._micro_fwd_bwd(
+                    self.state["params"], self.state["grad_acc"],
+                    self.state["loss_scale"], batch,
+                    self._next_rng(), True, None,
+                    self.state["wire_error"])
+                self.state["wire_error"] = new_werr
+            else:
+                loss, new_acc = self._micro_fwd_bwd(
+                    self.state["params"], self.state["grad_acc"],
+                    self.state["loss_scale"], batch,
+                    self._next_rng(), True, **extra_kw)
             self.state["grad_acc"] = new_acc
             self._pending = loss
             if self.wall_clock_breakdown:
@@ -1216,13 +1215,11 @@ class HDSEngine:
             if self.wall_clock_breakdown:
                 self.timers(STEP_GLOBAL_TIMER).start()
             if self._offload is not None:
-                with self.platform.annotate("hds.optimizer_step"):
-                    finite = self._offload_step()
+                finite = self._offload_step()
             else:
                 lr = jnp.asarray(self._current_lr, jnp.float32)
-                with self.platform.annotate("hds.optimizer_step"):
-                    self.state, finite, grad_norm = self._apply_step(
-                        self.state, lr)
+                self.state, finite, grad_norm = self._apply_step(
+                    self.state, lr)
                 self._last_grad_norm = grad_norm
             self._after_step(finite)
             if self.wall_clock_breakdown:
@@ -1445,8 +1442,7 @@ class HDSEngine:
             t0 = time.perf_counter()
         # trace annotation (reference: instrument_w_nvtx on hot paths)
         with get_tracer().span("train.fused_dispatch",
-                               step=self.global_steps + 1, gas=gas), \
-                self.platform.annotate("hds.train_batch"):
+                               step=self.global_steps + 1, gas=gas):
             self.state, loss, finite, grad_norm = self._fused_train_batch(
                 self.state, batch, lr, self._next_rng(), moq_bits,
                 pld_theta, comp_step)

@@ -20,9 +20,10 @@ stack (and quadratic attention) loses to a link-bound linear ship.
                    + T / replay_rate * occ_penalty
     recompute_s(T) = (T / prefill_rate + attn_coeff * T^2) * occ_penalty
 
-calibrates the rates from telemetry samples at runtime (measured link
-bandwidth from ``serve.restore.stage`` spans, prefill token rate from
-``serve.prefill_dispatch`` spans), and the scheduler consults
+takes its rates from synced measurements (``observe_ship``,
+``observe_prefill``, ``observe_replay``: the ``restore_crossover``
+benchmark feeds them; the tracer's spans time asynchronous enqueues
+and are never read back), and the scheduler consults
 :meth:`decide` per preempted sequence instead of always restoring.
 Both compute terms carry the same batch-occupancy penalty — a busy
 batch slows replay and recompute alike but not the link, which shifts
@@ -36,11 +37,9 @@ behaves exactly like the old always-restore scheduler.
 """
 
 from dataclasses import dataclass
-from typing import Dict, Iterable, Optional
+from typing import Dict, Optional
 
 #: span names mined for calibration samples
-_STAGE_SPAN = "serve.restore.stage"
-_PREFILL_SPAN = "serve.prefill_dispatch"
 
 
 @dataclass
@@ -90,7 +89,6 @@ class RestoreCrossoverModel:
         self.prefill_tokens_per_s = float(c.prefill_tokens_per_s)
         self.replay_tokens_per_s = float(c.replay_tokens_per_s)
         self.samples = {"link": 0, "prefill": 0, "replay": 0}
-        self._seen_events = 0       # calibrate_from_events cursor
 
     # ------------------------------------------------------------- #
     # calibration
@@ -123,39 +121,6 @@ class RestoreCrossoverModel:
         self.replay_tokens_per_s = self._ema(self.replay_tokens_per_s,
                                              tokens / seconds)
         self.samples["replay"] += 1
-
-    def calibrate_from_events(self, events: Iterable[Dict]) -> int:
-        """Mine a tracer event stream (``tracer.events()`` or a loaded
-        trace) for calibration samples; events already consumed by a
-        previous call are skipped via a simple cursor (the tracer
-        buffer is append-only between clears). Returns samples taken.
-
-        Span durations are host *issue* time — through JAX's async
-        dispatch they under-estimate device time, so treat runtime
-        calibration as an order-of-magnitude steer; the
-        ``restore_crossover`` benchmark feeds properly synced
-        measurements through the ``observe_*`` hooks instead."""
-        events = list(events)
-        fresh, taken = events[self._seen_events:], 0
-        if len(events) < self._seen_events:      # buffer was cleared
-            fresh = events
-        self._seen_events = len(events)
-        for ev in fresh:
-            if ev.get("ph") != "X":
-                continue
-            args = ev.get("args", {}) or {}
-            dur_s = float(ev.get("dur", 0.0)) / 1e6
-            if ev["name"] == _STAGE_SPAN:
-                nbytes = float(args.get("bytes", 0) or 0)
-                if nbytes:
-                    self.observe_ship(nbytes, dur_s)
-                    taken += 1
-            elif ev["name"] == _PREFILL_SPAN:
-                tokens = float(args.get("tokens", 0) or 0)
-                if tokens:
-                    self.observe_prefill(tokens, dur_s)
-                    taken += 1
-        return taken
 
     # ------------------------------------------------------------- #
     # the analytic forms

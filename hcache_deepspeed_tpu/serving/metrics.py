@@ -112,6 +112,9 @@ class ServingMetrics:
         self.ttft = Histogram(LATENCY_BUCKETS_S, **kw)
         self.tpot = Histogram(LATENCY_BUCKETS_S, **kw)
         self.queue_wait = Histogram(LATENCY_BUCKETS_S, **kw)
+        #: the caller's wait for the server lock in ``submit``, before
+        #: ``arrival_time``: TTFT and queue wait above start after it
+        self.lock_wait = Histogram(LATENCY_BUCKETS_S, **kw)
         # the TTFT decomposition (queue-wait / prefill-compute /
         # handoff-transit): TTFT = queue_wait + prefill_compute; the
         # handoff-transit component is the cross-tier latent ship a
@@ -289,6 +292,8 @@ class ServingMetrics:
             self.tpot.observe(req.tpot())
         if req.queue_wait() is not None:
             self.queue_wait.observe(req.queue_wait())
+        if req.lock_wait() is not None:
+            self.lock_wait.observe(req.lock_wait())
         if req.prefill_compute() is not None:
             self.prefill_compute.observe(req.prefill_compute())
         if getattr(req, "n_handoffs", 0):
@@ -336,6 +341,7 @@ class ServingMetrics:
         out = []
         for name, hist in (("ttft_s", self.ttft), ("tpot_s", self.tpot),
                            ("queue_wait_s", self.queue_wait),
+                           ("lock_wait_s", self.lock_wait),
                            ("prefill_compute_s", self.prefill_compute),
                            ("handoff_transit_s", self.handoff_transit)):
             for q in (50, 90, 99):
@@ -408,6 +414,7 @@ class ServingMetrics:
         for name, hist in (("ttft_seconds", self.ttft),
                            ("tpot_seconds", self.tpot),
                            ("queue_wait_seconds", self.queue_wait),
+                           ("lock_wait_seconds", self.lock_wait),
                            ("prefill_compute_seconds",
                             self.prefill_compute),
                            ("handoff_transit_seconds",
@@ -444,6 +451,7 @@ class ServingMetrics:
             "ttft_s": self.ttft.summary(),
             "tpot_s": self.tpot.summary(),
             "queue_wait_s": self.queue_wait.summary(),
+            "lock_wait_s": self.lock_wait.summary(),
             "prefill_compute_s": self.prefill_compute.summary(),
             "handoff_transit_s": self.handoff_transit.summary(),
             "preemptions_per_request":

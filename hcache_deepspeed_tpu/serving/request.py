@@ -104,6 +104,10 @@ class Request:
     cancelled: bool = False
 
     # timeline (clock units of the owning scheduler)
+    #: entry into ``ServingServer.submit``, before the wait for the
+    #: server lock; ``arrival_time`` is stamped after it. None for a
+    #: request the caller built (its ``arrival_time`` is the caller's)
+    submitted_at: Optional[float] = None
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
@@ -222,6 +226,14 @@ class Request:
             return None
         return (self.finished_at - self.first_token_at) / \
             (len(self.tokens_out) - 1)
+
+    def lock_wait(self) -> Optional[float]:
+        """Entry into ``submit`` → the server lock taken: the wait the
+        caller paid before ``arrival_time``, which :meth:`ttft` and
+        :meth:`queue_wait` therefore leave out."""
+        if self.submitted_at is None:
+            return None
+        return self.arrival_time - self.submitted_at
 
     def queue_wait(self) -> Optional[float]:
         if self.admitted_at is None:

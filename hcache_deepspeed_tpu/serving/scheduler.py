@@ -148,7 +148,6 @@ class ContinuousBatchingScheduler:
                  sample_fn: Callable[[Request, np.ndarray], int] = None,
                  metrics=None, crossover: RestoreCrossoverModel = None,
                  restore_chunks_per_step: int = 1,
-                 calibrate_every: int = 25,
                  resilience: ResiliencePolicy = None,
                  replica_id: int = 0,
                  prefill_chunk: int = 0,
@@ -183,7 +182,6 @@ class ContinuousBatchingScheduler:
         #: (the decode-interleave grain: smaller = more decode steps
         #: hide under one restore; 0 = drain a lane in one step)
         self.restore_chunks_per_step = restore_chunks_per_step
-        self.calibrate_every = max(1, calibrate_every)
         #: scheduler-grain chunked prefill (Dynamic SplitFuse): a
         #: prompt longer than this dispatches in per-step slices that
         #: share each ragged put with the residents' decode tokens —
@@ -362,38 +360,34 @@ class ContinuousBatchingScheduler:
         self.step_idx += 1
         now = self.clock.now()
         report = StepReport(step=self.step_idx, t=now)
-        with get_tracer().span("sched.step",
-                               sched_step=self.step_idx,
-                               replica=self.replica_id) as sp:
-            self._cancellation_pass(report)
-            self._deadline_pass(report, now)
-            self._degradation_pass(report)
-            self._slo_pass(report)
-            self._restore_pass(report)
-            self._draft_pass()
-            admits = self._admission_pass(report, now)
-            admits = self._pressure_pass(admits, report)
+        tracer = get_tracer()
+        with tracer.span("sched.step", sched_step=self.step_idx,
+                         replica=self.replica_id) as sp:
+            with tracer.span("sched.passes"):
+                self._cancellation_pass(report)
+                self._deadline_pass(report, now)
+                self._degradation_pass(report)
+                self._slo_pass(report)
+                self._restore_pass(report)
+                self._draft_pass()
+            with tracer.span("sched.admission", queued=len(self.queue)):
+                admits = self._admission_pass(report, now)
+                admits = self._pressure_pass(admits, report)
             self._dispatch(admits, report, now)
-            self._watchdog_pass(report)
+            with tracer.span("sched.passes"):
+                self._watchdog_pass(report)
             if self.metrics is not None:
-                self.metrics.on_step(report, self)
-                if self.metrics.slo_gauges:
-                    # SLO burn rates ride the sched.step span, read-only
-                    # context for whoever drives the degradation ladder
-                    # from them later (ROADMAP item 4) — the span is the
-                    # contract, the tracker never steers the scheduler
-                    sp.set(**{k: round(float(v), 6) for k, v in
-                              self.metrics.slo_gauges.items()})
-                    self._flight_slo_check(now)
-        if self.crossover is not None and \
-                self.step_idx % self.calibrate_every == 0:
-            tracer = get_tracer()
-            if tracer.enabled:
-                # runtime calibration: mine the span buffer for link-
-                # bandwidth and prefill-rate samples (no-op when the
-                # tracer is off; the bench feeds synced measurements
-                # through observe_* instead)
-                self.crossover.calibrate_from_events(tracer.events())
+                with tracer.span("sched.metrics"):
+                    self.metrics.on_step(report, self)
+                    if self.metrics.slo_gauges:
+                        # SLO burn rates ride the sched.step span,
+                        # read-only context for whoever drives the
+                        # degradation ladder from them later (ROADMAP
+                        # item 4) — the span is the contract, the
+                        # tracker never steers the scheduler
+                        sp.set(**{k: round(float(v), 6) for k, v in
+                                  self.metrics.slo_gauges.items()})
+                        self._flight_slo_check(now)
         return report
 
     # ------------------------------------------------------------- #
@@ -1640,16 +1634,20 @@ class ContinuousBatchingScheduler:
             spec_lanes = [r for r in decodes if r.uid in self._drafts]
             decodes = [r for r in decodes
                        if r.uid not in self._drafts]
-        for req in admits:
-            self.queue.remove(req)
-            req.transition(RequestState.PREFILL)
-            req.admitted_at = now
-            report.admitted.append(req.uid)
-            self._event("admit", req.uid,
-                        f"prompt={len(req.prompt)}")
-            if self.prefix_cache is not None and \
-                    self.latent_preemption:
-                self._try_adopt_prefix(req, report)
+        tracer = get_tracer()
+        n_slices = len(chunking) + len(admits)
+        with tracer.span("sched.batch_build", lanes=len(decodes),
+                         slices=n_slices):
+            for req in admits:
+                self.queue.remove(req)
+                req.transition(RequestState.PREFILL)
+                req.admitted_at = now
+                report.admitted.append(req.uid)
+                self._event("admit", req.uid,
+                            f"prompt={len(req.prompt)}")
+                if self.prefix_cache is not None and \
+                        self.latent_preemption:
+                    self._try_adopt_prefix(req, report)
         spec_ok = False
         if spec_lanes:
             spec_ok = self._spec_dispatch(spec_lanes, report, now)
@@ -1660,17 +1658,19 @@ class ContinuousBatchingScheduler:
             # compute the open lanes' ships hide under
             self._advance_restore_lanes(report, had_decode=spec_ok)
             return
-        slices: Dict[int, List[int]] = {}
-        toks: List = [[r.tokens_out[-1]] for r in decodes]
-        for req in chunking + admits:
-            n = self._next_feed(req)
-            slices[req.uid] = list(
-                req.prompt[req.prefill_pos:req.prefill_pos + n])
-            toks.append(slices[req.uid])
-        report.decode_lanes = len(decodes)
-        report.prefill_tokens = sum(len(s) for s in slices.values())
-        if self._prefill_chunk_now:
-            report.prefill_chunks = len(slices)
+        with tracer.span("sched.batch_build", lanes=len(decodes),
+                         slices=n_slices):
+            slices: Dict[int, List[int]] = {}
+            toks: List = [[r.tokens_out[-1]] for r in decodes]
+            for req in chunking + admits:
+                n = self._next_feed(req)
+                slices[req.uid] = list(
+                    req.prompt[req.prefill_pos:req.prefill_pos + n])
+                toks.append(slices[req.uid])
+            report.decode_lanes = len(decodes)
+            report.prefill_tokens = sum(len(s) for s in slices.values())
+            if self._prefill_chunk_now:
+                report.prefill_chunks = len(slices)
         # the decode half of the restore-overlap span pair (see
         # _restore_pass): the decode dispatch computes while the open
         # lanes' latent ships ride the link; the replay chunks issued
@@ -1678,7 +1678,7 @@ class ContinuousBatchingScheduler:
         # shipped under THIS dispatch's compute. overlapped_restores
         # lands on the span via set() once the lane advance decides it,
         # so the ratio is read straight off the pair's attributes.
-        with get_tracer().span(
+        with tracer.span(
                 "sched.decode_dispatch", sched_step=self.step_idx,
                 replica=self.replica_id,
                 lanes=report.decode_lanes,
@@ -1707,44 +1707,56 @@ class ContinuousBatchingScheduler:
                     report, had_decode=bool(decodes) or spec_ok)
                 sp.set(overlapped_restores=report.overlapped_restores,
                        restore_chunks=report.restore_chunks)
-        for j, req in enumerate(step_reqs):
-            if self.latent_preemption:
-                try:
-                    req.absorb_latents(latents[j])
-                except Exception as exc:
-                    # host latent store fault: without an intact
-                    # payload the request can no longer be preempted
-                    # safely — quarantine it, keep the rest of the
-                    # batch's results
-                    self._note_fault(exc, report)
-                    self.running.pop(req.uid, None)
-                    self._safe_flush(req.uid)
-                    self._fail(req,
-                               f"latent_fault:"
-                               f"{getattr(exc, 'site', 'host')}",
-                               report, now, quarantined=True)
+        # two passes over the step's requests, each its own span: the
+        # host latent store first, then sampling and state edges. A
+        # request whose absorb faulted is closed in the first and
+        # skipped by the second.
+        faulted = set()
+        if self.latent_preemption:
+            with tracer.span("sched.absorb_latents",
+                             lanes=len(step_reqs)):
+                for j, req in enumerate(step_reqs):
+                    try:
+                        req.absorb_latents(latents[j])
+                    except Exception as exc:
+                        # host latent store fault: without an intact
+                        # payload the request can no longer be
+                        # preempted safely — quarantine it, keep the
+                        # rest of the batch's results
+                        self._note_fault(exc, report)
+                        self.running.pop(req.uid, None)
+                        self._safe_flush(req.uid)
+                        self._fail(req,
+                                   f"latent_fault:"
+                                   f"{getattr(exc, 'site', 'host')}",
+                                   report, now, quarantined=True)
+                        faulted.add(req.uid)
+        with tracer.span("sched.sample", lanes=len(step_reqs)):
+            for j, req in enumerate(step_reqs):
+                if req.uid in faulted:
                     continue
-            if req.state == RequestState.PREFILL:
-                req.prefill_pos += len(slices[req.uid])
-                if req.prefill_pos < len(req.prompt):
-                    # prompt not fully fed yet: stays a PREFILL
-                    # resident, no token sampled from a mid-chunk row
+                if req.state == RequestState.PREFILL:
+                    req.prefill_pos += len(slices[req.uid])
+                    if req.prefill_pos < len(req.prompt):
+                        # prompt not fully fed yet: stays a PREFILL
+                        # resident, no token sampled from a mid-chunk
+                        # row
+                        self.running[req.uid] = req
+                        continue
+                tok = self.sample_fn(req, logits[j])
+                req.tokens_out.append(tok)
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                if req.state == RequestState.PREFILL:
+                    req.transition(RequestState.DECODE)
                     self.running[req.uid] = req
-                    continue
-            tok = self.sample_fn(req, logits[j])
-            req.tokens_out.append(tok)
-            if req.first_token_at is None:
-                req.first_token_at = now
-            if req.state == RequestState.PREFILL:
-                req.transition(RequestState.DECODE)
-                self.running[req.uid] = req
-                self._register_prefix(req)
-            if len(req.tokens_out) >= req.max_new_tokens or (
-                    req.eos_token_id is not None and
-                    tok == req.eos_token_id):
-                del self.running[req.uid]
-                self.engine.flush(req.uid)
-                self._close(req, report, now)
+                    self._register_prefix(req)
+                if len(req.tokens_out) >= req.max_new_tokens or (
+                        req.eos_token_id is not None and
+                        tok == req.eos_token_id):
+                    del self.running[req.uid]
+                    self.engine.flush(req.uid)
+                    self._close(req, report, now)
 
     def _quarantine_dispatch(self, exc: BaseException,
                              decodes: List[Request],

@@ -183,10 +183,21 @@ class ServingServer:
         ``REJECTED`` state with ``reject_reason`` set ("queue_full" or
         "kv_overload") — the caller is expected to check.
         """
+        # the wait for the lock, on the caller's thread. Its name is
+        # outside the prefixes the device trace's reduction matches
+        # (only the thread that drives the device may use those), so a
+        # human sees it beside the loop's spans and no idle gap of the
+        # loop is handed to it. Closed by hand: it ends where the
+        # ``with`` below has the lock, not where it lets it go.
+        entered = self.clock.now()
+        waited = get_tracer().span("front.submit.lock_wait")
+        waited.__enter__()
         with self._handoff(), self._lock:
+            waited.__exit__(None, None, None)
             if request is None:
                 request = Request(uid=self._next_uid, prompt=list(prompt),
-                                  arrival_time=self.clock.now(), **kw)
+                                  arrival_time=self.clock.now(),
+                                  submitted_at=entered, **kw)
             if request.trace is None:
                 # causal tracing starts at the front door: the root
                 # queue span opens at arrival so queue-wait attribution
@@ -252,9 +263,11 @@ class ServingServer:
         — the fleet steps N replicas at one simulated instant and
         advances the shared clock once by the parallel-max cost."""
         with self._lock:
-            for req in self._ingress:
-                self.scheduler.submit(req)
-            self._ingress.clear()
+            with get_tracer().span("serve.loop.ingress",
+                                   n=len(self._ingress)):
+                for req in self._ingress:
+                    self.scheduler.submit(req)
+                self._ingress.clear()
             report = self.scheduler.step()
             if self.virtual and advance_clock:
                 self.clock.sleep(self._virtual_cost(report))
@@ -433,14 +446,17 @@ class ServingServer:
 
     def _loop(self) -> None:
         try:
+            tracer = get_tracer()
             while not self._stop.is_set():
                 report = self.step()
-                if not report.work_done:
-                    self._stop.wait(self.config.idle_sleep_s)
-                elif self._lock_waiters:
-                    # long enough for the woken caller to take the lock
-                    # this thread has just released
-                    self._stop.wait(_HANDOFF_S)
+                if report.work_done and not self._lock_waiters:
+                    continue
+                with tracer.span("serve.loop.yield",
+                                 waiters=len(self._lock_waiters)):
+                    # idle, or long enough for the woken caller to
+                    # take the lock this thread has just released
+                    self._stop.wait(_HANDOFF_S if report.work_done
+                                    else self.config.idle_sleep_s)
         except BaseException as exc:          # noqa: BLE001
             self._on_loop_error(exc)
 

@@ -20,15 +20,21 @@ Design constraints:
   tracer in a long serving process cannot grow without bound.
 * **device alignment** — on TPU each host span additionally opens the
   platform's XLA profiler trace annotation
-  (``platform/tpu.py`` ``annotate``), so host spans line up with device
-  traces captured via ``profiler_start``; on CPU spans stand alone and
-  the whole layer is tier-1 testable.
+  (``platform/tpu.py`` ``annotate``) with the attributes the span was
+  opened with, so host spans line up with device traces captured via
+  ``profiler_start`` and carry ``sched_step``, ``lanes``, ``bytes``
+  there (attributes added later with ``set()`` stay in the ring buffer
+  only); on CPU spans stand alone and the whole layer is tier-1
+  testable.
+* **read-only** — nothing in the program reads the buffer to decide
+  anything: a traced run executes the same schedule as an untraced one.
 
 Spans are recorded at *exit* time (when the duration is known); the
 exporter sorts by start timestamp, so nesting never breaks per-thread
 monotonicity.
 """
 
+import functools
 import os
 import threading
 import time
@@ -71,7 +77,7 @@ class _Span:
         return self
 
     def __enter__(self):
-        ann = self._tracer._annotation(self.name)
+        ann = self._tracer._annotation(self.name, self.args)
         if ann is not None:
             self._ann = ann
             ann.__enter__()
@@ -139,11 +145,11 @@ class Tracer:
     # -------------------------------------------------------------- #
     # internals
     # -------------------------------------------------------------- #
-    def _annotation(self, name):
+    def _annotation(self, name, attrs):
         fn = self._annotate_fn
         if fn == 0:
             fn = self._resolve_annotate()
-        return fn(name) if fn is not None else None
+        return fn(name, **attrs) if fn is not None else None
 
     def _resolve_annotate(self):
         fn = None
@@ -285,3 +291,15 @@ if os.environ.get("HDS_TRACE", "") not in ("", "0"):
 
 def get_tracer() -> Tracer:
     return _tracer
+
+
+def traced(name):
+    """Decorator: every call of the function is one ``name`` span (an
+    entry point whose whole body is the interval)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with _tracer.span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco

@@ -116,24 +116,6 @@ def test_occupancy_shifts_crossover_toward_restore():
     assert model.decide(t, occupancy=1.0) == "restore"
 
 
-def test_calibrate_from_events_cursor():
-    model = RestoreCrossoverModel(PROFILE,
-                                  CrossoverConfig(min_samples=1))
-    evs = [
-        {"ph": "X", "name": "serve.restore.stage", "dur": 1e3,
-         "args": {"bytes": 1 << 20}},
-        {"ph": "X", "name": "serve.prefill_dispatch", "dur": 2e3,
-         "args": {"tokens": 128}},
-        {"ph": "i", "name": "sched.admit", "args": {}},
-    ]
-    assert model.calibrate_from_events(evs) == 2
-    assert model.calibrated
-    assert model.link_bytes_per_s == pytest.approx((1 << 20) / 1e-3)
-    assert model.prefill_tokens_per_s == pytest.approx(128 / 2e-3)
-    # same list again: cursor skips everything already seen
-    assert model.calibrate_from_events(evs) == 0
-
-
 # ------------------------------------------------------------------ #
 # scheduler integration (deterministic sim)
 # ------------------------------------------------------------------ #

@@ -43,6 +43,41 @@ def test_span_records_duration_and_attrs():
     assert ev["dur"] >= 0 and ev["args"] == {"step": 3, "bytes": 17}
 
 
+def test_span_hands_its_opening_attributes_to_the_profiler_annotation():
+    """What a span is opened with goes to the platform's annotation
+    (``jax.profiler.TraceAnnotation(name, **attrs)`` on the chip);
+    what ``set()`` adds later stays in the ring buffer only."""
+    import contextlib
+    t = tracer()
+    seen = []
+
+    def annotate(name, **attrs):
+        seen.append((name, dict(attrs)))
+        return contextlib.nullcontext()
+
+    t._annotate_fn = annotate
+    with t.span("serve.fetch", bytes=12, lanes=3) as sp:
+        sp.set(late=1)
+    assert seen == [("serve.fetch", {"bytes": 12, "lanes": 3})]
+    assert t.events()[0]["args"] == {"bytes": 12, "lanes": 3, "late": 1}
+
+
+def test_traced_decorator_is_one_span_per_call(monkeypatch):
+    from hcache_deepspeed_tpu.telemetry import tracer as mod
+    t = tracer()
+    monkeypatch.setattr(mod, "_tracer", t)
+
+    @mod.traced("hds.serve.entry")
+    def entry(x, y=1):
+        """doc"""
+        return x + y
+
+    assert entry(2, y=3) == 5 and entry.__doc__ == "doc"
+    assert [e["name"] for e in t.events()] == ["hds.serve.entry"]
+    t.configure(enabled=False)
+    assert entry(1) == 2 and len(t.events()) == 1
+
+
 def test_nested_spans_and_sorted_export_monotone():
     t = tracer()
     with t.span("outer"):
