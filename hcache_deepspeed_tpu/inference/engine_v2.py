@@ -14,6 +14,9 @@ buckets.
 """
 
 import functools
+import time
+import weakref
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Tuple
 
@@ -28,6 +31,7 @@ from ..utils.logging import log_dist
 from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
 from .ragged.kv_cache import BlockedKVCache, StateManager
+from .ragged.latents import HostLink, LatentProgram, PendingLatents
 from .scheduling import SchedulingError, SchedulingResult
 
 
@@ -101,6 +105,13 @@ def _sample_host(row, rng, temperature, top_k, top_p):
 
 
 class InferenceEngineV2:
+
+    #: latents still on the device, on their way to the host, may hold
+    #: as much device memory as this many of the largest program seen:
+    #: the one whose copy is on the link and the one behind it. Past
+    #: that the oldest are waited for (``serve.latents.force``), which
+    #: is what every dispatch did before landing was deferred.
+    _PENDING_PROGRAMS = 2
 
     def __init__(self, model_config, params,
                  config: RaggedInferenceEngineConfig = None,
@@ -206,6 +217,15 @@ class InferenceEngineV2:
         #: open decode-interleaved restore lanes (FIFO), advanced by
         #: advance_restores between the scheduler's decode dispatches
         self._restore_lanes: List[_RestoreLane] = []
+        #: deferred latent landing (``ragged/latents.py``): the link's
+        #: model and ledger, and weak references to the chunks handed
+        #: out and not yet on the host, oldest first. Whoever holds a
+        #: chunk (a request's store, a caller) keeps it alive; one that
+        #: nobody holds is dropped, and its program with it.
+        self._latent_link = HostLink()
+        self._latent_parts: deque = deque()
+        self._latent_program_max = 0
+        self._latent_pending_peak = 0
         log_dist(f"InferenceEngineV2: {num_blocks} KV blocks x "
                  f"{self.block_size} tokens, max_context="
                  f"{self.max_context}", ranks=[0])
@@ -464,11 +484,11 @@ class InferenceEngineV2:
                     seq.history.extend(int(t) for t in toks)
                     self._register_full_blocks(seq)
 
-            if lead_latents:   # chunked prefill: stitch per-chunk latents
+            if lead_latents:   # chunked prefill: its slices, in order
                 for i, parts in lead_latents.items():
                     tail = [latents_out[i]] if latents_out[i] is not None \
                         else []
-                    latents_out[i] = np.concatenate(parts + tail, axis=1)
+                    latents_out[i] = PendingLatents.joined(parts + tail)
 
             if defer_fetch:
                 return logits_out, latents_out
@@ -505,16 +525,18 @@ class InferenceEngineV2:
                          lanes=len(idx), bucket=B):
             logits, latents = self.model.forward_chunk(
                 self.cache, tok, start, tables, t_len)
+            if not defer:
+                latents = self._start_copies(logits, latents)
         if defer:   # keep the device array whole (row slicing here would
             for j, i in enumerate(idx):   # dispatch an op per lane) —
                 logits_out[i] = (logits, j)   # every uid gets its lane
             return
-        logits, latents = self._fetch(logits, latents)
+        logits = self._fetch(logits, latents)
         with tracer.span("serve.scatter"):
             for j, i in enumerate(idx):
                 logits_out[i] = logits[j]
-                if latents is not None:
-                    latents_out[i] = latents[:, j]     # [L, B, 1, H]
+                if latents is not None:                # [L, B, 1, H]
+                    latents_out[i] = self._hand_out(latents, j, 1)
 
     def _run_prefill(self, uids, tokens, idx, T, logits_out, latents_out,
                      defer=False):
@@ -537,33 +559,150 @@ class InferenceEngineV2:
                          if tracer.enabled else 0):
             logits, latents = self.model.forward_chunk(
                 self.cache, tok, start, tables, t_len)
+            if not defer:
+                latents = self._start_copies(logits, latents)
         if defer:
             for j, i in enumerate(idx):
                 logits_out[i] = (logits, j)
             return
-        logits, latents = self._fetch(logits, latents)
+        logits = self._fetch(logits, latents)
         with tracer.span("serve.scatter"):
             for j, i in enumerate(idx):
                 logits_out[i] = logits[j]
                 if latents is not None:            # [L, B, T, H]
-                    latents_out[i] = latents[:, j, :len(tokens[i])]
+                    latents_out[i] = self._hand_out(
+                        latents, j, len(tokens[i]))
 
-    def _fetch(self, logits, latents):
-        """A dispatch's results on the host: wait for the device
-        (``serve.device_wait``), then copy (``serve.fetch``). Latents
-        come back ``None`` when HCache capture is off."""
-        tracer = get_tracer()
+    def _start_copies(self, logits, latents):
+        """Part of a dispatch: start the copies of its results to the
+        host, the logits' first (the next dispatch waits for those
+        alone). Returns the latents as a :class:`LatentProgram`, or
+        ``None`` when HCache capture is off."""
+        logits.copy_to_host_async()
         if not self.config.hcache.enable_latents:
-            latents = None
+            return None
+        return LatentProgram(latents, self._latent_link)
+
+    def _fetch(self, logits, program):
+        """A dispatch's logits on the host. In the time the device
+        needs for the program, what earlier programs left pending is
+        landed (``serve.latents.land``); then the wait for the device
+        (``serve.device_wait``) and the logits' copy
+        (``serve.fetch``). The latents stay where they are:
+        ``program`` is only told when its copy could start."""
+        tracer = get_tracer()
+        self._land_pending(logits, program)
         with tracer.span("serve.device_wait"):
-            jax.block_until_ready((logits, latents))
+            logits.block_until_ready()
+            if program is not None:
+                self._latent_link.enqueue(program, time.perf_counter())
         with tracer.span("serve.fetch",
-                         bytes=_nbytes(logits, latents)
-                         if tracer.enabled else 0):
-            logits = np.asarray(logits)
-            if latents is not None:
-                latents = np.asarray(latents)
-        return logits, latents
+                         bytes=_nbytes(logits) if tracer.enabled else 0):
+            return np.asarray(logits)
+
+    # -------------------------------------------------------------- #
+    # Deferred latent landing (ragged/latents.py)
+    # -------------------------------------------------------------- #
+    def _hand_out(self, program, lane, n):
+        """``n`` tokens of ``lane`` of ``program`` as a pending chunk,
+        remembered here (weakly) until it has landed."""
+        chunk = program.chunk(lane, n)
+        self._latent_parts.append(weakref.ref(chunk.parts[0]))
+        return chunk
+
+    def _pending_parts(self) -> List:
+        """The chunks handed out that are alive and not yet landed,
+        oldest first; the rest are forgotten here."""
+        live = [(ref, part) for ref in self._latent_parts
+                if (part := ref()) is not None and not part.landed]
+        self._latent_parts = deque(ref for ref, _ in live)
+        return [part for _, part in live]
+
+    def _land_pending(self, in_flight, new_program) -> None:
+        """Copy what earlier programs left pending into the stores that
+        adopted it, oldest first, while ``in_flight`` (this dispatch's
+        logits) is not ready; what is left waits for the next dispatch.
+        A program whose copy the link's model does not expect yet is
+        left alone, and so is everything behind it — unless the
+        latents still on the device, ``new_program``'s among them, hold
+        more than ``_PENDING_PROGRAMS`` of the largest program: then
+        the oldest copies are waited for, as every dispatch did before
+        landing was deferred."""
+        held = 0
+        if new_program is not None:
+            held = new_program.nbytes
+            self._latent_program_max = max(self._latent_program_max, held)
+        if not self._latent_parts:
+            self._latent_pending_peak = max(self._latent_pending_peak,
+                                            held)
+            return
+        with get_tracer().span("serve.latents.land") as span:
+            parts, last = self._pending_parts(), None
+            for part in parts:      # a program's lanes lie together
+                if part.program is not last:
+                    last = part.program
+                    held += last.device_bytes
+            self._latent_pending_peak = max(self._latent_pending_peak,
+                                            held)
+            over = held - self._PENDING_PROGRAMS * self._latent_program_max
+            if new_program is not None and over > 0:
+                self._force_pending(parts, over)
+            link, now = self._latent_link, time.perf_counter()
+            nbytes = chunks = 0
+            for part in parts:
+                store = part.store and part.store()
+                if store is None or part.landed:
+                    continue        # nobody's yet: nowhere to land it
+                if part.program.device_bytes and \
+                        not link.due(part.program, now):
+                    break
+                try:
+                    while not part.landed and not in_flight.is_ready():
+                        nbytes += store.land(part, hidden=True)
+                except Exception as exc:   # the store truncated itself
+                    log_dist(f"latent landing failed, payload "
+                             f"truncated: {exc!r}", ranks=[0])
+                    continue
+                if not part.landed:
+                    break           # the program in flight is done
+                chunks += 1
+            span.set(bytes=nbytes, chunks=chunks)
+
+    def _force_pending(self, parts, over: int) -> None:
+        """Wait for the oldest copies until ``over`` bytes of device
+        memory are free again, landing what stores adopted."""
+        with get_tracer().span("serve.latents.force", bytes=over):
+            for part in parts:
+                if over <= 0:
+                    break
+                over -= part.program.device_bytes
+                store = part.store and part.store()
+                try:
+                    if store is None:
+                        part.program.host()
+                    while store is not None and not part.landed:
+                        store.land(part, hidden=False)
+                except Exception as exc:
+                    log_dist(f"latent landing failed, payload "
+                             f"truncated: {exc!r}", ranks=[0])
+
+    def latent_stats(self) -> Dict[str, int]:
+        """Where the captured latents went, in bytes of live lanes:
+        landed while a program ran (``landed_hidden_bytes``), landed or
+        read while the caller waited (``landed_forced_bytes``), released
+        unread (``dropped_bytes``), still pending; and the most device
+        memory latents on their way have held (``pending_peak_bytes``,
+        whole padded programs)."""
+        link = self._latent_link
+        pending = sum(p.unread_bytes for p in self._pending_parts())
+        return {
+            "landed_hidden_bytes": link.landed_hidden_bytes,
+            "landed_forced_bytes": link.landed_forced_bytes,
+            "dropped_bytes": link.captured_bytes - pending
+            - link.landed_hidden_bytes - link.landed_forced_bytes,
+            "pending_bytes": pending,
+            "pending_peak_bytes": self._latent_pending_peak,
+        }
 
     # -------------------------------------------------------------- #
     # Serving loop (reference: the generate() surface the v1 engine

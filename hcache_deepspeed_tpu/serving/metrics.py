@@ -192,6 +192,11 @@ class ServingMetrics:
                        # SPEC_SERVE artifact gates > 1.3 on the
                        # lookup-friendly trace)
                        "spec_accepted_tokens_per_step": 0.0,
+                       # of the latent bytes landed on the host, the
+                       # share copied while a program ran (the rest
+                       # was landed while a reader or the engine
+                       # waited); engine.latent_stats()
+                       "latent_land_hidden_share": 0.0,
                        "slo_level": 0.0}
 
     # ------------------------------------------------------------- #
@@ -259,6 +264,14 @@ class ServingMetrics:
             self.gauges["spec_accepted_tokens_per_step"] = \
                 scheduler.total_spec_emitted / \
                 scheduler.total_spec_lane_steps
+        latent_stats = getattr(engine, "latent_stats", None)
+        if latent_stats is not None:
+            stats = latent_stats()
+            landed = stats["landed_hidden_bytes"] + \
+                stats["landed_forced_bytes"]
+            if landed:
+                self.gauges["latent_land_hidden_share"] = \
+                    stats["landed_hidden_bytes"] / landed
         self.gauges["slo_level"] = float(report.slo_level)
         if self.slo is not None:
             # degradation level is SLO *context* (read-only), and the

@@ -210,7 +210,8 @@ class Request:
         if new_latents is None:
             return
         if self.latents is None:
-            self.latents = HostLatentStore()
+            # sized once: everything this request can ever cache
+            self.latents = HostLatentStore(capacity=self.total_tokens)
         self.latents.append(new_latents)
 
     # timing summaries (None until the respective edge happened)

@@ -1269,12 +1269,20 @@ class ContinuousBatchingScheduler:
         del self.running[req.uid]
         if self.latent_preemption:
             # HCache eviction: the accumulated latents ARE the host
-            # copy; drop the device KV and the tracked slot entirely
-            assert req.latents is not None and \
-                req.latents.shape[1] == req.cached_tokens, \
-                f"latent cover mismatch for uid {req.uid}"
-            self.engine.flush(req.uid)
-            mode = "latents"
+            # copy; drop the device KV and the tracked slot entirely.
+            # Chunks still pending in the store count as covered: the
+            # engine lands them under a later program, or the restore
+            # that reads the store does
+            if req.latents is not None and \
+                    req.latents.shape[1] == req.cached_tokens:
+                self.engine.flush(req.uid)
+                mode = "latents"
+            else:
+                # a landing failed and truncated the payload: nothing
+                # restorable, the request re-enters via recompute
+                self._safe_flush(req.uid)
+                req.latents = None
+                mode = "latents_lost"
         else:
             self.engine.suspend_sequence(req.uid)
             mode = "kv"

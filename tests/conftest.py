@@ -106,10 +106,12 @@ _SLOW_PATH_PARTS = (
 )
 
 
-#: files under a slow directory that build no engine and stay in the
-#: smoke tier
+#: files under a slow directory that build no engine, or only the
+#: two-layer toy one in seconds, and stay in the smoke tier
 _TIER1_IN_SLOW_DIRS = (
     "inference/test_kv_pool_in_place.py",
+    "inference/test_host_latents.py",
+    "inference/test_latent_landing.py",
 )
 
 

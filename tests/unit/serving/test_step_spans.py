@@ -36,6 +36,7 @@ PARENT = {
     "serve.batch_build": "hds.serve.put",
     "serve.decode_dispatch": "hds.serve.put",
     "serve.prefill_dispatch": "hds.serve.put",
+    "serve.latents.land": "hds.serve.put",
     "serve.device_wait": "hds.serve.put",
     "serve.fetch": "hds.serve.put",
     "serve.scatter": "hds.serve.put",
@@ -82,8 +83,17 @@ def seeded_trace(cfg, seed=0):
             for i in range(3)]
 
 
-def virtual_server(build):
-    return ServingServer(build(), clock=VirtualClock(),
+def virtual_server(build, landing=None):
+    """``landing``: whether the engine's landing pass finds the program
+    in flight still running (a tiny CPU program finishes when it
+    likes; ``None`` leaves it to chance)."""
+    engine = build()
+    if landing is not None:
+        land = engine._land_pending
+        in_flight = type("InFlight", (), {
+            "is_ready": staticmethod(lambda: not landing)})
+        engine._land_pending = lambda _, program: land(in_flight, program)
+    return ServingServer(engine, clock=VirtualClock(),
                          config=ServerConfig(
                              kv_demand_fraction=float("inf")))
 
@@ -107,7 +117,7 @@ def recorded(tiny):
     tracer.configure(enabled=True, xla=False)
     tracer.clear()
     try:
-        srv = virtual_server(build)
+        srv = virtual_server(build, landing=True)
         srv.run_trace(seeded_trace(cfg))
         assert srv.scheduler.engine.restore_stats["restores"] >= 1
         return [e for e in tracer.events() if e["ph"] == "X"]
@@ -157,6 +167,62 @@ def test_leaf_spans_cover_their_parent(recorded, parent):
         named += got
     assert named / total >= 0.9
     assert sorted(shares)[len(shares) // 2] >= 0.9
+
+
+def test_latents_land_between_the_dispatch_and_the_wait(recorded):
+    """``serve.latents.land`` is a leaf on the loop's thread, after the
+    put's program is enqueued and before the wait for it; the
+    scheduler's absorb pass still opens every dispatching step (it
+    records the chunks; the bytes land here)."""
+    lands = [e for e in recorded if e["name"] == "serve.latents.land"]
+    puts = [e for e in recorded if e["name"] == "hds.serve.put"]
+    assert len(lands) >= len(puts) - 1          # all but the first put
+    for land in lands:
+        assert not any(e is not land and inside(e, land)
+                       for e in recorded), "not a leaf"
+        put = next(p for p in puts if inside(land, p))
+        mine = [e for e in recorded if e is not put and inside(e, put)]
+        before = [e for e in mine if e["ts"] + e["dur"] <= land["ts"]]
+        after = [e for e in mine if e["ts"] >= land["ts"] + land["dur"]]
+        assert max(before, key=lambda e: e["ts"])["name"] in (
+            "serve.decode_dispatch", "serve.prefill_dispatch")
+        assert min(after, key=lambda e: e["ts"])["name"] == \
+            "serve.device_wait"
+    assert sum(e["args"]["bytes"] for e in lands) > 0
+    steps = [e for e in recorded if e["name"] == "sched.decode_dispatch"
+             and any(inside(p, e) for p in puts)]
+    absorbs = [e for e in recorded if e["name"] == "sched.absorb_latents"]
+    assert len(absorbs) == len(steps)
+
+
+def test_force_is_a_reader_that_found_chunks_pending(tiny, tracing, recorded):
+    """No ``serve.latents.force`` in a trace whose landings all found
+    time under a program — its preemption's payload had landed before
+    the restore read it — and one when a preemption's payload is read
+    with the last step's chunk still pending in the store."""
+    assert not any(e["name"] == "serve.latents.force" for e in recorded)
+    cfg, build = tiny
+    srv = virtual_server(build, landing=True)
+    req = Request(uid=0, prompt=list(range(1, 11)), max_new_tokens=8)
+    srv.scheduler.submit(req)
+    while len(req.tokens_out) < 3:
+        srv.scheduler.step()
+    tracing.clear()
+    assert srv.scheduler.detach_for_migration(0) is req
+    assert req.latents.shape[1] == req.cached_tokens
+    assert not [e for e in tracing.events() if e["ph"] == "X"
+                and e["name"] == "serve.latents.force"]
+    payload = np.asarray(req.latents)           # the wire reads it
+    forces = [e for e in tracing.events() if e["ph"] == "X"
+              and e["name"] == "serve.latents.force"]
+    assert len(forces) == 1 and forces[0]["args"]["chunks"] == 1
+    assert forces[0]["args"]["bytes"] == payload[:, :1].nbytes
+    stats = srv.scheduler.engine.latent_stats()
+    assert stats["landed_forced_bytes"] == payload[:, :1].nbytes
+    assert stats["landed_hidden_bytes"] == payload[:, 1:].nbytes
+    np.asarray(req.latents)                     # nothing left to force
+    assert len([e for e in tracing.events() if e["ph"] == "X" and
+                e["name"] == "serve.latents.force"]) == 1
 
 
 def test_opening_attributes_are_the_tables(recorded):
