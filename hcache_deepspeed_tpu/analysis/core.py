@@ -49,8 +49,6 @@ SIM_DETERMINISTIC = (
     "hcache_deepspeed_tpu/serving/",
     "hcache_deepspeed_tpu/resilience/",
     "hcache_deepspeed_tpu/fabric/",
-    "hcache_deepspeed_tpu/comm/ring.py",
-    "hcache_deepspeed_tpu/comm/hierarchical.py",
     "hcache_deepspeed_tpu/runtime/zero/qwire.py",
     "hcache_deepspeed_tpu/perf/",
     "hcache_deepspeed_tpu/utils/io_bench.py",

@@ -4,16 +4,7 @@ from .comm import (ReduceOp, all_gather, all_reduce, all_to_all, axis_index,
                    init_distributed, is_initialized, log_summary, ppermute,
                    reduce_scatter)
 from .comms_logging import CommsLogger, get_comms_logger
-from .hierarchical import (HierMeshSpec, MeshAxis, axis_groups,
-                           hierarchical_all_gather,
-                           hierarchical_all_reduce_sum,
-                           hierarchical_all_to_all_rows,
-                           hierarchical_reduce_scatter_sum,
-                           make_mesh_spec, validate_mesh_spec)
 from .overlap import CollectiveIssue, Ticket
-from .ring import (COLLECTIVE_IMPLS, decomposed_all_to_all_rows,
-                   decomposed_reduce_scatter_sum, ring_all_gather,
-                   ring_all_reduce_sum)
 
 __all__ = [
     "CollectiveIssue", "Ticket",
@@ -22,10 +13,4 @@ __all__ = [
     "get_local_device_count", "get_rank", "get_world_size",
     "init_distributed", "is_initialized", "log_summary", "ppermute",
     "reduce_scatter", "CommsLogger", "get_comms_logger",
-    "COLLECTIVE_IMPLS", "ring_all_gather", "ring_all_reduce_sum",
-    "decomposed_all_to_all_rows", "decomposed_reduce_scatter_sum",
-    "HierMeshSpec", "MeshAxis", "axis_groups", "make_mesh_spec",
-    "validate_mesh_spec", "hierarchical_all_gather",
-    "hierarchical_all_to_all_rows", "hierarchical_reduce_scatter_sum",
-    "hierarchical_all_reduce_sum",
 ]

@@ -35,11 +35,9 @@ class CommsLogger:
         self.debug = debug
         # op_name -> msg_size -> [count, total_bytes]
         self.comms_dict = defaultdict(lambda: defaultdict(lambda: [0, 0]))
-        # op_name -> op kind ("collective" | "collective_permute"):
-        # which transport carried the bytes. Ring-decomposed sites
-        # (comm/ring.py) record per-chunk permute sends under their own
-        # kind so the decomposed wire is attributable, not silently
-        # folded into (or missing from) the monolithic-collective rows.
+        # op_name -> op kind ("collective" | "latent_handoff"): which
+        # transport carried the bytes (a mesh collective, or the
+        # disaggregated serving handoff of serving/disagg.py)
         self.op_kinds = {}
 
     def configure(self, enabled=None, verbose=None, prof_all=None,
@@ -71,13 +69,7 @@ class CommsLogger:
         reduce lane's wire volume. Convention: ``n_bytes`` is the
         per-device collective INPUT buffer (the same convention the
         facade's ``reduce_scatter``/``all_gather`` wrappers use), so
-        bucketed and per-leaf programs report identical totals.
-
-        ``op_kind="collective_permute"`` marks decomposed ring-chunk
-        sends (``comm/ring.py``): one record per permute step, so the
-        ring transport's bytes land in the accounting —
-        ``wire_savings_summary`` / ``axis_summary`` rows carry the kind
-        — instead of being silently unattributed."""
+        bucketed and per-leaf programs report identical totals."""
         self.op_kinds[op_name] = op_kind
         self.append(op_name, tuple(axes), int(n_bytes))
 
@@ -101,9 +93,7 @@ class CommsLogger:
     def wire_savings_summary(self):
         """Pair each quantized op with its ``_unquantized_equiv``
         record: ``{op: {"wire_bytes", "unquantized_equiv_bytes",
-        "saved_bytes", "fraction"}}`` — the per-collective wire-bytes
-        evidence ``bench.py --zero-overlap`` emits alongside the
-        overlap ratios."""
+        "saved_bytes", "fraction", "op_kind"}}``."""
         totals = {}
         for op, by_axis in self.axis_summary().items():
             totals[op] = sum(t for _, t in by_axis.values())
@@ -122,79 +112,6 @@ class CommsLogger:
                 "op_kind": self.op_kinds.get(op, "collective"),
             }
         return out
-
-    def permute_bytes_summary(self, kinds=("collective_permute",)):
-        """Total bytes per op carried by decomposed ring permutes
-        (``op_kind == "collective_permute"``): ``{op: total_bytes}``.
-        The matched-pair complement of :meth:`wire_savings_summary` for
-        the ring transport — proves ring-chunk traffic is attributed.
-        Per-mesh-axis breakdown: :meth:`permute_axis_bytes`. ``kinds``
-        widens the filter (e.g. ``("collective_permute",
-        "fused_permute")`` for the lumped summary a fused run must
-        reconcile against byte-exactly)."""
-        out = {}
-        for op, by_axis in self.axis_summary().items():
-            if self.op_kinds.get(op) in kinds:
-                out[op] = sum(t for _, t in by_axis.values())
-        return out
-
-    def fused_bytes_summary(self):
-        """Total bytes per op carried INSIDE fused
-        computation-collective kernels (``op_kind == "fused_permute"``,
-        logged per in-kernel ring step by
-        ``ops/fused_collective_matmul.py``): ``{op: total_bytes}``.
-        The fused kernel's wire volume is never silent: these rows
-        reconcile byte-exactly with what the unfused transport of the
-        same payload logs as ``collective_permute`` rows (gated by
-        test_wire_bytes.py)."""
-        out = {}
-        for op, by_axis in self.axis_summary().items():
-            if self.op_kinds.get(op) == "fused_permute":
-                out[op] = sum(t for _, t in by_axis.values())
-        return out
-
-    def permute_axis_bytes(self):
-        """Ring-permute bytes attributed PER MESH-AXIS NAME:
-        ``{op: {axis_label: total_bytes}}`` — the hierarchical
-        transport (``comm/hierarchical.py``) labels every phase with
-        the mesh axis its bytes physically ride (the LAST component of
-        the axis group; flat rings label with the collective axis
-        itself), so intra- vs inter-axis wire volume is separately
-        queryable and the per-axis wire-cost model
-        (``profiling/hlo_audit.py``) can price it. The matched-pair
-        convention is untouched: quantized long-haul phases still
-        report ``<op>_longhaul`` / ``..._unquantized_equiv`` pairs
-        through :meth:`wire_savings_summary`."""
-        out = {}
-        for op, by_axis in self.axis_summary().items():
-            if self.op_kinds.get(op) not in ("collective_permute",
-                                             "fused_permute"):
-                continue
-            per_axis = {}
-            for axes, (_, total) in by_axis.items():
-                label = axes.rpartition(",")[2] or axes
-                per_axis[label] = per_axis.get(label, 0) + total
-            out[op] = per_axis
-        return out
-
-    def total_axis_bytes(self, kinds=("collective_permute",
-                                      "fused_permute")):
-        """Aggregate ``{axis_label: bytes}`` over every op of the given
-        kinds — the direct input to ``hlo_audit.wire_cost_seconds``.
-        ``_unquantized_equiv`` shadow rows and ``_longhaul``
-        matched-pair site markers are excluded (bookkeeping, not wire —
-        the long-haul phase's actual sends are already logged per
-        permute step by the underlying rings)."""
-        totals = {}
-        for op, by_axis in self.axis_summary().items():
-            if self.op_kinds.get(op) not in kinds \
-                    or op.endswith("_unquantized_equiv") \
-                    or op.endswith("_longhaul"):
-                continue
-            for axes, (_, total) in by_axis.items():
-                label = axes.rpartition(",")[2] or axes
-                totals[label] = totals.get(label, 0) + total
-        return totals
 
     def append(self, op_name, axes, msg_size):
         if not self.should_log(op_name):

@@ -106,8 +106,7 @@ def all_to_all_quant_reduce(x, axis=DATA_AXIS, group_size=256, num_bits=8,
     return _shmap(a2a_reduce, topo.mesh, axis, (P(axis),), P(axis))(x)
 
 
-def quantized_allreduce_body(x, error, axis, group_size=2048, num_bits=8,
-                             collective_impl="native", mesh_spec=None):
+def quantized_allreduce_body(x, error, axis, group_size=2048, num_bits=8):
     """Error-feedback INT8-wire allreduce body for use INSIDE a manual
     (shard_map) region — Domino's opt-in compressed half-batch
     all-reduce (``runtime/domino.py``, full-width remains the default).
@@ -151,56 +150,12 @@ def quantized_allreduce_body(x, error, axis, group_size=2048, num_bits=8,
         return (q, s), deq_rows(q, s).reshape(-1)
 
     (q, scale), _, new_err = error_feedback_step(flat, err, compress)
-    if collective_impl == "decomposed":
-        # ring transport (comm/ring.py): quantization above is
-        # untouched — same rows, same EF residual — only the bytes
-        # move as chunked ppermute chains. Bit-identical to the
-        # all_to_all/all_gather path (source-order delivery).
-        from .ring import decomposed_all_to_all_rows, ring_all_gather
-        q_t = decomposed_all_to_all_rows(
-            q, axis, op_name="domino_ring_allreduce_int8")
-        s_t = decomposed_all_to_all_rows(
-            scale, axis, op_name="domino_ring_allreduce_int8")
-    elif collective_impl == "hierarchical":
-        # mesh-ring transport (comm/hierarchical.py): same int8 rows,
-        # same EF residual, bytes attributed per mesh axis. Source-
-        # order delivery keeps the dequant-accumulate graph identical.
-        from .hierarchical import hierarchical_all_to_all_rows
-        q_t = hierarchical_all_to_all_rows(
-            q, axis, mesh_spec, op_name="domino_hier_allreduce_int8")
-        s_t = hierarchical_all_to_all_rows(
-            scale, axis, mesh_spec, op_name="domino_hier_allreduce_int8")
-    elif collective_impl == "fused":
-        # FUSED reduce-scatter epilogue exchange
-        # (ops/fused_collective_matmul.py): same int8 rows, same EF
-        # residual; payload + scales ride the in-kernel exchange and
-        # log op_kind="fused_permute" byte rows. Source-order delivery
-        # keeps the dequant-accumulate graph identical — bit-identical
-        # to the native int8 body.
-        from ..ops.fused_collective_matmul import fused_qrs_exchange
-        q_t, s_t = fused_qrs_exchange(q, scale, axis_name=axis)
-    else:
-        q_t = jax.lax.all_to_all(q, axis, 0, 0)      # int8 on the wire
-        s_t = jax.lax.all_to_all(scale, axis, 0, 0)
+    q_t = jax.lax.all_to_all(q, axis, 0, 0)          # int8 on the wire
+    s_t = jax.lax.all_to_all(scale, axis, 0, 0)
     part = jnp.sum(deq_rows(q_t, s_t), axis=0)       # local chunk SUM
     q2, s2, pshape, pcount = quantize(part, gsz, num_bits)
-    if collective_impl == "decomposed":
-        q2_a = ring_all_gather(q2, axis,
-                               op_name="domino_ring_allreduce_int8")
-        s2_a = ring_all_gather(s2, axis,
-                               op_name="domino_ring_allreduce_int8")
-    elif collective_impl in ("hierarchical", "fused"):
-        # the broadcast leg has no consuming matmul to fuse into —
-        # "fused" rides the hierarchical mesh rings (normal
-        # collective_permute byte rows: wire honesty)
-        from .hierarchical import hierarchical_all_gather
-        q2_a = hierarchical_all_gather(
-            q2, axis, mesh_spec, op_name="domino_hier_allreduce_int8")
-        s2_a = hierarchical_all_gather(
-            s2, axis, mesh_spec, op_name="domino_hier_allreduce_int8")
-    else:
-        q2_a = jax.lax.all_gather(q2, axis)          # int8 on the wire
-        s2_a = jax.lax.all_gather(s2, axis)
+    q2_a = jax.lax.all_gather(q2, axis)              # int8 on the wire
+    s2_a = jax.lax.all_gather(s2, axis)
     get_comms_logger().log_quantized(
         "domino_half_allreduce_int8",
         q.size + 4 * scale.size + q2.size + 4 * s2.size,

@@ -135,9 +135,8 @@ def _ensure_loaded():
         return
     _loaded = True
     from . import (evoformer_attention, flash_attention,  # noqa: F401
-                   fp_quantizer, fused_collective_matmul, grouped_gemm,
-                   paged_attention, quantized_matmul, quantizer,
-                   rms_norm, rope)
+                   fp_quantizer, grouped_gemm, paged_attention,
+                   quantized_matmul, quantizer, rms_norm, rope)
 
 
 __all__ = ["register_op", "get_op", "get_op_impl", "op_report",

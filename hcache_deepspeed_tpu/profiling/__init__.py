@@ -5,5 +5,4 @@ refutes collective/compute overlap in the compiled program)."""
 from .flops_profiler import (FlopsProfiler, analyze_fn,  # noqa: F401
                              count_params, get_model_profile)
 from .hlo_audit import (AuditReport, audit_compiled,  # noqa: F401
-                        audit_hlo_text, audit_jit,
-                        pod_scale_wire_seconds, wire_cost_seconds)
+                        audit_hlo_text, audit_jit)

@@ -15,16 +15,6 @@ Two evidence tiers, reported side by side and never conflated:
   between start and done — the measured overlap. ``DOMINO_TPU_r4.log``
   is the cautionary tale: a backend may compile ZERO such pairs, which
   is exactly what this tier detects.
-* **in-kernel tier** — fused computation-collective kernels
-  (``ops/fused_collective_matmul.py``) stamp every op they emit with a
-  ``hds_fused*`` ``jax.named_scope``, which XLA threads through to the
-  optimized module's ``metadata op_name``. This tier counts the scoped
-  permute+dot pairs a fused kernel SUBSUMES (each ring step's permute
-  rides beside the previous chunk's dot by construction — no scheduler
-  needed), the fused ``custom-call``s themselves (the Pallas form on a
-  real chip), and the wire bytes moving inside fused scopes. An
-  unfused program reports zero on all three — the differential is the
-  evidence that the fused route compiled, not just traced.
 * **derived pairs** — for backends that keep collectives synchronous
   (the CPU backend at every flag combination we probed; injecting async
   HLO via MHLO ``async_start`` segfaults the CPU compiler), the auditor
@@ -71,12 +61,9 @@ DERIVED_COMPUTE_OPS = ("dot", "convolution")
 
 _HEADER_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*(\(.*\))?\s*"
                         r"(?:->\s*.*?)?\s*{\s*$")
-_STP_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
-_STP_PAIR_RE = re.compile(r"\{(\d+),(\d+)\}")
 _INSTR_RE = re.compile(r"^(ROOT\s+)?(%?[\w.\-]+)\s+=\s+(.*?)"
                        r"([a-z][a-z0-9\-]*)\((.*)$")
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
-_CALLS_RE = re.compile(r"(?:calls|to_apply|called_computation)=%?([\w.\-]+)")
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 
 #: HLO element-type byte widths (sub-byte types fractional)
@@ -89,12 +76,6 @@ _DTYPE_BYTES = {
 
 #: element types that count as a QUANTIZED wire (int8/int4/fp8 payloads)
 _QUANT_DTYPES = ("s8", "u8", "s4", "u4")
-
-#: metadata marker of ops emitted inside a fused computation-collective
-#: kernel's ``jax.named_scope`` (ops/fused_collective_matmul.py
-#: FUSED_SCOPE_GATHER_MM / FUSED_SCOPE_RS) — XLA threads the scope into
-#: the optimized module's per-instruction ``op_name``
-_FUSED_META_RE = re.compile(r'op_name="[^"]*hds_fused[^"]*"')
 
 
 def _type_bytes(type_str: str):
@@ -161,12 +142,6 @@ class Pair:
     done: str           # native: the -done op; derived: == start
     interleaved: int    # dot/fusion ops inside the window / legally free
     provenance: str     # "native" | "derived"
-    #: derived tier only: dependence-free fusions whose called
-    #: computation contains real math (a dot/convolution). Excluded
-    #: from ``interleaved`` (elementwise fusions are free next to
-    #: anything) but counted by the STRUCTURAL tier — a dot-bearing
-    #: fusion really can hide an in-flight permute chunk's wire time.
-    free_fused: int = 0
 
     def to_dict(self):
         return {
@@ -271,13 +246,10 @@ def _native_pairs(comp: Computation) -> List[Pair]:
     return pairs
 
 
-def _derived_pairs(comp: Computation, dot_fusions=frozenset()):
+def _derived_pairs(comp: Computation):
     """(overlappable, sequential) sync collectives, from def-use
-    independence: a dot/fusion that is neither ancestor nor descendant
-    of a collective is legally schedulable inside its window.
-    ``dot_fusions`` is the set of fusion instruction names (in this
-    computation) whose called computation contains a dot/convolution —
-    counted separately as ``free_fused`` for the structural tier."""
+    independence: a dot that is neither ancestor nor descendant of a
+    collective is legally schedulable inside its window."""
     graph = _graph(comp)
     rev = _reverse(graph)
     overlappable, sequential = [], []
@@ -290,202 +262,11 @@ def _derived_pairs(comp: Computation, dot_fusions=frozenset()):
                 if i.opcode in DERIVED_COMPUTE_OPS
                 and i.name != c.name
                 and i.name not in anc and i.name not in desc]
-        n_fused = sum(
-            1 for i in comp.instrs
-            if i.name in dot_fusions
-            and i.name not in anc and i.name not in desc)
         pair = Pair(kind=c.opcode, computation=comp.name,
                     start=c.name, done=c.name,
-                    interleaved=len(free), provenance="derived",
-                    free_fused=n_fused)
+                    interleaved=len(free), provenance="derived")
         (overlappable if free else sequential).append(pair)
     return overlappable, sequential
-
-
-def _dot_fusion_names(comps: List[Computation]) -> Dict[str, set]:
-    """Per computation: names of fusion instructions whose called
-    computation (transitively) contains a dot/convolution. A one-pass
-    fixpoint over the ``calls=`` edges — fused computations are flat in
-    practice, but nested calls cost nothing to honor."""
-    has_math: Dict[str, bool] = {
-        c.name: any(i.opcode in DERIVED_COMPUTE_OPS for i in c.instrs)
-        for c in comps}
-    calls: Dict[str, List[str]] = {}
-    for c in comps:
-        calls[c.name] = []
-        for i in c.instrs:
-            m = _CALLS_RE.search(i.raw)
-            if m:
-                calls[c.name].append(m.group(1))
-    changed = True
-    while changed:
-        changed = False
-        for name, targets in calls.items():
-            if not has_math.get(name) and any(
-                    has_math.get(t) for t in targets):
-                has_math[name] = True
-                changed = True
-    out: Dict[str, set] = {}
-    for c in comps:
-        names = set()
-        for i in c.instrs:
-            if i.opcode != "fusion":
-                continue
-            m = _CALLS_RE.search(i.raw)
-            if m and has_math.get(m.group(1)):
-                names.add(i.name)
-        out[c.name] = names
-    return out
-
-
-def _permute_group_signature(raw: str):
-    """The rank-group PARTITION a ``collective-permute``'s
-    ``source_target_pairs`` induce (union-find over the pairs).
-    ``None`` when the instruction carries no pair list. Compared with
-    :func:`_same_axis` (partition refinement), not equality: a
-    distance-``s`` delivery step splits its ring into ``gcd(s, m)``
-    cosets — finer than the distance-1 partition but still INSIDE the
-    same axis groups — while a different mesh axis's partition crosses
-    them."""
-    m = _STP_RE.search(raw)
-    if not m:
-        return None
-    pairs = [(int(a), int(b)) for a, b in _STP_PAIR_RE.findall(m.group(1))]
-    if not pairs:
-        return None
-    parent: Dict[int, int] = {}
-
-    def find(x):
-        parent.setdefault(x, x)
-        while parent[x] != x:
-            parent[x] = parent[parent[x]]
-            x = parent[x]
-        return x
-
-    for a, b in pairs:
-        ra, rb = find(a), find(b)
-        if ra != rb:
-            parent[ra] = rb
-    comps: Dict[int, List[int]] = {}
-    for rank in parent:
-        comps.setdefault(find(rank), []).append(rank)
-    return frozenset(frozenset(v) for v in comps.values())
-
-
-def _refines(a, b) -> bool:
-    """Partition ``a`` refines ``b``: every component of ``a`` lies
-    inside some component of ``b``."""
-    return all(any(ca <= cb for cb in b) for ca in a)
-
-
-def _same_axis(a, b) -> bool:
-    """Two permute partitions ride the same mesh axis when one refines
-    the other — ring steps, delivery distances, and hpZ sub-runs of
-    one axis all nest inside that axis's groups; a genuinely different
-    axis's groups cross them."""
-    return _refines(a, b) or _refines(b, a)
-
-
-def _cross_axis_pairs(comp: Computation) -> Dict:
-    """CROSS-AXIS permute tier (phase pipelining evidence, ISSUE 15):
-    count pairs of ``collective-permute`` ops that (a) ride DIFFERENT
-    mesh axes (distinct rank-group partitions in their
-    ``source_target_pairs``) and (b) are mutually dependence-free —
-    i.e. chunk k's long-haul phase can be on the wire at the same time
-    as chunk k+1's intra phase, by dataflow construction. An
-    UNPIPELINED hierarchical collective has zero such pairs inside one
-    gather: every long-haul permute consumes the concatenation of ALL
-    intra chunks, so every intra permute is its ancestor. Returns
-    ``{"pairs", "partnered", "permutes"}``."""
-    permutes = [i for i in comp.instrs
-                if i.opcode in ("collective-permute",
-                                "collective-permute-start")]
-    if len(permutes) < 2:
-        return {"pairs": 0, "partnered": 0, "permutes": len(permutes)}
-    sigs = {p.name: _permute_group_signature(p.raw) for p in permutes}
-    graph = _graph(comp)
-    anc = {p.name: _ancestors(graph, p.name) for p in permutes}
-    pairs = 0
-    partnered = set()
-    for i, a in enumerate(permutes):
-        if sigs[a.name] is None:
-            continue
-        for b in permutes[i + 1:]:
-            if sigs[b.name] is None \
-                    or _same_axis(sigs[a.name], sigs[b.name]):
-                continue
-            if a.name in anc[b.name] or b.name in anc[a.name]:
-                continue
-            pairs += 1
-            partnered.add(a.name)
-            partnered.add(b.name)
-    return {"pairs": pairs, "partnered": len(partnered),
-            "permutes": len(permutes)}
-
-
-def _fused_in_kernel(comp: Computation, dot_fusions=frozenset()) -> Dict:
-    """IN-KERNEL tier for one computation: ops stamped with the
-    ``hds_fused*`` scope marker. ``subsumed_pairs`` is
-    ``min(scoped permutes, scoped dots)`` — each ring step of a fused
-    gather-matmul pairs one in-flight permute with one resident-chunk
-    dot BY CONSTRUCTION (the permute's chunk is not the dot's operand),
-    so the pairing needs no scheduler and no dependence analysis; the
-    min is conservative when a schedule is permute- or dot-heavy.
-    Dot-bearing fusions count as dots (CPU folds the dequant-dot into
-    one fusion). ``custom_calls`` counts scoped ``custom-call``s — the
-    Pallas kernel itself on a compiled-for-TPU module. ``wire_bytes``
-    sums the scoped permutes' result buffers (the bytes moving INSIDE
-    the kernel's window)."""
-    scoped = [i for i in comp.instrs if _FUSED_META_RE.search(i.raw)]
-    permutes = [i for i in scoped
-                if i.opcode in ("collective-permute",
-                                "collective-permute-start")]
-    dots = [i for i in scoped
-            if i.opcode in DERIVED_COMPUTE_OPS or i.name in dot_fusions]
-    return {
-        "custom_calls": sum(1 for i in scoped
-                            if i.opcode == "custom-call"),
-        "scoped_permutes": len(permutes),
-        "scoped_dots": len(dots),
-        "subsumed_pairs": min(len(permutes), len(dots)),
-        "wire_bytes": sum(i.result_bytes for i in permutes),
-    }
-
-
-def _permute_chains(comp: Computation) -> List[Dict]:
-    """Group this computation's ``collective-permute`` ops into CHAINS:
-    permutes connected by a def-use path (step ``s`` consumes step
-    ``s-1``'s chunk — the decomposed ring all-gather). Point-to-point
-    delivery permutes that share no path (the decomposed
-    reduce-scatter's distance-``s`` sends) report as length-1 chains.
-    The chain structure is the evidence that a decomposed collective
-    exists in the compiled program, not just in the Python."""
-    permutes = [i for i in comp.instrs
-                if i.opcode in ("collective-permute",
-                                "collective-permute-start")]
-    if not permutes:
-        return []
-    graph = _graph(comp)
-    anc = {p.name: _ancestors(graph, p.name) for p in permutes}
-    parent = {p.name: p.name for p in permutes}
-
-    def find(x):
-        while parent[x] != x:
-            parent[x] = parent[parent[x]]
-            x = parent[x]
-        return x
-
-    for a in permutes:
-        for b in permutes:
-            if a.name != b.name and a.name in anc[b.name]:
-                ra, rb = find(a.name), find(b.name)
-                if ra != rb:
-                    parent[ra] = rb
-    chains: Dict[str, List[str]] = {}
-    for p in permutes:
-        chains.setdefault(find(p.name), []).append(p.name)
-    return [{"computation": comp.name, "length": len(members)}
-            for members in chains.values()]
 
 
 @dataclass
@@ -498,25 +279,7 @@ class AuditReport:
     #: module ``{kind: {bytes, quantized_bytes, count}}`` — the
     #: HLO-measured wire evidence (an int8 wire shows up as s8/u8
     #: buffers here, independent of the trace-time comms attribution).
-    #: ``collective-permute`` rows price the decomposed ring chunks.
     wire_bytes: Dict[str, Dict] = field(default_factory=dict)
-    #: decomposed-ring evidence: every collective-permute CHAIN in the
-    #: module (``[{computation, length}]``; length >= 2 = a ppermute
-    #: step chain, length 1 = a point-to-point delivery send)
-    permute_chains: List[Dict] = field(default_factory=list)
-    #: CROSS-AXIS tier (phase pipelining, ISSUE 15): module-wide
-    #: totals of mutually dependence-free permute pairs riding
-    #: DIFFERENT mesh axes — ``{"pairs", "partnered", "permutes"}``
-    cross_axis: Dict = field(default_factory=lambda: {
-        "pairs": 0, "partnered": 0, "permutes": 0})
-    #: IN-KERNEL tier (fused computation-collective kernels, ISSUE 18):
-    #: module-wide totals over ops stamped with the ``hds_fused*``
-    #: scope marker — ``{"custom_calls", "scoped_permutes",
-    #: "scoped_dots", "subsumed_pairs", "wire_bytes"}``. All zero on an
-    #: unfused module.
-    fused_kernel: Dict = field(default_factory=lambda: {
-        "custom_calls": 0, "scoped_permutes": 0, "scoped_dots": 0,
-        "subsumed_pairs": 0, "wire_bytes": 0})
 
     def pairs(self, kind: Optional[str] = None,
               min_interleaved: int = 1) -> List[Pair]:
@@ -549,39 +312,8 @@ class AuditReport:
             out[p.kind] = out.get(p.kind, 0) + 1
         return out
 
-    def structural_overlap_ratio(self,
-                                 kind: str = "collective-permute") -> float:
-        """STRUCTURAL overlap: the fraction of ``kind`` collectives
-        (the decomposed ring's permute steps) with >= 1 dependence-free
-        dot OR dot-bearing fusion — compute that can hide the in-flight
-        chunk's wire time by dataflow construction, no async scheduler
-        required. Distinct from :meth:`overlap_ratio`'s derived tier in
-        two ways: dot-bearing fusions count (the block math of an
-        already-landed layer often compiles into one), and the name
-        says what the decomposed transport guarantees — the overlap is
-        a property of the program's dependence structure, not of
-        scheduler goodwill. 1.0 on an empty set."""
-        every = self._all(kind)
-        if not every:
-            return 1.0
-        return sum(1 for p in every
-                   if p.interleaved + p.free_fused >= 1) / len(every)
-
-    def cross_axis_overlap_ratio(self) -> float:
-        """Fraction of the module's collective-permutes with at least
-        one dependence-free partner on a DIFFERENT mesh axis — the
-        phase-pipelining evidence (chunk k's long-haul phase live
-        beside chunk k+1's intra phase). 0.0 on a module with no
-        permutes (nothing is phase-pipelined), and 0.0 for any
-        single-axis (flat-ring) program — this tier only scores
-        multi-axis structure."""
-        n = self.cross_axis.get("permutes", 0)
-        if not n:
-            return 0.0
-        return self.cross_axis.get("partnered", 0) / n
-
     def to_row(self) -> Dict:
-        """JSON-safe summary row (the ZERO_OVERLAP.jsonl payload)."""
+        """JSON-safe summary row."""
         return {
             "native_async_pairs": len(self.native_pairs),
             "derived_async_pairs": len(self.derived_pairs),
@@ -592,19 +324,6 @@ class AuditReport:
                 self.overlap_ratio("reduce-scatter"), 4),
             "allreduce_overlap_ratio": round(
                 self.overlap_ratio("all-reduce"), 4),
-            "permute_overlap_ratio": round(
-                self.overlap_ratio("collective-permute"), 4),
-            "structural_overlap_ratio": round(
-                self.structural_overlap_ratio(), 4),
-            "cross_axis_pairs": self.cross_axis.get("pairs", 0),
-            "cross_axis_overlap_ratio": round(
-                self.cross_axis_overlap_ratio(), 4),
-            "fused_custom_calls": self.fused_kernel.get(
-                "custom_calls", 0),
-            "fused_subsumed_pairs": self.fused_kernel.get(
-                "subsumed_pairs", 0),
-            "fused_wire_bytes": self.fused_kernel.get("wire_bytes", 0),
-            "permute_chains": list(self.permute_chains),
             "collective_counts": self.counts(),
             "wire_bytes": self.wire_bytes,
             "pairs": [p.to_dict() for p in
@@ -615,110 +334,16 @@ class AuditReport:
         return json.dumps(self.to_row())
 
 
-# ------------------------------------------------------------------ #
-# Per-axis wire-cost model (ISSUE 12): bytes x declared per-axis link
-# bandwidth -> modeled wire SECONDS. The auditor measures bytes (above,
-# and the comms logger attributes ring-permute bytes per mesh axis via
-# CommsLogger.total_axis_bytes()); this prices them against a DECLARED
-# mesh spec — a model input (what the target pod's links do), never a
-# measurement. Everything is plain dicts so the auditor stays
-# stdlib-only and the spec can come from config, bench, or a test.
-# ------------------------------------------------------------------ #
-
-def wire_cost_seconds(axis_bytes: Dict[str, float],
-                      axis_gbytes_per_s: Dict[str, float],
-                      calibration: str = "declared") -> Dict:
-    """Price per-axis wire bytes in seconds: ``bytes / (GB/s * 1e9)``
-    per axis. Axes with no declared bandwidth report ``seconds: None``
-    (unpriceable is not free — the row stays visible). Returns
-    ``{"per_axis": {axis: {bytes, gbytes_per_s, seconds}},
-    "total_seconds", "bottleneck_axis", "calibration"}`` —
-    ``total_seconds`` sums the priced axes (serialized-wire upper
-    bound; phases on different axes may overlap on hardware),
-    ``bottleneck_axis`` is the slowest. ``calibration`` labels WHERE
-    the bandwidths came from — ``"declared"`` (a model input) or
-    ``"measured"`` (``comm/benchmark.py calibrate_mesh_axes`` wall
-    clock) — and rides in the row so a projection can never pass
-    itself off as a measurement (ISSUE 15 satellite)."""
-    per_axis = {}
-    total = 0.0
-    bottleneck, worst = None, -1.0
-    for axis, nbytes in sorted(axis_bytes.items()):
-        bw = axis_gbytes_per_s.get(axis)
-        seconds = None
-        if bw:
-            seconds = float(nbytes) / (float(bw) * 1e9)
-            total += seconds
-            if seconds > worst:
-                bottleneck, worst = axis, seconds
-        per_axis[axis] = {"bytes": int(nbytes),
-                          "gbytes_per_s": bw,
-                          "seconds": seconds}
-    return {"per_axis": per_axis,
-            "total_seconds": total,
-            "bottleneck_axis": bottleneck,
-            "calibration": calibration}
-
-
-def pod_scale_wire_seconds(axis_bytes: Dict[str, float],
-                           toy_axis_sizes: Dict[str, int],
-                           pod_axis_sizes: Dict[str, int],
-                           axis_gbytes_per_s: Dict[str, float],
-                           calibration: str = "declared") -> Dict:
-    """Project toy-mesh per-axis wire bytes to a pod-scale mesh and
-    price them: a ring phase over an axis of size ``k`` makes ``k - 1``
-    sends of the same per-device payload, so bytes scale by
-    ``(K - 1) / (k - 1)`` when the axis grows ``k -> K`` with the
-    per-device payload held fixed (the ZeRO case: shard sizes are set
-    per device, not per world). That is the whole model — declared,
-    deliberately simple, and labeled as such in the artifact row via
-    ``assumption``; the projection TARGET is configurable (``--pod-
-    shape`` in bench), never hard-coded here. Returns the
-    :func:`wire_cost_seconds` dict plus ``{"scaled_axis_bytes",
-    "assumption", "pod_axis_sizes", "toy_axis_sizes"}`` and the
-    ``calibration`` source label."""
-    scaled = {}
-    for axis, nbytes in axis_bytes.items():
-        k = toy_axis_sizes.get(axis)
-        K = pod_axis_sizes.get(axis)
-        if k and K and k > 1:
-            scaled[axis] = float(nbytes) * (K - 1) / (k - 1)
-        else:
-            scaled[axis] = float(nbytes)
-    out = wire_cost_seconds(scaled, axis_gbytes_per_s,
-                            calibration=calibration)
-    out["scaled_axis_bytes"] = {a: int(b) for a, b in scaled.items()}
-    out["assumption"] = ("ring bytes scale (K-1)/(k-1) per axis at "
-                         "fixed per-device payload")
-    out["toy_axis_sizes"] = dict(toy_axis_sizes)
-    out["pod_axis_sizes"] = dict(pod_axis_sizes)
-    return out
-
-
 def audit_hlo_text(text: str) -> AuditReport:
     """Audit one optimized-HLO module's async-overlap structure."""
     native, derived, sequential = [], [], []
-    chains: List[Dict] = []
     wire: Dict[str, Dict] = {}
-    cross = {"pairs": 0, "partnered": 0, "permutes": 0}
-    fused = {"custom_calls": 0, "scoped_permutes": 0, "scoped_dots": 0,
-             "subsumed_pairs": 0, "wire_bytes": 0}
     comps = parse_hlo_computations(text)
-    dot_fusions = _dot_fusion_names(comps)
     for comp in comps:
         native.extend(_native_pairs(comp))
-        over, seq = _derived_pairs(comp,
-                                   dot_fusions.get(comp.name, frozenset()))
+        over, seq = _derived_pairs(comp)
         derived.extend(over)
         sequential.extend(seq)
-        chains.extend(_permute_chains(comp))
-        ca = _cross_axis_pairs(comp)
-        for k in cross:
-            cross[k] += ca[k]
-        fk = _fused_in_kernel(comp,
-                              dot_fusions.get(comp.name, frozenset()))
-        for k in fused:
-            fused[k] += fk[k]
         for i in comp.instrs:
             if not (i.is_collective or i.opcode.endswith("-start")):
                 continue
@@ -732,9 +357,7 @@ def audit_hlo_text(text: str) -> AuditReport:
             rec["count"] += 1
     return AuditReport(native_pairs=native, derived_pairs=derived,
                        sequential_collectives=sequential,
-                       computations=len(comps), wire_bytes=wire,
-                       permute_chains=chains, cross_axis=cross,
-                       fused_kernel=fused)
+                       computations=len(comps), wire_bytes=wire)
 
 
 def audit_compiled(compiled) -> AuditReport:

@@ -127,79 +127,6 @@ class ZeroConfig(HDSConfigModel):
     #: materializes for eligible (Dense-kernel) qwZ leaves. Requires
     #: ``zero_quantized_weights``.
     zero_quantized_weights_fused_matmul: bool = False
-    #: Collective TRANSPORT of the layered ZeRO-3 lanes (and of
-    #: ``domino_split_async`` when asked): ``"native"`` issues
-    #: monolithic ``all_gather``/``psum_scatter``/``all_to_all`` ops
-    #: and relies on the backend's latency-hiding scheduler to overlap
-    #: them (which ``DOMINO_TPU_r4.log`` proved can silently not
-    #: happen); ``"decomposed"`` re-expresses them as chunked
-    #: ``ppermute`` ring chains (``comm/ring.py``) whose steps are
-    #: dependence-free of block compute by dataflow construction —
-    #: bitwise-equal to native, structural overlap scored by
-    #: ``hlo_audit.structural_overlap_ratio``; ``"hierarchical"``
-    #: factors the flat data axis into a declared multi-axis mesh
-    #: (``zero_mesh_shape``) and runs per-axis grouped ring phases
-    #: (``comm/hierarchical.py``) — still bitwise-equal, with wire
-    #: bytes attributed per mesh axis and the long-haul axis
-    #: quantizable on its own (``zero_longhaul_wire_bits``);
-    #: ``"fused"`` is the IN-KERNEL tier (ROADMAP item 3,
-    #: ``ops/fused_collective_matmul.py``): bucket transports ride the
-    #: hierarchical mesh rings, but each qwZ matmul leaf stays a
-    #: mid-gather shard consumed by the fused gather-matmul kernel at
-    #: its Dense (chunk k's partial dot overlaps chunk k+1's in-kernel
-    #: permute), and the quantized reduce lane folds through the fused
-    #: quantize+error-feedback epilogue — bitwise-equal to the unfused
-    #: pipeline via the transport-swap twin contract.
-    #: Decomposed/hierarchical/fused require the layered step, a data
-    #: axis > 1, and ``overlap_comm=true``; hierarchical and fused
-    #: additionally need ``zero_mesh_shape`` to factor the data world
-    #: size exactly (validated with typed errors, no silent
-    #: fallthrough).
-    zero_collective_impl: str = "native"
-    #: Mesh factoring of the flat data axis for the hierarchical
-    #: transport, outer (long-haul) axis first — e.g. ``[2, 4]`` on 8
-    #: devices, ``[16, 16]`` on a v5e-256 pod. Every axis must have
-    #: size >= 2 and the product must equal the data world size.
-    zero_mesh_shape: Optional[List[int]] = None
-    #: Names for the mesh axes (default ``["inter", "intra"]`` for 2-D
-    #: meshes): the labels wire bytes are attributed under
-    #: (``CommsLogger.permute_axis_bytes``) and the per-axis wire-cost
-    #: model prices.
-    zero_mesh_axis_names: Optional[List[str]] = None
-    #: Declared per-axis link bandwidth (GB/s per device) for the
-    #: wire-cost model — a MODEL input (what the pod's links do), not a
-    #: measurement; aligned with ``zero_mesh_shape``.
-    zero_mesh_link_gbps: Optional[List[float]] = None
-    #: Parallelism ROLE per mesh axis (``data`` / ``model`` / ``pipe``
-    #: / ``expert``, aligned with ``zero_mesh_shape``; default: all
-    #: ``data``). Non-data roles declare a COMPOSED multi-parallelism
-    #: factoring — e.g. ``["data", "model", "pipe"]`` for the 3-D
-    #: v5e-256 target: the ZeRO collectives (and the fused kernel's
-    #: ring) ride only the data-role axes
-    #: (``HierMeshSpec.zero_subspec``), and the data-axis product must
-    #: factor the data world size. At least one axis must be ``data``.
-    zero_mesh_axis_roles: Optional[List[str]] = None
-    #: Which mesh axis is the slow/long-haul wire (default: the
-    #: outermost). Must name a declared axis — an unknown name is a
-    #: typed config error, not a silent fallback.
-    zero_longhaul_axis: Optional[str] = None
-    #: Axis-selective quantization (EQuARX's bandwidth-proportional
-    #: scheme): ship the LONG-HAUL phase of hierarchical gathers
-    #: int8 (8) or nibble-packed int4 (4) + fp32 group scales, full
-    #: width on the fast axis. ``null`` = full width everywhere.
-    #: Requires ``zero_collective_impl: hierarchical``.
-    zero_longhaul_wire_bits: Optional[int] = None
-    #: PHASE PIPELINING of the hierarchical collectives: split every
-    #: gather/exchange payload into this many column chunks, each
-    #: riding its own full intra->long-haul phase chain — chunk k's
-    #: long-haul phase is structurally independent of chunk k+1's intra
-    #: phase (the PR 9 def-use discipline applied ACROSS mesh axes),
-    #: scored by ``hlo_audit``'s cross-axis permute-pair tier. 1 =
-    #: unpipelined (phases back to back). Full-width results are
-    #: bitwise-identical at any chunk count; a quantized long-haul
-    #: wire quantizes per chunk (deterministic, trajectory-gated).
-    #: Requires ``zero_collective_impl: hierarchical``.
-    zero_mesh_pipeline_chunks: int = Field(1, ge=1)
     #: ZeRO++ stage-3 gather granularity: scan-over-layers (gather one
     #: block at a time inside the micro step) when the model provides a
     #: layered spec (models/layered.py). False forces the whole-tree
@@ -216,70 +143,6 @@ class ZeroConfig(HDSConfigModel):
         # combinations (stage interplay re-checked at engine build,
         # where the topology is known)
         from .zero.overlap import validate_quantized_wire
-        if self.zero_collective_impl not in ("native", "decomposed",
-                                             "hierarchical", "fused"):
-            raise HDSConfigError(
-                f"zero_collective_impl="
-                f"{self.zero_collective_impl!r}: expected 'native' "
-                f"(monolithic collectives), 'decomposed' (chunked "
-                f"ppermute ring transport, comm/ring.py), "
-                f"'hierarchical' (multi-axis mesh rings, "
-                f"comm/hierarchical.py) or 'fused' (in-kernel "
-                f"gather-matmul / reduce-scatter epilogue, "
-                f"ops/fused_collective_matmul.py)")
-        if self.zero_collective_impl in ("decomposed", "hierarchical",
-                                         "fused") \
-                and not self.overlap_comm:
-            # world-size interplay is re-checked at engine build
-            # (validate_overlap_config), where the topology is known;
-            # the overlap_comm contradiction is knowable right here
-            raise HDSConfigError(
-                f"zero_collective_impl={self.zero_collective_impl} "
-                "with overlap_comm=false: the decomposed transports "
-                "exist to make overlap structural — enable "
-                "overlap_comm or use zero_collective_impl=native")
-        if self.zero_collective_impl in ("hierarchical", "fused"):
-            # shape/name sanity is knowable at parse time (the
-            # world-size product check needs the topology: engine
-            # build re-validates via validate_overlap_config)
-            from ..comm.hierarchical import make_mesh_spec
-            if self.zero_mesh_shape is None:
-                raise HDSConfigError(
-                    f"zero_collective_impl="
-                    f"{self.zero_collective_impl} needs "
-                    f"zero_mesh_shape (the mesh factoring of the data "
-                    f"axis, outer/long-haul axis first — e.g. [2, 4])")
-            spec = make_mesh_spec(
-                self.zero_mesh_shape, self.zero_mesh_axis_names,
-                self.zero_mesh_link_gbps, self.zero_longhaul_axis,
-                self.zero_mesh_axis_roles)
-            if self.zero_longhaul_wire_bits is not None \
-                    and self.zero_longhaul_wire_bits not in (4, 8):
-                raise HDSConfigError(
-                    f"zero_longhaul_wire_bits="
-                    f"{self.zero_longhaul_wire_bits}: the long-haul "
-                    f"wire ships int8 or nibble-packed int4 — use 8, "
-                    f"4, or null for full width")
-            del spec
-        else:
-            for knob in ("zero_mesh_shape", "zero_longhaul_axis",
-                         "zero_longhaul_wire_bits",
-                         "zero_mesh_axis_roles"):
-                if getattr(self, knob) is not None:
-                    raise HDSConfigError(
-                        f"{knob} has no effect without a mesh "
-                        f"transport (zero_collective_impl=hierarchical "
-                        f"or fused); set the transport or drop the "
-                        f"knob (no silent ignores)")
-            if self.zero_mesh_pipeline_chunks != 1:
-                raise HDSConfigError(
-                    f"zero_mesh_pipeline_chunks="
-                    f"{self.zero_mesh_pipeline_chunks} has no effect "
-                    f"without a mesh transport "
-                    f"(zero_collective_impl=hierarchical or fused — "
-                    f"phase pipelining overlaps a gather's intra and "
-                    f"long-haul PHASES); set the transport or drop "
-                    f"the knob (no silent ignores)")
         validate_quantized_wire(
             quantized_reduce_scatter=self.zero_quantized_reduce_scatter,
             error_feedback=self.zero_reduce_scatter_error_feedback,

@@ -36,9 +36,8 @@ Evidence (``tests/unit/runtime/test_domino_hlo.py``), not assertion:
 ``compute_fn`` + ``collective_fn`` and the half-batch all-reduces are
 routed through :class:`comm.overlap.CollectiveIssue` — issued in
 program order between the halves' compute, auditable with
-``profiling/hlo_audit.py`` (``bench.py --zero-overlap`` re-runs that
-audit and records the numbers in ``ZERO_OVERLAP.jsonl``), and honoring
-``overlap=False`` as a fenced serialization instead of a no-op.
+``profiling/hlo_audit.py``, and honoring ``overlap=False`` as a real
+serialization (the unsplit layer) instead of a no-op.
 """
 
 import jax.numpy as jnp
@@ -66,9 +65,7 @@ def domino_split(layer_fn, x, *args, **kwargs):
 
 def domino_split_async(compute_fn, collective_fn, x, *args,
                        overlap=True, wire_bits=None, axis=None,
-                       wire_error=None, group_size=2048,
-                       collective_impl="native", mesh_spec=None,
-                       **kwargs):
+                       wire_error=None, group_size=2048, **kwargs):
     """Half-batch split with the collective EXPLICITLY issued through
     :class:`comm.overlap.CollectiveIssue` instead of buried inside an
     opaque layer function — the reference's hand-scheduled form
@@ -102,71 +99,8 @@ def domino_split_async(compute_fn, collective_fn, x, *args,
     seeds zeros) — and the return becomes
     ``(y, (e0_new, e1_new))`` for the caller to thread. Must run
     inside the shard_map region, like the plain collective.
-
-    ``collective_impl="decomposed"`` replaces each half's all-reduce
-    with a decomposed reduce-scatter + ring all-gather built from
-    chunked ``ppermute`` chains (``comm/ring.py ring_all_reduce_sum``)
-    — the two derived-legal pairs overlap *without* native async
-    support: every permute step of half 0's ring is dependence-free of
-    half 1's dots by dataflow, the structure ``DOMINO_TPU_r4.log``
-    showed XLA would not synthesize on its own. Requires ``axis`` (the
-    mesh axis the layer reduces over); ``collective_fn`` is ignored in
-    favor of the ring. Value-equivalent to the native ``psum``
-    (index-order fold, fp32-accumulated); composed with ``wire_bits``
-    the int8 body's two collectives ride rings instead — bit-identical
-    to the native int8 body (quantization happens before the transport
-    choice).
-
-    ``collective_impl="hierarchical"`` additionally needs ``mesh_spec``
-    (``comm.hierarchical.make_mesh_spec``): each half's all-reduce runs
-    as per-mesh-axis grouped ring phases (hierarchical reduce-scatter +
-    all-gather), bitwise-equal to the flat rings with wire bytes
-    attributed to the mesh axis they ride — the 2-D torus form of the
-    same scheduler-independent overlap.
-
-    ``collective_impl="fused"``: the full-width all-reduce rides the
-    hierarchical mesh rings (the transport twin — an all-reduce has no
-    consuming matmul to fuse into), and composed with ``wire_bits`` the
-    int8 body's reduce exchange runs the FUSED reduce-scatter epilogue
-    (``ops/fused_collective_matmul.fused_qrs_exchange`` — in-kernel
-    ``fused_permute`` byte rows), bit-identical to the native int8 body.
     """
     B = x.shape[0]
-    if collective_impl not in ("native", "decomposed", "hierarchical",
-                               "fused"):
-        raise ValueError(f"collective_impl={collective_impl!r}: "
-                         f"expected 'native', 'decomposed', "
-                         f"'hierarchical' or 'fused'")
-    if collective_impl in ("decomposed", "hierarchical", "fused"):
-        if axis is None:
-            raise ValueError(
-                f"domino_split_async(collective_impl="
-                f"{collective_impl!r}) needs the mesh axis the layer "
-                f"reduces over (axis=...)")
-        if collective_impl in ("hierarchical", "fused") \
-                and mesh_spec is None:
-            raise ValueError(
-                f"domino_split_async(collective_impl="
-                f"{collective_impl!r}) needs the declared mesh "
-                f"factoring (mesh_spec=..., "
-                f"comm.hierarchical.make_mesh_spec)")
-        if wire_bits is None:
-            if collective_impl == "decomposed":
-                from ..comm.ring import ring_all_reduce_sum
-
-                def collective_fn(t):
-                    return ring_all_reduce_sum(
-                        t, axis, op_name="domino_ring_allreduce")
-            else:
-                # hierarchical RS+AG mesh rings: per-axis grouped
-                # phases, destination index-order fold — bitwise-equal
-                # to the flat rings, value-equal to psum
-                from ..comm.hierarchical import hierarchical_all_reduce_sum
-
-                def collective_fn(t):
-                    return hierarchical_all_reduce_sum(
-                        t, axis, mesh_spec,
-                        op_name="domino_hier_allreduce")
     if wire_bits is not None:
         if axis is None:
             raise ValueError(
@@ -176,8 +110,7 @@ def domino_split_async(compute_fn, collective_fn, x, *args,
 
         def q_collective(t, e):
             return quantized_allreduce_body(
-                t, e, axis, group_size=group_size, num_bits=wire_bits,
-                collective_impl=collective_impl, mesh_spec=mesh_spec)
+                t, e, axis, group_size=group_size, num_bits=wire_bits)
 
         if B < 2 or not overlap:
             t = compute_fn(x, *args, **kwargs)
@@ -221,28 +154,22 @@ class DominoTransformer:
 
     def __init__(self, layer_fn=None, *, compute_fn=None,
                  collective_fn=None, overlap=True, wire_bits=None,
-                 axis=None, collective_impl="native"):
+                 axis=None):
         if (layer_fn is None) == (compute_fn is None):
             raise ValueError(
                 "pass either layer_fn (opaque form) or compute_fn + "
                 "collective_fn (explicit async-issue form)")
-        if compute_fn is not None and collective_fn is None \
-                and collective_impl != "decomposed":
+        if compute_fn is not None and collective_fn is None:
             raise ValueError("compute_fn requires collective_fn")
         if wire_bits is not None and compute_fn is None:
             raise ValueError("wire_bits needs the explicit "
                              "compute_fn + collective_fn form")
-        if collective_impl == "decomposed" and compute_fn is None:
-            raise ValueError("collective_impl='decomposed' needs the "
-                             "explicit compute_fn form (the collective "
-                             "must be ours to decompose)")
         self.layer_fn = layer_fn
         self.compute_fn = compute_fn
         self.collective_fn = collective_fn
         self.overlap = overlap
         self.wire_bits = wire_bits
         self.axis = axis
-        self.collective_impl = collective_impl
 
     def __call__(self, x, *args, **kwargs):
         if self.layer_fn is not None:
@@ -250,6 +177,4 @@ class DominoTransformer:
         return domino_split_async(self.compute_fn, self.collective_fn,
                                   x, *args, overlap=self.overlap,
                                   wire_bits=self.wire_bits,
-                                  axis=self.axis,
-                                  collective_impl=self.collective_impl,
-                                  **kwargs)
+                                  axis=self.axis, **kwargs)

@@ -276,16 +276,10 @@ class HDSEngine:
         self._batch_spec_fn = batch_spec_fn
 
         # ---- ZeRO++ (qwZ / qgZ / hpZ / quantized reduce-scatter) ----
-        # a non-native collective transport (decomposed rings,
-        # hierarchical mesh rings) also engages the explicit step: the
-        # transports only exist on its hand-written gather/reduce
-        # lanes, and silently running GSPMD-native instead would be
-        # exactly the fallthrough the config validation forbids
         self._zeropp = (zcfg.zero_quantized_weights
                         or zcfg.zero_quantized_gradients
                         or zcfg.zero_hpz_partition_size > 1
-                        or zcfg.zero_quantized_reduce_scatter
-                        or zcfg.zero_collective_impl != "native")
+                        or zcfg.zero_quantized_reduce_scatter)
         if self._zeropp:
             from .config import HDSConfigError
             from .zero.zeropp import validate_zeropp
@@ -1627,8 +1621,8 @@ class HDSEngine:
         (``profiling/hlo_audit.py``): native async start/done pairs and
         the derived (dependence-legal) schedule. Returns
         ``(AuditReport, row)`` where ``row`` is the JSON-safe summary
-        merged with :attr:`zero_overlap_plan` — the ``ZERO_OVERLAP.jsonl``
-        payload. None on the GSPMD path (no explicit program to audit).
+        merged with :attr:`zero_overlap_plan`. None on the GSPMD path
+        (no explicit program to audit).
         Emits a ``zero.overlap.audit`` tracer instant with the span-level
         gather/reduce overlap ratios."""
         if not self._zeropp:

@@ -167,14 +167,7 @@ def validate_overlap_config(*, reduce_bucket_elements: int = 0,
                             max_live_parameters: int = 0,
                             layer_params: int = 0,
                             outer_params: int = 0,
-                            knob: str = "reduce_bucket_size",
-                            collective_impl: Optional[str] = None,
-                            world_size: int = 0,
-                            overlap_comm: bool = True,
-                            mesh_spec=None,
-                            longhaul_bits: Optional[int] = None,
-                            hpz: int = 1,
-                            pipeline_chunks: int = 1) -> None:
+                            knob: str = "reduce_bucket_size") -> None:
     """Build-time rejection of nonsensical overlap knobs — a clear
     error instead of the silent clamping the knobs used to get.
 
@@ -185,64 +178,8 @@ def validate_overlap_config(*, reduce_bucket_elements: int = 0,
     * ``stage3_max_live_parameters`` smaller than one layer + the
       outer (embedding/head) leaves cannot run the layered step at all
       (depth 0 already keeps that much alive). Reject.
-    * ``zero_collective_impl="decomposed"`` (the chunked-ppermute ring
-      transport, ``comm/ring.py``) with a data world size of 1 has no
-      ring to decompose — every "permute" would be a self-send — and
-      with ``overlap_comm=False`` it contradicts itself: the point of
-      the decomposition is structural overlap, and the serialization
-      fallback deliberately puts every collective on the critical
-      path. Both are rejected with a typed error, no silent
-      fallthrough to the native transport.
     """
     from ..config import HDSConfigError
-    if collective_impl in ("decomposed", "hierarchical", "fused"):
-        if world_size == 1:
-            raise HDSConfigError(
-                f"zero_collective_impl={collective_impl} with data "
-                f"world size 1: a one-device ring has no permutes to "
-                f"decompose into — use zero_collective_impl=native "
-                f"(or a data axis > 1)")
-        if not overlap_comm:
-            raise HDSConfigError(
-                f"zero_collective_impl={collective_impl} with "
-                f"overlap_comm=false: the decomposed transports exist "
-                f"to make comm/compute overlap structural, and "
-                f"overlap_comm=false is the explicit serialization "
-                f"fallback — enable overlap_comm or use "
-                f"zero_collective_impl=native")
-    if collective_impl in ("hierarchical", "fused"):
-        from ...comm.hierarchical import hpz_tier_dims, validate_mesh_spec
-        if mesh_spec is None:
-            raise HDSConfigError(
-                f"zero_collective_impl={collective_impl} needs "
-                f"zero_mesh_shape (the mesh factoring of the data "
-                f"axis); declare it — the transport never guesses a "
-                f"factoring")
-        if hpz > 1:
-            # UNIFIED hpZ tiering (ISSUE 15): hpZ's secondary groups
-            # map onto the mesh's innermost axes — per-micro gathers
-            # ride the fast tier's grouped rings, the secondary refresh
-            # rides the full mesh. Only GENUINE mismatches (hpz neither
-            # a divisor nor a whole-axis multiple of the fast-tier
-            # axes) are rejected, by hpz_tier_dims itself.
-            hpz_tier_dims(mesh_spec, hpz)
-        if world_size:
-            validate_mesh_spec(mesh_spec, world_size=world_size,
-                               longhaul_bits=longhaul_bits)
-    if pipeline_chunks != 1:
-        if pipeline_chunks < 1:
-            raise HDSConfigError(
-                f"zero_mesh_pipeline_chunks={pipeline_chunks}: the "
-                f"phase pipeline needs a positive chunk count (1 = "
-                f"unpipelined)")
-        if collective_impl not in ("hierarchical", "fused"):
-            raise HDSConfigError(
-                f"zero_mesh_pipeline_chunks={pipeline_chunks} has no "
-                f"effect without a mesh transport "
-                f"(zero_collective_impl=hierarchical or fused — phase "
-                f"pipelining overlaps a gather's intra and long-haul "
-                f"PHASES; flat transports have one phase); set the "
-                f"transport or drop the knob")
     if largest_leaf > reduce_bucket_elements:
         name = f" ({largest_leaf_name})" if largest_leaf_name else ""
         raise HDSConfigError(

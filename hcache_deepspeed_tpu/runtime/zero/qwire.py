@@ -91,28 +91,11 @@ def plan_wire_residual_widths(sizes, dims, *, bucket_elements,
 
 
 def _quantized_wide_reduce(wide, residual, *, group_size, bits,
-                           equiv_bytes, collective_impl="native",
-                           mesh_spec=None, pipeline_chunks=1):
+                           equiv_bytes):
     """One bucket: ``wide`` is the full ``[n, W]`` cotangent buffer
     (row j -> device j). Returns ``(mean [W] fp32,
     new_residual [n, W] fp32)``. ``residual`` None means error
-    feedback off (the quantization error is dropped, not carried).
-
-    ``collective_impl="decomposed"`` replaces the two ``all_to_all``s
-    with per-row ``ppermute`` delivery (``comm/ring.py``): rows are
-    quantized per ring chunk exactly as before (same group layout,
-    same EF residual semantics — quantization happens BEFORE the
-    transport choice), shipped point-to-point, and reordered to source
-    order on arrival, so the dequant-accumulate is the same local
-    computation graph as the native path — bitwise-equal.
-
-    ``collective_impl="fused"`` runs the FUSED EPILOGUE
-    (``ops/fused_collective_matmul.py``): the quantize + error-feedback
-    trio folds through one ``fused_quant_ef`` op (Pallas on TPU, the
-    bitwise host twin elsewhere — same bucket layout, same residual
-    state, so depth parity stays bitwise) and the wire rides
-    :func:`~...ops.fused_collective_matmul.fused_qrs_exchange`
-    (source-order direct delivery, ``fused_permute`` byte rows)."""
+    feedback off (the quantization error is dropped, not carried)."""
     n, W = wide.shape
     gsz = max(1, min(group_size, W))
     num_bits = 4 if bits == 4 else 8
@@ -131,14 +114,8 @@ def _quantized_wide_reduce(wide, residual, *, group_size, bits,
         return (q, s), deq_rows(q, s)
 
     if residual is not None:
-        if collective_impl == "fused" and W % gsz == 0:
-            from ...ops import get_op
-            q, s_flat, new_residual = get_op("fused_quant_ef")(
-                wide, residual, group_size=gsz, num_bits=num_bits)
-            scale = s_flat[..., None]
-        else:
-            (q, scale), _, new_residual = error_feedback_step(
-                wide, residual, compress)
+        (q, scale), _, new_residual = error_feedback_step(
+            wide, residual, compress)
     else:
         q, scale = quant_rows(wide)
         new_residual = None
@@ -147,33 +124,8 @@ def _quantized_wide_reduce(wide, residual, *, group_size, bits,
         QRS_OP,
         payload.size * payload.dtype.itemsize + 4 * scale.size,
         equiv_bytes, (DATA_AXIS,))
-    if collective_impl == "decomposed":
-        from ...comm.ring import decomposed_all_to_all_rows
-        payload_t = decomposed_all_to_all_rows(
-            payload, DATA_AXIS, op_name="zero_ring_qrs")
-        scale_t = decomposed_all_to_all_rows(
-            scale, DATA_AXIS, op_name="zero_ring_qrs")
-    elif collective_impl == "fused":
-        from ...ops.fused_collective_matmul import fused_qrs_exchange
-        payload_t, scale_t = fused_qrs_exchange(
-            payload, scale, axis_name=DATA_AXIS)
-    elif collective_impl == "hierarchical":
-        # per-mesh-axis grouped delivery of the SAME int8 payload +
-        # scales (quantization still happens before the transport
-        # choice, EF residuals untouched) — source-order arrival, so
-        # the dequant-accumulate below is the same local graph:
-        # bitwise-equal to the native and flat-ring qrs wires, with
-        # every byte attributed to the mesh axis it rides
-        from ...comm.hierarchical import hierarchical_all_to_all_rows
-        payload_t = hierarchical_all_to_all_rows(
-            payload, DATA_AXIS, mesh_spec,
-            pipeline_chunks=pipeline_chunks, op_name="zero_hier_qrs")
-        scale_t = hierarchical_all_to_all_rows(
-            scale, DATA_AXIS, mesh_spec,
-            pipeline_chunks=pipeline_chunks, op_name="zero_hier_qrs")
-    else:
-        payload_t = jax.lax.all_to_all(payload, DATA_AXIS, 0, 0)
-        scale_t = jax.lax.all_to_all(scale, DATA_AXIS, 0, 0)
+    payload_t = jax.lax.all_to_all(payload, DATA_AXIS, 0, 0)
+    scale_t = jax.lax.all_to_all(scale, DATA_AXIS, 0, 0)
     q_t = unpack_int4(payload_t, q.shape[-1]) if bits == 4 else payload_t
     red = jnp.mean(deq_rows(q_t, scale_t), axis=0)      # [W] fp32
     return red, new_residual
@@ -182,10 +134,7 @@ def _quantized_wide_reduce(wide, residual, *, group_size, bits,
 def quantized_bucket_reduce_scatter_mean(flat, dims, *, bucket_elements,
                                          group_size, bits=8,
                                          residuals: Optional[list] = None,
-                                         error_feedback=True,
-                                         collective_impl="native",
-                                         mesh_spec=None,
-                                         pipeline_chunks=1):
+                                         error_feedback=True):
     """Bucketed QUANTIZED reduce-mean of the sharded leaves of ``flat``
     (full cotangents) onto their data-axis shards — the qgZ all-to-all
     topology at IPG-bucket granularity, one collective pair (payload +
@@ -228,8 +177,7 @@ def quantized_bucket_reduce_scatter_mean(flat, dims, *, bucket_elements,
                 else jnp.zeros(wide.shape, jnp.float32)
         red, nr = _quantized_wide_reduce(
             wide, res, group_size=group_size, bits=bits,
-            equiv_bytes=equiv_bytes, collective_impl=collective_impl,
-            mesh_spec=mesh_spec, pipeline_chunks=pipeline_chunks)
+            equiv_bytes=equiv_bytes)
         if error_feedback:
             new_res.append(nr)
         off = 0

@@ -1,4 +1,4 @@
-"""ZeRO++ — quantized / hierarchical collectives wired into the train step.
+"""ZeRO++ — quantized / hpZ collectives wired into the train step.
 
 Reference analogs:
 * ``deepspeed/runtime/engine.py:994-1008`` — the ``zero_quantized_weights``
@@ -108,21 +108,15 @@ def _log_wire(op, n_int8, n_scale_f32, equiv_bytes):
         (DATA_AXIS,))
 
 
-def _quantized_all_gather_dim(x, dim, *, group_size, axis_index_groups=None,
-                              gather_fn=None):
+def _quantized_all_gather_dim(x, dim, *, group_size, axis_index_groups=None):
     """int8-wire all-gather of ``x`` along named DATA_AXIS into dim
-    ``dim``. ``gather_fn`` overrides the transport (the hierarchical
-    mesh rings pass one): any ``arr -> [n_g, *arr.shape]`` stacked
-    gather in group-rank order — the int8 payload + scales are pure
-    data movement, so the swap is bitwise-free."""
+    ``dim``."""
     group_size = min(group_size, x.size)  # avoid pad blowup on small leaves
     q, scale, shape, count = quantize(x, group_size=group_size, num_bits=8)
-    if gather_fn is None:
-        def gather_fn(arr):
-            return jax.lax.all_gather(arr, DATA_AXIS,
-                                      axis_index_groups=axis_index_groups)
-    q_all = gather_fn(q)
-    s_all = gather_fn(scale)
+    q_all = jax.lax.all_gather(q, DATA_AXIS,
+                               axis_index_groups=axis_index_groups)
+    s_all = jax.lax.all_gather(scale, DATA_AXIS,
+                               axis_index_groups=axis_index_groups)
     _log_wire("qwZ_all_gather", q.size, scale.size,
               x.size * x.dtype.itemsize)
     deq = jax.vmap(lambda qi, si: dequantize(qi, si, shape, count))(
@@ -159,25 +153,11 @@ def _quant_reduce_mean_dim(g, dim, *, group_size):
     return jnp.moveaxis(jnp.mean(deq, axis=0), 0, dim)
 
 
-def _psum_scatter_mean_dim(g, dim, collective_impl="native",
-                           mesh_spec=None, pipeline_chunks=1):
+def _psum_scatter_mean_dim(g, dim):
     n = jax.lax.axis_size(DATA_AXIS)
     _log_plain("zero_reduce_scatter", g.size * g.dtype.itemsize)
-    gm = jnp.moveaxis(g, dim, 0)
-    if collective_impl == "decomposed":
-        from ...comm.ring import decomposed_reduce_scatter_sum
-        out = decomposed_reduce_scatter_sum(
-            gm, DATA_AXIS, op_name="zero_ring_reduce_scatter")
-    elif collective_impl in ("hierarchical", "fused"):
-        # fused rides the hierarchical twin for the fp reduce lane —
-        # the fused epilogue applies to the QUANTIZED reduce (qwire)
-        from ...comm.hierarchical import hierarchical_reduce_scatter_sum
-        out = hierarchical_reduce_scatter_sum(
-            gm, DATA_AXIS, mesh_spec, pipeline_chunks=pipeline_chunks,
-            op_name="zero_hier_reduce_scatter")
-    else:
-        out = jax.lax.psum_scatter(gm, DATA_AXIS,
-                                   scatter_dimension=0, tiled=True)
+    out = jax.lax.psum_scatter(jnp.moveaxis(g, dim, 0), DATA_AXIS,
+                               scatter_dimension=0, tiled=True)
     return jnp.moveaxis(out, 0, dim) / n
 
 
@@ -191,8 +171,7 @@ def _log_plain(op, n_bytes):
 
 
 def bucketed_reduce_scatter_mean(flat, dims, *, bucket_elements, qg,
-                                 group_size, collective_impl="native",
-                                 mesh_spec=None, pipeline_chunks=1):
+                                 group_size):
     """Reduce-mean the sharded leaves of ``flat`` (full cotangents) onto
     their data-axis shards — coalesced into flat reduce-scatter buckets
     of at most ``bucket_elements`` elements (the stage-1/2 IPG-bucket
@@ -238,28 +217,8 @@ def bucketed_reduce_scatter_mean(flat, dims, *, bucket_elements, qg,
                 else jnp.concatenate(parts, axis=1)
             _log_plain("zero_bucket_reduce_scatter",
                        wide.size * wide.dtype.itemsize)
-            if collective_impl == "decomposed":
-                # chunked-ppermute delivery + index-order fold:
-                # bitwise-equal to psum_scatter (comm/ring.py contract)
-                from ...comm.ring import decomposed_reduce_scatter_sum
-                red = decomposed_reduce_scatter_sum(
-                    wide, DATA_AXIS,
-                    op_name="zero_ring_reduce_scatter")
-            elif collective_impl in ("hierarchical", "fused"):
-                # per-mesh-axis grouped delivery, same destination
-                # index-order fold: still bitwise-equal to psum_scatter
-                # (comm/hierarchical.py contract; fused rides the same
-                # twin for the fp bucket reduce)
-                from ...comm.hierarchical import \
-                    hierarchical_reduce_scatter_sum
-                red = hierarchical_reduce_scatter_sum(
-                    wide, DATA_AXIS, mesh_spec,
-                    pipeline_chunks=pipeline_chunks,
-                    op_name="zero_hier_reduce_scatter")
-            else:
-                red = jax.lax.psum_scatter(wide, DATA_AXIS,
-                                           scatter_dimension=0,
-                                           tiled=True)
+            red = jax.lax.psum_scatter(wide, DATA_AXIS,
+                                       scatter_dimension=0, tiled=True)
             red = red.reshape(-1) / n
             off = 0
             for idx, shard_shape in metas:
@@ -271,9 +230,7 @@ def bucketed_reduce_scatter_mean(flat, dims, *, bucket_elements, qg,
 
 
 def bucketed_all_gather_start(flat, sec, dims, *, qw, hpz, group_size,
-                              bucket_elements, matmul_plan=None,
-                              collective_impl="native", mesh_spec=None,
-                              longhaul_bits=None, pipeline_chunks=1):
+                              bucket_elements, matmul_plan=None):
     """ISSUE half of the layer-granular gather: coalesce the sharded
     leaves of ``flat`` (local shards; the hpZ ``sec`` partition when
     hpz > 1) into flat all-gather payloads of at most
@@ -322,12 +279,9 @@ def bucketed_all_gather_start(flat, sec, dims, *, qw, hpz, group_size,
         groups, n_g = None, n
         src = list(flat)
 
-    def pack(items, log_op, lh_bits=None):
+    def pack(items, log_op):
         # items: [(leaf index, 1-D payload)]; one all-gather per
-        # dtype-bucket; payloads flattened to 1-D for the carry.
-        # ``lh_bits``: axis-selective quantization of this family's
-        # long-haul phase (hierarchical transport only, fp payloads —
-        # the qwZ families are already int8 on every axis)
+        # dtype-bucket; payloads flattened to 1-D for the carry
         by_dtype = {}
         for it in items:
             by_dtype.setdefault(jnp.dtype(it[1].dtype), []).append(it)
@@ -342,34 +296,8 @@ def bucketed_all_gather_start(flat, sec, dims, *, qw, hpz, group_size,
                 if log_op:
                     _log_plain(log_op,
                                payload.size * payload.dtype.itemsize)
-                if collective_impl == "decomposed":
-                    # neighbor-ring ppermute chain: identical bytes,
-                    # identical [n_g, W] row order (comm/ring.py)
-                    from ...comm.ring import ring_all_gather
-                    wide = ring_all_gather(
-                        payload, DATA_AXIS, axis_index_groups=groups,
-                        op_name="zero_ring_all_gather")
-                elif collective_impl in ("hierarchical", "fused"):
-                    # per-mesh-axis ring phases, same [n_g, W] row
-                    # order; the long-haul phase optionally ships
-                    # int8/int4 (comm/hierarchical.py). Under hpZ the
-                    # gather runs the UNIFIED tier — grouped ring
-                    # phases over only the mesh axes the hpZ box
-                    # covers (n_g = hpz), bitwise-equal to the native
-                    # grouped gather. The fused impl's BUCKET payloads
-                    # ride the same twin — only the matmul-plan leaves
-                    # bypass the bucket for mid-gather consumption
-                    from ...comm.hierarchical import \
-                        hierarchical_all_gather
-                    wide = hierarchical_all_gather(
-                        payload, DATA_AXIS, mesh_spec,
-                        hpz=hpz if hpz > 1 else None,
-                        longhaul_bits=lh_bits, group_size=group_size,
-                        pipeline_chunks=pipeline_chunks,
-                        op_name="zero_hier_all_gather")
-                else:
-                    wide = jax.lax.all_gather(payload, DATA_AXIS,
-                                              axis_index_groups=groups)
+                wide = jax.lax.all_gather(payload, DATA_AXIS,
+                                          axis_index_groups=groups)
                 payloads.append(wide.reshape(-1))
                 plan.append([(it[0], int(it[1].size)) for it in sel])
         return payloads, plan
@@ -381,25 +309,12 @@ def bucketed_all_gather_start(flat, sec, dims, *, qw, hpz, group_size,
         from ...ops.quantized_matmul import quantize_for_matmul
         matmul_plan = matmul_plan or {}
         qitems, sitems, qmeta = [], [], {}
-        mm_sharded, mm_payloads = [], []
         for i, (p, d) in enumerate(zip(src, dims)):
             if d is None:
                 continue
             if i in matmul_plan:
                 group_k = matmul_plan[i]
                 q, scale = quantize_for_matmul(p, group_k=group_k)
-                if collective_impl == "fused":
-                    # MID-GATHER bypass: the shard pair rides the
-                    # payload list UN-gathered — the gather happens
-                    # inside the fused gather-matmul kernel when the
-                    # consuming Dense fires (its in-kernel permute
-                    # bytes land as ``fused_permute`` rows, so this
-                    # leaf's wire is attributed there, not here)
-                    qmeta[i] = ("mm_sharded", q.shape, scale.shape,
-                                group_k, d)
-                    mm_sharded.append(i)
-                    mm_payloads += [q.reshape(-1), scale.reshape(-1)]
-                    continue
                 qmeta[i] = ("mm", q.shape, scale.shape, group_k, d)
             else:
                 gsz = min(group_size, p.size)
@@ -413,19 +328,17 @@ def bucketed_all_gather_start(flat, sec, dims, *, qw, hpz, group_size,
                       sum(int(q.size) for _, q in qitems),
                       sum(int(s.size) for _, s in sitems),
                       sum(int(flat[i].size) * flat[i].dtype.itemsize
-                          for i in qmeta if qmeta[i][0] != "mm_sharded"))
+                          for i in qmeta))
         pq, plan_q = pack(qitems, None)
         ps, plan_s = pack(sitems, None)
         meta.update(plan_q=plan_q, plan_s=plan_s, qmeta=qmeta,
-                    n_q=len(pq), n_s=len(ps), mm_sharded=mm_sharded,
-                    hpz_groups=groups)
-        payloads = pq + ps + mm_payloads
+                    n_q=len(pq), n_s=len(ps))
+        payloads = pq + ps
     else:
         items = [(i, p.reshape(-1))
                  for i, (p, d) in enumerate(zip(src, dims))
                  if d is not None]
-        pr, plan_r = pack(items, "zero_bucket_all_gather",
-                          lh_bits=longhaul_bits)
+        pr, plan_r = pack(items, "zero_bucket_all_gather")
         meta.update(plan_r=plan_r, n_r=len(pr),
                     shapes={i: tuple(src[i].shape) for i, _ in items})
         payloads = pr
@@ -449,16 +362,7 @@ def bucketed_all_gather_finish(payloads, meta, fused=False):
     materializes. The backward re-gather calls this with
     ``fused=False``: the block VJP needs cotangents against the fp
     weight, so the recompute consumes the dequantized form (same
-    linearization point, the dequant value).
-
-    ``zero_collective_impl: fused`` leaves (``qmeta`` tag
-    ``"mm_sharded"``): the payload carries the UN-gathered shard pair.
-    With ``fused=True`` it comes back as a ``ShardedQuantizedTensor``
-    — the gather happens INSIDE the fused gather-matmul kernel at the
-    consuming Dense (the in-kernel overlap site); with ``fused=False``
-    it gathers + dequantizes here (``ShardedQuantizedTensor.gather()``
-    — same assembly, same bits as the unfused bucketed gather, the
-    transport-swap twin contract)."""
+    linearization point, the dequant value)."""
     n_g = meta["n_g"]
     out = [None] * meta["n_leaves"]
 
@@ -481,25 +385,12 @@ def bucketed_all_gather_finish(payloads, meta, fused=False):
         return parts.reshape(new_shape)
 
     if meta["qw"]:
-        from ...ops.fused_collective_matmul import ShardedQuantizedTensor
         from ...ops.quantized_matmul import MatmulQuantizedTensor
         q_all = unpack(payloads[:meta["n_q"]], meta["plan_q"])
         s_all = unpack(payloads[meta["n_q"]:meta["n_q"] + meta["n_s"]],
                        meta["plan_s"])
         n_buckets = meta["n_q"] + meta["n_s"]
-        mm_sharded = meta.get("mm_sharded", [])
-        for j, i in enumerate(mm_sharded):
-            _, qshape, sshape, group_k, d = meta["qmeta"][i]
-            sqt = ShardedQuantizedTensor(
-                payloads[n_buckets + 2 * j].reshape(qshape),
-                payloads[n_buckets + 2 * j + 1].reshape(sshape),
-                group_k=group_k, dim=d, axis_name=DATA_AXIS,
-                groups=meta.get("hpz_groups"))
-            out[i] = sqt if fused else sqt.gather().dequantize()
-        n_buckets += 2 * len(mm_sharded)
         for i, ent in meta["qmeta"].items():
-            if ent[0] == "mm_sharded":
-                continue
             if ent[0] == "mm":
                 _, qshape, sshape, group_k, d = ent
                 qa = q_all[i].reshape((n_g,) + tuple(qshape))
@@ -528,54 +419,14 @@ def bucketed_all_gather_finish(payloads, meta, fused=False):
     return out
 
 
-def bucketed_all_gather(flat, sec, dims, *, qw, hpz, group_size,
-                        bucket_elements, matmul_plan=None, fused=False,
-                        collective_impl="native", mesh_spec=None,
-                        longhaul_bits=None, pipeline_chunks=1):
-    """One-shot layer-granular gather: start + finish back to back
-    (the sequential form). Values are bitwise-identical to the
-    per-leaf gathers — buckets only batch the data movement (the
-    axis-selective ``longhaul_bits`` wire is the one declared
-    exception: long-haul rows dequantize, documented in
-    comm/hierarchical.py)."""
-    payloads, meta = bucketed_all_gather_start(
-        flat, sec, dims, qw=qw, hpz=hpz, group_size=group_size,
-        bucket_elements=bucket_elements, matmul_plan=matmul_plan,
-        collective_impl=collective_impl, mesh_spec=mesh_spec,
-        longhaul_bits=longhaul_bits, pipeline_chunks=pipeline_chunks)
-    return bucketed_all_gather_finish(payloads, meta, fused=fused)
-
-
-def make_leaf_gather(*, qw: bool, hpz: int, group_size: int = 2048,
-                     collective_impl: str = "native", mesh_spec=None,
-                     longhaul_bits=None, pipeline_chunks: int = 1):
+def make_leaf_gather(*, qw: bool, hpz: int, group_size: int = 2048):
     """Per-leaf ``(primary, secondary, dim) -> full`` gather: quantized
     wire under qwZ, intra-group (ICI-only) under hpZ, identity for
-    replicated leaves. Must run inside the shard_map region.
-
-    ``collective_impl="hierarchical"``: full-width (fp) leaf gathers
-    ride the mesh's grouped ring phases (``comm/hierarchical.py``) —
-    under hpZ the UNIFIED tier (only the mesh axes the hpZ box
-    covers), otherwise the full mesh with the optional
-    ``longhaul_bits`` axis-selective wire — so the per-leaf OUTER
-    gathers of the layered step get per-mesh-axis byte attribution
-    instead of staying native (ISSUE 15); pure data movement, bitwise
-    vs the native grouped gather. The qwZ (int8) per-leaf gather is
-    the one documented exception: it keeps the native transport (see
-    the in-function comment — its wire is already compressed, and the
-    quantize math is not round-stable next to ring ops on XLA CPU)."""
+    replicated leaves. Must run inside the shard_map region."""
 
     def _hpz_groups():
         n = jax.lax.axis_size(DATA_AXIS)
         return [list(range(g * hpz, (g + 1) * hpz)) for g in range(n // hpz)]
-
-    def _hier_gather(arr, lh_bits):
-        from ...comm.hierarchical import hierarchical_all_gather
-        return hierarchical_all_gather(
-            arr, DATA_AXIS, mesh_spec, hpz=hpz if hpz > 1 else None,
-            longhaul_bits=lh_bits, group_size=group_size,
-            pipeline_chunks=pipeline_chunks,
-            op_name="zero_hier_leaf_gather")
 
     def gather_leaf(primary, secondary, dim):
         if dim is None:
@@ -585,34 +436,8 @@ def make_leaf_gather(*, qw: bool, hpz: int, group_size: int = 2048,
         else:
             src, groups = primary, None
         if qw:
-            # the qwZ per-leaf gather keeps the native grouped
-            # transport under EVERY collective_impl: its wire is
-            # already int8 + scales (the compressed format the mesh
-            # would carry unchanged), and measured on XLA CPU the
-            # quantize/dequantize math does NOT compile round-stably
-            # next to ring permute/concat ops — routing it through the
-            # rings flips low bits of the dequantized weights and
-            # breaks the cross-engine bitwise contract. The fp-width
-            # leaves below (where the longhaul-bits option applies)
-            # and the hpZ secondary refresh DO ride the mesh.
             return _quantized_all_gather_dim(src, dim, group_size=group_size,
                                              axis_index_groups=groups)
-        if collective_impl in ("hierarchical", "fused") and hpz > 1:
-            # UNIFIED hpZ tier: the per-leaf gather rides only the
-            # mesh axes the hpZ box covers (grouped ring phases,
-            # per-axis byte attribution; longhaul_bits fires when the
-            # tier spans the slow axis) — bitwise vs the native
-            # grouped gather, proven at engine scope. At hpz == 1 the
-            # flat per-leaf gather keeps the native transport: on XLA
-            # CPU the embed/head consumers do not compile round-stably
-            # against a full-mesh ring producer (measured), and the
-            # cross-engine bitwise gates outrank attribution of the
-            # two outer collectives — the bucketed lanes and the hpZ
-            # secondary refresh carry the mesh evidence there.
-            wide = _hier_gather(src, longhaul_bits)
-            parts = jnp.moveaxis(wide, 0, dim)
-            new_shape = src.shape[:dim] + (-1,) + src.shape[dim + 1:]
-            return parts.reshape(new_shape)
         return jax.lax.all_gather(src, DATA_AXIS, axis=dim, tiled=True,
                                   axis_index_groups=groups)
 
@@ -621,9 +446,7 @@ def make_leaf_gather(*, qw: bool, hpz: int, group_size: int = 2048,
 
 def make_param_gather(param_dims, grad_dims, *, qw: bool, qg: bool, hpz: int,
                       group_size: int = 2048,
-                      reduce_bucket_elements: int = 500_000_000,
-                      collective_impl: str = "native", mesh_spec=None,
-                      longhaul_bits=None, pipeline_chunks: int = 1):
+                      reduce_bucket_elements: int = 500_000_000):
     """Build ``gather(primary, secondary) -> full params`` with a custom
     VJP that performs the (optionally quantized) gradient reduce-scatter.
 
@@ -635,11 +458,7 @@ def make_param_gather(param_dims, grad_dims, *, qw: bool, qg: bool, hpz: int,
     shard_map region.
     """
 
-    _gather_leaf = make_leaf_gather(qw=qw, hpz=hpz, group_size=group_size,
-                                    collective_impl=collective_impl,
-                                    mesh_spec=mesh_spec,
-                                    longhaul_bits=longhaul_bits,
-                                    pipeline_chunks=pipeline_chunks)
+    _gather_leaf = make_leaf_gather(qw=qw, hpz=hpz, group_size=group_size)
 
     def _reduce_leaf(g, dim):
         n = jax.lax.axis_size(DATA_AXIS)
@@ -647,10 +466,7 @@ def make_param_gather(param_dims, grad_dims, *, qw: bool, qg: bool, hpz: int,
             return jax.lax.psum(g, DATA_AXIS) / n
         if qg:
             return _quant_reduce_mean_dim(g, dim, group_size=group_size)
-        return _psum_scatter_mean_dim(g, dim,
-                                      collective_impl=collective_impl,
-                                      mesh_spec=mesh_spec,
-                                      pipeline_chunks=pipeline_chunks)
+        return _psum_scatter_mean_dim(g, dim)
 
     @jax.custom_vjp
     def gather(primary, secondary):
@@ -673,9 +489,7 @@ def make_param_gather(param_dims, grad_dims, *, qw: bool, qg: bool, hpz: int,
         g_primary = jax.tree.unflatten(
             treedef, bucketed_reduce_scatter_mean(
                 flat, param_dims, bucket_elements=reduce_bucket_elements,
-                qg=qg, group_size=group_size,
-                collective_impl=collective_impl, mesh_spec=mesh_spec,
-                pipeline_chunks=pipeline_chunks))
+                qg=qg, group_size=group_size))
         # secondary is a value-copy of primary; its cotangent is defined
         # to be zero (all gradient flows to the primary partition).
         return g_primary, [None] * len(param_dims)
@@ -695,42 +509,18 @@ def make_param_gather(param_dims, grad_dims, *, qw: bool, qg: bool, hpz: int,
     return gather, reduce_grads
 
 
-def build_secondary(params, param_dims, hpz: int, *,
-                    collective_impl: str = "native", mesh_spec=None,
-                    longhaul_bits=None, pipeline_chunks: int = 1):
+def build_secondary(params, param_dims, hpz: int):
     """hpZ secondary partition: from the primary 1/n shard, build this
     device's 1/hpz shard (reference: the ZeRO-param secondary groups,
     ``utils/groups.py:650``). Runs INSIDE the shard_map region, once per
     optimizer step. Wire: one full-parameter all-gather over the data
     axis (the amortized refresh the reference does after each step).
-    Returns a flat list in ``jax.tree.flatten`` order.
-
-    ``collective_impl="hierarchical"``: the refresh rides the full
-    mesh's grouped ring phases (``zero_hier_secondary``) so the ONE
-    cross-mesh collective of the hpZ step gets per-axis byte
-    attribution and, with ``longhaul_bits``, the axis-selective
-    quantized wire — the EQuARX trade applied exactly where hpZ's
-    traffic actually crosses the slow axis. Full width is bitwise-equal
-    to the native refresh; a quantized long haul dequantizes
-    deterministically and IDENTICALLY on every member of an hpZ group
-    (they share the long-haul coordinate), so the secondary stays
-    consistent within each group (trajectory-gated like every lossy
-    wire)."""
+    Returns a flat list in ``jax.tree.flatten`` order."""
 
     def leaf(p, dim):
         if dim is None or hpz <= 1:
             return None
-        if collective_impl in ("hierarchical", "fused"):
-            from ...comm.hierarchical import hierarchical_all_gather
-            wide = hierarchical_all_gather(
-                p, DATA_AXIS, mesh_spec, longhaul_bits=longhaul_bits,
-                pipeline_chunks=pipeline_chunks,
-                op_name="zero_hier_secondary")
-            parts = jnp.moveaxis(wide, 0, dim)
-            full = parts.reshape(p.shape[:dim] + (-1,)
-                                 + p.shape[dim + 1:])
-        else:
-            full = jax.lax.all_gather(p, DATA_AXIS, axis=dim, tiled=True)
+        full = jax.lax.all_gather(p, DATA_AXIS, axis=dim, tiled=True)
         idx = jax.lax.axis_index(DATA_AXIS)
         within = idx % hpz
         # my 1/hpz slice of the sharded dim
@@ -779,7 +569,7 @@ def validate_zeropp(zcfg, stage: int, data_size: int):
     if zcfg.zero_quantized_gradients and stage < 2:
         raise HDSConfigError("zero_quantized_gradients (qgZ) requires "
                              "zero stage >= 2 (sharded gradients)")
-    from .overlap import validate_overlap_config, validate_quantized_wire
+    from .overlap import validate_quantized_wire
     validate_quantized_wire(
         quantized_reduce_scatter=zcfg.zero_quantized_reduce_scatter,
         error_feedback=zcfg.zero_reduce_scatter_error_feedback,
@@ -788,17 +578,6 @@ def validate_zeropp(zcfg, stage: int, data_size: int):
         fused_matmul=zcfg.zero_quantized_weights_fused_matmul,
         quantized_weights=zcfg.zero_quantized_weights,
         stage=stage)
-    # decomposed/hierarchical ring transports: world-size/overlap/mesh
-    # interplay is only knowable here (topology in hand) — typed
-    # rejection, no silent fallthrough to the native transport
-    from ...comm.hierarchical import mesh_spec_from_zero_config
-    validate_overlap_config(
-        collective_impl=getattr(zcfg, "zero_collective_impl", "native"),
-        world_size=data_size, overlap_comm=zcfg.overlap_comm,
-        mesh_spec=mesh_spec_from_zero_config(zcfg),
-        longhaul_bits=getattr(zcfg, "zero_longhaul_wire_bits", None),
-        hpz=hpz,
-        pipeline_chunks=getattr(zcfg, "zero_mesh_pipeline_chunks", 1))
 
 
 def build_zeropp_micro_fn(*, adapter_loss, mesh, param_specs, grad_specs,
@@ -839,33 +618,6 @@ def build_zeropp_micro_fn(*, adapter_loss, mesh, param_specs, grad_specs,
     qw = zcfg.zero_quantized_weights
     qg = zcfg.zero_quantized_gradients
     hpz = zcfg.zero_hpz_partition_size
-    collective_impl = getattr(zcfg, "zero_collective_impl", "native")
-    mesh_spec = None
-
-    if collective_impl in ("decomposed", "hierarchical", "fused"):
-        # the ring transports ride the layered step's explicit lanes;
-        # the whole-tree fallback's gathers are AD-generated per-leaf
-        # ops with no bucket site to decompose. Reject loudly instead
-        # of silently running a half-native hybrid.
-        from ...comm.hierarchical import mesh_spec_from_zero_config
-        from .overlap import validate_overlap_config
-        mesh_spec = mesh_spec_from_zero_config(zcfg)
-        validate_overlap_config(
-            collective_impl=collective_impl,
-            world_size=int(mesh.shape[DATA_AXIS]),
-            overlap_comm=zcfg.overlap_comm,
-            mesh_spec=mesh_spec,
-            longhaul_bits=getattr(zcfg, "zero_longhaul_wire_bits", None),
-            hpz=hpz,
-            pipeline_chunks=getattr(zcfg, "zero_mesh_pipeline_chunks",
-                                    1))
-        if layered is None:
-            from ..config import HDSConfigError
-            raise HDSConfigError(
-                f"zero_collective_impl={collective_impl} requires the "
-                f"layered ZeRO-3 step: keep zero_optimization."
-                f"layered_gather=true and use a model with a layered "
-                f"spec (models/layered.py)")
 
     if (zcfg.zero_quantized_reduce_scatter
             or zcfg.zero_quantized_weights_fused_matmul) \
@@ -917,10 +669,7 @@ def build_zeropp_micro_fn(*, adapter_loss, mesh, param_specs, grad_specs,
 
     gather, reduce_grads = make_param_gather(
         param_dims, grad_dims, qw=qw, qg=qg, hpz=hpz,
-        reduce_bucket_elements=zcfg.reduce_bucket_size,
-        collective_impl=collective_impl, mesh_spec=mesh_spec,
-        longhaul_bits=getattr(zcfg, "zero_longhaul_wire_bits", None),
-        pipeline_chunks=getattr(zcfg, "zero_mesh_pipeline_chunks", 1))
+        reduce_bucket_elements=zcfg.reduce_bucket_size)
 
     if layered is not None:
         return _build_layered(
@@ -929,7 +678,7 @@ def build_zeropp_micro_fn(*, adapter_loss, mesh, param_specs, grad_specs,
             grad_accum_dtype=grad_accum_dtype, remat_policy=remat_policy,
             qw=qw, qg=qg, hpz=hpz, reduce_grads=reduce_grads,
             params_proj=params_proj, grads_proj=grads_proj,
-            zcfg=zcfg, param_shapes=param_shapes, mesh_spec=mesh_spec)
+            zcfg=zcfg, param_shapes=param_shapes)
 
     prepare_secondary = None
     if hpz > 1:
@@ -991,7 +740,6 @@ def build_zeropp_micro_fn(*, adapter_loss, mesh, param_specs, grad_specs,
         "mode": "whole-tree", "depth": None,
         "bucket_elements": zcfg.reduce_bucket_size,
         "overlap_comm": zcfg.overlap_comm,
-        "collective_impl": collective_impl,
         "quantized_reduce_scatter": False,
     }
     return micro_fwd_bwd, prepare_secondary, plan_info
@@ -1010,7 +758,7 @@ _ZO_DEBUG = False
 def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
                    grad_accum_dtype, remat_policy, qw, qg, hpz,
                    reduce_grads, params_proj, grads_proj, zcfg,
-                   param_shapes=None, mesh_spec=None):
+                   param_shapes=None):
     """Software-pipelined scan-over-layers ZeRO-3 micro step.
 
     The fwd+bwd over transformer blocks is written by hand (no
@@ -1067,15 +815,6 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
     qrs_ef = zcfg.zero_reduce_scatter_error_feedback
     qrs_bits = zcfg.zero_quantized_reduce_scatter_bits
     fused_mm = zcfg.zero_quantized_weights_fused_matmul
-    # collective transport of the gather/reduce lanes: "native" =
-    # monolithic all-gather / psum_scatter / all-to-all; "decomposed"
-    # = chunked ppermute ring chains (comm/ring.py); "hierarchical" =
-    # per-mesh-axis grouped ring phases (comm/hierarchical.py, with
-    # optional long-haul-only wire quantization) — both bitwise-equal
-    # to native, structurally overlappable by dataflow construction
-    impl = getattr(zcfg, "zero_collective_impl", "native")
-    longhaul_bits = getattr(zcfg, "zero_longhaul_wire_bits", None)
-    mesh_pipeline = getattr(zcfg, "zero_mesh_pipeline_chunks", 1)
     if (qrs or fused_mm) and param_shapes is None:
         from ..config import HDSConfigError
         raise HDSConfigError(
@@ -1136,14 +875,7 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
              f"({plan.reason}); reduce bucket={bucket_elems} elements",
              ranks=[0])
 
-    # per-leaf OUTER (embedding/head) gathers ride the same transport
-    # as the bucketed lanes — under the hierarchical impl they become
-    # grouped mesh rings with per-axis byte attribution (ISSUE 15)
-    gather_leaf = make_leaf_gather(qw=qw, hpz=hpz, group_size=group_size,
-                                   collective_impl=impl,
-                                   mesh_spec=mesh_spec,
-                                   longhaul_bits=longhaul_bits,
-                                   pipeline_chunks=mesh_pipeline)
+    gather_leaf = make_leaf_gather(qw=qw, hpz=hpz, group_size=group_size)
 
     # ---- fused qwZ consumption plan: which block leaves gather in the
     # matmul (per-(k-group, n) scale) layout. Dense kernels only — the
@@ -1211,15 +943,9 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
 
     def build_layered_secondary(params_local):
         outer_local, stacked_local = split(params_local)
-        sec_outer = build_secondary(
-            outer_local, outer_pdims, hpz, collective_impl=impl,
-            mesh_spec=mesh_spec, longhaul_bits=longhaul_bits,
-            pipeline_chunks=mesh_pipeline)
+        sec_outer = build_secondary(outer_local, outer_pdims, hpz)
         sec_stacked = build_secondary(
-            jax.tree.flatten(stacked_local)[0], stacked_pdims, hpz,
-            collective_impl=impl, mesh_spec=mesh_spec,
-            longhaul_bits=longhaul_bits,
-            pipeline_chunks=mesh_pipeline)
+            jax.tree.flatten(stacked_local)[0], stacked_pdims, hpz)
         return sec_outer, sec_stacked
 
     def _sec_specs():
@@ -1322,9 +1048,7 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
                 payloads, meta = bucketed_all_gather_start(
                     flat, sec, block_pdims, qw=qw, hpz=hpz,
                     group_size=group_size, bucket_elements=ag_bucket,
-                    matmul_plan=matmul_plan, collective_impl=impl,
-                    mesh_spec=mesh_spec, longhaul_bits=longhaul_bits,
-                    pipeline_chunks=mesh_pipeline)
+                    matmul_plan=matmul_plan)
                 gmeta.setdefault("m", meta)
                 return list(iso(tuple(payloads)))
 
@@ -1346,16 +1070,12 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
                         flat_cots, block_pdims,
                         bucket_elements=bucket_elems,
                         group_size=group_size, bits=qrs_bits,
-                        residuals=res, error_feedback=qrs_ef,
-                        collective_impl=impl, mesh_spec=mesh_spec,
-                        pipeline_chunks=mesh_pipeline)
+                        residuals=res, error_feedback=qrs_ef)
                 else:
                     out = bucketed_reduce_scatter_mean(
                         flat_cots, block_pdims,
                         bucket_elements=bucket_elems,
-                        qg=qg, group_size=group_size,
-                        collective_impl=impl, mesh_spec=mesh_spec,
-                        pipeline_chunks=mesh_pipeline)
+                        qg=qg, group_size=group_size)
                     nres = []
                 out = list(iso(tuple(out)))
                 if nres:
@@ -1374,20 +1094,11 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
                 if fused_mm:
                     # Dense kernels arrive as (int8, scales); the
                     # interceptor routes them through quantized_matmul
-                    # so the fp weight never materializes. Under the
-                    # fused transport they arrive as MID-GATHER shards
-                    # (ShardedQuantizedTensor) and the interceptor runs
-                    # the fused gather-matmul kernel — the in-kernel
-                    # overlap site
+                    # so the fp weight never materializes
                     import flax.linen as fnn
-                    if impl == "fused":
-                        from ...ops.fused_collective_matmul import \
-                            fused_collective_dense_interceptor as \
-                            _make_interceptor
-                    else:
-                        from ...ops.quantized_matmul import \
-                            fused_dense_interceptor as _make_interceptor
-                    with fnn.intercept_methods(_make_interceptor()):
+                    from ...ops.quantized_matmul import \
+                        fused_dense_interceptor
+                    with fnn.intercept_methods(fused_dense_interceptor()):
                         return iso(block_fn(layer_tree, x, batch_local,
                                             key, train))
                 return iso(block_fn(layer_tree, x, batch_local, key,
@@ -1569,15 +1280,12 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
                         jax.tree.flatten(outer_cot)[0], outer_pdims,
                         bucket_elements=bucket_elems,
                         group_size=group_size, bits=qrs_bits,
-                        residuals=res_outer, error_feedback=qrs_ef,
-                        collective_impl=impl, mesh_spec=mesh_spec,
-                        pipeline_chunks=mesh_pipeline)
+                        residuals=res_outer, error_feedback=qrs_ef)
             else:
                 outer_red = bucketed_reduce_scatter_mean(
                     jax.tree.flatten(outer_cot)[0], outer_pdims,
                     bucket_elements=bucket_elems, qg=qg,
-                    group_size=group_size, collective_impl=impl,
-                    mesh_spec=mesh_spec, pipeline_chunks=mesh_pipeline)
+                    group_size=group_size)
 
             grads = dict(jax.tree.unflatten(outer_def, outer_red))
             for i in range(n_layer):
@@ -1632,30 +1340,13 @@ def _build_layered(*, layered, mesh, param_specs, batch_spec_of, gas,
         "mode": "layered", "depth": depth, "reason": plan.reason,
         "n_layer": n_layer, "bucket_elements": bucket_elems,
         "overlap_comm": zcfg.overlap_comm,
-        "collective_impl": impl,
         "quantized_reduce_scatter": qrs,
         "error_feedback": qrs_ef,
         "wire_bits": qrs_bits if qrs else None,
         "fused_matmul_leaves": len(matmul_plan) if matmul_plan else 0,
-        # in-kernel overlap sites: matmul leaves consumed MID-GATHER by
-        # the fused gather-matmul kernel (zero_collective_impl=fused)
-        "mid_gather_leaves": (len(matmul_plan)
-                              if impl == "fused" and matmul_plan else 0),
         "wire_error_buckets": len(block_res_widths)
         + len(outer_res_widths),
-        "mesh_spec": mesh_spec.describe() if mesh_spec is not None
-        else None,
-        "longhaul_wire_bits": longhaul_bits,
-        "mesh_pipeline_chunks": mesh_pipeline
-        if impl in ("hierarchical", "fused") else None,
-        "hpz_tiers": None,
     }
-    if impl in ("hierarchical", "fused") and hpz > 1:
-        from ...comm.hierarchical import hpz_tier_dims
-        sub = mesh_spec.zero_subspec()
-        plan_info["hpz_tiers"] = [
-            {"axis": sub.axes[dim].name, "span": span}
-            for dim, span in hpz_tier_dims(mesh_spec, hpz)]
     if qrs_ef:
         # non-JSON engine hook: allocates the error-feedback state
         # (the engine pops it off before logging the plan)

@@ -16,9 +16,6 @@ import pytest
 torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
 
-# multi-minute torch/transformers parity sweep -> integration tier
-pytestmark = pytest.mark.slow
-
 from hcache_deepspeed_tpu.checkpoint.hf_loader import (  # noqa: E402
     convert_hf_state_dict, hf_config_to_model)
 

@@ -129,180 +129,6 @@ class TestWireByteAttribution:
         assert wire < equiv
 
 
-class TestDecomposedTransportAttribution:
-    """The ring transport must keep the quantized matched pairs intact
-    (quantization logs before the transport choice) AND attribute its
-    per-chunk permute sends under the ``collective_permute`` op kind —
-    ring bytes never go missing from the accounting."""
-
-    def test_qrs_decomposed_keeps_pair_and_logs_permutes(
-            self, eight_devices, comms):
-        from hcache_deepspeed_tpu.runtime.zero.qwire import (
-            QRS_OP, quantized_bucket_reduce_scatter_mean)
-        leaf = jnp.ones((8 * 256,), jnp.float32)
-
-        def reduce(a):
-            out, _ = quantized_bucket_reduce_scatter_mean(
-                [a], [0], bucket_elements=10 ** 9, group_size=2048,
-                error_feedback=False, collective_impl="decomposed")
-            return out[0]
-
-        _shmap(reduce, (P(),), P(DATA_AXIS))(leaf)
-        # the quantized matched pair survives the transport swap
-        wire, equiv = _pair(comms, QRS_OP)
-        assert equiv == leaf.size * 4
-        assert wire < equiv
-        # and the ring chunks are attributed with their kind
-        permutes = comms.permute_bytes_summary()
-        assert "zero_ring_qrs" in permutes, permutes
-        assert permutes["zero_ring_qrs"] > 0
-        assert comms.op_kinds["zero_ring_qrs"] == "collective_permute"
-        rec = comms.wire_savings_summary()[QRS_OP]
-        assert rec["op_kind"] == "collective"
-
-    def test_domino_decomposed_int8_same_totals(self, eight_devices,
-                                                comms):
-        """Transport swap must not change the quantized pair totals —
-        same rows quantized, same bytes claimed."""
-        from hcache_deepspeed_tpu.comm.quantized import \
-            quantized_allreduce_body
-        x = jnp.ones((16, 64), jnp.float32)
-
-        def ar(impl):
-            def f(x_local):
-                return quantized_allreduce_body(
-                    x_local, jnp.zeros_like(x_local), DATA_AXIS,
-                    group_size=128, collective_impl=impl)
-            return f
-
-        _shmap(ar("native"), (P(),), (P(), P()))(x)
-        native_pair = _pair(comms, "domino_half_allreduce_int8")
-        comms.reset()
-        _shmap(ar("decomposed"), (P(),), (P(), P()))(x)
-        dec_pair = _pair(comms, "domino_half_allreduce_int8")
-        assert native_pair == dec_pair
-        assert comms.permute_bytes_summary().get(
-            "domino_ring_allreduce_int8", 0) > 0
-
-
-class TestFusedPermuteReconciliation:
-    """ISSUE 18 satellite gate: the fused computation-collective
-    kernels log their in-kernel ring steps as ``op_kind =
-    "fused_permute"`` rows — and those rows must reconcile BYTE-EXACTLY
-    with what the unfused transport of the same payload logs as
-    ``collective_permute`` rows. Fusing the permute into the kernel
-    never makes wire volume silent, and never double-counts it: the
-    default lumped summary excludes fused rows, the widened-``kinds``
-    summary and ``total_axis_bytes`` include them exactly once."""
-
-    def _shards(self):
-        from hcache_deepspeed_tpu.ops.quantized_matmul import \
-            quantize_for_matmul
-        rng = np.random.default_rng(18)
-        w = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
-        q, s = quantize_for_matmul(w, 8)          # q [64,16], s [8,16]
-        x = jnp.asarray(rng.normal(size=(4, 64)), jnp.float32)
-        return x, q, s
-
-    def test_fused_gather_rows_reconcile_with_unfused_ring(
-            self, eight_devices, comms):
-        from hcache_deepspeed_tpu.comm.ring import ring_all_gather
-        from hcache_deepspeed_tpu.ops.fused_collective_matmul import (
-            FUSED_GATHER_MM_OP, reference_fused_gather_matmul)
-        x, q, s = self._shards()
-
-        def fused(q_sh, s_sh):
-            return reference_fused_gather_matmul(
-                x, q_sh, s_sh, group_k=8, axis_name=DATA_AXIS,
-                shard_dim=0)
-
-        _shmap(fused, (P(DATA_AXIS), P(DATA_AXIS)), P())(q, s)
-        fused_rows = comms.fused_bytes_summary()
-        assert FUSED_GATHER_MM_OP in fused_rows, sorted(fused_rows)
-        assert comms.op_kinds[FUSED_GATHER_MM_OP] == "fused_permute"
-        # fused rows are NOT in the default (collective_permute-only)
-        # lumped summary, ARE in the widened-kinds summary, exactly once
-        assert FUSED_GATHER_MM_OP not in comms.permute_bytes_summary()
-        widened = comms.permute_bytes_summary(
-            kinds=("collective_permute", "fused_permute"))
-        assert widened[FUSED_GATHER_MM_OP] == \
-            fused_rows[FUSED_GATHER_MM_OP]
-        # ...and they land in the wire-cost aggregate under the ring's
-        # axis label
-        assert comms.total_axis_bytes().get(DATA_AXIS, 0) >= \
-            fused_rows[FUSED_GATHER_MM_OP]
-
-        # unfused transport of the SAME payload: the plain ring gather
-        # the bucketed pipeline would run — byte-exact reconciliation
-        comms.reset()
-
-        def unfused(q_sh, s_sh):
-            wq = ring_all_gather(q_sh.reshape(-1), DATA_AXIS,
-                                 op_name="unfused_gather")
-            ws = ring_all_gather(s_sh.reshape(-1), DATA_AXIS,
-                                 op_name="unfused_gather")
-            return wq, ws
-
-        _shmap(unfused, (P(DATA_AXIS), P(DATA_AXIS)),
-               (P(DATA_AXIS), P(DATA_AXIS)))(q, s)
-        unfused_rows = comms.permute_bytes_summary()
-        assert unfused_rows["unfused_gather"] == \
-            fused_rows[FUSED_GATHER_MM_OP], (unfused_rows, fused_rows)
-
-    def test_streamed_schedule_same_wire_bytes(self, eight_devices,
-                                               comms):
-        """The in-flight lane (streamed schedule) moves the SAME bytes
-        as the gather-then-matmul reference twin — overlap changes
-        wall-clock, never wire volume."""
-        from hcache_deepspeed_tpu.ops.fused_collective_matmul import (
-            FUSED_GATHER_MM_OP, reference_fused_gather_matmul,
-            streamed_fused_gather_matmul)
-        x, q, s = self._shards()
-
-        def run(fn):
-            comms.reset()
-            _shmap(lambda q_sh, s_sh: fn(
-                x, q_sh, s_sh, group_k=8, axis_name=DATA_AXIS,
-                shard_dim=0), (P(DATA_AXIS), P(DATA_AXIS)), P())(q, s)
-            return comms.fused_bytes_summary()[FUSED_GATHER_MM_OP]
-
-        assert run(reference_fused_gather_matmul) == \
-            run(streamed_fused_gather_matmul)
-
-    def test_fused_qrs_rows_reconcile_with_ring_a2a(
-            self, eight_devices, comms):
-        from hcache_deepspeed_tpu.comm.ring import \
-            decomposed_all_to_all_rows
-        from hcache_deepspeed_tpu.ops.fused_collective_matmul import (
-            FUSED_QRS_OP, fused_qrs_exchange)
-        rng = np.random.default_rng(7)
-        pay = jnp.asarray(rng.integers(-127, 128, (8, 8, 6)), jnp.int8)
-        sc = jnp.asarray(rng.normal(size=(8, 8, 2)), jnp.float32)
-
-        def fused(p, s):
-            return fused_qrs_exchange(p[0], s[0], axis_name=DATA_AXIS)
-
-        _shmap(fused, (P(DATA_AXIS), P(DATA_AXIS)),
-               (P(DATA_AXIS), P(DATA_AXIS)))(pay, sc)
-        fused_rows = comms.fused_bytes_summary()
-        assert FUSED_QRS_OP in fused_rows, sorted(fused_rows)
-        assert comms.op_kinds[FUSED_QRS_OP] == "fused_permute"
-        comms.reset()
-
-        def unfused(p, s):
-            pt = decomposed_all_to_all_rows(p[0], DATA_AXIS,
-                                            op_name="unfused_a2a")
-            st = decomposed_all_to_all_rows(s[0], DATA_AXIS,
-                                            op_name="unfused_a2a")
-            return pt, st
-
-        _shmap(unfused, (P(DATA_AXIS), P(DATA_AXIS)),
-               (P(DATA_AXIS), P(DATA_AXIS)))(pay, sc)
-        unfused_rows = comms.permute_bytes_summary()
-        assert unfused_rows["unfused_a2a"] == \
-            fused_rows[FUSED_QRS_OP], (unfused_rows, fused_rows)
-
-
 class TestInt4Pack:
 
     def test_roundtrip(self):
@@ -315,57 +141,3 @@ class TestInt4Pack:
         assert packed.shape == (4, 17)
         back = unpack_int4(packed, 33)
         np.testing.assert_array_equal(np.asarray(back), np.asarray(q))
-
-
-class TestHierSiteReconciliation:
-    """ISSUE 15 satellite bugfix gate: with the hpZ secondary refresh
-    and the bucketed/per-leaf gathers riding the mesh, the per-axis
-    map (``permute_axis_bytes``) must still reconcile EXACTLY with the
-    lumped ``permute_bytes_summary`` — every new mesh site attributes
-    each byte exactly once (no double-count between the new
-    ``zero_hier_secondary`` / ``zero_hier_leaf_gather`` ops and the
-    bucketed lanes' ``zero_hier_all_gather``)."""
-
-    def test_per_axis_reconciles_with_lumped_summary(
-            self, eight_devices, comms):
-        import jax.numpy as jnp
-
-        from hcache_deepspeed_tpu.comm.hierarchical import \
-            make_mesh_spec
-        from hcache_deepspeed_tpu.runtime.zero.zeropp import (
-            bucketed_all_gather, build_secondary, make_leaf_gather)
-        spec = make_mesh_spec([2, 4])
-        rng = np.random.default_rng(9)
-        x = jnp.asarray(rng.normal(size=(64, 4)), jnp.float32)
-        y = jnp.asarray(rng.normal(size=(32, 2)), jnp.float32)
-
-        def f(a, b):
-            sec = build_secondary(
-                {"a": a, "b": b}, [0, 0], 4,
-                collective_impl="hierarchical", mesh_spec=spec)
-            lg = make_leaf_gather(qw=False, hpz=4, group_size=64,
-                                  collective_impl="hierarchical",
-                                  mesh_spec=spec)
-            full_a = lg(a, sec[0], 0)
-            out = bucketed_all_gather(
-                [b], [sec[1]], [0], qw=False, hpz=4, group_size=64,
-                bucket_elements=10 ** 9,
-                collective_impl="hierarchical", mesh_spec=spec)
-            return full_a, out[0]
-
-        _shmap(f, (P(DATA_AXIS), P(DATA_AXIS)), (P(), P()))(x, y)
-        lumped = comms.permute_bytes_summary()
-        per_axis = comms.permute_axis_bytes()
-        # all three mesh sites present...
-        assert {"zero_hier_secondary", "zero_hier_leaf_gather",
-                "zero_hier_all_gather"} <= set(lumped), sorted(lumped)
-        # ...and every op's per-axis map sums exactly to its lumped
-        # total — byte-exact reconciliation, no double-count
-        for op, total in lumped.items():
-            assert sum(per_axis[op].values()) == total, (op, per_axis)
-        # the secondary refresh crosses the mesh (both axes); the
-        # hpZ-tier gathers stay intra-only
-        assert set(per_axis["zero_hier_secondary"]) == {"intra",
-                                                        "inter"}
-        assert set(per_axis["zero_hier_leaf_gather"]) == {"intra"}
-        assert set(per_axis["zero_hier_all_gather"]) == {"intra"}

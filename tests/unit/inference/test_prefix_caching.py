@@ -232,7 +232,6 @@ class TestPrefixCaching:
         assert not engine._chain_children
 
 
-@pytest.mark.slow
 class TestPrefixCachingFuzz:
     """Randomized interleavings of shared-prefix admissions, decodes,
     flushes and suspend/resume under pool pressure; every decode's

@@ -5,8 +5,7 @@ cannot cover — or because ``HDS_DISABLE_PALLAS`` says so on a platform
 that has the kernel — calls ``ops.note_fallback`` first. The
 substitution is counted per (op, reason), warned ONCE per pair, and
 read back through ``ops.fallback_report()``; nothing falls back
-silently. (The fused gather-matmul's layout guard needs a mesh and is
-pinned in test_fused_collective_matmul.py.)
+silently.
 """
 
 import logging

@@ -73,47 +73,6 @@ ENTRY %main (p: f32[8,64]) -> (f32[64,64], f32[8,64]) {
 }
 """
 
-# A decomposed ring: a 3-step collective-permute CHAIN (each permute
-# consumes the previous chunk) plus one point-to-point delivery
-# permute, with an independent dot and a dot-bearing fusion alongside
-# — the structural-overlap shape the decomposed transport compiles to.
-RING_BODY = """
-HloModule ring
-
-%mathy (a: f32[8,8]) -> f32[8,8] {
-  %a = f32[8,8] parameter(0)
-  %dm = f32[8,8] dot(f32[8,8] %a, f32[8,8] %a), lhs_contracting_dims={1}, rhs_contracting_dims={1}
-}
-
-ENTRY %main (p: (f32[8,16], f32[8,8])) -> (f32[8,16], f32[8,8]) {
-  %p = (f32[8,16], f32[8,8]) parameter(0)
-  %shard = f32[8,16] get-tuple-element(%p), index=0
-  %x = f32[8,8] get-tuple-element(%p), index=1
-  %cp1 = f32[8,16] collective-permute(f32[8,16] %shard), source_target_pairs={{0,1},{1,0}}
-  %cp2 = f32[8,16] collective-permute(f32[8,16] %cp1), source_target_pairs={{0,1},{1,0}}
-  %cp3 = f32[8,16] collective-permute(f32[8,16] %cp2), source_target_pairs={{0,1},{1,0}}
-  %cp4 = f32[8,16] collective-permute(f32[8,16] %shard), source_target_pairs={{0,1},{1,0}}
-  %d1 = f32[8,8] dot(f32[8,8] %x, f32[8,8] %x), lhs_contracting_dims={1}, rhs_contracting_dims={1}
-  %f1 = f32[8,8] fusion(f32[8,8] %x), kind=kOutput, calls=%mathy
-  ROOT %out = (f32[8,16], f32[8,8]) tuple(%cp3, %d1)
-}
-"""
-
-# A sequential ring: every permute feeds the dot — zero structural
-# overlap, and a NATIVE collective-permute-start/done window for the
-# scheduled (TPU) tier.
-RING_NATIVE = """
-HloModule ringsched, is_scheduled=true
-
-ENTRY %main (p: f32[8,16]) -> (f32[8,16], f32[8,8]) {
-  %p = f32[8,16] parameter(0)
-  %cps = (f32[8,16], f32[8,16]) collective-permute-start(f32[8,16] %p), source_target_pairs={{0,1},{1,0}}
-  %d1 = f32[8,8] dot(f32[8,16] %p, f32[8,16] %p), lhs_contracting_dims={1}, rhs_contracting_dims={1}
-  %cpd = f32[8,16] collective-permute-done((f32[8,16], f32[8,16]) %cps)
-  ROOT %out = (f32[8,16], f32[8,8]) tuple(%cpd, %d1)
-}
-"""
-
 
 class TestParser:
 
@@ -183,76 +142,6 @@ class TestDerivedPairs:
         assert rep.overlap_ratio("reduce-scatter") == 0.0
 
 
-class TestPermuteChains:
-    """The decomposed-ring evidence tier: chain detection, the
-    structural overlap ratio, and collective-permute wire pricing."""
-
-    def test_chain_detection(self):
-        rep = audit_hlo_text(RING_BODY)
-        lengths = sorted(c["length"] for c in rep.permute_chains)
-        # one 3-step chain + one point-to-point delivery send
-        assert lengths == [1, 3], rep.permute_chains
-
-    def test_structural_ratio_counts_dot_bearing_fusions(self):
-        rep = audit_hlo_text(RING_BODY)
-        # every permute is dependence-free of both the dot and the
-        # dot-bearing fusion
-        assert rep.structural_overlap_ratio() == 1.0
-        pairs = rep.pairs("collective-permute", min_interleaved=1)
-        assert len(pairs) == 4
-        assert all(p.free_fused == 1 for p in pairs)
-
-    def test_sequential_permute_scores_zero(self):
-        """A chain whose landed result every dot/fusion consumes has
-        nothing to hide behind — fully sequential ring."""
-        text = """
-HloModule seqring
-
-%mathy (a: f32[8,16]) -> f32[8,16] {
-  %a = f32[8,16] parameter(0)
-  %dm = f32[8,16] dot(f32[8,16] %a, f32[8,16] %a), lhs_contracting_dims={0}, rhs_contracting_dims={0}
-}
-
-ENTRY %main (p: f32[8,16]) -> f32[8,16] {
-  %p = f32[8,16] parameter(0)
-  %cp1 = f32[8,16] collective-permute(f32[8,16] %p), source_target_pairs={{0,1},{1,0}}
-  %cp2 = f32[8,16] collective-permute(f32[8,16] %cp1), source_target_pairs={{0,1},{1,0}}
-  %d1 = f32[16,16] dot(f32[8,16] %cp2, f32[8,16] %cp2), lhs_contracting_dims={0}, rhs_contracting_dims={0}
-  ROOT %f1 = f32[8,16] fusion(f32[8,16] %cp2), kind=kOutput, calls=%mathy
-}
-"""
-        rep = audit_hlo_text(text)
-        assert rep.structural_overlap_ratio() == 0.0
-        assert rep.pairs("collective-permute", min_interleaved=1) == []
-
-    def test_permute_wire_bytes_priced(self):
-        """Satellite gate: collective-permute result buffers must show
-        up in per-collective wire_bytes like ag/rs/ar do."""
-        rep = audit_hlo_text(RING_BODY)
-        cp = rep.wire_bytes.get("collective-permute")
-        assert cp is not None, rep.wire_bytes
-        assert cp["count"] == 4
-        assert cp["bytes"] == 4 * 8 * 16 * 4  # four f32[8,16] buffers
-
-    def test_native_permute_window(self):
-        rep = audit_hlo_text(RING_NATIVE)
-        assert len(rep.native_pairs) == 1
-        pair = rep.native_pairs[0]
-        assert pair.kind == "collective-permute"
-        assert pair.interleaved == 1      # the dot inside the window
-        # -start tuple result priced once, under the base kind
-        assert "collective-permute" in rep.wire_bytes
-
-    def test_row_carries_structural_fields(self):
-        import json
-        row = audit_hlo_text(RING_BODY).to_row()
-        json.dumps(row)
-        assert row["structural_overlap_ratio"] == 1.0
-        assert row["permute_overlap_ratio"] == 1.0
-        assert sorted(c["length"] for c in row["permute_chains"]) \
-            == [1, 3]
-
-
 class TestReport:
 
     def test_row_is_json_safe(self):
@@ -267,217 +156,3 @@ class TestReport:
         rep = audit_hlo_text("not hlo at all\n{}\nrandom { tokens }")
         assert rep.pairs() == []
         assert rep.overlap_ratio() == 1.0  # nothing on the critical path
-
-
-class TestWireCostModel:
-    """Per-axis wire-cost model (ISSUE 12): bytes x declared per-axis
-    link bandwidth -> modeled seconds, plus the (K-1)/(k-1) pod-scale
-    ring projection. Pure dict math — deliberately unit-testable
-    without any HLO."""
-
-    def test_seconds_are_bytes_over_bandwidth(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            wire_cost_seconds
-        out = wire_cost_seconds({"inter": 6.75e9, "intra": 45e9},
-                                {"inter": 6.75, "intra": 45.0})
-        assert out["per_axis"]["inter"]["seconds"] == 1.0
-        assert out["per_axis"]["intra"]["seconds"] == 1.0
-        assert out["total_seconds"] == 2.0
-        # ties resolve to the first-seen slowest; both are 1.0 here
-        assert out["bottleneck_axis"] in ("inter", "intra")
-
-    def test_bottleneck_is_slowest_axis(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            wire_cost_seconds
-        out = wire_cost_seconds({"inter": 100.0, "intra": 100.0},
-                                {"inter": 1.0, "intra": 10.0})
-        assert out["bottleneck_axis"] == "inter"
-
-    def test_undeclared_bandwidth_visible_not_free(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            wire_cost_seconds
-        out = wire_cost_seconds({"inter": 100.0, "mystery": 100.0},
-                                {"inter": 1.0})
-        assert out["per_axis"]["mystery"]["seconds"] is None
-        assert out["per_axis"]["mystery"]["bytes"] == 100
-        # total sums only the priced axes
-        assert out["total_seconds"] == out["per_axis"]["inter"]["seconds"]
-
-    def test_pod_projection_scales_ring_sends(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            pod_scale_wire_seconds
-        # toy axis of 2 -> pod axis of 16: (16-1)/(2-1) = 15x bytes
-        out = pod_scale_wire_seconds(
-            {"inter": 100.0, "intra": 300.0},
-            {"inter": 2, "intra": 4}, {"inter": 16, "intra": 16},
-            {"inter": 1.0, "intra": 1.0})
-        assert out["scaled_axis_bytes"]["inter"] == 1500
-        assert out["scaled_axis_bytes"]["intra"] == 300 * 15 // 3
-        assert "assumption" in out
-
-    def test_unknown_axis_size_passes_through_unscaled(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            pod_scale_wire_seconds
-        out = pod_scale_wire_seconds({"x": 64.0}, {}, {}, {"x": 1.0})
-        assert out["scaled_axis_bytes"]["x"] == 64
-
-
-CROSS_AXIS = """
-HloModule crossaxis
-
-ENTRY %main (p: f32[8,16]) -> f32[8,16] {
-  %p = f32[8,16] parameter(0)
-  %intra1 = f32[8,16] collective-permute(f32[8,16] %p), source_target_pairs={{0,1},{1,2},{2,3},{3,0},{4,5},{5,6},{6,7},{7,4}}
-  %inter1 = f32[8,16] collective-permute(f32[8,16] %p), source_target_pairs={{0,4},{4,0},{1,5},{5,1},{2,6},{6,2},{3,7},{7,3}}
-  %dep = f32[8,16] add(f32[8,16] %intra1, f32[8,16] %intra1)
-  ROOT %inter2 = f32[8,16] collective-permute(f32[8,16] %dep), source_target_pairs={{0,4},{4,0},{1,5},{5,1},{2,6},{6,2},{3,7},{7,3}}
-}
-"""
-
-SAME_AXIS_STEPS = """
-HloModule sameaxis
-
-ENTRY %main (p: f32[8,16]) -> f32[8,16] {
-  %p = f32[8,16] parameter(0)
-  %s1 = f32[8,16] collective-permute(f32[8,16] %p), source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
-  ROOT %s2 = f32[8,16] collective-permute(f32[8,16] %p), source_target_pairs={{0,2},{1,3},{2,0},{3,1}}
-}
-"""
-
-
-class TestCrossAxisTier:
-    """Phase-pipelining evidence (ISSUE 15): permute pairs on
-    DIFFERENT mesh axes (distinct rank-group partitions in their
-    source_target_pairs) that are mutually dependence-free. The
-    unpipelined hierarchical gather has none (every long-haul permute
-    descends from every intra permute); the pipelined form has one per
-    co-resident chunk pair."""
-
-    def test_signature_classifies_axes_not_steps(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import (
-            _permute_group_signature, _same_axis)
-        intra = _permute_group_signature(
-            "source_target_pairs={{0,1},{1,2},{2,3},{3,0}}")
-        intra_d2 = _permute_group_signature(
-            "source_target_pairs={{0,2},{1,3},{2,0},{3,1}}")
-        inter = _permute_group_signature(
-            "source_target_pairs={{0,4},{4,0},{1,5},{5,1}}")
-        # a distance-2 delivery splits the ring into cosets — finer
-        # than distance-1 but nested inside the SAME axis groups; the
-        # strided (other-axis) exchange crosses them
-        assert _same_axis(intra, intra_d2)
-        assert not _same_axis(intra, inter)
-        assert not _same_axis(intra_d2, inter)
-        assert _permute_group_signature("no pairs here") is None
-
-    def test_independent_cross_axis_pair_counted(self):
-        rep = audit_hlo_text(CROSS_AXIS)
-        # intra1 x inter1 independent (1 pair); inter2 DEPENDS on
-        # intra1 (not counted); inter1 x inter2 same axis (not
-        # counted)
-        assert rep.cross_axis == {"pairs": 1, "partnered": 2,
-                                  "permutes": 3}
-        assert 0.0 < rep.cross_axis_overlap_ratio() < 1.0
-
-    def test_same_axis_steps_never_pair(self):
-        rep = audit_hlo_text(SAME_AXIS_STEPS)
-        assert rep.cross_axis["pairs"] == 0
-        assert rep.cross_axis_overlap_ratio() == 0.0
-
-    def test_row_carries_cross_axis_fields(self):
-        import json
-        row = audit_hlo_text(CROSS_AXIS).to_row()
-        json.dumps(row)
-        assert row["cross_axis_pairs"] == 1
-        assert row["cross_axis_overlap_ratio"] > 0.0
-
-
-class TestCalibrationSource:
-    """Every emitted wire-cost row must say where its bandwidths came
-    from (ISSUE 15 satellite): declared model inputs vs measured
-    calibration — and the pod projection must carry its target shape
-    and ring-send assumption."""
-
-    def test_default_is_declared(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            wire_cost_seconds
-        out = wire_cost_seconds({"inter": 1.0}, {"inter": 1.0})
-        assert out["calibration"] == "declared"
-
-    def test_measured_label_rides_through_projection(self):
-        from hcache_deepspeed_tpu.profiling.hlo_audit import \
-            pod_scale_wire_seconds
-        out = pod_scale_wire_seconds(
-            {"inter": 100.0}, {"inter": 2}, {"inter": 16},
-            {"inter": 1.0}, calibration="measured")
-        assert out["calibration"] == "measured"
-        assert out["pod_axis_sizes"] == {"inter": 16}
-        assert out["toy_axis_sizes"] == {"inter": 2}
-        assert "assumption" in out
-
-
-# A module with fused-kernel markers (ISSUE 18): the named-scope
-# metadata ``hds_fused_*`` survives into optimized-HLO ``op_name``, and
-# the in-kernel tier scores ONLY the scoped instructions — two scoped
-# ring permutes riding beside a scoped dot and a scoped dot-bearing
-# fusion, with an unscoped permute+dot pair alongside that must not
-# leak into the fused counts.
-FUSED_KERNEL = """
-HloModule fused
-
-%mathy (a: f32[8,8]) -> f32[8,8] {
-  %a = f32[8,8] parameter(0)
-  %dm = f32[8,8] dot(f32[8,8] %a, f32[8,8] %a), lhs_contracting_dims={1}, rhs_contracting_dims={1}
-}
-
-ENTRY %main (p: (f32[8,16], f32[8,8])) -> (f32[8,16], f32[8,8]) {
-  %p = (f32[8,16], f32[8,8]) parameter(0)
-  %shard = f32[8,16] get-tuple-element(%p), index=0
-  %x = f32[8,8] get-tuple-element(%p), index=1
-  %cp1 = f32[8,16] collective-permute(f32[8,16] %shard), source_target_pairs={{0,1},{1,0}}, metadata={op_name="jit(step)/hds_fused_gather_matmul/ppermute"}
-  %cp2 = f32[8,16] collective-permute(f32[8,16] %cp1), source_target_pairs={{0,1},{1,0}}, metadata={op_name="jit(step)/hds_fused_gather_matmul/ppermute"}
-  %cp3 = f32[8,16] collective-permute(f32[8,16] %shard), source_target_pairs={{0,1},{1,0}}
-  %d1 = f32[8,8] dot(f32[8,8] %x, f32[8,8] %x), lhs_contracting_dims={1}, rhs_contracting_dims={1}, metadata={op_name="jit(step)/hds_fused_gather_matmul/dot_general"}
-  %f1 = f32[8,8] fusion(f32[8,8] %x), kind=kOutput, calls=%mathy, metadata={op_name="jit(step)/hds_fused_rs_epilogue/quant"}
-  %d2 = f32[8,8] dot(f32[8,8] %x, f32[8,8] %x), lhs_contracting_dims={1}, rhs_contracting_dims={1}
-  %cc = f32[8,8] custom-call(f32[8,8] %x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/hds_fused_gather_matmul/pallas_call"}
-  ROOT %out = (f32[8,16], f32[8,8]) tuple(%cp2, %d1)
-}
-"""
-
-
-class TestFusedInKernelTier:
-    """ISSUE 18: the in-kernel tier recognizes ``hds_fused_*``
-    named-scope markers in instruction metadata and scores the permutes
-    a fused kernel SUBSUMES (pairs with scoped dots, incl. dot-bearing
-    fusions), attributing their wire bytes — while unscoped
-    instructions stay invisible to it."""
-
-    def test_scoped_counts_and_pairs(self):
-        rep = audit_hlo_text(FUSED_KERNEL)
-        fk = rep.fused_kernel
-        # cp3 (unscoped) excluded; d2 (unscoped) excluded; f1 counts as
-        # a dot via its dot-bearing called computation
-        assert fk["scoped_permutes"] == 2
-        assert fk["scoped_dots"] == 2
-        assert fk["subsumed_pairs"] == 2
-        assert fk["custom_calls"] == 1
-
-    def test_wire_bytes_attributed_to_scoped_permutes_only(self):
-        rep = audit_hlo_text(FUSED_KERNEL)
-        # two scoped f32[8,16] permutes — the unscoped cp3 is priced by
-        # the permute-chain tier, never by the fused tier
-        assert rep.fused_kernel["wire_bytes"] == 2 * 8 * 16 * 4
-
-    def test_unfused_module_scores_zero(self):
-        rep = audit_hlo_text(RING_BODY)
-        assert rep.fused_kernel["subsumed_pairs"] == 0
-        assert rep.fused_kernel["wire_bytes"] == 0
-
-    def test_row_carries_fused_fields(self):
-        import json
-        row = audit_hlo_text(FUSED_KERNEL).to_row()
-        json.dumps(row)
-        assert row["fused_subsumed_pairs"] == 2
-        assert row["fused_wire_bytes"] == 2 * 8 * 16 * 4
-        assert row["fused_custom_calls"] == 1

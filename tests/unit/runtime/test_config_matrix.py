@@ -59,7 +59,6 @@ COMBOS = [
 ]
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("name,overrides",
                          COMBOS, ids=[c[0] for c in COMBOS])
 def test_config_combo_initializes_and_steps(eight_devices, name,

@@ -71,7 +71,6 @@ def _ours_losses(hf_model, batches, model_type="gpt2", replace_cfg=None,
             for b in batches]
 
 
-@pytest.mark.slow
 class TestTorchLossParity:
     @pytest.mark.parametrize("extra", [
         {},

@@ -191,167 +191,6 @@ class TestQuantizedWire:
         assert all(np.isfinite(a).all() for a in after)
 
 
-class TestDecomposedTransportMatrix:
-    """``zero_collective_impl=decomposed`` (chunked-ppermute ring
-    transport, comm/ring.py) must be BITWISE-equal to the native
-    transport — fp32/bf16 x qwZ/qgZ, at prefetch depth 1 AND depth 0
-    (``stage3_prefetch_bucket_size=0``; ``overlap_comm=false`` is
-    rejected for decomposed by construction). The ring changes how the
-    bytes move, never what they say."""
-
-    def _assert_transport_bitwise(self, bf16=False, depth0=False,
-                                  steps=3, impl="decomposed",
-                                  **zero_extra):
-        extra_dec = dict(zero_extra, zero_collective_impl=impl)
-        if impl in ("hierarchical", "fused"):
-            extra_dec["zero_mesh_shape"] = [2, 4]
-        if depth0:
-            zero_extra = dict(zero_extra,
-                              stage3_prefetch_bucket_size=0)
-            extra_dec["stage3_prefetch_bucket_size"] = 0
-        a = _build(_gpt2, True, bf16=bf16, **zero_extra)
-        b = _build(_gpt2, True, bf16=bf16, **extra_dec)
-        want = 0 if depth0 else 1
-        assert a.zero_overlap_plan["depth"] == want
-        assert b.zero_overlap_plan["depth"] == want
-        assert b.zero_overlap_plan["collective_impl"] == impl
-        batch = _batch()
-        la = [float(a.train_batch(batch=batch)) for _ in range(steps)]
-        lb = [float(b.train_batch(batch=batch)) for _ in range(steps)]
-        assert la == lb, (la, lb)
-        for xa, xb in zip(jax.tree.leaves(a.state["params"]),
-                          jax.tree.leaves(b.state["params"])):
-            np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
-
-    def test_fp32_qwz_depth1(self, eight_devices):
-        self._assert_transport_bitwise(zero_quantized_weights=True)
-
-    def test_fp32_qwz_depth0(self, eight_devices):
-        self._assert_transport_bitwise(zero_quantized_weights=True,
-                                       depth0=True)
-
-    def test_bf16_qwz_depth1(self, eight_devices):
-        self._assert_transport_bitwise(bf16=True,
-                                       zero_quantized_weights=True)
-
-    def test_bf16_qwz_depth0(self, eight_devices):
-        self._assert_transport_bitwise(bf16=True,
-                                       zero_quantized_weights=True,
-                                       depth0=True)
-
-    def test_fp32_qgz_depth1(self, eight_devices):
-        self._assert_transport_bitwise(zero_quantized_weights=True,
-                                       zero_quantized_gradients=True)
-
-    def test_bf16_qgz_depth1(self, eight_devices):
-        self._assert_transport_bitwise(bf16=True,
-                                       zero_quantized_weights=True,
-                                       zero_quantized_gradients=True)
-
-    def test_fp32_qrs_ef_depth1(self, eight_devices):
-        """The PR 6 quantized wire rides the ring: per-ring-chunk
-        quantization preserves EF residual semantics and the
-        deterministic bucket layout — still bitwise."""
-        self._assert_transport_bitwise(
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True)
-
-    def test_bf16_qrs_ef_depth0(self, eight_devices):
-        self._assert_transport_bitwise(
-            bf16=True, depth0=True,
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True)
-
-    def test_fp32_qrs_int4_depth1(self, eight_devices):
-        self._assert_transport_bitwise(
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True,
-            zero_quantized_reduce_scatter_bits=4)
-
-    def test_hpz_decomposed_depth1(self, eight_devices):
-        """hpZ secondary gathers ride intra-group rings
-        (axis_index_groups)."""
-        self._assert_transport_bitwise(zero_quantized_weights=True,
-                                       zero_hpz_partition_size=2)
-
-    # ---- hierarchical (2-D mesh) transport: same bitwise contract,
-    # the 2x4 factoring of the 8-device axis (comm/hierarchical.py)
-    def test_fp32_qwz_hier_depth1(self, eight_devices):
-        self._assert_transport_bitwise(impl="hierarchical",
-                                       zero_quantized_weights=True)
-
-    def test_bf16_qwz_hier_depth0(self, eight_devices):
-        self._assert_transport_bitwise(bf16=True, depth0=True,
-                                       impl="hierarchical",
-                                       zero_quantized_weights=True)
-
-    def test_fp32_qrs_ef_hier_depth1(self, eight_devices):
-        """The quantized wire rides the mesh rings: quantization
-        happens before the transport choice, EF residuals intact —
-        still bitwise vs the native transport."""
-        self._assert_transport_bitwise(
-            impl="hierarchical",
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True)
-
-    # ---- fused (ISSUE 18) transport: the fused gather-matmul /
-    # reduce-scatter-epilogue kernels behind zero_collective_impl=fused
-    # must be BITWISE-equal to the native transport on every cell —
-    # fp32/bf16 x qwZ / qrs-EF / int4, depth 1 AND depth 0. On
-    # platforms without Pallas the fused paths dispatch to their
-    # reference twins (same assembly, same consumption kernel), so
-    # parity here is the transport-swap contract, not luck.
-    def test_fp32_qwz_fused_depth1(self, eight_devices):
-        self._assert_transport_bitwise(impl="fused",
-                                       zero_quantized_weights=True)
-
-    def test_fp32_qwz_fused_depth0(self, eight_devices):
-        self._assert_transport_bitwise(impl="fused", depth0=True,
-                                       zero_quantized_weights=True)
-
-    def test_bf16_qwz_fused_depth1(self, eight_devices):
-        self._assert_transport_bitwise(bf16=True, impl="fused",
-                                       zero_quantized_weights=True)
-
-    def test_fp32_qwz_fused_matmul_depth1(self, eight_devices):
-        """Mid-gather consumption: qwZ leaves are handed to the Dense
-        kernel as ShardedQuantizedTensor and consumed by the fused
-        gather-matmul — bitwise vs the native gather-then-matmul."""
-        self._assert_transport_bitwise(
-            impl="fused",
-            zero_quantized_weights=True,
-            zero_quantized_weights_fused_matmul=True)
-
-    def test_fp32_qrs_ef_fused_depth1(self, eight_devices):
-        """The fused reduce-scatter epilogue quantizes + error-feeds
-        the cotangent bucket as it folds — same deterministic bucket
-        layout and residual state as the unfused lagged lane."""
-        self._assert_transport_bitwise(
-            impl="fused",
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True)
-
-    def test_bf16_qrs_ef_fused_depth0(self, eight_devices):
-        self._assert_transport_bitwise(
-            bf16=True, depth0=True, impl="fused",
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True)
-
-    def test_fp32_qrs_int4_fused_depth1(self, eight_devices):
-        self._assert_transport_bitwise(
-            impl="fused",
-            zero_quantized_weights=True,
-            zero_quantized_reduce_scatter=True,
-            zero_reduce_scatter_error_feedback=True,
-            zero_quantized_reduce_scatter_bits=4)
-
-
 class TestGradAccumulation:
 
     def test_gas2_bitwise(self, eight_devices):
