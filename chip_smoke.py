@@ -399,17 +399,25 @@ def serve_phase(size, topology=None, one_chip=None):
     req = next(r for r in live.values() if r.uid == uid)
     context = list(req.prompt) + req.tokens_out[:n_out]
     fresh = _engine(size, params, topology)
-    from hcache_deepspeed_tpu.inference.ragged.kv_cache import \
-        pool_sized_copies
+    import jax
+    from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
+        pool_sized_copies, stacked_layer_copies)
     ref = _uninterrupted_logits(fresh, context)
-    # under tensor parallelism the program is one device's: its pool is
-    # this device's shard
-    copies = pool_sized_copies(
-        _decode_program_text(fresh),
-        fresh.cache.k.addressable_shards[0].data.shape)
+    # under tensor parallelism the program is one device's: its pool and
+    # its weights are this device's shards
+    local = lambda x: x.addressable_shards[0].data.shape
+    text = _decode_program_text(fresh)
+    copies = pool_sized_copies(text, local(fresh.cache.k))
     check(not copies,
           "the decode program neither copies nor slices the KV pool or a "
           f"layer of it: {copies}")
+    copies = stacked_layer_copies(
+        text, [local(leaf) for leaf in
+               jax.tree.leaves(fresh.model.params["layers"])
+               if leaf.ndim == 3])
+    check(not copies,
+          "the decode program reads each layer's weights inside the "
+          f"matmul that uses them: {copies}")
     gap = _logit_gap(got, ref)
     check(np.all(np.isfinite(got)) and got.shape == (vocab,),
           f"restored logits are finite, shape {got.shape}")

@@ -445,27 +445,36 @@ class PagedInferenceModel:
     # -------------------------------------------------------------- #
     # Layer math (mirrors models/llama.py LlamaBlock exactly)
     # -------------------------------------------------------------- #
+    def _qkv_heads(self, attn, h):
+        """The q, k and v projections of ``h`` [B, T, H] by one layer's
+        attention parameters (biased where the family has biases), split
+        into heads: q [B, T, Hq, D], k/v [B, T, KV, D]. Head counts come
+        from the kernel widths so the same code runs on the full model or
+        a tensor-parallel shard (H/tp local heads).
+
+        The barrier stands between the dots and the split into heads.
+        Without it the TPU compiler folds the reshape into the dot, makes
+        it a convolution over the head dimension that wants the kernel
+        transposed, and so slices each kernel out of the stacked leaf
+        into a buffer of its own and copies that into the other layout:
+        two more passes over the layer's q, k and v weights in every
+        program. Behind it the dots are plain matmuls and read their
+        layer of the stacked leaf in place, as ``o_proj`` and the MLP do
+        (``tests/unit/inference/test_kv_pool_in_place.py``)."""
+        D = self.cfg.head_dim
+
+        def proj(p):
+            y = self._mm(h, p["kernel"])
+            return y + p["bias"] if "bias" in p else y
+
+        qkv = jax.lax.optimization_barrier(tuple(
+            proj(attn[name]) for name in ("q_proj", "k_proj", "v_proj")))
+        return tuple(y.reshape(*y.shape[:-1], y.shape[-1] // D, D)
+                     for y in qkv)
+
     def _qkv(self, lp, h, positions):
-        """h: [B, T, H]; returns q [B,T,Hq,D], k/v [B,T,KV,D] (roped).
-        Head counts come from the kernel widths so the same code runs on
-        the full model or a tensor-parallel shard (H/tp local heads)."""
-        cfg = self.cfg
-        B, T, _ = h.shape
-        D = cfg.head_dim
-        def proj(p, x):
-            y = self._mm(x, p["kernel"])
-            if "bias" in p:   # qwen-style attention biases
-                y = y + p["bias"]
-            return y
-        qk = lp["self_attn"]["q_proj"]
-        kk = lp["self_attn"]["k_proj"]
-        vk = lp["self_attn"]["v_proj"]
-        q = proj(qk, h)
-        k = proj(kk, h)
-        v = proj(vk, h)
-        q = q.reshape(B, T, q.shape[-1] // D, D)
-        k = k.reshape(B, T, k.shape[-1] // D, D)
-        v = v.reshape(B, T, v.shape[-1] // D, D)
+        """h: [B, T, H]; returns q [B,T,Hq,D], k/v [B,T,KV,D] (roped)."""
+        q, k, v = self._qkv_heads(lp["self_attn"], h)
         q = apply_rope(q, self.cos, self.sin, positions)
         k = apply_rope(k, self.cos, self.sin, positions)
         return q, k, v

@@ -92,17 +92,8 @@ class PagedGPT2Model(PagedInferenceModel):
 
     # -------------------------------------------------------------- #
     def _qkv(self, lp, h, positions):
-        """Separate biased projections, no rope; head counts from the
-        (possibly TP-sharded) kernel widths."""
-        B, T, _ = h.shape
-        D = self.cfg.head_dim
-        a = lp["attn"]
-        q = self._mm(h, a["q_proj"]["kernel"]) + a["q_proj"]["bias"]
-        k = self._mm(h, a["k_proj"]["kernel"]) + a["k_proj"]["bias"]
-        v = self._mm(h, a["v_proj"]["kernel"]) + a["v_proj"]["bias"]
-        return (q.reshape(B, T, q.shape[-1] // D, D),
-                k.reshape(B, T, k.shape[-1] // D, D),
-                v.reshape(B, T, v.shape[-1] // D, D))
+        """Separate biased projections, no rope."""
+        return self._qkv_heads(lp["attn"], h)
 
     def _attn_out_parts(self, lp, attn):
         p = lp["attn"]["c_proj"]

@@ -57,16 +57,7 @@ class PagedPhiModel(PagedFalconModel):
 
     def _qkv(self, lp, h, positions):
         cfg = self.cfg
-        B, T, _ = h.shape
-        D = cfg.head_dim
-        a = lp["self_attn"]
-        # head counts from the (possibly TP-sharded) kernel widths
-        q = self._mm(h, a["q_proj"]["kernel"]) + a["q_proj"]["bias"]
-        k = self._mm(h, a["k_proj"]["kernel"]) + a["k_proj"]["bias"]
-        v = self._mm(h, a["v_proj"]["kernel"]) + a["v_proj"]["bias"]
-        q = q.reshape(B, T, q.shape[-1] // D, D)
-        k = k.reshape(B, T, k.shape[-1] // D, D)
-        v = v.reshape(B, T, v.shape[-1] // D, D)
+        q, k, v = self._qkv_heads(lp["self_attn"], h)
         q = partial_rope(q, self.cos, self.sin, positions,
                          rotary_dim=cfg.rotary_dim)
         k = partial_rope(k, self.cos, self.sin, positions,

@@ -34,9 +34,10 @@ from .blocked_allocator import BlockedAllocator
 from .sequence import SequenceDescriptor
 
 
-_HLO_COPY = re.compile(
-    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* "
-    r"(copy|dynamic-slice|dynamic-update-slice)\(", re.M)
+#: an instruction with an array result: (name, result dimensions, opcode)
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", re.M)
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", re.M)
 
 
 def pool_sized_copies(hlo_text: str, pool_shape) -> List[str]:
@@ -48,8 +49,33 @@ def pool_sized_copies(hlo_text: str, pool_shape) -> List[str]:
     through HBM once per execution."""
     extents = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
     return [f"{op} {name} [{dims}]"
-            for name, dims, op in _HLO_COPY.findall(hlo_text)
-            if int(np.prod([int(d) for d in dims.split(",")])) in extents]
+            for name, dims, op in _HLO_INSTRUCTION.findall(hlo_text)
+            if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+            and int(np.prod([int(d) for d in dims.split(",")])) in extents]
+
+
+def stacked_layer_copies(hlo_text: str, stacked_shapes) -> List[str]:
+    """The ``copy``, ``fusion`` and ``dynamic-slice`` instructions of an
+    optimised program that run as operations of their own (outside every
+    fused computation: what a device trace names) and whose result is
+    one layer ``[1, ...]`` of a stacked parameter leaf of
+    ``stacked_shapes`` (``[L, ...]`` each): a slice of the leaf into a
+    second buffer, or a copy of that buffer into another layout. A layer
+    scan whose matmuls read their layer of the leaf inside their own
+    fusion has none; each one found is one more pass over that layer's
+    weights in every execution."""
+    layers = {(1,) + tuple(shape[1:]) for shape in stacked_shapes}
+    heads = list(_HLO_COMPUTATION.finditer(hlo_text))
+    found = []
+    for head, following in zip(heads, heads[1:] + [None]):
+        if head.group(1).startswith("fused_computation"):
+            continue
+        body = hlo_text[head.end():following.start() if following else None]
+        found += [f"{op} {name} [{dims}]"
+                  for name, dims, op in _HLO_INSTRUCTION.findall(body)
+                  if op in ("copy", "fusion", "dynamic-slice")
+                  and tuple(int(d) for d in dims.split(",")) in layers]
+    return found
 
 
 class BlockedKVCache:

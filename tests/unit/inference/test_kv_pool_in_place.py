@@ -21,7 +21,7 @@ import pytest
 
 from hcache_deepspeed_tpu.inference.model import PagedInferenceModel
 from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
-    BlockedKVCache, pool_sized_copies)
+    BlockedKVCache, pool_sized_copies, stacked_layer_copies)
 from hcache_deepspeed_tpu.models.llama import LlamaForCausalLM, llama_tiny
 
 BS, NBLK, NB = 16, 2048, 8       # 32,768 slots; a sequence holds 128
@@ -139,10 +139,11 @@ class _ShapesOnly(PagedInferenceModel):
 
 
 @functools.lru_cache(maxsize=None)
-def _v5e_program(one_chip, B, T):
+def _v5e_program(one_chip, B, T, restore=False):
     """Mistral-7B widths, 2 layers, the serve cell's block size and pool
     (2560 blocks of 64), compiled for the described chip: ``(compiled,
-    pool)``."""
+    pool, params)``. ``restore``: the program that replays both layers'
+    K and V from ``[2, B, T, H]`` latents, in place of the forward."""
     from jax.experimental.compilation_cache import compilation_cache
     from hcache_deepspeed_tpu import platform
     from hcache_deepspeed_tpu.inference.model import stack_layer_params
@@ -181,13 +182,21 @@ def _v5e_program(one_chip, B, T):
             jnp.bfloat16))
         i32 = lambda *shape: on_chip(
             jax.ShapeDtypeStruct(shape, jnp.int32), jnp.int32)
-        compiled = model._fwd.lower(params, pool, pool, i32(B, T), i32(B),
-                                    i32(B, 32), i32(B)).compile()
+        if restore:
+            latents = on_chip(jax.ShapeDtypeStruct(
+                (cfg.n_layer, B, T, cfg.hidden_size), jnp.bfloat16))
+            lowered = model._restore.lower(
+                params, pool, pool, i32(), latents, i32(B), i32(B, 32),
+                i32(B))
+        else:
+            lowered = model._fwd.lower(params, pool, pool, i32(B, T),
+                                       i32(B), i32(B, 32), i32(B))
+        compiled = lowered.compile()
     finally:
         platform._platform = None
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
         compilation_cache.reset_cache()
-    return compiled, pool
+    return compiled, pool, params
 
 
 V5E_SHAPES = pytest.mark.parametrize("B,T", [(8, 1), (1, 512)],
@@ -199,12 +208,32 @@ def test_v5e_program_moves_no_layer_of_the_pool(one_chip, B, T):
     """The optimised v5e program holds no copy or slice of the pool's or
     a layer's extent, and its temporaries stay under one layer of one
     pool."""
-    compiled, pool = _v5e_program(one_chip, B, T)
+    compiled, pool, _ = _v5e_program(one_chip, B, T)
     text = compiled.as_text()
     assert "tpu_custom_call" in text        # the paged kernel is there
     assert pool_sized_copies(text, pool.shape) == []
     layer_bytes = int(np.prod(pool.shape[1:])) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("B,T,restore",
+                         [(8, 1, False), (1, 512, False), (1, 512, True)],
+                         ids=["decode", "slice", "restore"])
+def test_v5e_program_reads_each_layers_weights_in_place(one_chip, B, T,
+                                                        restore):
+    """No operation of the optimised v5e program has one layer of a
+    stacked weight leaf for its result: every matmul of the layer loop
+    takes its layer of the leaf inside its own fusion, so a layer's
+    weights cross HBM once a program. (With the split into heads folded
+    into the q, k and v dots the compiler made them convolutions that
+    wanted each kernel sliced out of the leaf and copied into another
+    layout: two more passes, 15% of the serve cell's device time.)"""
+    compiled, _, params = _v5e_program(one_chip, B, T, restore)
+    kernels = [leaf.shape for leaf in jax.tree.leaves(params["layers"])
+               if leaf.ndim == 3]
+    assert len(kernels) == 7 and all(np.prod(shape[1:]) >= 1 << 20
+                                     for shape in kernels)
+    assert stacked_layer_copies(compiled.as_text(), kernels) == []
 
 
 @V5E_SHAPES
@@ -224,7 +253,7 @@ def test_v5e_program_names_the_paged_kernel_to_its_finders(one_chip, B, T,
     with open(os.path.join(os.path.dirname(benchmarks.__file__), "metrics",
                            f"{metric}.json")) as f:
         spec = json.load(f)
-    compiled, pool = _v5e_program(one_chip, B, T)
+    compiled, pool, _ = _v5e_program(one_chip, B, T)
     _, KV, P, D = pool.shape
     rx = re.compile(spec["pattern"].format_map(
         {"kv_blocks": f"{KV},{P // 64},64,{D}"}))
