@@ -439,6 +439,97 @@ def serve_phase(size, topology=None, one_chip=None):
 
 
 # ------------------------------------------------------------------ #
+# serve, a hybrid trunk (recurrent layers beside full attention)
+# ------------------------------------------------------------------ #
+#: one period of Olmo-Hybrid-7B at its published widths: three
+#: gated-delta-rule layers and a full-attention layer (1.6 B parameters
+#: with the embedding and the head, 3.2 GB in bf16)
+OLMO_HYBRID_PERIOD = {
+    "model_type": "olmo_hybrid", "vocab_size": 100352, "hidden_size": 3840,
+    "intermediate_size": 11008, "num_hidden_layers": 4,
+    "num_attention_heads": 30, "num_key_value_heads": 30,
+    "max_position_embeddings": 8192, "rms_norm_eps": 1e-6,
+    "layer_types": ["linear_attention"] * 3 + ["full_attention"],
+    "linear_num_key_heads": 30, "linear_num_value_heads": 30,
+    "linear_key_head_dim": 96, "linear_value_head_dim": 192,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rope_parameters": {"rope_theta": None}, "torch_dtype": "bfloat16"}
+#: the same period at toy width in fp32, for the CPU test of the phase
+TINY_HYBRID_PERIOD = dict(
+    OLMO_HYBRID_PERIOD, vocab_size=256, hidden_size=64,
+    intermediate_size=128, num_attention_heads=4, num_key_value_heads=4,
+    max_position_embeddings=256, linear_num_key_heads=4,
+    linear_num_value_heads=4, linear_key_head_dim=8,
+    linear_value_head_dim=16, torch_dtype="float32")
+
+
+def hybrid_phase(hf, block_size=64, prefill_chunk=512):
+    """A prompt of three slices and a few decode steps through the
+    hybrid trunk's two pools, then the chip's own decode program read
+    for what it must not hold: a layer of a stacked weight leaf, a copy
+    of the KV pool or of the recurrent-state pool."""
+    import jax
+
+    from hcache_deepspeed_tpu.inference import RaggedInferenceEngineConfig
+    from hcache_deepspeed_tpu.inference.factory import (MODEL_FAMILIES,
+                                                        build_hf_engine)
+    from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
+        pool_sized_copies, stacked_layer_copies)
+    from hcache_deepspeed_tpu.models.olmo_hybrid import \
+        OlmoHybridForCausalLM
+    from hcache_deepspeed_tpu.models.seeded import seeded_params
+    params = seeded_params(
+        OlmoHybridForCausalLM(MODEL_FAMILIES[hf["model_type"]](hf)),
+        {"input_ids": np.zeros((1, 128), np.int32)}, seed=0,
+        dtype=hf["torch_dtype"])
+    n_prompt = 2 * prefill_chunk + prefill_chunk // 3
+    engine = build_hf_engine(hf, params, RaggedInferenceEngineConfig(
+        state_manager={"max_tracked_sequences": 8,
+                       "max_ragged_sequence_count": 8,
+                       "max_context": 4 * prefill_chunk,
+                       "prefill_chunk": prefill_chunk},
+        kv_cache={"block_size": block_size,
+                  "num_blocks": 2 + 4 * prefill_chunk // block_size,
+                  "cache_dtype": hf["torch_dtype"]}))
+    del params
+    rng = np.random.default_rng(0)
+    logits, latents = engine.put(
+        [0], [rng.integers(0, hf["vocab_size"], n_prompt)])
+    for _ in range(3):
+        logits, _ = engine.put([0], [[int(np.argmax(logits[0]))]])
+    check(np.all(np.isfinite(logits)),
+          f"a {n_prompt}-token prompt in three slices and three decode "
+          "steps through both pools give finite logits")
+    check(np.asarray(latents[0]).shape[0] == 1,
+          "latents left the program for the full layer only")
+    tok, start, t_len, tables = engine._blank_lanes(8)
+    model, cache = engine.model, engine.cache
+    text = model._fwd.lower(
+        model.params, cache.k, cache.v, cache.state, cache.conv, tok,
+        start, tables, t_len,
+        np.full((8,), engine.state.state_slots, np.int32)
+    ).compile().as_text()
+    copies = pool_sized_copies(text, cache.k.shape) + \
+        pool_sized_copies(text, cache.state.shape)
+    check(not copies,
+          "the hybrid decode program neither copies nor slices the KV "
+          f"pool, the recurrent-state pool or a layer of either: {copies}")
+    copies = stacked_layer_copies(
+        text, [leaf.shape for stack in ("lin_layers", "full_layers")
+               for leaf in jax.tree.leaves(model.params[stack])
+               if leaf.ndim == 3])
+    check(not copies,
+          "the hybrid decode program reads each layer's weights inside "
+          f"the matmul that uses them: {copies}")
+    engine.flush(0)
+    check(engine.state.state_slots_in_use == 0 and
+          engine.free_blocks == cache.num_blocks - 1,
+          "the flush gave back the state slot and every KV block")
+    print(f"  peak_bytes_in_use (process lifetime): {_peak_bytes()}",
+          flush=True)
+
+
+# ------------------------------------------------------------------ #
 # train
 # ------------------------------------------------------------------ #
 def train_phase(size, devices, zero_stage=0):
@@ -561,6 +652,7 @@ def main():
         return out
 
     one_chip = run("serve", serve_phase, size)
+    run("serve hybrid", hybrid_phase, OLMO_HYBRID_PERIOD)
     losses = run("train", train_phase, size, jax.devices()[:1])["losses"]
     if device["count"] >= 4:
         four = jax.devices()[:4]

@@ -30,7 +30,7 @@ from ..utils.compile_cache import ensure_compile_cache
 from ..utils.logging import log_dist
 from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
-from .ragged.kv_cache import BlockedKVCache, StateManager
+from .ragged.kv_cache import BlockedKVCache, HybridCache, StateManager
 from .ragged.latents import HostLink, LatentProgram, PendingLatents
 from .scheduling import SchedulingError, SchedulingResult
 
@@ -138,14 +138,23 @@ class InferenceEngineV2:
             num_blocks = min(self._size_cache_blocks(model_config, kv_cfg),
                              cap)
         self._model_config = model_config
+        from ..models.olmo_hybrid import OlmoHybridConfig
+        #: a trunk with recurrent layers keeps a state slot a sequence
+        #: beside its KV blocks (``ragged/kv_cache.py HybridCache``)
+        self.recurrent = isinstance(model_config, OlmoHybridConfig)
 
-        self.state = StateManager(sm_cfg.max_tracked_sequences,
-                                  num_blocks, self.block_size,
-                                  self.max_context)
+        self.state = StateManager(
+            sm_cfg.max_tracked_sequences, num_blocks, self.block_size,
+            self.max_context,
+            state_slots=sm_cfg.max_tracked_sequences
+            if self.recurrent else 0)
         # block 0 is reserved scratch: padded decode lanes write there
         self._scratch_block = self.state.allocator.allocate(1)[0]
 
         self.prefix_caching = sm_cfg.prefix_caching
+        if self.prefix_caching and self.recurrent:
+            from .model_hybrid import refuse
+            raise refuse("prefix_caching (shared prefixes)")
         if self.prefix_caching and self.config.hcache.enable_latents:
             raise ValueError(
                 "prefix_caching requires hcache.enable_latents=false: a "
@@ -193,6 +202,9 @@ class InferenceEngineV2:
         elif isinstance(model_config, MixtralConfig):
             from .model_moe import PagedMoEModel
             model_cls = PagedMoEModel
+        elif self.recurrent:
+            from .model_hybrid import PagedHybridModel
+            model_cls = PagedHybridModel
         self.model = model_cls(
             model_config, params, block_size=self.block_size,
             max_blocks_per_seq=self.max_blocks_per_seq,
@@ -202,11 +214,29 @@ class InferenceEngineV2:
             latent_dtype=self.config.hcache.latent_dtype,
             topology=topology, quantization=self.config.quantization)
         self._check_paged_attention_fits(model_config)
-        self.cache = BlockedKVCache(
-            model_config.n_layer, num_blocks, self.block_size,
-            model_config.n_kv_head, model_config.head_dim,
-            dtype=jnp.dtype(kv_cfg.cache_dtype),
-            sharding=self.model.cache_sharding())
+        if self.recurrent:
+            self.cache = HybridCache(
+                self.model.n_latent_layers, num_blocks, self.block_size,
+                model_config.n_kv_head, model_config.head_dim,
+                n_linear_layers=model_config.n_layer
+                - self.model.n_latent_layers,
+                state_slots=self.state.state_slots,
+                n_heads=model_config.linear_num_value_heads,
+                key_dim=model_config.linear_key_head_dim,
+                value_dim=model_config.linear_value_head_dim,
+                conv_taps=model_config.linear_conv_kernel_dim,
+                conv_channels=model_config.conv_channels,
+                dtype=jnp.dtype(kv_cfg.cache_dtype))
+        else:
+            self.cache = BlockedKVCache(
+                model_config.n_layer, num_blocks, self.block_size,
+                model_config.n_kv_head, model_config.head_dim,
+                dtype=jnp.dtype(kv_cfg.cache_dtype),
+                sharding=self.model.cache_sharding())
+        #: recurrent-state evictions and returns (``snapshot_state``,
+        #: ``begin_restore(states=...)``) and the bytes they moved
+        self.state_stats = {"snapshots": 0, "restores": 0,
+                            "bytes_out": 0, "bytes_in": 0}
         #: restore staging progress for the serving layer: cumulative
         #: counts of restore groups, sequences, per-chunk dispatches
         #: issued and latent bytes shipped host->device (a dispatch is
@@ -252,8 +282,11 @@ class InferenceEngineV2:
         """'reserve' allocation mode: size the pool from free device memory
         (reference: memory_config reserve fraction)."""
         from ..platform import get_platform
+        layer_types = getattr(model_config, "layer_types", None)
+        kv_layers = model_config.n_layer if layer_types is None else \
+            sum(1 for kind in layer_types if kind == "full_attention")
         per_token = BlockedKVCache.token_bytes(
-            model_config.n_layer, model_config.n_kv_head,
+            kv_layers, model_config.n_kv_head,
             model_config.head_dim, kv_cfg.cache_dtype)
         platform = get_platform()
         free = platform.available_memory()
@@ -278,6 +311,9 @@ class InferenceEngineV2:
         seq = self.state.get_sequence(uid)
         seen = seq.seen_tokens if seq else 0
         max_tokens = min(max_request_tokens, self.max_context - seen)
+        if self.recurrent and seq is None and \
+                not self.state.free_state_slots:
+            return 0, 0         # no recurrent-state slot to start in
         blocks = self.state.blocks_needed(seq, max_tokens)
         return max_tokens, min(blocks, max_request_blocks)
 
@@ -294,6 +330,10 @@ class InferenceEngineV2:
         new_seqs = sum(1 for u in uids if self.state.get_sequence(u) is None)
         if self.state.n_tracked_sequences + new_seqs > \
                 sm.max_tracked_sequences:
+            return SchedulingResult.EngineSequenceLimitExceeded
+        if self.recurrent and new_seqs > self.state.free_state_slots:
+            # a new sequence takes a recurrent-state slot with its first
+            # forward: none free is the tracked-sequence verdict
             return SchedulingResult.EngineSequenceLimitExceeded
         if len(uids) > sm.max_ragged_sequence_count:
             return SchedulingResult.BatchSequenceLimitExceeded
@@ -510,6 +550,19 @@ class InferenceEngineV2:
         tables[:, 0] = self._scratch_block
         return tok, start, t_len, tables
 
+    def _forward(self, tok, start, tables, t_len, uids, idx):
+        """One program over the built lanes. A trunk with recurrent
+        layers also gets each lane's state slot, blank lanes the spare
+        one."""
+        if not self.recurrent:
+            return self.model.forward_chunk(self.cache, tok, start, tables,
+                                            t_len)
+        slots = np.full((len(t_len),), self.state.state_slots, np.int32)
+        for j, i in enumerate(idx):
+            slots[j] = self.state.get_sequence(uids[i]).state_slot
+        return self.model.forward_chunk(self.cache, tok, start, tables,
+                                        t_len, slots)
+
     def _run_decode(self, uids, tokens, idx, logits_out, latents_out,
                     defer=False):
         tracer = get_tracer()
@@ -523,8 +576,8 @@ class InferenceEngineV2:
                 t_len[j] = 1
         with tracer.span("serve.decode_dispatch",
                          lanes=len(idx), bucket=B):
-            logits, latents = self.model.forward_chunk(
-                self.cache, tok, start, tables, t_len)
+            logits, latents = self._forward(tok, start, tables, t_len,
+                                            uids, idx)
             if not defer:
                 latents = self._start_copies(logits, latents)
         if defer:   # keep the device array whole (row slicing here would
@@ -557,8 +610,8 @@ class InferenceEngineV2:
                          lanes=len(idx), bucket=B, bucket_T=T,
                          tokens=_token_count(tokens[i] for i in idx)
                          if tracer.enabled else 0):
-            logits, latents = self.model.forward_chunk(
-                self.cache, tok, start, tables, t_len)
+            logits, latents = self._forward(tok, start, tables, t_len,
+                                            uids, idx)
             if not defer:
                 latents = self._start_copies(logits, latents)
         if defer:
@@ -696,6 +749,8 @@ class InferenceEngineV2:
         link = self._latent_link
         pending = sum(p.unread_bytes for p in self._pending_parts())
         return {
+            "captured_bytes": link.captured_bytes,
+            "captured_tokens": link.captured_tokens,
             "landed_hidden_bytes": link.landed_hidden_bytes,
             "landed_forced_bytes": link.landed_forced_bytes,
             "dropped_bytes": link.captured_bytes - pending
@@ -853,6 +908,7 @@ class InferenceEngineV2:
         covers prompt + fed tokens (None when latent capture is off) —
         a returning sequence can be HCache-restored from them after a
         flush."""
+        self._refuse_recurrent("generate_fused (the fused decode loop)")
         if not 0.0 < top_p <= 1.0:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         if top_k < 0:
@@ -947,6 +1003,13 @@ class InferenceEngineV2:
                                    for l in logprobs]
         return outs, latents
 
+    def _refuse_recurrent(self, feature: str) -> None:
+        """Raise for a call that would roll a recurrent state back or
+        carry it through a program that does not hold it."""
+        if self.recurrent:
+            from .model_hybrid import refuse
+            raise refuse(feature)
+
     @staticmethod
     def _lookup_draft(history, ngram: int, k: int):
         """Prompt-lookup drafting: find the most recent PRIOR occurrence
@@ -992,6 +1055,7 @@ class InferenceEngineV2:
         Returns ``(outs, stats)`` with
         ``stats = {drafted, accepted, dispatches, tokens}``.
         """
+        self._refuse_recurrent("generate_lookup (speculative rollback)")
         if self.prefix_caching:
             raise ValueError(
                 "generate_lookup with prefix_caching is unsupported: "
@@ -1111,6 +1175,8 @@ class InferenceEngineV2:
         ``lane_iters*max_draft``, now summed over actual live
         iterations instead of ``iters*max_draft`` for the whole
         batch)."""
+        self._refuse_recurrent("generate_lookup_fused (speculative "
+                               "rollback in a fused loop)")
         if self.prefix_caching:
             raise ValueError(
                 "generate_lookup_fused with prefix_caching is "
@@ -1224,6 +1290,7 @@ class InferenceEngineV2:
         latent payload); in exact-KV mode the entries are all None.
         ``prefix_caching`` stays unsupported (rolled-back KV must
         never register as a sharable prefix)."""
+        self._refuse_recurrent("put_spec (rollback of rejected drafts)")
         capture = bool(self.config.hcache.enable_latents)
         if self.prefix_caching:
             raise RuntimeError(
@@ -1304,7 +1371,7 @@ class InferenceEngineV2:
     # -------------------------------------------------------------- #
     @traced("hds.serve.restore_kv")
     def restore_kv(self, batch_uids: Iterable[int], batch_tokens: Iterable,
-                   batch_latents: Iterable) -> None:
+                   batch_latents: Iterable, states=None) -> None:
         """Rebuild the blocked KV cache for ``batch_uids`` from saved
         latents without a full forward: allocate blocks, then per layer
         replay the K/V projection + RoPE + cache write with host→HBM copies
@@ -1314,12 +1381,14 @@ class InferenceEngineV2:
         (:meth:`begin_restore` + :meth:`advance_restores`); the serving
         scheduler holds the lane open instead and trickles chunks
         between resident decode dispatches."""
-        self.begin_restore(batch_uids, batch_tokens, batch_latents)
+        self.begin_restore(batch_uids, batch_tokens, batch_latents,
+                           states=states)
         self.advance_restores()
 
     def begin_restore(self, batch_uids: Iterable[int],
                       batch_tokens: Iterable,
-                      batch_latents: Iterable) -> "RestoreTicket":
+                      batch_latents: Iterable,
+                      states=None) -> "RestoreTicket":
         """Open a restore lane: validate + admit the batch
         all-or-nothing, allocate KV blocks, build the padded lane slabs
         and issue the FIRST layer-chunks' host→device ships — but
@@ -1328,9 +1397,26 @@ class InferenceEngineV2:
         sequences are tracked and in-flight (their blocks are held, and
         they must not be decoded). The ship of chunk 0 is already on
         the link when this returns, so whatever the engine dispatches
-        next (typically the residents' decode) computes under it."""
+        next (typically the residents' decode) computes under it.
+
+        ``states``: for a trunk with recurrent layers, each sequence's
+        :meth:`snapshot_state` (latents replay the full layers' K and V
+        only; a recurrent state is copied back whole into the fresh
+        slot). A sequence without one is refused."""
         batch_uids = list(batch_uids)
         self._reject_suspended(batch_uids)
+        by_uid = {}
+        if self.recurrent:
+            batch_latents = list(batch_latents)
+            by_uid = dict(zip(batch_uids, states or ()))
+            missing = [u for u, lat in zip(batch_uids, batch_latents)
+                       if lat is not None and by_uid.get(u) is None]
+            if missing:
+                from .model_hybrid import refuse
+                raise refuse(
+                    f"restore of sequences {missing} from latents alone",
+                    "their recurrent state (snapshot_state at eviction): "
+                    "it cannot be replayed from a projection")
         # group sequences by length bucket: ONE batched restore dispatch
         # chain per bucket (the per-sequence loop costs a full layer-chunk
         # dispatch chain per uid — latency-bound on slow host links)
@@ -1393,6 +1479,8 @@ class InferenceEngineV2:
             for T, group in sorted(groups.items()):
                 lat, start, t_len, tables, seqs = \
                     self._stage_restore_group(group, T)
+                if self.recurrent:
+                    self._put_states(seqs, [by_uid[it[0]] for it in group])
                 pipe = self.model.restore_pipeline(
                     self.cache, lat, start, tables, t_len,
                     progress_cb=_progress)
@@ -1405,6 +1493,54 @@ class InferenceEngineV2:
         if ticket.pending == 0:
             ticket.done = True
         return ticket
+
+    # -------------------------------------------------------------- #
+    # Recurrent state of a hybrid trunk: out whole at eviction, back
+    # whole at restore
+    # -------------------------------------------------------------- #
+    def snapshot_state(self, uid: int):
+        """The sequence's rows of the recurrent-state pools on the host
+        (``(state [L_lin, H, d_k, d_v], conv [L_lin, (K - 1) * C])``), to be
+        handed back to :meth:`begin_restore` after its flush; ``None``
+        for a trunk with no recurrent layer. Waits for the programs that
+        wrote the slot."""
+        if not self.recurrent:
+            return None
+        seq = self.state.get_sequence(uid)
+        if seq is None:
+            raise KeyError(f"unknown sequence {uid}")
+        with get_tracer().span("serve.state.snapshot", uid=uid,
+                               bytes=self.cache.slot_bytes):
+            rows = tuple(np.asarray(r) for r in self._take_state(
+                self.cache.state, self.cache.conv,
+                jnp.int32(seq.state_slot)))
+        self.state_stats["snapshots"] += 1
+        self.state_stats["bytes_out"] += rows[0].nbytes + rows[1].nbytes
+        return rows
+
+    def _put_states(self, seqs, rows) -> None:
+        """Copy snapshots back into the (fresh) slots of ``seqs``."""
+        slots = np.asarray([s.state_slot for s in seqs], np.int32)
+        state = np.stack([r[0] for r in rows], axis=1)
+        conv = np.stack([r[1] for r in rows], axis=1)
+        with get_tracer().span("serve.state.restore", sequences=len(seqs),
+                               bytes=state.nbytes + conv.nbytes):
+            self.cache.replace_state(*self._swap_in_state(
+                self.cache.state, self.cache.conv, jnp.asarray(slots),
+                jnp.asarray(state), jnp.asarray(conv)))
+        self.state_stats["restores"] += len(seqs)
+        self.state_stats["bytes_in"] += state.nbytes + conv.nbytes
+
+    @staticmethod
+    @jax.jit
+    def _take_state(state, conv, slot):
+        return state[:, slot], conv[:, slot]
+
+    @staticmethod
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def _swap_in_state(state, conv, slots, rows, conv_rows):
+        return (state.at[:, slots].set(rows),
+                conv.at[:, slots].set(conv_rows.astype(conv.dtype)))
 
     def advance_restores(self, max_chunks: int = 0):
         """Issue up to ``max_chunks`` replay-chunk dispatches across
@@ -1490,7 +1626,7 @@ class InferenceEngineV2:
         return {
             "n_layer": cfg.n_layer,
             "latent_bytes_per_token": cfg.hidden_size * latent_itemsize
-            * cfg.n_layer,
+            * self.model.n_latent_layers,
             "replay_flops_frac": replay / full,
             "restore_chunk_layers": self.model.restore_chunk_layers,
             "restore_chunk_bytes": self.model.restore_chunk_bytes,

@@ -169,13 +169,44 @@ def _qwen2_moe_like(hf: Dict[str, Any]):
     )
 
 
+def _olmo_hybrid_like(hf: Dict[str, Any]):
+    """Olmo-Hybrid: ``layer_types`` names each layer ``linear_attention``
+    (gated delta rule) or ``full_attention``; the ``linear_*`` keys are
+    the Gated DeltaNet layer's. ``rope_parameters.rope_theta`` is read as
+    it stands: ``null`` means no rotary step."""
+    from ..models.olmo_hybrid import OlmoHybridConfig
+    n_head = hf.get("num_attention_heads", 30)
+    return OlmoHybridConfig(
+        vocab_size=hf.get("vocab_size", 100352),
+        hidden_size=hf.get("hidden_size", 3840),
+        intermediate_size=hf.get("intermediate_size", 11008),
+        n_layer=hf.get("num_hidden_layers", 32),
+        n_head=n_head,
+        n_kv_head=hf.get("num_key_value_heads", n_head),
+        max_positions=hf.get("max_position_embeddings", 65536),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        rope_theta=(hf.get("rope_parameters") or {}).get("rope_theta"),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attention_bias=hf.get("attention_bias", False),
+        layer_types=tuple(hf["layer_types"]),
+        linear_num_key_heads=hf.get("linear_num_key_heads", n_head),
+        linear_num_value_heads=hf.get("linear_num_value_heads", n_head),
+        linear_key_head_dim=hf.get("linear_key_head_dim", 96),
+        linear_value_head_dim=hf.get("linear_value_head_dim", 192),
+        linear_conv_kernel_dim=hf.get("linear_conv_kernel_dim", 4),
+        linear_allow_neg_eigval=hf.get("linear_allow_neg_eigval", True),
+        dtype=hf.get("torch_dtype") or "bfloat16",
+    )
+
+
 #: model_type -> config adapter (reference: the policy map in
 #: engine_factory.py:69 — llama/mistral/qwen2/phi3 share the llama block
 #: layout; mixtral/qwen2_moe route through the MoE paged model
 #: (model_moe.py: dropless grouped GEMM, and for qwen2_moe the shared
 #: expert + raw top-k gate mass); gpt2/opt/falcon/phi have their own
 #: paged trunks; qwen (v1) translates its idiosyncratic config keys
-#: onto the llama trunk (_qwen_v1_like).
+#: onto the llama trunk (_qwen_v1_like); olmo_hybrid is the hybrid trunk
+#: (model_hybrid.py: gated-delta-rule layers beside full attention).
 MODEL_FAMILIES = {
     "llama": _llama_like,
     "mistral": _llama_like,
@@ -188,6 +219,7 @@ MODEL_FAMILIES = {
     "phi": _phi_like,
     "mixtral": _mixtral_like,
     "qwen2_moe": _qwen2_moe_like,
+    "olmo_hybrid": _olmo_hybrid_like,
 }
 
 

@@ -126,6 +126,9 @@ class PagedInferenceModel:
         self.latent_dtype = jnp.dtype(latent_dtype) if latent_dtype \
             else jnp.dtype(cfg.compute_dtype)
         self.n_layers = cfg.n_layer
+        #: layers that capture a latent and replay K/V on restore (a
+        #: hybrid trunk's full-attention layers only)
+        self.n_latent_layers = cfg.n_layer
         self.topology = topology
         self.tp = topology.tensor_size if topology is not None else 1
         self.quantization = quantization if (
@@ -1197,7 +1200,7 @@ class RestorePipeline:
         self._tables = jnp.asarray(tables, jnp.int32)
         self._t_len = jnp.asarray(t_len, jnp.int32)
         self.staged = isinstance(latents, jax.Array)
-        L = model.n_layers
+        L = model.n_latent_layers
         C = model.restore_chunk_layers
         if C <= 0:
             per_layer = (int(np.prod(latents.shape[1:])) *
@@ -1287,7 +1290,7 @@ class RestorePipeline:
         tracer = get_tracer()
         _inj = get_injector()
         issued = 0
-        L = self.model.n_layers
+        L = self.model.n_latent_layers
         while not self.done and (max_chunks <= 0 or
                                  issued < max_chunks):
             i = self._next_replay

@@ -115,16 +115,63 @@ class BlockedKVCache:
         self.k, self.v = k, v
 
 
+class HybridCache(BlockedKVCache):
+    """The block pool of a hybrid trunk's full-attention layers, and
+    beside it the slot pools of its recurrent layers: ``state``
+    ``[L_lin, slots + 1, H, d_k, d_v]`` float32 and ``conv``
+    ``[L_lin, slots + 1, (K - 1) * C]`` (the convolution's last ``K - 1``
+    inputs, flat: a minor dimension of three rows makes the TPU compiler
+    lay the pool out afresh at every program's entry and exit).
+    A sequence holds one slot of both, every recurrent layer's row of
+    it, from admission to flush; slot ``slots`` is spare: blank lanes of
+    a dispatch bucket read and write it. All four arrays are donated to
+    every forward and replaced by its results."""
+
+    def __init__(self, n_full_layers: int, num_blocks: int,
+                 block_size: int, n_kv_heads: int, head_dim: int, *,
+                 n_linear_layers: int, state_slots: int, n_heads: int,
+                 key_dim: int, value_dim: int, conv_taps: int,
+                 conv_channels: int, dtype=jnp.bfloat16):
+        super().__init__(n_full_layers, num_blocks, block_size,
+                         n_kv_heads, head_dim, dtype=dtype)
+        self.state_slots = state_slots
+        self.state = jnp.zeros((n_linear_layers, state_slots + 1, n_heads,
+                                key_dim, value_dim), jnp.float32)
+        self.conv = jnp.zeros((n_linear_layers, state_slots + 1,
+                               (conv_taps - 1) * conv_channels), dtype)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one sequence's slot holds, all recurrent layers."""
+        return (self.state.nbytes + self.conv.nbytes) // \
+            (self.state_slots + 1)
+
+    def replace_state(self, state, conv):
+        self.state, self.conv = state, conv
+
+
 class StateManager:
-    """uid → SequenceDescriptor tracking + block budget arithmetic."""
+    """uid → SequenceDescriptor tracking + block budget arithmetic, and
+    for a trunk with recurrent layers the slots of its state pools
+    (``state_slots`` of them; 0: no such layer, nothing is kept)."""
 
     def __init__(self, max_tracked_sequences: int, num_blocks: int,
-                 block_size: int, max_seq_len: int):
+                 block_size: int, max_seq_len: int, state_slots: int = 0):
         self.max_tracked_sequences = max_tracked_sequences
         self.block_size = block_size
         self.max_seq_len = max_seq_len
         self.allocator = BlockedAllocator(num_blocks)
         self._seqs: Dict[int, SequenceDescriptor] = {}
+        self.state_slots = state_slots
+        self._free_slots: List[int] = list(range(state_slots))
+
+    @property
+    def free_state_slots(self) -> int:
+        return len(self._free_slots)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.state_slots - len(self._free_slots)
 
     @property
     def n_tracked_sequences(self) -> int:
@@ -143,7 +190,12 @@ class StateManager:
             if len(self._seqs) >= self.max_tracked_sequences:
                 raise RuntimeError(
                     f"sequence limit {self.max_tracked_sequences} reached")
+            if self.state_slots and not self._free_slots:
+                raise RuntimeError(
+                    f"no free recurrent-state slot of {self.state_slots}")
             seq = SequenceDescriptor(uid)
+            if self.state_slots:
+                seq.state_slot = self._free_slots.pop()
             self._seqs[uid] = seq
         return seq
 
@@ -167,6 +219,9 @@ class StateManager:
             return
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if seq.state_slot >= 0:
+            self._free_slots.append(seq.state_slot)
+            seq.state_slot = -1
 
     def block_table(self, seq: SequenceDescriptor,
                     max_blocks: int) -> np.ndarray:

@@ -74,6 +74,7 @@ class HostLink:
         self._lag = 0.0
         self._free_at = 0.0             # link time
         self.captured_bytes = 0
+        self.captured_tokens = 0        # tokens of the lanes handed out
         self.landed_hidden_bytes = 0
         self.landed_forced_bytes = 0
 
@@ -155,6 +156,7 @@ class _Part:
         self.tokens_done = 0       # of layer ``layers_done``
         self.credited = 0          # bytes the ledger already counts
         program.link.captured_bytes += self.nbytes
+        program.link.captured_tokens += n
 
     @property
     def landed(self) -> bool:

@@ -16,6 +16,10 @@ class SequenceDescriptor:
         self.seen_tokens = 0            # tokens whose KV is materialized
         self.in_flight_tokens = 0       # tokens in the current forward
         self.blocks: List[int] = []     # KV pool block ids, in order
+        #: slot of the recurrent-state pools this sequence holds from its
+        #: first scheduling to its flush (-1: the model has no recurrent
+        #: layer, see ``StateManager``)
+        self.state_slot = -1
         #: host copy of the KV while suspended (engine.suspend_sequence;
         #: reference: BlockedKVCache's host-offloaded blocks)
         self.host_kv = None

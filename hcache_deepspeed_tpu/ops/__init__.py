@@ -135,7 +135,8 @@ def _ensure_loaded():
         return
     _loaded = True
     from . import (evoformer_attention, flash_attention,  # noqa: F401
-                   fp_quantizer, grouped_gemm, paged_attention,
+                   fp_quantizer, gated_delta, grouped_gemm,
+                   paged_attention,
                    quantized_matmul, quantizer, rms_norm, rope)
 
 

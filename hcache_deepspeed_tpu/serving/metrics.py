@@ -255,6 +255,14 @@ class ServingMetrics:
             1.0 - alloc.free_blocks / max(alloc.num_blocks, 1)
         self.gauges["queue_depth"] = float(len(scheduler.queue))
         self.gauges["suspended"] = float(len(scheduler.suspended))
+        if getattr(engine, "recurrent", False):
+            # a hybrid trunk: slots of the recurrent-state pools in use,
+            # and the evicted sequences' state rows held on the host
+            self.gauges["state_slots_in_use"] = float(report.state_slots)
+            self.gauges["state_host_bytes"] = float(sum(
+                sum(a.nbytes for a in r.state_rows)
+                for r in scheduler.suspended.values()
+                if r.state_rows is not None))
         self.gauges["degradation_level"] = \
             float(report.degradation_level)
         if scheduler.total_restores:

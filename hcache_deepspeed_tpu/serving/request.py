@@ -97,6 +97,10 @@ class Request:
     #: layer-major buffer, O(1) amortized per-token absorption; quacks
     #: like the ndarray the restore contract expects).
     latents: Optional["HostLatentStore"] = None
+    #: a hybrid trunk's recurrent state of this sequence while it is
+    #: evicted (``engine.snapshot_state``): latents replay the full
+    #: layers' K and V, these rows are copied back whole
+    state_rows: Optional[tuple] = None
     #: exact-KV preempt mode: engine keeps host KV under this uid.
     reject_reason: str = ""
     #: typed hard-failure cause; set exactly when state is FAILED

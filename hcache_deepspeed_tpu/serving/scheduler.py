@@ -123,6 +123,9 @@ class StepReport:
     #: SLO-aware degradation level applied this step (0 = normal,
     #: 1 = speculation off, 2 = + forced chunked prefill, 3 = + shed)
     slo_level: int = 0
+    #: sequences holding a slot of the recurrent-state pools after this
+    #: step (a hybrid trunk; 0 for a trunk with no recurrent layer)
+    state_slots: int = 0
 
     @property
     def work_done(self) -> bool:
@@ -168,6 +171,9 @@ class ContinuousBatchingScheduler:
         #: restore = restore_kv (frees the tracked slot too). Without
         #: latent capture the exact-KV suspend/resume path is used.
         self.latent_preemption = bool(engine.config.hcache.enable_latents)
+        #: a trunk with recurrent layers: eviction also takes the
+        #: sequence's state rows to the host (``Request.state_rows``)
+        self._recurrent = bool(getattr(engine, "recurrent", False))
         #: restore-vs-recompute crossover model consulted per preempted
         #: sequence at re-entry (latent mode only; None = always
         #: restore, the pre-policy behavior). Built lazily from the
@@ -376,6 +382,8 @@ class ContinuousBatchingScheduler:
             self._dispatch(admits, report, now)
             with tracer.span("sched.passes"):
                 self._watchdog_pass(report)
+            if self._recurrent:
+                report.state_slots = self.engine.state.state_slots_in_use
             if self.metrics is not None:
                 with tracer.span("sched.metrics"):
                     self.metrics.on_step(report, self)
@@ -1017,6 +1025,8 @@ class ContinuousBatchingScheduler:
     def _restore_pass(self, report: StepReport) -> None:
         now = self.clock.now()
         for req in self._restore_candidates():
+            if self._recurrent and req.state_rows is None:
+                req.latents = None      # K/V alone cannot bring it back
             if self.latent_preemption and req.latents is None:
                 # no restorable payload (crash-recovered from a dead
                 # replica, or migrated out of exact-KV suspension):
@@ -1071,9 +1081,14 @@ class ContinuousBatchingScheduler:
                                    tokens=req.cached_tokens):
                 if self.latent_preemption:
                     tokens = list(req.prompt) + req.tokens_out[:-1]
+                    # only a hybrid trunk's engine takes ``states``: the
+                    # simulator and the fabric's engines keep the
+                    # three-argument form
+                    states = {"states": [req.state_rows]} \
+                        if self._recurrent else {}
                     try:
                         self.engine.begin_restore([req.uid], [tokens],
-                                                  [req.latents])
+                                                  [req.latents], **states)
                     except SchedulingError:
                         raise
                     except Exception as exc:
@@ -1086,6 +1101,7 @@ class ContinuousBatchingScheduler:
                         continue
                     self.total_restores += 1
                     self.restoring[req.uid] = req
+                    req.state_rows = None   # back on the device
                     self._event("restore_begin", req.uid,
                                 f"tokens={req.cached_tokens}")
                     # the lane drains chunk by chunk between this
@@ -1275,6 +1291,8 @@ class ContinuousBatchingScheduler:
             # that reads the store does
             if req.latents is not None and \
                     req.latents.shape[1] == req.cached_tokens:
+                if self._recurrent:
+                    req.state_rows = self.engine.snapshot_state(req.uid)
                 self.engine.flush(req.uid)
                 mode = "latents"
             else:
@@ -1396,6 +1414,11 @@ class ContinuousBatchingScheduler:
                 # batch full / waiting on a slot or on blocks nobody
                 # preemptible holds: stop scanning this step
                 self._event("wait", req.uid, verdict.name)
+                if self._recurrent and \
+                        action == BackpressureAction.WAIT_TRACKED_SLOT:
+                    get_tracer().instant(
+                        "sched.state_admit", uid=req.uid,
+                        free_slots=self.engine.state.free_state_slots)
                 break
         return admits
 

@@ -264,3 +264,94 @@ def test_v5e_program_names_the_paged_kernel_to_its_finders(one_chip, B, T,
              if rx.search(text)]
     assert found and all(name.startswith("%hds_paged_attention")
                          for name in found), found
+
+
+# ------------------------------------------------------------------ #
+# the hybrid trunk's program (gated-delta-rule layers beside full
+# attention): here, because only this file describes a topology
+# ------------------------------------------------------------------ #
+@functools.lru_cache(maxsize=None)
+def _v5e_hybrid_program(one_chip, B, T):
+    """Olmo-Hybrid-7B widths, two periods (6 linear layers, 2 full), the
+    cell's pools (1536 blocks of 64; 64 state slots and the spare),
+    compiled for the described chip: ``(compiled, pools, params)``."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from hcache_deepspeed_tpu import platform
+    from hcache_deepspeed_tpu.inference.model_hybrid import (
+        PagedHybridModel, serving_layout)
+    from hcache_deepspeed_tpu.models.olmo_hybrid import (
+        FULL, LINEAR, OlmoHybridConfig, OlmoHybridForCausalLM)
+
+    class ShapesOnly(PagedHybridModel):
+        def load_params(self, params):
+            self.params = params
+
+    platform.set_platform("tpu")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = OlmoHybridConfig(
+            vocab_size=100352, hidden_size=3840, intermediate_size=11008,
+            n_layer=8, n_head=30, n_kv_head=30, max_positions=8192,
+            layer_types=(LINEAR, LINEAR, LINEAR, FULL) * 2,
+            dtype="bfloat16")
+        tree = jax.eval_shape(lambda: OlmoHybridForCausalLM(cfg).init(
+            jax.random.PRNGKey(0),
+            {"input_ids": np.zeros((1, 8), np.int32)}))["params"]
+
+        def on_chip(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, x: on_chip(
+                x.shape, jnp.float32 if PagedHybridModel._keep_fp32(path)
+                else jnp.bfloat16),
+            jax.eval_shape(lambda p: serving_layout(cfg, p), tree))
+        model = ShapesOnly(cfg, params, block_size=64,
+                           max_blocks_per_seq=128)
+        pools = {
+            "kv": on_chip((2, 30, 1536 * 64, 128), jnp.bfloat16),
+            "state": on_chip((6, 65, 30, 96, 192), jnp.float32),
+            "conv": on_chip((6, 65, 3 * cfg.conv_channels), jnp.bfloat16)}
+        i32 = lambda *shape: on_chip(shape, jnp.int32)
+        compiled = model._fwd.lower(
+            params, pools["kv"], pools["kv"], pools["state"],
+            pools["conv"], i32(B, T), i32(B), i32(B, 128), i32(B),
+            i32(B)).compile()
+    finally:
+        platform._platform = None
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    return compiled, pools, params
+
+
+@pytest.mark.parametrize("B,T,kernel",
+                         [(8, 1, "gated_delta_step"),
+                          (1, 512, "gated_delta_chunk")],
+                         ids=["decode", "slice"])
+def test_v5e_hybrid_program_holds_pools_and_weights_in_place(one_chip, B,
+                                                             T, kernel):
+    """The optimised v5e program of the hybrid trunk runs the
+    gated-delta kernel of its shape and the paged kernel, copies or
+    slices nothing of the extent of the KV pool or the state pool (or of
+    a layer of either), lays the convolution tails' pool out only once,
+    and reads every layer's weights inside the matmul that uses them (a
+    period's layers handed over as the scan's ``xs`` were sliced out of
+    their stack and copied: 1.3 GB of temporaries)."""
+    compiled, pools, params = _v5e_hybrid_program(one_chip, B, T)
+    text = compiled.as_text()
+    assert f"hds_{kernel}" in text and "hds_paged_attention" in text
+    for name in ("kv", "state"):
+        assert pool_sized_copies(text, pools[name].shape) == [], name
+    assert [c for c in pool_sized_copies(text, pools["conv"].shape)
+            if c.startswith("copy")] == []
+    kernels = [leaf.shape for stack in ("lin_layers", "full_layers")
+               for leaf in jax.tree.leaves(params[stack])
+               if leaf.ndim == 3 and np.prod(leaf.shape[1:]) >= 1 << 20]
+    assert len(kernels) == 8 + 7
+    assert stacked_layer_copies(text, kernels) == []
+    # temporaries stay under one state slot's worth of a few lanes, far
+    # under a layer of any pool
+    layer_bytes = int(np.prod(pools["state"].shape[1:])) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

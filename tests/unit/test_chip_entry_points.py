@@ -33,6 +33,19 @@ def test_smoke_phases_pass_at_tiny_size(eight_devices):
     chip_smoke.tensor_phase(size, eight_devices[:2], one_chip)
 
 
+def test_hybrid_smoke_phase_passes_at_tiny_size():
+    """One period of the hybrid trunk at toy width: the phase's checks
+    (finite logits through both pools, latents of the full layer only,
+    nothing pool-sized copied, slot and blocks given back) hold on the
+    CPU's program too; the stacked-layer check is the chip's."""
+    chip_smoke.hybrid_phase(chip_smoke.TINY_HYBRID_PERIOD, block_size=8,
+                            prefill_chunk=16)
+    published = chip_smoke.OLMO_HYBRID_PERIOD
+    assert (published["hidden_size"], published["intermediate_size"],
+            published["linear_key_head_dim"],
+            published["linear_value_head_dim"]) == (3840, 11008, 96, 192)
+
+
 def test_smoke_sizes_keep_the_full_mistral_7b_width():
     hf = chip_smoke.MISTRAL_7B.hf_config
     assert (hf["hidden_size"], hf["intermediate_size"],
