@@ -52,7 +52,8 @@ def kernel_mesh(mesh):
     return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
-def per_shard(kernel, operands, in_roles=None, out_roles=None):
+def per_shard(kernel, operands, in_roles=None, out_roles=None,
+              out_shapes=None):
     """``kernel(*operands)`` where Mosaic accepts it.
 
     ``in_roles``/``out_roles`` give, per operand and per result, one
@@ -62,7 +63,9 @@ def per_shard(kernel, operands, in_roles=None, out_roles=None):
     and KV heads are split together or not at all). Without roles
     every array is whole in every shard: right for a kernel that runs
     on the local block of a ``shard_map`` body (the quantized wire),
-    a gather anywhere else.
+    a gather anywhere else. ``out_shapes`` are the results' whole shapes
+    (``ShapeDtypeStruct``s) where the caller knows them; otherwise the
+    kernel is traced once more, on the whole operands, to ask.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
@@ -71,7 +74,9 @@ def per_shard(kernel, operands, in_roles=None, out_roles=None):
     if not auto or (mesh.size == 1 and not mesh.manual_axes):
         return kernel(*operands)
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-    results, out_tree = jax.tree.flatten(jax.eval_shape(kernel, *operands))
+    if out_shapes is None:
+        out_shapes = jax.eval_shape(kernel, *operands)
+    results, out_tree = jax.tree.flatten(out_shapes)
     if in_roles is None:
         in_roles = tuple((None,) * x.ndim for x in operands)
         out_roles = tuple((None,) * len(r.shape) for r in results)

@@ -112,8 +112,9 @@ def test_forwards_change_only_the_slots_they_write(model):
 
 # ------------------------------------------------------------------ #
 # the TPU compiler's program, compiled for a chip that is described and
-# not attached (only this file describes a topology: one worker loads
-# the TPU's library)
+# not attached (this file and ops/test_flash_structure.py describe a
+# topology, each inside a fixture: where only one process may load the
+# TPU's library, the second to ask skips its compiled cases)
 # ------------------------------------------------------------------ #
 @pytest.fixture(scope="module")
 def one_chip():
@@ -268,7 +269,7 @@ def test_v5e_program_names_the_paged_kernel_to_its_finders(one_chip, B, T,
 
 # ------------------------------------------------------------------ #
 # the hybrid trunk's program (gated-delta-rule layers beside full
-# attention): here, because only this file describes a topology
+# attention): here, beside the serving programs' other compiled cases
 # ------------------------------------------------------------------ #
 @functools.lru_cache(maxsize=None)
 def _v5e_hybrid_program(one_chip, B, T):

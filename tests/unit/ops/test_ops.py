@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hcache_deepspeed_tpu.ops import get_op_impl, op_report
+from hcache_deepspeed_tpu.ops import (fallback_report, get_op_impl,
+                                      op_report, reset_fallback_report)
 from hcache_deepspeed_tpu.ops.flash_attention import (pallas_attention,
                                                       reference_attention)
 from hcache_deepspeed_tpu.ops.quantizer import (pallas_quantize,
@@ -18,44 +19,90 @@ from hcache_deepspeed_tpu.ops.rms_norm import (pallas_rms_norm,
 from hcache_deepspeed_tpu.ops.rope import apply_rope, rope_frequencies
 
 
+def _flash_case(T=512, heads=(4, 1), D=128, causal=True, blocks=(128, 128),
+                B=1, falls_back=None, tol=(2e-5, 1e-4)):
+    return dict(T=T, heads=heads, D=D, causal=causal, blocks=blocks, B=B,
+                falls_back=falls_back, tol=tol)
+
+
+# T=512 in blocks of 128 is a 4 x 4 grid of tiles: tiles on, below and
+# above the diagonal all occur, and under a causal mask two rows (or
+# columns) share a grid slot
+FLASH_CASES = {
+    # GQA (4 query heads on 1 KV head: rep 4) and multi-head (rep 1),
+    # causal and not, head sizes 128 and 64
+    **{f"{'causal' if causal else 'full'}-rep{H // KV}-D{D}":
+       _flash_case(heads=(H, KV), causal=causal, D=D)
+       for causal in (True, False) for H, KV in ((4, 1), (2, 2))
+       for D in (128, 64)},
+    "rep2-two-kv-heads": _flash_case(heads=(4, 2)),
+    # a tile that touches the diagonal is not (row == col) here
+    "block_q>block_k": _flash_case(blocks=(256, 128)),
+    "block_q<block_k": _flash_case(blocks=(128, 256)),
+    "full-block_q>block_k": _flash_case(blocks=(256, 128), causal=False),
+    # 384 and 256 do not divide each other: a slot has spare steps
+    "undivided-blocks": _flash_case(T=768, blocks=(384, 256)),
+    # three lines: the middle one has a slot to itself
+    "odd-lines": _flash_case(T=384, heads=(2, 1)),
+    "one-block": _flash_case(T=128, heads=(2, 1)),
+    "one-block-full": _flash_case(T=128, heads=(2, 1), causal=False),
+    # the tiles the kernels choose: 1024 x 512 forward (the row sums
+    # kept over four lane groups), 1024 x 1024 backward
+    "own-tiles": _flash_case(T=1024, heads=(2, 1), blocks=(None, None)),
+    "batch-2": _flash_case(T=256, heads=(2, 1), B=2),
+    # blocks under 128, or a length no block divides: the reference
+    # runs, under the fall-back's name
+    "blocks-64-causal": _flash_case(
+        T=128, heads=(4, 4), D=64, blocks=(64, 64), B=2,
+        falls_back="seq_not_block_multiple", tol=(2e-3, 5e-3)),
+    "blocks-64-full": _flash_case(
+        T=128, heads=(4, 4), D=64, blocks=(64, 64), B=2, causal=False,
+        falls_back="seq_not_block_multiple", tol=(2e-3, 5e-3)),
+    "blocks-64-D32": _flash_case(
+        T=128, heads=(2, 2), D=32, blocks=(64, 64),
+        falls_back="seq_not_block_multiple", tol=(2e-3, 5e-3)),
+    "length-100": _flash_case(
+        T=100, heads=(4, 4), D=64, blocks=(None, None), B=2,
+        falls_back="seq_not_block_multiple", tol=(2e-3, 5e-3)),
+}
+
+
 class TestFlashAttention:
-    def _qkv(self, B=2, T=128, H=4, D=64, dtype=jnp.float32, seed=0):
-        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-        shape = (B, T, H, D)
-        return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+    @pytest.mark.parametrize("case", sorted(FLASH_CASES))
+    def test_matches_reference(self, case):
+        """Values and all three gradients against
+        ``reference_attention``, the kernels in interpret mode."""
+        c = FLASH_CASES[case]
+        (H, KV), T, D, B = c["heads"], c["T"], c["D"], c["B"]
+        ks = jax.random.split(jax.random.PRNGKey(T + H + KV + D), 4)
+        q = jax.random.normal(ks[0], (B, T, H, D))
+        k = jax.random.normal(ks[1], (B, T, KV, D))
+        v = jax.random.normal(ks[2], (B, T, KV, D))
+        w = jax.random.normal(ks[3], (B, T, H, D))
+        tiling = {name: size for name, size in
+                  zip(("block_q", "block_k"), c["blocks"]) if size}
 
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_fwd_matches_reference(self, causal):
-        q, k, v = self._qkv()
-        ref = reference_attention(q, k, v, causal=causal)
-        got = pallas_attention(q, k, v, causal=causal, block_q=64,
-                               block_k=64, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)
+        def kernel(q, k, v):
+            return pallas_attention(q, k, v, causal=c["causal"],
+                                    interpret=True, **tiling)
 
-    def test_bwd_matches_reference(self):
-        q, k, v = self._qkv(B=1, T=128, H=2, D=32)
+        def plain(q, k, v):
+            return reference_attention(q, k, v, causal=c["causal"])
 
-        def loss_ref(q, k, v):
-            return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
-
-        def loss_pl(q, k, v):
-            return jnp.sum(pallas_attention(q, k, v, causal=True,
-                                            block_q=64, block_k=64,
-                                            interpret=True) ** 2)
-
-        ref_grads = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        got_grads = jax.grad(loss_pl, argnums=(0, 1, 2))(q, k, v)
-        for g, r in zip(got_grads, ref_grads):
+        reset_fallback_report()
+        fwd_tol, grad_tol = c["tol"]
+        np.testing.assert_allclose(
+            np.asarray(kernel(q, k, v)), np.asarray(plain(q, k, v)),
+            rtol=fwd_tol, atol=fwd_tol)
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                              argnums=(0, 1, 2))(q, k, v)
+                     for f in (kernel, plain))
+        for g, r in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                       rtol=5e-3, atol=5e-3)
-
-    def test_non_divisible_falls_back(self):
-        q, k, v = self._qkv(T=100)
-        out = pallas_attention(q, k, v, interpret=True)
-        ref = reference_attention(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)
+                                       rtol=grad_tol, atol=grad_tol)
+        # the kernels ran, or the reference did under its reason's name
+        assert list(fallback_report().get("flash_attention", {})) == \
+            ([c["falls_back"]] if c["falls_back"] else [])
 
 
 class TestRMSNorm:
