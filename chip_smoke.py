@@ -297,13 +297,39 @@ def _logit_gap(got, ref):
     return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
 
 
-def _decode_program_text(engine):
-    """Optimised HLO of the engine's decode forward at the smallest lane
-    bucket, as this device's compiler wrote it."""
-    tok, start, t_len, tables = engine._blank_lanes(8)
+def _program_text(engine, B, T):
+    """Optimised HLO of the engine's forward over ``B`` lanes of ``T``
+    positions, as this device's compiler wrote it. A trunk with
+    recurrent layers also takes its state pools and the lanes' slots."""
+    tok, start, t_len, tables = engine._blank_lanes(B, T)
     model, cache = engine.model, engine.cache
+    if engine.recurrent:
+        return model._fwd.lower(
+            model.params, cache.k, cache.v, cache.state, cache.conv, tok,
+            start, tables, t_len,
+            np.full((B,), engine.state.state_slots, np.int32)
+        ).compile().as_text()
     return model._fwd.lower(model.params, cache.k, cache.v, tok, start,
                             tables, t_len).compile().as_text()
+
+
+def _check_slice_program(engine, pool_shape, prefill_chunk):
+    """A prompt slice's program holds the pool in place too, and where
+    the platform has the kernel writes its K and V a block run at a time
+    (``ops/kv_write.py``): no scatter of one-row updates into a pool."""
+    from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
+        pool_scatters, pool_sized_copies)
+    from hcache_deepspeed_tpu.platform import get_platform
+    text = _program_text(engine, 1, prefill_chunk)
+    copies = pool_sized_copies(text, pool_shape)
+    check(not copies,
+          "the slice program neither copies nor slices the KV pool or a "
+          f"layer of it: {copies}")
+    if get_platform().supports_pallas():
+        scatters = pool_scatters(text, pool_shape)
+        check(not scatters and "hds_kv_write" in text,
+              "the slice program writes its K and V through hds_kv_write, "
+              f"a block run at a time, and scatters no row: {scatters}")
 
 
 def serve_phase(size, topology=None, one_chip=None):
@@ -406,7 +432,8 @@ def serve_phase(size, topology=None, one_chip=None):
     # under tensor parallelism the program is one device's: its pool and
     # its weights are this device's shards
     local = lambda x: x.addressable_shards[0].data.shape
-    text = _decode_program_text(fresh)
+    _check_slice_program(fresh, local(fresh.cache.k), size.prefill_chunk)
+    text = _program_text(fresh, 8, 1)
     copies = pool_sized_copies(text, local(fresh.cache.k))
     check(not copies,
           "the decode program neither copies nor slices the KV pool or a "
@@ -502,13 +529,9 @@ def hybrid_phase(hf, block_size=64, prefill_chunk=512):
           "steps through both pools give finite logits")
     check(np.asarray(latents[0]).shape[0] == 1,
           "latents left the program for the full layer only")
-    tok, start, t_len, tables = engine._blank_lanes(8)
     model, cache = engine.model, engine.cache
-    text = model._fwd.lower(
-        model.params, cache.k, cache.v, cache.state, cache.conv, tok,
-        start, tables, t_len,
-        np.full((8,), engine.state.state_slots, np.int32)
-    ).compile().as_text()
+    _check_slice_program(engine, cache.k.shape, prefill_chunk)
+    text = _program_text(engine, 8, 1)
     copies = pool_sized_copies(text, cache.k.shape) + \
         pool_sized_copies(text, cache.state.shape)
     check(not copies,

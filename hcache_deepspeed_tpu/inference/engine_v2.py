@@ -759,6 +759,17 @@ class InferenceEngineV2:
             "pending_peak_bytes": self._latent_pending_peak,
         }
 
+    def kv_write_stats(self) -> Dict[str, int]:
+        """Dispatches, and the rows of K and V they wrote into the
+        pools, by the write they took: ``run_*`` a block run at a time
+        (prompt slices, restore replays, verification tails: lanes of
+        more than one position), ``row_*`` a row at a time (decode
+        lanes). A row is one ``[head_dim]`` vector of one KV head of one
+        layer, K and V counted apart. Counted on the host from each
+        dispatch's shape; whether the run path's kernel ran or gave way
+        to the rows is ``ops.fallback_report()``."""
+        return dict(self.model.kv_write_stats)
+
     # -------------------------------------------------------------- #
     # Serving loop (reference: the generate() surface the v1 engine
     # exposes via HF and hybrid_engine.py wraps; v2's counterpart is the

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import LlamaConfig
+from ..ops.kv_write import flat_slots, kv_write, write_rows
 from ..ops.paged_attention import paged_attention
 from ..ops.rms_norm import rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
@@ -129,6 +130,14 @@ class PagedInferenceModel:
         #: layers that capture a latent and replay K/V on restore (a
         #: hybrid trunk's full-attention layers only)
         self.n_latent_layers = cfg.n_layer
+        #: dispatches, and the ``[D]`` rows of K and V they wrote into the
+        #: pools, by the granularity of the write (``_scatter_kv``): a
+        #: block run at a time (lanes of more than one position) or a
+        #: row at a time. Reckoned on the host from each dispatch's shape
+        #: (``_count_kv_write``); a kernel that gave way to the rows is
+        #: in ``ops.fallback_report()``.
+        self.kv_write_stats = {"run_dispatches": 0, "run_rows": 0,
+                               "row_dispatches": 0, "row_rows": 0}
         self.topology = topology
         self.tp = topology.tensor_size if topology is not None else 1
         self.quantization = quantization if (
@@ -482,22 +491,20 @@ class PagedInferenceModel:
         k = apply_rope(k, self.cos, self.sin, positions)
         return q, k, v
 
-    def _scatter_kv(self, ck, cv, layer, k, v, flat_idx):
+    def _scatter_kv(self, ck, cv, layer, k, v, flat_idx, tables, start,
+                    kv_len):
         """ck/cv: the whole [L, KV, P, D] pools; k/v: [B, T, KV, D] of
-        ``layer``; flat_idx: [B, T] (OOB ⇒ dropped — padded lanes use an
-        index past the pool end)."""
-        KV = k.shape[2]
-        kt = k.reshape(-1, KV, k.shape[-1]).swapaxes(0, 1)   # [KV, N, D]
-        vt = v.reshape(-1, KV, v.shape[-1]).swapaxes(0, 1)
-        # single [D] rows at (layer, head, slot), not [KV, D] windows at
-        # (layer, :, slot): for windows the TPU compiler keeps the carried
-        # pool token-major and transposes it whole to the kernel's
-        # head-major layout and back in every layer
-        heads = jnp.arange(KV)[:, None]
-        idx = flat_idx.reshape(1, -1)
-        ck = ck.at[layer, heads, idx].set(kt.astype(ck.dtype), mode="drop")
-        cv = cv.at[layer, heads, idx].set(vt.astype(cv.dtype), mode="drop")
-        return ck, cv
+        ``layer``, the rows of positions ``start + [0, T)`` of each lane;
+        those before ``kv_len`` are written to their slots by ``tables``
+        (``flat_idx`` [B, T]: the same slots, reckoned once a program),
+        the rest (padding) are not. One write at the granularity its
+        shape wants (``ops/kv_write.py``): a lane of a decode program is
+        one row in a block of its own, a row an update; a lane of ``T``
+        positions is runs of consecutive slots, a block an update."""
+        if k.shape[1] > 1:
+            return kv_write(ck, cv, k, v, layer, tables, start, kv_len,
+                            self.block_size)
+        return write_rows(ck, cv, layer, k, v, flat_idx)
 
     def _paged_attention(self, q, ck, cv, layer, tables, q_positions,
                          kv_len):
@@ -526,7 +533,8 @@ class PagedInferenceModel:
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
         q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
+                                  positions[:, 0], kv_len)
         attn = self._paged_attention(q, ck, cv, layer, tables, positions,
                                      kv_len)
         proj = self._mm(attn, lp["self_attn"]["o_proj"]["kernel"])
@@ -563,19 +571,13 @@ class PagedInferenceModel:
         # resident HBM holds int8 weights + one bf16 layer, not L of them
         params = {k: (v if k == "layers" else dequantize_tree(v))
                   for k, v in params.items()}
-        B, T = tokens.shape
-        BS = self.block_size
-        P = cache_k.shape[2]   # [L, KV, P, D]
-        offs = jnp.arange(T)
-        positions = start[:, None] + offs[None, :]              # [B, T]
+        T = tokens.shape[1]
+        positions = start[:, None] + jnp.arange(T)[None, :]     # [B, T]
         x = self._embed_lookup(params["embed"], tokens) + \
             self._embed_extra(params, positions)
-        token_valid = offs[None, :] < t_len[:, None]
-        local_blk = positions // BS                             # in-table idx
-        flat_idx = tables[jnp.arange(B)[:, None], local_blk] * BS + \
-            positions % BS
-        flat_idx = jnp.where(token_valid, flat_idx, P)          # drop pads
         kv_len = start + t_len
+        flat_idx = flat_slots(tables, start, t_len, T, self.block_size,
+                              cache_k.shape[2])
 
         # the pools are carried, never scanned over: a scanned-over pool
         # is two buffers of the loop, every layer sliced out of one and
@@ -686,7 +688,20 @@ class PagedInferenceModel:
             x = table[tokens]
         return x.astype(self.cfg.compute_dtype)
 
+    def _count_kv_write(self, T, positions, layers=None):
+        """One dispatch whose lanes carry ``T`` positions wrote
+        ``positions`` of them (the lanes' ``t_len``, summed) into
+        ``layers`` layers of both pools (default: every layer that has
+        them)."""
+        path = "run" if T > 1 else "row"
+        if layers is None:
+            layers = self.n_latent_layers
+        self.kv_write_stats[path + "_dispatches"] += 1
+        self.kv_write_stats[path + "_rows"] += \
+            int(positions) * 2 * self.cfg.n_kv_head * layers
+
     def forward_chunk(self, cache, tokens, start, tables, t_len):
+        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
         ck, cv, logits, latents = self._fwd(
             self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
@@ -721,6 +736,7 @@ class PagedInferenceModel:
                            tail: int):
         """Verification forward: head logits for the last ``tail``
         positions of each lane (speculative decoding)."""
+        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
         ck, cv, logits = self._fwd_tail_for(tail)(
             self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
@@ -758,6 +774,7 @@ class PagedInferenceModel:
         ``(logits [B, tail, V], latents [L, B, T, H])`` — latent
         columns align with ``tokens`` columns (left-aligned feeds), so
         a lane's accepted span is ``latents[:, j, :acc+1]``."""
+        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
         ck, cv, logits, latents = self._fwd_tail_lat_for(tail)(
             self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
@@ -783,19 +800,13 @@ class PagedInferenceModel:
         # ever materialized full-precision
         lp = jax.tree.map(lambda p: p[layer], params["layers"])
         lp = dequantize_tree(lp)
-        B, T, _ = latent.shape
-        BS = self.block_size
-        P = cache_k.shape[2]   # [L, KV, P, D]
-        offs = jnp.arange(T)
-        positions = start[:, None] + offs[None, :]
-        token_valid = offs[None, :] < t_len[:, None]
-        local_blk = positions // BS
-        flat_idx = tables[jnp.arange(B)[:, None], local_blk] * BS + \
-            positions % BS
-        flat_idx = jnp.where(token_valid, flat_idx, P)
+        positions = start[:, None] + jnp.arange(latent.shape[1])[None, :]
         _, k, v = self._qkv(lp, latent.astype(self.cfg.compute_dtype),
                             positions)
-        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx)
+        flat_idx = flat_slots(tables, start, t_len, latent.shape[1],
+                              self.block_size, cache_k.shape[2])
+        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx,
+                                tables, start, start + t_len)
 
     # -------------------------------------------------------------- #
     # Fused decode loop: N greedy steps in ONE device program
@@ -1097,8 +1108,12 @@ class PagedInferenceModel:
             jnp.asarray(hist_len, jnp.int32),
             eos, max_new, ngram, max_draft, window, has_eos)
         cache.replace(ck, cv)
+        lane_iters = np.asarray(lane_iters)
+        # every iteration of a lane verifies a draft of max_draft + 1
+        self._count_kv_write(max_draft + 1,
+                             lane_iters.sum() * (max_draft + 1))
         return (np.asarray(outs), np.asarray(out_len), int(iters),
-                np.asarray(accepted), np.asarray(lane_iters))
+                np.asarray(accepted), lane_iters)
 
     def decode_loop(self, cache, tokens, start, t_len, tables, n_steps,
                     temperature=0.0, top_k=0, top_p=1.0, seed=0,
@@ -1116,6 +1131,8 @@ class PagedInferenceModel:
             int(n_steps), temperature <= 0, int(top_k), top_p < 1.0,
             bool(want_logprobs), eos_token_id is not None)
         cache.replace(ck, cv)
+        # the steps asked for; lanes that met EOS early stopped writing
+        self._count_kv_write(1, int(n_steps) * np.count_nonzero(t_len))
         return (np.asarray(toks), lats,
                 np.asarray(lps) if lps is not None else None)
 
@@ -1199,6 +1216,7 @@ class RestorePipeline:
         self._start = jnp.asarray(start, jnp.int32)
         self._tables = jnp.asarray(tables, jnp.int32)
         self._t_len = jnp.asarray(t_len, jnp.int32)
+        self._positions = int(np.sum(t_len))
         self.staged = isinstance(latents, jax.Array)
         L = model.n_latent_layers
         C = model.restore_chunk_layers
@@ -1315,6 +1333,9 @@ class RestorePipeline:
                 with tracer.span("restore.replay", layer0=l0,
                                  layers=min(self.chunk_layers, L - l0),
                                  bytes=nbytes):
+                    self.model._count_kv_write(
+                        self.latents.shape[2], self._positions,
+                        min(self.chunk_layers, L - l0))
                     ck, cv = self.model._restore(
                         self.model.params, self.cache.k, self.cache.v,
                         jnp.int32(l0), cur, self._start, self._tables,

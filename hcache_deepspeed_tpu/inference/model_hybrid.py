@@ -38,9 +38,11 @@ is not written yet (tensor parallelism, weight quantisation) raise
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
 from ..ops.gated_delta import gated_delta_rule
+from ..ops.kv_write import flat_slots
 from ..ops.rms_norm import reference_rms_norm, rms_norm
 from .model import PagedInferenceModel, stack_layer_params
 
@@ -133,7 +135,8 @@ class PagedHybridModel(PagedInferenceModel):
         latent = x.astype(self.latent_dtype) if self.capture_latents \
             else jnp.zeros((x.shape[0], x.shape[1], 0), x.dtype)
         q, k, v = self._full_qkv(attn, x)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
+                                  positions[:, 0], kv_len)
         y = self._paged_attention(q, ck, cv, layer, tables, positions,
                                   kv_len)
         x = self._post(x, self._mm(y, attn["o_proj"]["kernel"]),
@@ -215,17 +218,12 @@ class PagedHybridModel(PagedInferenceModel):
     # -------------------------------------------------------------- #
     def _trunk(self, params, cache_k, cache_v, state, conv, tokens, start,
                tables, t_len, slots):
-        B, T = tokens.shape
-        BS = self.block_size
-        P = cache_k.shape[2]
-        offs = jnp.arange(T)
-        positions = start[:, None] + offs[None, :]
+        T = tokens.shape[1]
+        positions = start[:, None] + jnp.arange(T)[None, :]
         x = self._embed_lookup(params["embed"], tokens)
-        token_valid = offs[None, :] < t_len[:, None]
-        flat_idx = tables[jnp.arange(B)[:, None], positions // BS] * BS + \
-            positions % BS
-        flat_idx = jnp.where(token_valid, flat_idx, P)      # drop pads
         kv_len = start + t_len
+        flat_idx = flat_slots(tables, start, t_len, T, self.block_size,
+                              cache_k.shape[2])
 
         # every pool is carried: a scanned-over pool is two buffers of
         # the loop (inference/model.py _trunk)
@@ -278,6 +276,7 @@ class PagedHybridModel(PagedInferenceModel):
 
     def forward_chunk(self, cache, tokens, start, tables, t_len, slots):
         i32 = jnp.int32
+        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
         ck, cv, state, conv, logits, latents = self._fwd(
             self.params, cache.k, cache.v, cache.state, cache.conv,
             jnp.asarray(tokens, i32), jnp.asarray(start, i32),
@@ -294,17 +293,12 @@ class PagedHybridModel(PagedInferenceModel):
                        start, tables, t_len):
         attn = jax.tree.map(lambda p: p[layer],
                             params["full_layers"]["self_attn"])
-        B, T, _ = latent.shape
-        BS = self.block_size
-        P = cache_k.shape[2]
-        offs = jnp.arange(T)
-        positions = start[:, None] + offs[None, :]
-        flat_idx = tables[jnp.arange(B)[:, None], positions // BS] * BS + \
-            positions % BS
-        flat_idx = jnp.where(offs[None, :] < t_len[:, None], flat_idx, P)
         _, k, v = self._full_qkv(attn,
                                  latent.astype(self.cfg.compute_dtype))
-        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx)
+        flat_idx = flat_slots(tables, start, t_len, latent.shape[1],
+                              self.block_size, cache_k.shape[2])
+        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx,
+                                tables, start, start + t_len)
 
     # -------------------------------------------------------------- #
     # What needs a state snapshot at a block boundary refuses by name

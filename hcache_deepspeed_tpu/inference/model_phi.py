@@ -72,7 +72,8 @@ class PagedPhiModel(PagedFalconModel):
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
         q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
+                                  positions[:, 0], kv_len)
         attn = self._paged_attention(q, ck, cv, layer, tables, positions,
                                      kv_len)
         d = lp["self_attn"]["dense"]

@@ -9,18 +9,20 @@ Reference analogs:
 TPU-native layout: one pool per k/v of shape ``[L, KV, P, D]`` with
 ``P = num_blocks * block_size`` token slots, kept as jnp arrays that flow
 *functionally* through the jitted forward. They are donated, ride whole in
-the carry of the layer loop, are written by a scatter of ``[D]`` rows at
-``[layer, head, slot]`` and read by the paged kernel at a layer index
-(``inference/model.py _trunk``), so the compiled program holds one buffer
-a pool and updates it in place in HBM: no layer is ever sliced out of it
-(``tests/unit/inference/test_kv_pool_in_place.py`` reads that off the
-compiled program). Head-major (KV before P) so the paged-attention
+the carry of the layer loop, are written in place (``ops/kv_write.py``:
+a decode lane's one row by a scatter of ``[D]`` rows at ``[layer, head,
+slot]``, a prompt slice's rows a block run at a time by a kernel that
+takes the pool as an aliased operand) and read by the paged kernel at a
+layer index (``inference/model.py _trunk``), so the compiled program
+holds one buffer a pool and updates it in place in HBM: no layer is ever
+sliced out of it (``tests/unit/inference/test_kv_pool_in_place.py`` reads
+that off the compiled program). Head-major (KV before P) so the paged-attention
 kernel's per-(head, block) DMA tile is ``[block_size, D]`` — a legal Mosaic
 tile whose last two dims match the array's minor dims; token-major would
-force an un-tileable ``[BS, 1, D]`` block. Block granularity exists only
-in the host-side allocator and the flat scatter indices built from block
-tables — the device never sees a block structure, which keeps every cache
-write a single scatter instead of the reference's per-block copy kernels.
+force an un-tileable ``[BS, 1, D]`` block. Block granularity exists in
+the host-side allocator, in the paged kernel's index map and in the write
+of a lane that carries more than one position, which is whole blocks but
+for its two ends.
 """
 
 import re
@@ -40,6 +42,16 @@ _HLO_INSTRUCTION = re.compile(
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", re.M)
 
 
+def _pool_sized(hlo_text: str, pool_shape, ops) -> List[str]:
+    """The instructions of ``ops`` whose result has as many elements as
+    the ``[L, KV, P, D]`` pool or one layer of it."""
+    extents = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
+    return [f"{op} {name} [{dims}]"
+            for name, dims, op in _HLO_INSTRUCTION.findall(hlo_text)
+            if op in ops
+            and int(np.prod([int(d) for d in dims.split(",")])) in extents]
+
+
 def pool_sized_copies(hlo_text: str, pool_shape) -> List[str]:
     """The ``copy``, ``dynamic-slice`` and ``dynamic-update-slice``
     instructions of an optimised program (fused ones too) whose result
@@ -47,11 +59,18 @@ def pool_sized_copies(hlo_text: str, pool_shape) -> List[str]:
     it, whatever dimensions spell them. A forward that holds the pool in
     place has none; each one found moves a layer's cache, or all of it,
     through HBM once per execution."""
-    extents = {int(np.prod(pool_shape)), int(np.prod(pool_shape[1:]))}
-    return [f"{op} {name} [{dims}]"
-            for name, dims, op in _HLO_INSTRUCTION.findall(hlo_text)
-            if op in ("copy", "dynamic-slice", "dynamic-update-slice")
-            and int(np.prod([int(d) for d in dims.split(",")])) in extents]
+    return _pool_sized(hlo_text, pool_shape,
+                       ("copy", "dynamic-slice", "dynamic-update-slice"))
+
+
+def pool_scatters(hlo_text: str, pool_shape) -> List[str]:
+    """The ``scatter`` instructions of an optimised program (fused ones
+    too) that write into the ``[L, KV, P, D]`` pool, found like
+    :func:`pool_sized_copies` by their result's extent. That is the row
+    write (``ops/kv_write.py write_rows``): one ``[D]`` row an update,
+    right for a decode program's one row a lane; a program whose lanes
+    carry more positions writes by block runs and has none."""
+    return _pool_sized(hlo_text, pool_shape, ("scatter",))
 
 
 def stacked_layer_copies(hlo_text: str, stacked_shapes) -> List[str]:

@@ -13,6 +13,7 @@ for a described v5e at the serve cell's real shapes, and by
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ import pytest
 
 from hcache_deepspeed_tpu.inference.model import PagedInferenceModel
 from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
-    BlockedKVCache, pool_sized_copies, stacked_layer_copies)
+    BlockedKVCache, pool_scatters, pool_sized_copies, stacked_layer_copies)
 from hcache_deepspeed_tpu.models.llama import LlamaForCausalLM, llama_tiny
 
 BS, NBLK, NB = 16, 2048, 8       # 32,768 slots; a sequence holds 128
@@ -218,6 +219,40 @@ def test_v5e_program_moves_no_layer_of_the_pool(one_chip, B, T):
 
 
 @pytest.mark.parametrize("B,T,restore",
+                         [(8, 1, False), (1, 512, False), (1, 512, True),
+                          (4, 64, False)],
+                         ids=["decode", "slice", "restore", "four-lanes"])
+def test_v5e_program_writes_its_rows_at_its_own_granularity(one_chip, B, T,
+                                                            restore):
+    """A lane that carries one position is one row in a block of its
+    own: the decode program keeps the row scatter. A lane that carries
+    more is runs of consecutive slots: the slice and restore programs
+    write them through ``hds_kv_write``, a block an update, and hold no
+    scatter of ``T x KV`` one-row updates into a pool. Either way both
+    pools alias input to output, nothing of the pool's or a layer's
+    extent is copied, and the temporaries stay under one layer of one
+    pool."""
+    compiled, pool, _ = _v5e_program(one_chip, B, T, restore)
+    text = compiled.as_text()
+    scatters = pool_scatters(text, pool.shape)
+    if T == 1:
+        assert len(scatters) == 2 and "hds_kv_write" not in text
+    else:
+        assert scatters == [] and "hds_kv_write" in text
+    assert pool_sized_copies(text, pool.shape) == []
+    # (a restore program keeps only the parameters it reads, so the
+    # pools' argument numbers are its own)
+    aliases = re.search(r"input_output_alias=\{ \{0\}: \((\d+), \{\}, "
+                        r"may-alias\), \{1\}: \((\d+), \{\}, may-alias\) \}",
+                        text.split("\n", 1)[0])
+    assert aliases and int(aliases[2]) == int(aliases[1]) + 1
+    mem = compiled.memory_analysis()
+    layer_bytes = int(np.prod(pool.shape[1:])) * 2
+    assert mem.alias_size_in_bytes >= 2 * pool.shape[0] * layer_bytes
+    assert mem.temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("B,T,restore",
                          [(8, 1, False), (1, 512, False), (1, 512, True)],
                          ids=["decode", "slice", "restore"])
 def test_v5e_program_reads_each_layers_weights_in_place(one_chip, B, T,
@@ -343,6 +378,10 @@ def test_v5e_hybrid_program_holds_pools_and_weights_in_place(one_chip, B,
     compiled, pools, params = _v5e_hybrid_program(one_chip, B, T)
     text = compiled.as_text()
     assert f"hds_{kernel}" in text and "hds_paged_attention" in text
+    # the slice writes its K and V by block runs, the decode lanes by rows
+    scatters = pool_scatters(text, pools["kv"].shape)
+    assert ("hds_kv_write" in text, len(scatters)) == \
+        ((True, 0) if T > 1 else (False, 2)), scatters
     for name in ("kv", "state"):
         assert pool_sized_copies(text, pools[name].shape) == [], name
     assert [c for c in pool_sized_copies(text, pools["conv"].shape)
