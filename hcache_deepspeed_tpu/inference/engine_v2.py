@@ -374,8 +374,7 @@ class InferenceEngineV2:
         sequences dispatched in one group share the same padded device
         array."""
         batch_uids = list(batch_uids)
-        batch_tokens = [np.asarray(t, np.int32).reshape(-1)
-                        for t in batch_tokens]
+        batch_tokens = list(batch_tokens)
         tracer = get_tracer()
         with tracer.span("hds.serve.put", n_seqs=len(batch_uids),
                          tokens=_token_count(batch_tokens)
@@ -388,6 +387,8 @@ class InferenceEngineV2:
         dispatch build / enqueue / wait / fetch / scatter."""
         tracer = get_tracer()
         with tracer.span("serve.put.admit"):
+            batch_tokens = [np.asarray(t, np.int32).reshape(-1)
+                            for t in batch_tokens]
             if do_checks:
                 # NOTE: with prefix caching the block budget is
                 # conservative (checked before any prefix attaches
@@ -647,8 +648,8 @@ class InferenceEngineV2:
         self._land_pending(logits, program)
         with tracer.span("serve.device_wait"):
             logits.block_until_ready()
-            if program is not None:
-                self._latent_link.enqueue(program, time.perf_counter())
+        if program is not None:     # two assignments: the parent's time
+            self._latent_link.enqueue(program, time.perf_counter())
         with tracer.span("serve.fetch",
                          bytes=_nbytes(logits) if tracer.enabled else 0):
             return np.asarray(logits)
@@ -973,14 +974,23 @@ class InferenceEngineV2:
                     for j in range(n):
                         if outs[j][0] == eos_token_id:
                             t_len[j] = 0
-                with get_tracer().span("serve.fused_decode",
-                                       lanes=n, n_feed=n_feed):
+                tracer = get_tracer()
+                with tracer.span("serve.fused_decode",
+                                 lanes=n, n_feed=n_feed):
                     toks, lats, lps = self.model.decode_loop(
                         self.cache, tok[:, 0], start, t_len, tables,
                         n_feed, temperature=temperature, top_k=top_k,
                         top_p=top_p, seed=seed,
                         want_logprobs=return_logprobs,
                         eos_token_id=eos_token_id)
+                with tracer.span("serve.device_wait"):
+                    toks.block_until_ready()
+                with tracer.span("serve.fetch",
+                                 bytes=_nbytes(toks, lps)
+                                 if tracer.enabled else 0):
+                    toks = np.asarray(toks)
+                    if lps is not None:
+                        lps = np.asarray(lps)
                 for j, uid in enumerate(uids):
                     self.state.get_sequence(uid).post_forward()
                     outs[j].extend(int(t) for t in toks[:, j])
@@ -1343,18 +1353,24 @@ class InferenceEngineV2:
             start[j] = starts[j]
             t_len[j] = len(feed)
         tables[:n] = self._tables(list(range(n)), batch_uids)
-        tracer = get_tracer()
+        tracer, lat = get_tracer(), None
         with tracer.span("serve.spec_dispatch", lanes=n,
                          tokens=_token_count(batch_feeds)
                          if tracer.enabled else 0):
             if capture:
                 tail_logits, lat = self.model.forward_chunk_tail_lat(
                     self.cache, tok, start, tables, t_len, T)
-                tail_logits = np.asarray(tail_logits)
-                lat = np.asarray(lat)          # [L, B, T, H]
             else:
-                tail_logits = np.asarray(self.model.forward_chunk_tail(
-                    self.cache, tok, start, tables, t_len, T))
+                tail_logits = self.model.forward_chunk_tail(
+                    self.cache, tok, start, tables, t_len, T)
+        with tracer.span("serve.device_wait"):
+            tail_logits.block_until_ready()
+        with tracer.span("serve.fetch",
+                         bytes=_nbytes(tail_logits, lat)
+                         if tracer.enabled else 0):
+            tail_logits = np.asarray(tail_logits)
+            if capture:
+                lat = np.asarray(lat)          # [L, B, T, H]
         emitted_out: List[List[int]] = []
         lat_out: List = []
         for j, (uid, feed) in enumerate(zip(batch_uids, batch_feeds)):

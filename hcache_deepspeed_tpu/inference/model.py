@@ -1133,8 +1133,7 @@ class PagedInferenceModel:
         cache.replace(ck, cv)
         # the steps asked for; lanes that met EOS early stopped writing
         self._count_kv_write(1, int(n_steps) * np.count_nonzero(t_len))
-        return (np.asarray(toks), lats,
-                np.asarray(lps) if lps is not None else None)
+        return toks, lats, lps     # on the device: the caller fetches
 
     def _restore_chunk(self, params, cache_k, cache_v, layer0, lat_chunk,
                        start, tables, t_len):

@@ -262,9 +262,14 @@ class ServingServer:
         ``advance_clock=False`` leaves the virtual clock to the caller
         — the fleet steps N replicas at one simulated instant and
         advances the shared clock once by the parallel-max cost."""
+        tracer = get_tracer()
+        # the wait for a submitter to let go of the lock, closed by
+        # hand so that the body stays a ``with self._lock`` block (the
+        # lock rules of ``analysis/`` read those)
+        waited = tracer.span("serve.loop.lock").__enter__()
         with self._lock:
-            with get_tracer().span("serve.loop.ingress",
-                                   n=len(self._ingress)):
+            waited.__exit__(None, None, None)
+            with tracer.span("serve.loop.ingress", n=len(self._ingress)):
                 for req in self._ingress:
                     self.scheduler.submit(req)
                 self._ingress.clear()

@@ -9,12 +9,16 @@ Two consumers:
   use.
 * :func:`summarize` / :func:`render_table` — offline reduction of a
   span stream (live tracer buffer or a loaded ``trace.json``) into a
-  per-step breakdown plus comm-volume and HCache-restore attribution,
+  per-step breakdown (training steps, and per ``sched.step`` the
+  serving loop's leaf spans, its wait for the device and the host's
+  turn between two programs) plus comm-volume and HCache-restore
+  attribution,
   including the restore-overlap ratio *computed from the explicit
   restore/decode span pair* the serving scheduler emits (not inferred
   from wall-clock adjacency).
 """
 
+import statistics
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
@@ -30,6 +34,14 @@ RESTORE_SPAN = "serve.restore_kv"
 RESTORE_STAGE_SPAN = "serve.restore.stage"
 SCHED_RESTORE_SPAN = "sched.restore_issue"
 SCHED_DISPATCH_SPAN = "sched.decode_dispatch"
+#: the per-serving-step grouping span, the wait for the device, and
+#: the spans that close when a program's enqueue has returned
+#: (docs/observability.md, "The enqueue spans")
+SERVE_STEP_SPAN = "sched.step"
+DEVICE_WAIT_SPAN = "serve.device_wait"
+ENQUEUE_SPANS = frozenset((
+    "serve.decode_dispatch", "serve.prefill_dispatch",
+    "serve.spec_dispatch", "serve.fused_decode", "restore.replay"))
 
 
 class StepMetrics:
@@ -134,6 +146,104 @@ def step_breakdown(events) -> "OrderedDict":
     return out
 
 
+def _end(ev):
+    return ev["ts"] + ev["dur"]
+
+
+def _leaf_spans(spans):
+    """Of one thread's X events, those that hold no other, in time
+    order."""
+    leaves, stack = [], []              # stack of [event, is a leaf]
+    for ev in sorted(spans, key=lambda e: (e["ts"], -e["dur"])):
+        while stack and _end(stack[-1][0]) <= ev["ts"]:
+            done, is_leaf = stack.pop()
+            if is_leaf:
+                leaves.append(done)
+        if stack:
+            stack[-1][1] = False
+        stack.append([ev, True])
+    leaves.extend(done for done, is_leaf in stack if is_leaf)
+    return sorted(leaves, key=lambda e: e["ts"])
+
+
+def serve_step_breakdown(events) -> "OrderedDict":
+    """``sched_step`` -> ``{"wall_ms", "leaves": {name: ms},
+    "device_wait_ms", "host_turn_ms", "turns"}`` per ``sched.step``
+    span of the loop thread, ordered by time: the step's length, its
+    time by leaf span (the spans inside it that hold no other; the wait
+    for the device apart, as ``device_wait_ms``), and the host's turns
+    that *end* in the step, a turn being the stretch from a
+    ``serve.device_wait``'s end to the end of the next enqueue span
+    (:data:`ENQUEUE_SPANS`): what the host does between two programs
+    while the device has none. ``benchmarks/reducers/idle_cut.py``
+    reads the same two edges off a profiler trace."""
+    steps = [ev for ev in events
+             if ev.get("ph") == "X" and ev["name"] == SERVE_STEP_SPAN]
+    out = OrderedDict()
+    if not steps:
+        return out
+    tid = steps[0]["tid"]
+    steps = sorted((ev for ev in steps if ev["tid"] == tid),
+                   key=lambda ev: ev["ts"])
+    rows = []
+    for ev in steps:
+        rows.append({"wall_ms": ev["dur"] / 1e3, "leaves": {},
+                     "device_wait_ms": 0.0, "host_turn_ms": 0.0,
+                     "turns": 0})
+        out[int(_args(ev).get("sched_step", len(rows)))] = rows[-1]
+    k, woke = 0, None
+    for ev in _leaf_spans(e for e in events
+                          if e.get("ph") == "X" and e["tid"] == tid):
+        while k < len(steps) and _end(steps[k]) <= ev["ts"]:
+            k += 1
+        row = rows[k] if k < len(steps) and steps[k]["ts"] <= ev["ts"] \
+            and ev is not steps[k] else None
+        name, ms = ev["name"], ev["dur"] / 1e3
+        if name == DEVICE_WAIT_SPAN:
+            woke = _end(ev)
+            if row is not None:
+                row["device_wait_ms"] += ms
+            continue
+        if row is not None:
+            row["leaves"][name] = row["leaves"].get(name, 0.0) + ms
+        if name in ENQUEUE_SPANS and woke is not None:
+            if row is not None:
+                row["host_turn_ms"] += (_end(ev) - woke) / 1e3
+                row["turns"] += 1
+            woke = None
+    return out
+
+
+def _median(values) -> float:
+    values = list(values)
+    return round(statistics.median(values), 3) if values else 0.0
+
+
+def serving_step_summary(events) -> Dict:
+    """The serving block of :func:`summarize`: medians over the
+    ``sched.step`` spans of :func:`serve_step_breakdown` (steps that
+    dispatched nothing count in ``n_steps`` only), the mean
+    milliseconds a dispatching step by leaf, and a turn's median."""
+    rows = list(serve_step_breakdown(events).values())
+    busy = [r for r in rows if r["device_wait_ms"] > 0.0]
+    turns = [r["host_turn_ms"] / r["turns"] for r in rows if r["turns"]]
+    leaves: Dict[str, float] = {}
+    for row in busy:
+        for name, ms in row["leaves"].items():
+            leaves[name] = leaves.get(name, 0.0) + ms
+    n = max(len(busy), 1)
+    return {
+        "n_steps": len(rows),
+        "dispatching_steps": len(busy),
+        "step_ms_p50": _median(r["wall_ms"] for r in busy),
+        "device_wait_ms_p50": _median(r["device_wait_ms"] for r in busy),
+        "host_turn_ms_p50": _median(turns),
+        "turns": sum(r["turns"] for r in rows),
+        "leaf_ms_mean": {k: round(v / n, 4)
+                         for k, v in sorted(leaves.items())},
+    }
+
+
 def restore_summary(events) -> Dict:
     """HCache restore attribution: counts/bytes from the engine-level
     restore spans and per-chunk staging spans, and the overlap ratio
@@ -223,6 +333,7 @@ def summarize(events) -> Dict:
         "restore": restore_summary(events),
         "comm": comm_summary(events),
         "serving": serving_summary(events),
+        "serving_steps": serving_step_summary(events),
     }
 
 
@@ -279,4 +390,15 @@ def render_table(summary: Dict) -> str:
     if serving:
         lines.append("serving edges: " + ", ".join(
             f"{k}={v}" for k, v in sorted(serving.items())))
+    ss = summary.get("serving_steps", {})
+    if ss.get("n_steps"):
+        lines.append(
+            f"serving steps: {ss['n_steps']} ({ss['dispatching_steps']} "
+            f"dispatching), step p50={ss['step_ms_p50']:.3f}ms, "
+            f"device_wait p50={ss['device_wait_ms_p50']:.3f}ms, "
+            f"host_turn p50={ss['host_turn_ms_p50']:.3f}ms over "
+            f"{ss['turns']} turns; mean ms a dispatching step by leaf:")
+        for name, ms in sorted(ss["leaf_ms_mean"].items(),
+                               key=lambda kv: -kv[1]):
+            lines.append(f"  {name:<28} {ms:>9.4f}")
     return "\n".join(lines)

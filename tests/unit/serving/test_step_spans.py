@@ -18,10 +18,14 @@ from hcache_deepspeed_tpu.inference import (InferenceEngineV2,
 from hcache_deepspeed_tpu.inference import engine_v2
 from hcache_deepspeed_tpu.serving import (Request, ServerConfig,
                                           ServingServer, VirtualClock)
+from hcache_deepspeed_tpu.telemetry.metrics import (ENQUEUE_SPANS,
+                                                    serve_step_breakdown,
+                                                    serving_step_summary)
 from hcache_deepspeed_tpu.telemetry.tracer import get_tracer
 
 #: span -> the span it must lie inside (None: top level of its thread)
 PARENT = {
+    "serve.loop.lock": None,
     "serve.loop.ingress": None,
     "sched.step": None,
     "sched.passes": "sched.step",
@@ -195,6 +199,104 @@ def test_latents_land_between_the_dispatch_and_the_wait(recorded):
     assert len(absorbs) == len(steps)
 
 
+def test_device_wait_holds_only_the_wait(tiny, tracing):
+    """``serve.device_wait`` is ``block_until_ready`` and nothing else:
+    the link's bookkeeping for the program that has just finished (a
+    stub that takes 2 ms here) runs after the span has closed, inside
+    the put, before ``serve.fetch`` opens."""
+    cfg, build = tiny
+    srv = virtual_server(build, landing=True)
+    link, calls = srv.scheduler.engine._latent_link, []
+    enqueue = link.enqueue
+
+    def slow_enqueue(program, now):
+        t_in = tracing.now_us()
+        time.sleep(0.002)
+        enqueue(program, now)
+        calls.append((t_in, tracing.now_us()))
+
+    link.enqueue = slow_enqueue
+    srv.run_trace(seeded_trace(cfg))
+    spans = [e for e in tracing.events() if e["ph"] == "X"]
+    waits = [e for e in spans if e["name"] == "serve.device_wait"]
+    assert len(calls) == len(waits) > 10
+    for t_in, t_out in calls:
+        assert t_out - t_in >= 2000.0
+        wait = max((w for w in waits if w["ts"] <= t_in),
+                   key=lambda w: w["ts"])
+        assert wait["ts"] + wait["dur"] <= t_in
+        fetch = min((e for e in spans if e["name"] == "serve.fetch"
+                     and e["ts"] >= wait["ts"]), key=lambda e: e["ts"])
+        assert t_out <= fetch["ts"]
+        assert any(p["ts"] <= t_in and t_out <= p["ts"] + p["dur"]
+                   for p in spans if p["name"] == "hds.serve.put")
+
+
+def test_enqueue_spans_close_before_the_next_leaf_opens(recorded):
+    """The spans ``benchmarks/reducers/idle_cut.py`` and
+    ``serve_step_breakdown`` read as "the enqueue has returned" hold
+    no other span, and what follows them on the thread begins after
+    they end."""
+    seen = set()
+    for ev in recorded:
+        if ev["name"] not in ENQUEUE_SPANS:
+            continue
+        seen.add(ev["name"])
+        end = ev["ts"] + ev["dur"]
+        assert not any(o is not ev and inside(o, ev) for o in recorded)
+        later = [o for o in recorded if o["tid"] == ev["tid"]
+                 and o["ts"] > ev["ts"]]
+        if later:
+            assert min(o["ts"] for o in later) >= end - 1e-3
+    assert seen == {"serve.decode_dispatch", "serve.prefill_dispatch",
+                    "restore.replay"}
+
+
+def test_serve_step_breakdown_closes_and_times_the_host_turn(recorded):
+    """Per ``sched.step``: the leaves and the wait for the device make
+    up the step (as ``test_leaf_spans_cover_their_parent`` holds the
+    parents: 90% of the whole trace and of the median step), and the
+    host's turns are the distances, computed here by hand, from each
+    ``serve.device_wait``'s end to the end of the next enqueue span."""
+    rows = serve_step_breakdown(recorded)
+    steps = [e for e in recorded if e["name"] == "sched.step"]
+    assert len(rows) == len(steps) and list(rows) == sorted(rows)
+    assert [r["wall_ms"] for r in rows.values()] == \
+        [e["dur"] / 1e3 for e in sorted(steps, key=lambda e: e["ts"])]
+    busy = [r for r in rows.values() if r["device_wait_ms"] > 0]
+    assert len(busy) > 10
+    named = [sum(r["leaves"].values()) + r["device_wait_ms"] for r in busy]
+    assert sum(named) / sum(r["wall_ms"] for r in busy) >= 0.9
+    shares = sorted(n / r["wall_ms"] for n, r in zip(named, busy))
+    assert shares[len(shares) // 2] >= 0.9
+    assert all("serve.device_wait" not in r["leaves"] and
+               "sched.step" not in r["leaves"] and
+               "hds.serve.put" not in r["leaves"] for r in busy)
+    # by hand: walk the waits and the enqueue spans in time order
+    marks = sorted((e for e in recorded if e["name"] in ENQUEUE_SPANS or
+                    e["name"] == "serve.device_wait"),
+                   key=lambda e: e["ts"])
+    by_hand, woke = [], None
+    for ev in marks:
+        if ev["name"] == "serve.device_wait":
+            woke = ev["ts"] + ev["dur"]
+        elif woke is not None:
+            by_hand.append((ev["ts"] + ev["dur"] - woke) / 1e3)
+            woke = None
+    assert sum(r["turns"] for r in rows.values()) == len(by_hand) > 10
+    assert sum(r["host_turn_ms"] for r in rows.values()) == \
+        pytest.approx(sum(by_hand))
+    first = next(r for r in rows.values() if r["turns"])
+    assert first["host_turn_ms"] == pytest.approx(
+        sum(by_hand[:first["turns"]]))
+    assert all(t > 0 for t in by_hand)
+    block = serving_step_summary(recorded)
+    assert block["n_steps"] == len(steps) and block["turns"] == len(by_hand)
+    assert block["dispatching_steps"] == len(busy)
+    assert min(by_hand) <= block["host_turn_ms_p50"] <= max(by_hand)
+    assert "serve.fetch" in block["leaf_ms_mean"]
+
+
 def test_force_is_a_reader_that_found_chunks_pending(tiny, tracing, recorded):
     """No ``serve.latents.force`` in a trace whose landings all found
     time under a program — its preemption's payload had landed before
@@ -259,6 +361,14 @@ def test_tracer_off_buffers_nothing_and_evaluates_no_attribute(
     reqs = seeded_trace(cfg)
     srv.run_trace(reqs)
     assert all(r.finished and r.tokens_out for r in reqs)
+    # the fused loop and the speculative verify step: their waits and
+    # fetches are spans of their own since PR 37
+    engine = build()
+    outs, _ = engine.generate_fused([[1, 2, 3]], max_new_tokens=4)
+    assert len(outs[0]) == 4
+    engine.put([7], [[1, 2, 3, 4]])
+    emitted, _ = engine.put_spec([7], [[5, 6]])
+    assert len(emitted[0]) >= 1
     assert tracer.buffered == 0
     tracer.configure(enabled=True, xla=False)
     try:
@@ -375,8 +485,18 @@ def test_thread_mode_spans_stay_on_their_threads(tiny, tracing):
         if ev["ph"] == "X":
             by_thread.setdefault(names[ev["tid"]], set()).add(ev["name"])
     loop = by_thread["hds-serving"]
-    assert {"serve.loop.ingress", "serve.loop.yield", "sched.step",
-            "hds.serve.put", "serve.fetch"} <= loop
+    assert {"serve.loop.lock", "serve.loop.ingress", "serve.loop.yield",
+            "sched.step", "hds.serve.put", "serve.fetch"} <= loop
+    # the loop's wait for the server lock: its own thread's, once a
+    # step, before the step's first scheduler span and inside none
+    spans = [e for e in tracing.events() if e["ph"] == "X"]
+    locks = [e for e in spans if e["name"] == "serve.loop.lock"]
+    steps = [e for e in spans if e["name"] == "sched.step"]
+    assert len(locks) >= len(steps) > 0
+    for lock in locks:
+        assert names[lock["tid"]] == "hds-serving"
+        assert not any(inside(lock, o) for o in spans
+                       if o["name"].startswith("sched."))
     matched = re.compile(r"^(sched|serve|hds|train|zero|restore)\.")
     for thread, spans in by_thread.items():
         if thread != "hds-serving":
