@@ -301,16 +301,17 @@ def _program_text(engine, B, T):
     """Optimised HLO of the engine's forward over ``B`` lanes of ``T``
     positions, as this device's compiler wrote it. A trunk with
     recurrent layers also takes its state pools and the lanes' slots."""
+    from hcache_deepspeed_tpu.inference.ragged.lanes import pack_lanes
     tok, start, t_len, tables = engine._blank_lanes(B, T)
     model, cache = engine.model, engine.cache
+    pools = (cache.k, cache.v)
+    slots = None
     if engine.recurrent:
-        return model._fwd.lower(
-            model.params, cache.k, cache.v, cache.state, cache.conv, tok,
-            start, tables, t_len,
-            np.full((B,), engine.state.state_slots, np.int32)
-        ).compile().as_text()
-    return model._fwd.lower(model.params, cache.k, cache.v, tok, start,
-                            tables, t_len).compile().as_text()
+        pools += (cache.state, cache.conv)
+        slots = np.full((B,), engine.state.state_slots, np.int32)
+    return model._fwd.lower(
+        model.params, *pools, pack_lanes(tok, start, tables, t_len, slots)
+    ).compile().as_text()
 
 
 def _check_slice_program(engine, pool_shape, prefill_chunk):

@@ -551,18 +551,24 @@ class InferenceEngineV2:
         tables[:, 0] = self._scratch_block
         return tok, start, t_len, tables
 
-    def _forward(self, tok, start, tables, t_len, uids, idx):
-        """One program over the built lanes. A trunk with recurrent
-        layers also gets each lane's state slot, blank lanes the spare
-        one."""
-        if not self.recurrent:
-            return self.model.forward_chunk(self.cache, tok, start, tables,
-                                            t_len)
-        slots = np.full((len(t_len),), self.state.state_slots, np.int32)
-        for j, i in enumerate(idx):
-            slots[j] = self.state.get_sequence(uids[i]).state_slot
-        return self.model.forward_chunk(self.cache, tok, start, tables,
-                                        t_len, slots)
+    def _forward(self, span, tok, start, tables, t_len, uids, idx):
+        """One program over the built lanes, inside the enqueue span
+        ``span``, which is told what the lanes' description cost in
+        transfers. A trunk with recurrent layers also gets each lane's
+        state slot, blank lanes the spare one."""
+        lanes = (tok, start, tables, t_len)
+        if self.recurrent:
+            slots = np.full((len(t_len),), self.state.state_slots,
+                            np.int32)
+            for j, i in enumerate(idx):
+                slots[j] = self.state.get_sequence(uids[i]).state_slot
+            lanes += (slots,)
+        stats = self.model.dispatch_stats
+        arrays, nbytes = stats["h2d_arrays"], stats["h2d_bytes"]
+        out = self.model.forward_chunk(self.cache, *lanes)
+        span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
+                 h2d_bytes=stats["h2d_bytes"] - nbytes)
+        return out
 
     def _run_decode(self, uids, tokens, idx, logits_out, latents_out,
                     defer=False):
@@ -576,9 +582,9 @@ class InferenceEngineV2:
                 start[j] = self.state.get_sequence(uids[i]).seen_tokens
                 t_len[j] = 1
         with tracer.span("serve.decode_dispatch",
-                         lanes=len(idx), bucket=B):
-            logits, latents = self._forward(tok, start, tables, t_len,
-                                            uids, idx)
+                         lanes=len(idx), bucket=B) as span:
+            logits, latents = self._forward(span, tok, start, tables,
+                                            t_len, uids, idx)
             if not defer:
                 latents = self._start_copies(logits, latents)
         if defer:   # keep the device array whole (row slicing here would
@@ -610,9 +616,9 @@ class InferenceEngineV2:
         with tracer.span("serve.prefill_dispatch",
                          lanes=len(idx), bucket=B, bucket_T=T,
                          tokens=_token_count(tokens[i] for i in idx)
-                         if tracer.enabled else 0):
-            logits, latents = self._forward(tok, start, tables, t_len,
-                                            uids, idx)
+                         if tracer.enabled else 0) as span:
+            logits, latents = self._forward(span, tok, start, tables,
+                                            t_len, uids, idx)
             if not defer:
                 latents = self._start_copies(logits, latents)
         if defer:
@@ -770,6 +776,16 @@ class InferenceEngineV2:
         dispatch's shape; whether the run path's kernel ran or gave way
         to the rows is ``ops.fallback_report()``."""
         return dict(self.model.kv_write_stats)
+
+    def dispatch_stats(self) -> Dict[str, int]:
+        """Forwards enqueued (``dispatches``: prompt slices, decode
+        steps, verification tails) and what described their lanes to
+        the device: ``h2d_arrays`` host arrays handed to the programs, a
+        host-to-device transfer each, and their ``h2d_bytes``. One
+        packed array a dispatch (``ragged/lanes.py``), so ``h2d_arrays
+        == dispatches``. Counted on the host where the arrays are
+        handed over."""
+        return dict(self.model.dispatch_stats)
 
     # -------------------------------------------------------------- #
     # Serving loop (reference: the generate() surface the v1 engine

@@ -49,6 +49,7 @@ from ..ops.rms_norm import rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.topology import TENSOR_AXIS
 from ..telemetry.tracer import get_tracer
+from .ragged.lanes import pack_lanes, unpack_lanes
 
 
 def join_path(path):
@@ -111,6 +112,9 @@ class PagedInferenceModel:
     checkpoint drops in directly, the analog of the reference's checkpoint
     loading into inference containers)."""
 
+    #: a trunk with recurrent layers: its lanes carry a state slot each
+    recurrent = False
+
     def __init__(self, cfg: LlamaConfig, params, *, block_size: int,
                  max_blocks_per_seq: int, capture_latents: bool = True,
                  topology=None, quantization=None,
@@ -138,6 +142,11 @@ class PagedInferenceModel:
         #: in ``ops.fallback_report()``.
         self.kv_write_stats = {"run_dispatches": 0, "run_rows": 0,
                                "row_dispatches": 0, "row_rows": 0}
+        #: forwards enqueued, and the host arrays (and their bytes) that
+        #: described their lanes to the device: a transfer each
+        #: (``_enqueue``)
+        self.dispatch_stats = {"dispatches": 0, "h2d_arrays": 0,
+                               "h2d_bytes": 0}
         self.topology = topology
         self.tp = topology.tensor_size if topology is not None else 1
         self.quantization = quantization if (
@@ -157,12 +166,13 @@ class PagedInferenceModel:
             self.cos, self.sin = rope_frequencies(cfg.head_dim,
                                                   cfg.max_positions,
                                                   theta)
-        fwd, restore = self._forward_chunk, self._restore_chunk
-        if self.tp > 1:
-            fwd, restore = self._wrap_tp(fwd, restore)
-        self._fwd_inner = fwd
-        self._fwd = jax.jit(fwd, donate_argnums=(1, 2))
-        self._restore = jax.jit(restore, donate_argnums=(1, 2))
+        #: the forward over separate lane arrays, for the fused loops'
+        #: bodies; a dispatch goes through ``_fwd``, over packed lanes
+        self._fwd_inner = self._manual_tp(self._forward_chunk, 4, 2)
+        self._fwd = self._lane_program(self._forward_chunk, 2)
+        self._restore = jax.jit(
+            self._manual_tp(self._restore_chunk, 5, 0),
+            donate_argnums=(1, 2))
         self._fwd_tail_cache = {}
         self._fwd_tail_lat_cache = {}
         self._fwd_tail_inner_cache = {}
@@ -432,27 +442,35 @@ class PagedInferenceModel:
         return NamedSharding(self.topology.mesh,
                              P(None, TENSOR_AXIS, None, None))
 
-    def _wrap_tp(self, fwd, restore):
+    def _manual_tp(self, fn, operands: int, results: int):
+        """``fn(params, cache_k, cache_v, *operands) -> (cache_k',
+        cache_v', *results)`` over the tensor-parallel shards: the
+        parameters and the pools split as they lie, ``operands`` and
+        ``results`` replicated. ``fn`` itself on one chip."""
+        if self.tp == 1:
+            return fn
         from jax.sharding import PartitionSpec as P
-        mesh = self.topology.mesh
-        pspecs = self._param_spec_tree()
         cache_spec = P(None, TENSOR_AXIS, None, None)  # [L, KV, P, D]
-        rep = P()
-
         # every mesh axis manual (no ``axis_names``): Mosaic refuses a
         # kernel while any axis, even of size one, is left automatic
-        fwd_m = jax.shard_map(
-            fwd, mesh=mesh,
-            in_specs=(pspecs, cache_spec, cache_spec, rep, rep, rep, rep),
-            out_specs=(cache_spec, cache_spec, rep, rep),
+        return jax.shard_map(
+            fn, mesh=self.topology.mesh,
+            in_specs=(self._param_spec_tree(), cache_spec, cache_spec)
+            + (P(),) * operands,
+            out_specs=(cache_spec, cache_spec) + (P(),) * results,
             check_vma=False)
-        restore_m = jax.shard_map(
-            restore, mesh=mesh,
-            in_specs=(pspecs, cache_spec, cache_spec, rep, rep, rep, rep,
-                      rep),
-            out_specs=(cache_spec, cache_spec),
-            check_vma=False)
-        return fwd_m, restore_m
+
+    def _lane_program(self, fwd, results: int, pools: int = 2):
+        """The jitted program of one dispatch: ``fwd(params, *pools,
+        tokens, start, tables, t_len[, slots])`` with the lanes as one
+        packed operand (``ragged/lanes.py``), cut inside the program;
+        the pools donated. Named after ``fwd`` in a profile."""
+        def program(params, *operands):
+            return fwd(params, *operands[:pools], *unpack_lanes(
+                operands[pools], self.max_blocks_per_seq, self.recurrent))
+        program.__name__ = fwd.__name__
+        return jax.jit(self._manual_tp(program, 1, results),
+                       donate_argnums=tuple(range(1, 1 + pools)))
 
     # -------------------------------------------------------------- #
     # Layer math (mirrors models/llama.py LlamaBlock exactly)
@@ -700,14 +718,37 @@ class PagedInferenceModel:
         self.kv_write_stats[path + "_rows"] += \
             int(positions) * 2 * self.cfg.n_kv_head * layers
 
-    def forward_chunk(self, cache, tokens, start, tables, t_len):
+    def _enqueue(self, program, pools, tokens, start, tables, t_len,
+                 slots=None):
+        """One dispatch of ``program`` (a :meth:`_lane_program`) over
+        ``pools``: the lanes packed into one host array and handed to the
+        jitted call as they are, which makes the one transfer itself (a
+        ``jnp.asarray`` in front would be ``device_put``'s Python on top
+        of it). Counted in ``dispatch_stats`` and ``kv_write_stats``."""
         self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
-        ck, cv, logits, latents = self._fwd(
-            self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
-            jnp.asarray(t_len, jnp.int32))
+        lanes = pack_lanes(tokens, start, tables, t_len, slots)
+        stats = self.dispatch_stats
+        stats["dispatches"] += 1
+        stats["h2d_arrays"] += 1
+        stats["h2d_bytes"] += lanes.nbytes
+        return program(self.params, *pools, lanes)
+
+    def forward_chunk(self, cache, tokens, start, tables, t_len):
+        ck, cv, logits, latents = self._enqueue(
+            self._fwd, (cache.k, cache.v), tokens, start, tables, t_len)
         cache.replace(ck, cv)
         return logits, latents
+
+    def _tail_forward(self, tail: int, latents: bool):
+        """``_forward_chunk_tail`` (``_forward_chunk_tail_lat`` with
+        ``latents``) at one ``tail``, a trace constant, over separate
+        lane arrays."""
+        fwd = self._forward_chunk_tail_lat if latents \
+            else self._forward_chunk_tail
+
+        def fwd_tail(params, ck, cv, tokens, start, tables, t_len):
+            return fwd(params, ck, cv, tokens, start, tables, t_len, tail)
+        return fwd_tail
 
     def _fwd_tail_for(self, tail: int):
         """Per-``tail`` compiled verification forward (tail is a trace
@@ -715,32 +756,17 @@ class PagedInferenceModel:
         all reused across a generation)."""
         fn = self._fwd_tail_cache.get(tail)
         if fn is None:
-            def fwd_tail(params, ck, cv, tokens, start, tables, t_len):
-                return self._forward_chunk_tail(
-                    params, ck, cv, tokens, start, tables, t_len, tail)
-            if self.tp > 1:
-                from jax.sharding import PartitionSpec as P
-                cache_spec = P(None, TENSOR_AXIS, None, None)
-                rep = P()
-                fwd_tail = jax.shard_map(
-                    fwd_tail, mesh=self.topology.mesh,
-                    in_specs=(self._param_spec_tree(), cache_spec,
-                              cache_spec, rep, rep, rep, rep),
-                    out_specs=(cache_spec, cache_spec, rep),
-                    check_vma=False)
-            fn = jax.jit(fwd_tail, donate_argnums=(1, 2))
-            self._fwd_tail_cache[tail] = fn
+            fn = self._fwd_tail_cache[tail] = self._lane_program(
+                self._tail_forward(tail, latents=False), 1)
         return fn
 
     def forward_chunk_tail(self, cache, tokens, start, tables, t_len,
                            tail: int):
         """Verification forward: head logits for the last ``tail``
         positions of each lane (speculative decoding)."""
-        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
-        ck, cv, logits = self._fwd_tail_for(tail)(
-            self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
-            jnp.asarray(t_len, jnp.int32))
+        ck, cv, logits = self._enqueue(
+            self._fwd_tail_for(tail), (cache.k, cache.v), tokens, start,
+            tables, t_len)
         cache.replace(ck, cv)
         return logits
 
@@ -750,21 +776,8 @@ class PagedInferenceModel:
         a latent engine shares the process)."""
         fn = self._fwd_tail_lat_cache.get(tail)
         if fn is None:
-            def fwd_tail(params, ck, cv, tokens, start, tables, t_len):
-                return self._forward_chunk_tail_lat(
-                    params, ck, cv, tokens, start, tables, t_len, tail)
-            if self.tp > 1:
-                from jax.sharding import PartitionSpec as P
-                cache_spec = P(None, TENSOR_AXIS, None, None)
-                rep = P()
-                fwd_tail = jax.shard_map(
-                    fwd_tail, mesh=self.topology.mesh,
-                    in_specs=(self._param_spec_tree(), cache_spec,
-                              cache_spec, rep, rep, rep, rep),
-                    out_specs=(cache_spec, cache_spec, rep, rep),
-                    check_vma=False)
-            fn = jax.jit(fwd_tail, donate_argnums=(1, 2))
-            self._fwd_tail_lat_cache[tail] = fn
+            fn = self._fwd_tail_lat_cache[tail] = self._lane_program(
+                self._tail_forward(tail, latents=True), 2)
         return fn
 
     def forward_chunk_tail_lat(self, cache, tokens, start, tables,
@@ -774,11 +787,9 @@ class PagedInferenceModel:
         ``(logits [B, tail, V], latents [L, B, T, H])`` — latent
         columns align with ``tokens`` columns (left-aligned feeds), so
         a lane's accepted span is ``latents[:, j, :acc+1]``."""
-        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
-        ck, cv, logits, latents = self._fwd_tail_lat_for(tail)(
-            self.params, cache.k, cache.v, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(start, jnp.int32), jnp.asarray(tables, jnp.int32),
-            jnp.asarray(t_len, jnp.int32))
+        ck, cv, logits, latents = self._enqueue(
+            self._fwd_tail_lat_for(tail), (cache.k, cache.v), tokens,
+            start, tables, t_len)
         cache.replace(ck, cv)
         return logits, latents
 
@@ -944,20 +955,8 @@ class PagedInferenceModel:
         other compiled loops (the fused speculative decoder)."""
         fn = self._fwd_tail_inner_cache.get(tail)
         if fn is None:
-            def fwd_tail(params, ck, cv, tokens, start, tables, t_len):
-                return self._forward_chunk_tail(
-                    params, ck, cv, tokens, start, tables, t_len, tail)
-            if self.tp > 1:
-                from jax.sharding import PartitionSpec as P
-                cache_spec = P(None, TENSOR_AXIS, None, None)
-                rep = P()
-                fwd_tail = jax.shard_map(
-                    fwd_tail, mesh=self.topology.mesh,
-                    in_specs=(self._param_spec_tree(), cache_spec,
-                              cache_spec, rep, rep, rep, rep),
-                    out_specs=(cache_spec, cache_spec, rep),
-                    check_vma=False)
-            self._fwd_tail_inner_cache[tail] = fn = fwd_tail
+            fn = self._fwd_tail_inner_cache[tail] = self._manual_tp(
+                self._tail_forward(tail, latents=False), 4, 1)
         return fn
 
     def _lookup_decode_loop(self, params, cache_k, cache_v, first_tok,

@@ -38,7 +38,6 @@ is not written yet (tensor parallelism, weight quantisation) raise
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..models.olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
 from ..ops.gated_delta import gated_delta_rule
@@ -80,6 +79,8 @@ class PagedHybridModel(PagedInferenceModel):
     """Serves :class:`~..models.olmo_hybrid.OlmoHybridConfig` trees
     through the ragged engine."""
 
+    recurrent = True
+
     def __init__(self, cfg: OlmoHybridConfig, params, *, topology=None,
                  quantization=None, **kw):
         if topology is not None and topology.tensor_size > 1:
@@ -105,8 +106,7 @@ class PagedHybridModel(PagedInferenceModel):
         super().__init__(cfg, params, topology=None, quantization=None,
                          **kw)
         self.n_latent_layers = self.n_periods * self.full_per
-        self._fwd = jax.jit(self._forward_chunk,
-                            donate_argnums=(1, 2, 3, 4))
+        self._fwd = self._lane_program(self._forward_chunk, 2, pools=4)
 
     # -------------------------------------------------------------- #
     def load_params(self, params):
@@ -275,13 +275,9 @@ class PagedHybridModel(PagedInferenceModel):
                 self._head_logits(params, last), latents)
 
     def forward_chunk(self, cache, tokens, start, tables, t_len, slots):
-        i32 = jnp.int32
-        self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
-        ck, cv, state, conv, logits, latents = self._fwd(
-            self.params, cache.k, cache.v, cache.state, cache.conv,
-            jnp.asarray(tokens, i32), jnp.asarray(start, i32),
-            jnp.asarray(tables, i32), jnp.asarray(t_len, i32),
-            jnp.asarray(slots, i32))
+        ck, cv, state, conv, logits, latents = self._enqueue(
+            self._fwd, (cache.k, cache.v, cache.state, cache.conv),
+            tokens, start, tables, t_len, slots)
         cache.replace(ck, cv)
         cache.replace_state(state, conv)
         return logits, latents

@@ -16,6 +16,7 @@ from hcache_deepspeed_tpu.inference.model_hybrid import (
     PagedHybridModel, RecurrentStateUnsupported)
 from hcache_deepspeed_tpu.inference.ragged.kv_cache import \
     pool_sized_copies
+from hcache_deepspeed_tpu.inference.ragged.lanes import lanes_width
 from hcache_deepspeed_tpu.inference.scheduling import SchedulingResult
 from hcache_deepspeed_tpu.models.olmo_hybrid import OlmoHybridForCausalLM
 from hcache_deepspeed_tpu.serving import ServerConfig, ServingServer
@@ -247,8 +248,8 @@ def test_compiled_program_holds_every_pool_in_place(params, B, T):
     model, cache = engine.model, engine.cache
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     compiled = model._fwd.lower(
-        model.params, cache.k, cache.v, cache.state, cache.conv, i32(B, T),
-        i32(B), i32(B, 16), i32(B), i32(B)).compile()
+        model.params, cache.k, cache.v, cache.state, cache.conv,
+        i32(B, lanes_width(T, 16, slot=True))).compile()
     text = compiled.as_text()
     header = text.split("\n", 1)[0]
     n_leaves = len(jax.tree.leaves(model.params))

@@ -23,6 +23,7 @@ import pytest
 from hcache_deepspeed_tpu.inference.model import PagedInferenceModel
 from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
     BlockedKVCache, pool_scatters, pool_sized_copies, stacked_layer_copies)
+from hcache_deepspeed_tpu.inference.ragged.lanes import lanes_width
 from hcache_deepspeed_tpu.models.llama import LlamaForCausalLM, llama_tiny
 
 BS, NBLK, NB = 16, 2048, 8       # 32,768 slots; a sequence holds 128
@@ -53,8 +54,7 @@ def _i32(*shape):
 def test_compiled_forward_holds_one_buffer_a_pool(model, B, T):
     pool = _pool(model)
     compiled = model._fwd.lower(
-        model.params, pool, pool, _i32(B, T), _i32(B), _i32(B, NB),
-        _i32(B)).compile()
+        model.params, pool, pool, _i32(B, lanes_width(T, NB))).compile()
     layer_bytes = int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
     pool_bytes = model.cfg.n_layer * layer_bytes
     mem = compiled.memory_analysis()
@@ -191,8 +191,8 @@ def _v5e_program(one_chip, B, T, restore=False):
                 params, pool, pool, i32(), latents, i32(B), i32(B, 32),
                 i32(B))
         else:
-            lowered = model._fwd.lower(params, pool, pool, i32(B, T),
-                                       i32(B), i32(B, 32), i32(B))
+            lowered = model._fwd.lower(params, pool, pool,
+                                       i32(B, lanes_width(T, 32)))
         compiled = lowered.compile()
     finally:
         platform._platform = None
@@ -353,8 +353,8 @@ def _v5e_hybrid_program(one_chip, B, T):
         i32 = lambda *shape: on_chip(shape, jnp.int32)
         compiled = model._fwd.lower(
             params, pools["kv"], pools["kv"], pools["state"],
-            pools["conv"], i32(B, T), i32(B), i32(B, 128), i32(B),
-            i32(B)).compile()
+            pools["conv"],
+            i32(B, lanes_width(T, 128, slot=True))).compile()
     finally:
         platform._platform = None
         jax.config.update("jax_enable_compilation_cache", cache_was_on)

@@ -56,7 +56,7 @@ def tiny():
 
     from hcache_deepspeed_tpu.models.llama import (LlamaForCausalLM,
                                                    llama_tiny)
-    cfg = llama_tiny(max_positions=128, use_flash=False)
+    cfg = llama_tiny(max_positions=128, use_flash=False, n_layer=6)
     params = LlamaForCausalLM(cfg).init(
         jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)},
         train=False)["params"]
@@ -152,8 +152,10 @@ def test_each_phase_is_a_span_inside_its_parent(recorded, name):
 def test_leaf_spans_cover_their_parent(recorded, parent):
     """What a parent bin held is now in named children. Quiet, they
     cover 99.9% of the steps and 99.8% of the puts of this trace; a
-    decode put of this tiny model takes 0.3 ms on the CPU, of which
-    opening and closing eight child spans is itself a twentieth, and
+    decode put of this small model takes 1.9 ms on the CPU (six layers:
+    with two it took 1.0 ms once a dispatch's lanes travelled as one
+    array, and what lies between a step's twenty-odd spans, 0.13 ms,
+    was a ninth of the median step), and
     the suite's other workers take the core away now and then, so the
     test holds the median span and the whole trace to 90% (on the chip
     a put takes a hundred milliseconds and the parents keep 0.9% of
