@@ -32,7 +32,8 @@ from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
 from .ragged.kv_cache import BlockedKVCache, HybridCache, StateManager
 from .ragged.latents import HostLink, LatentProgram, PendingLatents
-from .scheduling import SchedulingError, SchedulingResult
+from .scheduling import (BlockChoice, BlockPass, SchedulingError,
+                         SchedulingResult)
 
 
 @dataclass
@@ -53,6 +54,11 @@ class _RestoreLane:
     seqs: List[object]
     uids: List[int]
     ticket: RestoreTicket
+
+
+class DiffusionUnsupported(NotImplementedError):
+    """A feature that assumes causal decoding, one token a sequence a
+    step, asked of a model that generates by diffusion over blocks."""
 
 
 def _bucket(n: int, minimum: int = 8) -> int:
@@ -143,6 +149,24 @@ class InferenceEngineV2:
         #: beside its KV blocks (``ragged/kv_cache.py HybridCache``)
         self.recurrent = isinstance(model_config, OlmoHybridConfig)
 
+        #: positions of a generation block (a model that generates by
+        #: diffusion over blocks, ``put``'s ``blocks``); 1: causal
+        self.block_len = getattr(model_config, "diffusion_block_length", 1)
+        self.diffusion = self.block_len > 1
+        #: the token an open block's undecided positions hold
+        self.mask_token_id = getattr(model_config, "mask_token_id", None)
+        if self.diffusion and (self.block_size % self.block_len or
+                               sm_cfg.prefill_chunk % self.block_len):
+            raise ValueError(
+                f"a generation block of {self.block_len} positions must "
+                f"divide kv_cache.block_size ({self.block_size}) and "
+                f"state_manager.prefill_chunk ({sm_cfg.prefill_chunk}): "
+                "a block never straddles two cache blocks or two slices")
+        self._diffusion_stats = {"lane_passes": 0, "positions_fed": 0,
+                                 "positions_masked": 0,
+                                 "tokens_committed": 0}
+        self._moe_stats = {"dispatches": 0, "picks": None, "touched": 0}
+
         self.state = StateManager(
             sm_cfg.max_tracked_sequences, num_blocks, self.block_size,
             self.max_context,
@@ -155,6 +179,10 @@ class InferenceEngineV2:
         if self.prefix_caching and self.recurrent:
             from .model_hybrid import refuse
             raise refuse("prefix_caching (shared prefixes)")
+        if self.prefix_caching:
+            self._refuse_diffusion(
+                "prefix_caching (a shared block's K and V depend on the "
+                "whole of the block that follows the shared prefix)")
         if self.prefix_caching and self.config.hcache.enable_latents:
             raise ValueError(
                 "prefix_caching requires hcache.enable_latents=false: a "
@@ -359,7 +387,7 @@ class InferenceEngineV2:
     # -------------------------------------------------------------- #
     def put(self, batch_uids: Iterable[int],
             batch_tokens: Iterable, do_checks: bool = True,
-            defer_fetch: bool = False):
+            defer_fetch: bool = False, blocks=None):
         """One forward over a ragged batch. Returns
         ``(logits [n_seqs, vocab], latents)`` where ``latents[i]`` is the
         per-sequence host array [L, new_tokens, H] (None when HCache latent
@@ -372,7 +400,18 @@ class InferenceEngineV2:
         logits return is then a per-uid list of ``(device_array, lane)``
         pairs — ``np.asarray(device_array)[lane]`` is that uid's row;
         sequences dispatched in one group share the same padded device
-        array."""
+        array.
+
+        A model that generates by diffusion over blocks: ``blocks`` maps
+        the uids whose tokens are an open block (``block_len`` of them,
+        masks and all) to a :class:`BlockPass`; they ride the decode
+        dispatch at ``T = block_len``, which writes the block's K and V
+        over its slots and advances the sequence only when the pass
+        commits. Every other uid feeds whole blocks of its prompt. The
+        first return is then a list: a :class:`BlockChoice` a block
+        lane, ``None`` a prompt slice (its logits row predicts nothing
+        and is not fetched); latents come from prompt slices and
+        committing passes only."""
         batch_uids = list(batch_uids)
         batch_tokens = list(batch_tokens)
         tracer = get_tracer()
@@ -380,9 +419,10 @@ class InferenceEngineV2:
                          tokens=_token_count(batch_tokens)
                          if tracer.enabled else 0):
             return self._put(batch_uids, batch_tokens, do_checks,
-                             defer_fetch)
+                             defer_fetch, blocks or {})
 
-    def _put(self, batch_uids, batch_tokens, do_checks, defer_fetch):
+    def _put(self, batch_uids, batch_tokens, do_checks, defer_fetch,
+             blocks):
         """The body of :meth:`put`, in leaf spans: admit, then per
         dispatch build / enqueue / wait / fetch / scatter."""
         tracer = get_tracer()
@@ -407,7 +447,12 @@ class InferenceEngineV2:
                           if any(len(t) > 1 for t in batch_tokens)
                           else "engine.decode",
                           uid=batch_uids[-1], uids=tuple(batch_uids))
-            if defer_fetch and (self.prefix_caching or
+            if self.diffusion:
+                self._check_blocks(batch_uids, batch_tokens, blocks)
+            elif blocks:
+                raise ValueError("put(blocks=...) is for a model that "
+                                 "generates by diffusion over blocks")
+            if defer_fetch and (self.prefix_caching or self.diffusion or
                                 self.config.hcache.enable_latents or
                                 self.config.state_manager.prefill_chunk):
                 raise ValueError(
@@ -491,8 +536,8 @@ class InferenceEngineV2:
             # everything else -> per-sequence bucketed prefill
             decode_idx = [i for i, (u, t) in enumerate(
                 zip(batch_uids, batch_tokens))
-                if len(t) == 1 and
-                self.state.get_sequence(u).seen_tokens > 0]
+                if (u in blocks if self.diffusion else len(t) == 1 and
+                    self.state.get_sequence(u).seen_tokens > 0)]
             prefill_idx = [i for i in range(len(batch_uids))
                            if i not in decode_idx]
             # prefills batch per length bucket: one dispatch per (B, T)
@@ -508,7 +553,10 @@ class InferenceEngineV2:
         logits_out: List = [None] * n
         latents_out: List = [None] * n
 
-        if decode_idx:
+        if decode_idx and self.diffusion:
+            self._run_blocks(batch_uids, batch_tokens, decode_idx, blocks,
+                             logits_out, latents_out)
+        elif decode_idx:
             self._run_decode(batch_uids, batch_tokens, decode_idx,
                              logits_out, latents_out, defer=defer_fetch)
         for T, idx in sorted(groups.items()):
@@ -531,7 +579,7 @@ class InferenceEngineV2:
                         else []
                     latents_out[i] = PendingLatents.joined(parts + tail)
 
-            if defer_fetch:
+            if defer_fetch or self.diffusion:
                 return logits_out, latents_out
             return np.stack(logits_out), latents_out
 
@@ -620,45 +668,179 @@ class InferenceEngineV2:
             logits, latents = self._forward(span, tok, start, tables,
                                             t_len, uids, idx)
             if not defer:
-                latents = self._start_copies(logits, latents)
+                latents = self._start_copies(logits, latents,
+                                             fetch=not self.diffusion)
         if defer:
             for j, i in enumerate(idx):
                 logits_out[i] = (logits, j)
             return
-        logits = self._fetch(logits, latents)
+        # a prompt slice of a diffusion model predicts nothing: its
+        # first block starts from masks, and no logits row is fetched
+        logits = self._fetch(logits, latents, fetch=not self.diffusion)
         with tracer.span("serve.scatter"):
             for j, i in enumerate(idx):
-                logits_out[i] = logits[j]
+                logits_out[i] = None if self.diffusion else logits[j]
                 if latents is not None:            # [L, B, T, H]
                     latents_out[i] = self._hand_out(
                         latents, j, len(tokens[i]))
 
-    def _start_copies(self, logits, latents):
+    def _start_copies(self, logits, latents, fetch=True):
         """Part of a dispatch: start the copies of its results to the
         host, the logits' first (the next dispatch waits for those
-        alone). Returns the latents as a :class:`LatentProgram`, or
-        ``None`` when HCache capture is off."""
-        logits.copy_to_host_async()
-        if not self.config.hcache.enable_latents:
+        alone; ``fetch`` false: they stay on the device). Returns the
+        latents as a :class:`LatentProgram`, or ``None`` when HCache
+        capture is off or ``latents`` is ``None``."""
+        if fetch:
+            logits.copy_to_host_async()
+        if not self.config.hcache.enable_latents or latents is None:
             return None
         return LatentProgram(latents, self._latent_link)
 
-    def _fetch(self, logits, program):
+    def _fetch(self, logits, program, fetch=True):
         """A dispatch's logits on the host. In the time the device
         needs for the program, what earlier programs left pending is
         landed (``serve.latents.land``); then the wait for the device
         (``serve.device_wait``) and the logits' copy
-        (``serve.fetch``). The latents stay where they are:
-        ``program`` is only told when its copy could start."""
+        (``serve.fetch``; none, and ``None`` back, when ``fetch`` is
+        false). The latents stay where they are: ``program`` is only
+        told when its copy could start."""
         tracer = get_tracer()
         self._land_pending(logits, program)
         with tracer.span("serve.device_wait"):
             logits.block_until_ready()
         if program is not None:     # two assignments: the parent's time
             self._latent_link.enqueue(program, time.perf_counter())
+        if not fetch:
+            return None
         with tracer.span("serve.fetch",
                          bytes=_nbytes(logits) if tracer.enabled else 0):
             return np.asarray(logits)
+
+    # -------------------------------------------------------------- #
+    # Generation by diffusion over blocks: the decode dispatch at
+    # T = block_len
+    # -------------------------------------------------------------- #
+    def _refuse_diffusion(self, feature: str) -> None:
+        """Raise for a call that assumes causal one-token decoding."""
+        if self.diffusion:
+            raise DiffusionUnsupported(
+                f"{feature} is not supported for a model that generates "
+                f"by diffusion over blocks (diffusion_block_length="
+                f"{self.block_len}): a step is a pass over a lane's "
+                "whole block and yields no token until the block "
+                "commits; serve it through put(blocks=...) or "
+                "ServingServer")
+
+    def _check_blocks(self, uids, tokens, blocks) -> None:
+        """A block lane feeds one whole block at a block's edge; a
+        prompt slice whole blocks."""
+        B = self.block_len
+        for uid, toks in zip(uids, tokens):
+            seq = self.state.get_sequence(uid)
+            seen = seq.seen_tokens if seq else 0
+            if uid in blocks and len(toks) != B:
+                raise ValueError(f"uid {uid}: a block pass feeds {B} "
+                                 f"positions, got {len(toks)}")
+            if len(toks) % B or seen % B:
+                raise ValueError(
+                    f"uid {uid}: {len(toks)} tokens from position {seen} "
+                    f"are not whole blocks of {B}")
+
+    def _run_blocks(self, uids, tokens, idx, blocks, out, latents_out):
+        """One pass over every open block: the decode dispatch with
+        lanes of ``block_len`` positions. The device chooses, greedy;
+        what comes back is a token and a confidence a position and the
+        experts' counts, in one array, plus the logits rows and the
+        routers' inputs of the one lane that asked. Latents go to the host for the committing lanes only."""
+        tracer = get_tracer()
+        T, B = self.block_len, _bucket(len(idx))
+        passes = [blocks[uids[i]] for i in idx]
+        commits = [j for j, p in enumerate(passes) if p.commit]
+        probe = next((j for j, p in enumerate(passes) if p.probe), None)
+        rank = {j: r for r, j in enumerate(commits)}
+        with tracer.span("serve.batch_build", bucket=B):
+            tok, start, t_len, tables = self._blank_lanes(B, T)
+            flags = np.zeros((B,), np.int32)
+            tables[:len(idx)] = self._tables(idx, uids)
+            for j, i in enumerate(idx):
+                tok[j] = tokens[i]
+                start[j] = self.state.get_sequence(uids[i]).seen_tokens
+                t_len[j] = T
+                flags[j] = (j == probe) | (passes[j].commit << 1)
+            masked = int(np.count_nonzero(
+                tok[:len(idx)] == self.mask_token_id))
+        with tracer.span("serve.decode_dispatch", lanes=len(idx),
+                         bucket=B, block=T, masked=masked,
+                         commit_lanes=len(commits)) as span:
+            stats = self.model.dispatch_stats
+            arrays, nbytes = stats["h2d_arrays"], stats["h2d_bytes"]
+            packed, probed, latents = self.model.forward_block(
+                self.cache, tok, start, tables, t_len, flags)
+            span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
+                     h2d_bytes=stats["h2d_bytes"] - nbytes)
+            if probe is not None:
+                for a in probed:
+                    a.copy_to_host_async()
+            # the committing lanes lie first in the latents: only the
+            # bucket that holds them goes
+            buckets = self.model.latent_buckets(B)
+            latents = self._start_copies(
+                packed, latents[buckets.index(min(B, _bucket(len(commits))))]
+                if commits else None)
+        packed = self._fetch(packed, latents)
+        if probe is not None:
+            # the one fetch of logits rows there is: told apart by ``probe``
+            with tracer.span("serve.fetch", probe=1,
+                             bytes=_nbytes(*probed)):
+                rows, router_in = (np.asarray(a) for a in probed)
+        with tracer.span("serve.scatter"):
+            chosen = packed[:B * T].reshape(B, T)
+            confidence = packed[B * T:2 * B * T].view(np.float32) \
+                .reshape(B, T)
+            for j, i in enumerate(idx):
+                out[i] = BlockChoice(chosen[j], confidence[j]) \
+                    if j != probe else \
+                    BlockChoice(chosen[j], confidence[j], rows, router_in)
+                seq = self.state.get_sequence(uids[i])
+                if not passes[j].commit:
+                    seq.in_flight_tokens = 0    # provisional: not seen
+                elif latents is not None:
+                    latents_out[i] = self._hand_out(latents, rank[j], T)
+            self._count_blocks(len(idx), masked, len(commits),
+                               packed[2 * B * T:])
+
+    def _count_blocks(self, lanes, masked, commits, counts) -> None:
+        d, T = self._diffusion_stats, self.block_len
+        d["lane_passes"] += lanes
+        d["positions_fed"] += lanes * T
+        d["positions_masked"] += masked
+        d["tokens_committed"] += commits * T
+        if len(counts) > 1:                 # a sparse-expert trunk
+            m = self._moe_stats
+            m["dispatches"] += 1
+            m["touched"] += int(counts[-1])
+            picks = counts[:-1].astype(np.int64)
+            m["picks"] = picks if m["picks"] is None else m["picks"] + picks
+
+    def diffusion_stats(self) -> Dict[str, int]:
+        """Block passes so far (a model that generates by diffusion over
+        blocks): ``lane_passes`` forwards of one lane's block,
+        ``positions_fed`` the positions they carried,
+        ``positions_masked`` those that were still a mask,
+        ``tokens_committed`` the positions made final. Committed over
+        passes is the tokens a forward of a lane yields."""
+        return dict(self._diffusion_stats)
+
+    def moe_stats(self) -> Dict:
+        """A sparse-expert trunk's routing over the block passes so far,
+        counted on the device and fetched with the tokens: ``picks``
+        ``[E]`` the positions routed to each expert, all layers summed
+        (``None`` before the first pass); ``touched`` the experts with
+        any pick, layer by layer and pass by pass; ``dispatches`` the
+        passes counted."""
+        m = self._moe_stats
+        return {"dispatches": m["dispatches"], "touched": m["touched"],
+                "picks": None if m["picks"] is None else m["picks"].copy()}
 
     # -------------------------------------------------------------- #
     # Deferred latent landing (ragged/latents.py)
@@ -805,6 +987,7 @@ class InferenceEngineV2:
         computation). Sequences are flushed from the KV cache on
         completion.
         """
+        self._refuse_diffusion("generate (the host-sampled decode loop)")
         if not 0.0 < top_p <= 1.0:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         rng = np.random.default_rng(seed)
@@ -937,6 +1120,7 @@ class InferenceEngineV2:
         a returning sequence can be HCache-restored from them after a
         flush."""
         self._refuse_recurrent("generate_fused (the fused decode loop)")
+        self._refuse_diffusion("generate_fused (the fused decode loop)")
         if not 0.0 < top_p <= 1.0:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         if top_k < 0:
@@ -1093,6 +1277,7 @@ class InferenceEngineV2:
         ``stats = {drafted, accepted, dispatches, tokens}``.
         """
         self._refuse_recurrent("generate_lookup (speculative rollback)")
+        self._refuse_diffusion("generate_lookup (prompt-lookup drafts)")
         if self.prefix_caching:
             raise ValueError(
                 "generate_lookup with prefix_caching is unsupported: "
@@ -1214,6 +1399,8 @@ class InferenceEngineV2:
         batch)."""
         self._refuse_recurrent("generate_lookup_fused (speculative "
                                "rollback in a fused loop)")
+        self._refuse_diffusion("generate_lookup_fused (the fused lookup "
+                               "loop)")
         if self.prefix_caching:
             raise ValueError(
                 "generate_lookup_fused with prefix_caching is "
@@ -1328,6 +1515,7 @@ class InferenceEngineV2:
         ``prefix_caching`` stays unsupported (rolled-back KV must
         never register as a sharable prefix)."""
         self._refuse_recurrent("put_spec (rollback of rejected drafts)")
+        self._refuse_diffusion("put_spec (speculative verification)")
         capture = bool(self.config.hcache.enable_latents)
         if self.prefix_caching:
             raise RuntimeError(

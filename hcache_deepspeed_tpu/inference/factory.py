@@ -169,6 +169,49 @@ def _qwen2_moe_like(hf: Dict[str, Any]):
     )
 
 
+def _sdar_moe_like(hf: Dict[str, Any]):
+    """SDAR-MoE: the Qwen3-MoE layer (explicit ``head_dim``, per-head
+    q/k norm, every layer sparse, no shared expert) generating by
+    diffusion over blocks of ``diffusion_block_length`` positions
+    (absent: causal, one token a step). A dense layer among the sparse
+    ones is refused by name: the trunk is one stack of one kind."""
+    from ..models.sdar_moe import SdarMoeConfig
+    if hf.get("decoder_sparse_step", 1) != 1 or hf.get("mlp_only_layers"):
+        raise NotImplementedError(
+            "sdar_moe with dense layers among the sparse ones "
+            f"(decoder_sparse_step={hf.get('decoder_sparse_step')}, "
+            f"mlp_only_layers={hf.get('mlp_only_layers')}) is not "
+            "supported: the serving trunk stacks layers of one kind")
+    if hf.get("use_sliding_window") or hf.get("rope_scaling"):
+        raise NotImplementedError(
+            "sdar_moe with a sliding window or rope scaling is not "
+            "supported")
+    n_head = hf.get("num_attention_heads", 32)
+    hidden = hf.get("hidden_size", 2048)
+    return SdarMoeConfig(
+        vocab_size=hf.get("vocab_size", 151936),
+        hidden_size=hidden,
+        # the experts' width; ``intermediate_size`` is the dense layers',
+        # which this family has none of
+        intermediate_size=hf.get("moe_intermediate_size", 768),
+        n_layer=hf.get("num_hidden_layers", 48),
+        n_head=n_head,
+        n_kv_head=hf.get("num_key_value_heads", 4),
+        head_width=hf.get("head_dim", hidden // n_head),
+        max_positions=hf.get("max_position_embeddings", 32768),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        rope_theta=hf.get("rope_theta", 1e6),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attention_bias=hf.get("attention_bias", False),
+        num_experts=hf.get("num_experts", 128),
+        top_k=hf.get("num_experts_per_tok", 8),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        diffusion_block_length=hf.get("diffusion_block_length", 1),
+        mask_token_id=hf.get("mask_token_id", 151669),
+        dtype=hf.get("torch_dtype") or "bfloat16",
+    )
+
+
 def _olmo_hybrid_like(hf: Dict[str, Any]):
     """Olmo-Hybrid: ``layer_types`` names each layer ``linear_attention``
     (gated delta rule) or ``full_attention``; the ``linear_*`` keys are
@@ -206,7 +249,9 @@ def _olmo_hybrid_like(hf: Dict[str, Any]):
 #: expert + raw top-k gate mass); gpt2/opt/falcon/phi have their own
 #: paged trunks; qwen (v1) translates its idiosyncratic config keys
 #: onto the llama trunk (_qwen_v1_like); olmo_hybrid is the hybrid trunk
-#: (model_hybrid.py: gated-delta-rule layers beside full attention).
+#: (model_hybrid.py: gated-delta-rule layers beside full attention);
+#: sdar_moe is the MoE paged model with a per-head q/k norm, an explicit
+#: head width and the block mask of generation by diffusion over blocks.
 MODEL_FAMILIES = {
     "llama": _llama_like,
     "mistral": _llama_like,
@@ -220,6 +265,7 @@ MODEL_FAMILIES = {
     "mixtral": _mixtral_like,
     "qwen2_moe": _qwen2_moe_like,
     "olmo_hybrid": _olmo_hybrid_like,
+    "sdar_moe": _sdar_moe_like,
 }
 
 

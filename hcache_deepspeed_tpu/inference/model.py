@@ -45,7 +45,7 @@ import numpy as np
 from ..models.llama import LlamaConfig
 from ..ops.kv_write import flat_slots, kv_write, write_rows
 from ..ops.paged_attention import paged_attention
-from ..ops.rms_norm import rms_norm
+from ..ops.rms_norm import reference_rms_norm, rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.topology import TENSOR_AXIS
 from ..telemetry.tracer import get_tracer
@@ -134,6 +134,10 @@ class PagedInferenceModel:
         #: layers that capture a latent and replay K/V on restore (a
         #: hybrid trunk's full-attention layers only)
         self.n_latent_layers = cfg.n_layer
+        #: positions of a generation block of a model that generates by
+        #: diffusion over blocks: the attention mask's static, and the
+        #: width of a lane of :meth:`forward_block`; 1: causal
+        self.mask_block = getattr(cfg, "diffusion_block_length", 1)
         #: dispatches, and the ``[D]`` rows of K and V they wrote into the
         #: pools, by the granularity of the write (``_scatter_kv``): a
         #: block run at a time (lanes of more than one position) or a
@@ -173,6 +177,8 @@ class PagedInferenceModel:
         self._restore = jax.jit(
             self._manual_tp(self._restore_chunk, 5, 0),
             donate_argnums=(1, 2))
+        self._fwd_block = self._lane_program(
+            self._forward_block, 3, column=True)
         self._fwd_tail_cache = {}
         self._fwd_tail_lat_cache = {}
         self._fwd_tail_inner_cache = {}
@@ -191,11 +197,17 @@ class PagedInferenceModel:
         hybrid engine after each training phase (reference:
         runtime/hybrid_engine.py — inference containers refreshed from
         ZeRO training params). Shapes are unchanged, so the compiled
-        forward/restore functions are reused without retracing."""
+        forward/restore functions are reused without retracing.
+
+        A tree that holds ``layers`` already stacked ``[L, ...]`` in
+        place of ``layers_<i>`` is taken as it is: stacking holds every
+        layer twice while it runs, which a model that fills most of a
+        chip cannot afford."""
         new = {
             "embed": params["embed_tokens"]["embedding"],
             "norm": params["norm"]["weight"],
-            "layers": stack_layer_params(params, self.cfg.n_layer),
+            "layers": params["layers"] if "layers" in params
+            else stack_layer_params(params, self.cfg.n_layer),
         }
         if not self.tied:
             new["lm_head"] = params["lm_head"]["kernel"]
@@ -460,14 +472,19 @@ class PagedInferenceModel:
             out_specs=(cache_spec, cache_spec) + (P(),) * results,
             check_vma=False)
 
-    def _lane_program(self, fwd, results: int, pools: int = 2):
+    def _lane_program(self, fwd, results: int, pools: int = 2,
+                      column=None):
         """The jitted program of one dispatch: ``fwd(params, *pools,
-        tokens, start, tables, t_len[, slots])`` with the lanes as one
+        tokens, start, tables, t_len[, column])`` with the lanes as one
         packed operand (``ragged/lanes.py``), cut inside the program;
-        the pools donated. Named after ``fwd`` in a profile."""
+        the pools donated. ``column``: the lanes carry a fifth column (a
+        recurrent trunk's state slots, a block dispatch's flags);
+        default: as the trunk is. Named after ``fwd`` in a profile."""
+        column = self.recurrent if column is None else column
+
         def program(params, *operands):
             return fwd(params, *operands[:pools], *unpack_lanes(
-                operands[pools], self.max_blocks_per_seq, self.recurrent))
+                operands[pools], self.max_blocks_per_seq, column))
         program.__name__ = fwd.__name__
         return jax.jit(self._manual_tp(program, 1, results),
                        donate_argnums=tuple(range(1, 1 + pools)))
@@ -499,8 +516,15 @@ class PagedInferenceModel:
 
         qkv = jax.lax.optimization_barrier(tuple(
             proj(attn[name]) for name in ("q_proj", "k_proj", "v_proj")))
-        return tuple(y.reshape(*y.shape[:-1], y.shape[-1] // D, D)
-                     for y in qkv)
+        q, k, v = (y.reshape(*y.shape[:-1], y.shape[-1] // D, D)
+                   for y in qkv)
+        if "q_norm" in attn:
+            # RMSNorm over each head's channels, before the rotary step
+            # (plain jnp: rows of ``D`` fuse into their neighbours)
+            eps = self.cfg.rms_norm_eps
+            q = reference_rms_norm(q, attn["q_norm"]["weight"], eps)
+            k = reference_rms_norm(k, attn["k_norm"]["weight"], eps)
+        return q, k, v
 
     def _qkv(self, lp, h, positions):
         """h: [B, T, H]; returns q [B,T,Hq,D], k/v [B,T,KV,D] (roped)."""
@@ -537,7 +561,7 @@ class PagedInferenceModel:
         B, T, Hq, D = q.shape
         start = q_positions[:, 0]  # chunk rows are consecutive positions
         out = paged_attention(q, ck, cv, layer, tables, start, kv_len,
-                              self.block_size)
+                              self.block_size, self.mask_block)
         return out.reshape(B, T, Hq * D)
 
     def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
@@ -561,8 +585,18 @@ class PagedInferenceModel:
         x = x + proj
         h2 = rms_norm(x, lp["post_attention_layernorm"]["weight"],
                       eps=cfg.rms_norm_eps).astype(cfg.compute_dtype)
-        x = x + self._mlp_out(lp, h2)
-        return x.astype(cfg.compute_dtype), ck, cv, latent
+        mlp, stats = self._mlp(lp, h2, flat_idx, ck.shape[2])
+        x = x + mlp
+        return x.astype(cfg.compute_dtype), ck, cv, latent, stats
+
+    def _mlp(self, lp, h2, flat_idx, pool_slots):
+        """:meth:`_mlp_out` and what the layer has to say of itself, a
+        dict of arrays that the layer scan stacks for
+        :meth:`forward_block` (``flat_idx`` [B, T] below ``pool_slots``
+        are the real positions; the rest is padding): nothing here; the
+        router's input and the picks an expert in the MoE family
+        (``model_moe.py``)."""
+        return self._mlp_out(lp, h2), {}
 
     def _mlp_out(self, lp, h2):
         """SwiGLU MLP on the post-attention hidden states. Overridden by
@@ -597,6 +631,8 @@ class PagedInferenceModel:
         flat_idx = flat_slots(tables, start, t_len, T, self.block_size,
                               cache_k.shape[2])
 
+        scanned, whole = self._whole_layers(params["layers"])
+
         # the pools are carried, never scanned over: a scanned-over pool
         # is two buffers of the loop, every layer sliced out of one and
         # written back into the other
@@ -604,23 +640,39 @@ class PagedInferenceModel:
             x, ck, cv = carry
             layer, lp = xs
             lp = dequantize_tree(lp)   # one layer's weights only
-            x, ck, cv, latent = self._layer_step(
+            if whole is not None:
+                lp = self._with_whole(lp, whole, layer)
+            x, ck, cv, latent, stats = self._layer_step(
                 x, lp, ck, cv, layer, tables, positions, flat_idx, kv_len)
-            return (x, ck, cv), latent
+            # a layer with nothing to say adds nothing to the loop
+            return (x, ck, cv), (latent, stats)
 
-        (x, cache_k, cache_v), latents = jax.lax.scan(
+        (x, cache_k, cache_v), (latents, stats) = jax.lax.scan(
             step, (x, cache_k, cache_v),
-            (jnp.arange(cache_k.shape[0]), params["layers"]))
+            (jnp.arange(cache_k.shape[0]), scanned))
 
         x = self._final_norm(params, x)
-        return params, cache_k, cache_v, x, latents
+        return params, cache_k, cache_v, x, latents, stats
+
+    def _whole_layers(self, layers):
+        """``(scanned, whole)`` of the stacked layers: what the layer
+        scan slices a layer at a time, and what every layer is handed
+        whole beside its index (:meth:`_with_whole`): leaves that a
+        kernel reads in place by a layer index, which sliced out would be
+        copied. Nothing here; the MoE family's expert stacks."""
+        return layers, None
+
+    def _with_whole(self, lp, whole, layer):
+        """One layer's parameters ``lp`` with ``whole`` and the layer's
+        index put where the layer looks for them."""
+        raise NotImplementedError
 
     def _forward_chunk(self, params, cache_k, cache_v, tokens, start,
                        tables, t_len):
         """tokens: [B, T] int32; start: [B] first absolute position;
         tables: [B, NB]; t_len: [B] valid new tokens (≤ T).
         Returns (cache_k', cache_v', logits [B, V], latents [L, B, T, H])."""
-        params, cache_k, cache_v, x, latents = self._trunk(
+        params, cache_k, cache_v, x, latents, _ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         last = jnp.take_along_axis(
             x, jnp.maximum(t_len - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -642,7 +694,7 @@ class PagedInferenceModel:
         (cache_k', cache_v', logits [B, tail, V]); positions before a
         short sequence's first valid slot clamp to 0 and the caller
         masks by its own accept arithmetic."""
-        params, cache_k, cache_v, x, _latents = self._trunk(
+        params, cache_k, cache_v, x, _latents, _ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         idx = jnp.maximum(
             t_len[:, None] - tail + jnp.arange(tail)[None, :], 0)  # [B,tail]
@@ -662,7 +714,7 @@ class PagedInferenceModel:
         and discards the rolled-back tail. A separate compiled family
         (``_fwd_tail_lat_cache``): engines running exact-KV suspension
         never pay for the latent output."""
-        params, cache_k, cache_v, x, latents = self._trunk(
+        params, cache_k, cache_v, x, latents, _ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         idx = jnp.maximum(
             t_len[:, None] - tail + jnp.arange(tail)[None, :], 0)
@@ -672,6 +724,86 @@ class PagedInferenceModel:
             logits = jax.lax.all_gather(logits, TENSOR_AXIS, axis=2,
                                         tiled=True)
         return cache_k, cache_v, logits, latents
+
+    def _forward_block(self, params, cache_k, cache_v, tokens, start,
+                       tables, t_len, flags):
+        """One pass over a generation block a lane (a model that
+        generates by diffusion over blocks): the decode forward at ``T =
+        mask_block``, position ``i``'s logits predicting position
+        ``i``'s token, with the choice made here, greedy, so that no
+        logits row leaves the device. ``flags`` [B]: bit 0 marks the one
+        lane whose logits rows the caller wants, bit 1 the lanes that
+        commit.
+
+        Returns (cache_k', cache_v', packed, probe, latents). ``packed``
+        is int32, one fetch: the tokens chosen [B * T], their confidence
+        (the softmax probability of the chosen token, float32 bit for
+        bit) [B * T], then the layers' counts summed [n + 1]:
+        ``_mlp``'s ``picks`` and, last, how many of them are not zero
+        layer by layer. ``probe``: of the flagged lane, the logits rows
+        [T, V] and ``_mlp``'s ``router_in`` [L, T, H] (what each layer's
+        router read: a check routes its reference by it, ``[L, T, 0]``
+        where the trunk has no router). ``latents``: the committing
+        lanes first, as a tuple of the first 8, 16, ... B lanes ``[L, n,
+        T, H]``, so that the caller sends the host the bucket that holds
+        its committing lanes and no second program cuts it."""
+        params, cache_k, cache_v, x, latents, stats = self._trunk(
+            params, cache_k, cache_v, tokens, start, tables, t_len)
+        B, T, H = x.shape
+        # positions as rows from here on: a [B, T, V] result with T = 4
+        # in its second-minor dimension is laid out afresh, and a dot over
+        # [B, T, H] has the head's kernel copied into another layout
+        logits = self._head_logits(params, x.reshape(B * T, H))  # [BT, V]
+        if self.tp > 1:
+            logits = jax.lax.all_gather(logits, TENSOR_AXIS, axis=1,
+                                        tiled=True)
+        # the mask token is no prediction: a position that chose it would
+        # read as still masked and be fed again unchanged, for ever. The
+        # bias folds into the reductions that read the logits
+        allowed = logits + jnp.where(
+            jnp.arange(logits.shape[-1]) == self.cfg.mask_token_id,
+            -jnp.inf, 0.0)[None, :]
+        chosen = jnp.argmax(allowed, axis=-1).astype(jnp.int32)
+        picked = jnp.take_along_axis(logits, chosen[:, None], axis=-1)
+        confidence = jnp.exp(
+            picked[:, 0] - jax.nn.logsumexp(allowed, axis=-1))
+        lane = jnp.argmax(flags & 1)
+        router_in = stats.get("router_in",
+                              jnp.zeros((latents.shape[0], B, T, 0),
+                                        x.dtype))
+        probe = (jax.lax.dynamic_slice_in_dim(logits, lane * T, T, axis=0),
+                 jax.lax.dynamic_index_in_dim(router_in, lane, axis=1,
+                                              keepdims=False))
+        # the lanes that commit first, in their order: the caller takes
+        # that many lanes of the latents to the host and no more
+        latents = latents[:, jnp.argsort(1 - ((flags >> 1) & 1),
+                                         stable=True)]
+        latents = tuple(latents[:, :n] for n in self.latent_buckets(B))
+        if "picks" in stats:
+            picks = stats["picks"]                              # [L, E]
+            counts = jnp.concatenate([
+                jnp.sum(picks, axis=0),
+                jnp.sum(picks > 0, dtype=jnp.int32)[None]])
+        else:
+            counts = jnp.zeros((1,), jnp.int32)
+        packed = jnp.concatenate([
+            chosen, jax.lax.bitcast_convert_type(confidence, jnp.int32),
+            counts])
+        return cache_k, cache_v, packed, probe, latents
+
+    @staticmethod
+    def latent_buckets(B):
+        """The lane counts :meth:`_forward_block` cuts its latents to."""
+        return [n for n in (8 << i for i in range(32)) if n < B] + [B]
+
+    def forward_block(self, cache, tokens, start, tables, t_len, flags):
+        """:meth:`_forward_block` over ``cache``; everything it returns
+        stays on the device."""
+        ck, cv, *out = self._enqueue(
+            self._fwd_block, (cache.k, cache.v),
+            tokens, start, tables, t_len, flags)
+        cache.replace(ck, cv)
+        return out
 
     def _final_norm(self, params, x):
         """Final RMSNorm; LayerNorm families (falcon) override."""

@@ -88,4 +88,4 @@ class PagedFalconModel(PagedInferenceModel):
         if self.tp > 1:   # one psum covers both row-parallel partials
             both = jax.lax.psum(both, TENSOR_AXIS)
         x = x + both
-        return x.astype(cfg.compute_dtype), ck, cv, latent
+        return x.astype(cfg.compute_dtype), ck, cv, latent, {}

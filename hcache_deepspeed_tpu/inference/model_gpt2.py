@@ -127,4 +127,4 @@ class PagedGPT2Model(PagedInferenceModel):
         if self.tp > 1:
             mp = jax.lax.psum(mp, TENSOR_AXIS)
         x = x + mp + mb
-        return x.astype(cfg.compute_dtype), ck, cv, latent
+        return x.astype(cfg.compute_dtype), ck, cv, latent, {}

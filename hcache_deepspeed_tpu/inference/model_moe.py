@@ -27,10 +27,12 @@ matching the reference's TP-sharded MoE inference.
 """
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
 from ..models.mixtral import MixtralConfig
-from ..moe.dropless import dropless_expert_ffn
+from ..moe.dropless import routed_expert_ffn
 from ..parallel.topology import TENSOR_AXIS
 from .model import PagedInferenceModel, join_path
 
@@ -72,14 +74,54 @@ class PagedMoEModel(PagedInferenceModel):
         return str(getattr(path[-1], "key", path[-1])) == "wg"
 
     # -------------------------------------------------------------- #
+    def _whole_layers(self, layers):
+        """The experts' three stacks stay out of the layer scan: the
+        grouped matmul reads a layer's experts out of the stacked leaf
+        by its index (``ops/grouped_gemm.py``: sliced out for a custom
+        call they are copied, 1.2 GB a layer at 128 experts of 2048 x
+        768). Quantized stacks dequantize a layer at a time and stay in
+        the scan."""
+        if self.quantization:
+            return layers, None
+        moe = layers["mlp"]["moe"]
+        rest = {k: v for k, v in moe.items() if k != "experts"}
+        return ({**layers, "mlp": {**layers["mlp"], "moe": rest}},
+                moe["experts"])
+
+    def _with_whole(self, lp, whole, layer):
+        moe = dict(lp["mlp"]["moe"], experts=whole, layer=layer)
+        return {**lp, "mlp": {**lp["mlp"], "moe": moe}}
+
     def _mlp_out(self, lp, h2):
+        return self._routed(lp, h2)[0]
+
+    def _mlp(self, lp, h2, flat_idx, pool_slots):
+        """The expert layer; ``picks`` an expert took over the real
+        positions (``engine.moe_stats()``) and ``router_in``, what the
+        router read (a probed block lane's goes to the host with its
+        logits rows)."""
+        out, experts = self._routed(lp, h2)
+        valid = (flat_idx < pool_slots).reshape(-1, 1)       # [B * T, 1]
+        E = lp["mlp"]["moe"]["wg"].shape[-1]
+        picks = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
+            jnp.broadcast_to(valid, experts.shape).reshape(-1)
+            .astype(jnp.int32))
+        return out, {"picks": picks, "router_in": h2}
+
+    def _routed(self, lp, h2):
+        """``(output [B, T, d], experts picked [B * T, k])``."""
         moe = lp["mlp"]["moe"]
         B, T, d = h2.shape
         renorm = getattr(self.cfg, "norm_topk_prob", True)
-        out, _aux = dropless_expert_ffn(
-            h2.reshape(B * T, d), moe["wg"], moe["experts"]["w1"],
-            moe["experts"]["w3"], moe["experts"]["w2"], self.cfg.top_k,
-            renorm)
+        # the scope names the layer's operations in a profile; a device
+        # trace names an operation by its HLO text, which carries the
+        # attribute and not the scope
+        with jax.named_scope("expert_ffn"), \
+                set_xla_metadata(hds_layer="expert_ffn"):
+            out, _aux, experts = routed_expert_ffn(
+                h2.reshape(B * T, d), moe["wg"], moe["experts"]["w1"],
+                moe["experts"]["w3"], moe["experts"]["w2"],
+                self.cfg.top_k, renorm, layer=moe.get("layer"))
         out = out.reshape(B, T, d)
         if "shared_gate_proj" in moe:   # qwen2-moe shared expert
             gate = self._mm(h2, moe["shared_gate_proj"]["kernel"])
@@ -90,7 +132,7 @@ class PagedMoEModel(PagedInferenceModel):
             out = out + jax.nn.sigmoid(sg) * shared
         if self.tp > 1:   # row-parallel partial sum over expert ff shards
             out = jax.lax.psum(out, TENSOR_AXIS)
-        return out
+        return out, experts
 
     # -------------------------------------------------------------- #
     def _param_spec_tree(self, params=None):

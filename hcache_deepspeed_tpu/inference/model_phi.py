@@ -86,7 +86,7 @@ class PagedPhiModel(PagedFalconModel):
             # biases add exactly once, after the sum
             both = jax.lax.psum(both, TENSOR_AXIS)
         x = x + both + d["bias"] + lp["fc2"]["bias"]
-        return x.astype(cfg.compute_dtype), ck, cv, latent
+        return x.astype(cfg.compute_dtype), ck, cv, latent, {}
 
     def _head_logits(self, params, last):
         head = params["lm_head"]

@@ -10,7 +10,9 @@ the ONE corrective action that can actually clear it, so the serving
 loop never retries a permanent failure or rejects a transient one.
 """
 
+from dataclasses import dataclass
 from enum import Enum
+from typing import Any
 
 
 class SchedulingResult(Enum):
@@ -69,3 +71,28 @@ class SchedulingError(RuntimeError):
     def __init__(self, result: SchedulingResult) -> None:
         self.result = result
         super().__init__(f"Batch scheduling failed with result {result}")
+
+
+@dataclass
+class BlockPass:
+    """What :meth:`InferenceEngineV2.put` is told of one lane's block
+    (a model that generates by diffusion over blocks): ``commit`` the
+    pass is over a clean block, whose K and V become final and whose
+    positions count as seen; ``probe`` the caller wants this pass's
+    logits rows and routers' inputs on the host (one lane a dispatch:
+    the first asked)."""
+    commit: bool = False
+    probe: bool = False
+
+
+@dataclass
+class BlockChoice:
+    """What a block pass gives a lane: per position the token chosen on
+    the device and its confidence (the softmax probability of that
+    token), and if it was probed the logits rows ``[B, vocab]`` and what
+    each layer's router read ``[L, B, hidden]`` (``[L, B, 0]`` of a
+    trunk with no router)."""
+    tokens: Any
+    confidence: Any
+    logits: Any = None
+    router_in: Any = None

@@ -62,10 +62,17 @@ class LlamaConfig:
     #: > 0: chunked LM loss — no full [B, T, V] fp32 logits (see
     #: GPT2Config.loss_chunk)
     loss_chunk: int = 0
+    #: width of one attention head where the family publishes it apart
+    #: from ``hidden_size // n_head`` (q and o are then ``n_head *
+    #: head_width`` wide, not ``hidden_size``); 0: the quotient
+    head_width: int = 0
+    #: RMSNorm over each head's channels of q and k before the rotary
+    #: step (weights ``q_norm``/``k_norm`` of ``[head_dim]``)
+    qk_norm: bool = False
 
     @property
     def head_dim(self):
-        return self.hidden_size // self.n_head
+        return self.head_width or self.hidden_size // self.n_head
 
     @property
     def compute_dtype(self):
@@ -122,6 +129,9 @@ class LlamaAttention(nn.Module):
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, KV, D)
         v = v.reshape(B, T, KV, D)
+        if getattr(cfg, "qk_norm", False):   # family configs may lack it
+            q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
 
         cos, sin = rope_frequencies(D, cfg.max_positions, cfg.rope_theta)
         q = apply_rope(q, cos, sin)

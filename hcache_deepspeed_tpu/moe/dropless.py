@@ -19,7 +19,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_gemm import grouped_matmul
+from ..ops.grouped_gemm import grouped_matmul, grouped_matmul_stacked
 
 
 def dropless_route(logits, k, renormalize=True):
@@ -42,9 +42,19 @@ def dropless_route(logits, k, renormalize=True):
 
 
 def dropless_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True):
+    """:func:`routed_expert_ffn` for the training layer below: ([N, d],
+    aux)."""
+    return routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize)[:2]
+
+
+def routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True,
+                      layer=None):
     """The routed grouped-GEMM SwiGLU computation shared by the training
     layer below and the paged serving model (inference/model_moe.py).
-    tokens: [N, d]; returns ([N, d], aux)."""
+    tokens: [N, d]; returns ([N, d], aux, experts picked [N, k]).
+    ``layer``: ``w1``/``w3``/``w2`` are every layer's experts stacked
+    ``[L, E, ...]`` and this is the layer to compute by; the stack is
+    read in place (``ops/grouped_gemm.py grouped_matmul_stacked``)."""
     N, d = tokens.shape
     E = wg.shape[-1]
     dt = tokens.dtype
@@ -55,13 +65,19 @@ def dropless_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True):
     token_of = order // k
     xs = tokens[token_of]
     group_sizes = jnp.bincount(flat_e, length=E)
-    h = jax.nn.silu(grouped_matmul(xs, w1.astype(dt), group_sizes)) \
-        * grouped_matmul(xs, w3.astype(dt), group_sizes)
-    ys = grouped_matmul(h, w2.astype(dt), group_sizes)   # [N*k, d]
+    if layer is None:
+        def product(x, w):
+            return grouped_matmul(x, w.astype(dt), group_sizes)
+    else:
+        def product(x, w):
+            return grouped_matmul_stacked(x, w.astype(dt), layer,
+                                          group_sizes)
+    h = jax.nn.silu(product(xs, w1)) * product(xs, w3)
+    ys = product(h, w2)                                  # [N*k, d]
     gate = probs.reshape(-1)[order].astype(dt)
     out = jax.ops.segment_sum(ys * gate[:, None], token_of,
                               num_segments=N)
-    return out, aux
+    return out, aux, experts
 
 
 class _ExpertWeights(nn.Module):

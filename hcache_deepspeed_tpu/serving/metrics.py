@@ -263,6 +263,13 @@ class ServingMetrics:
                 sum(a.nbytes for a in r.state_rows)
                 for r in scheduler.suspended.values()
                 if r.state_rows is not None))
+        if getattr(engine, "diffusion", False):
+            # generation by diffusion over blocks: tokens made final
+            # over the forwards of a lane's block that made them
+            stats = engine.diffusion_stats()
+            if stats["lane_passes"]:
+                self.gauges["tokens_per_forward"] = \
+                    stats["tokens_committed"] / stats["lane_passes"]
         self.gauges["degradation_level"] = \
             float(report.degradation_level)
         if scheduler.total_restores:

@@ -28,7 +28,7 @@ names the cause (``deadline_exceeded``, ``engine_fault:<site>``,
 
 from dataclasses import dataclass, field
 from enum import Enum
-from typing import List, Optional
+from typing import Any, List, Optional
 
 from ..inference.ragged.latents import HostLatentStore
 from ..telemetry.context import TraceContext
@@ -71,6 +71,50 @@ _TRANSITIONS = {
 
 
 @dataclass
+class OpenBlock:
+    """The block a request of a model that generates by diffusion over
+    blocks is denoising: ``tokens`` its positions (mask tokens where
+    nothing is chosen yet), the first ``carried`` of them the prompt's
+    partial last block; ``committed`` the sequence's tokens whose K and
+    V are final in the cache (whole blocks), ``passes`` the denoise
+    passes this block has had, ``ordinal`` the blocks the request has
+    committed before it (0: its first open block)."""
+    tokens: List[int]
+    carried: int = 0
+    committed: int = 0
+    passes: int = 0
+    ordinal: int = 0
+    #: a pass of this block asked for its probe and lost its turn
+    probe_lost: bool = False
+
+    def unmask(self, chosen, confidence, mask_id: int, count: int) -> int:
+        """One denoise pass's remasking rule (static low-confidence
+        remasking): fill the ``count`` masked positions of highest
+        ``confidence`` from ``chosen``; ties go to the lower position.
+        Returns how many it filled."""
+        masked = [i for i, t in enumerate(self.tokens) if t == mask_id]
+        masked.sort(key=lambda i: (-float(confidence[i]), i))
+        for i in masked[:count]:
+            self.tokens[i] = int(chosen[i])
+        self.passes += 1
+        return len(masked[:count])
+
+
+@dataclass
+class BlockProbe:
+    """One probed pass of an open block, as the serving path ran it:
+    the block's ``ordinal``, the committed ``context`` ids behind it,
+    the ``block`` ids fed (masks and all), the logits ``rows`` ``[B,
+    vocab]`` and what each layer's router read, ``router_in`` ``[L, B,
+    hidden]``."""
+    ordinal: int
+    context: List[int]
+    block: List[int]
+    rows: Any
+    router_in: Any
+
+
+@dataclass
 class Request:
     """One serving request plus its lifecycle bookkeeping.
 
@@ -87,6 +131,17 @@ class Request:
     deadline: Optional[float] = None
     priority: int = 0
     eos_token_id: Optional[int] = None
+
+    # -- generation by diffusion over blocks ------------------------- #
+    #: the open block; set at submit by a scheduler over such a model
+    block: Optional[OpenBlock] = None
+    #: what a check compares with a reference: for each ordinal here,
+    #: ascending, every pass of the first block at or after it is asked
+    #: for its :class:`BlockProbe` (one lane a dispatch can be probed: a
+    #: block that loses a turn, or is reopened, hands its wish to the
+    #: next); ``probes`` holds them, whole blocks only, in order
+    probe_blocks: List[int] = field(default_factory=list)
+    probes: List[BlockProbe] = field(default_factory=list)
 
     state: RequestState = RequestState.QUEUED
     tokens_out: List[int] = field(default_factory=list)
@@ -192,14 +247,62 @@ class Request:
     # ------------------------------------------------------------- #
     @property
     def total_tokens(self) -> int:
-        """Worst-case context footprint: prompt + whole generation."""
-        return len(self.prompt) + self.max_new_tokens
+        """Worst-case context footprint: prompt + whole generation (in
+        whole blocks, where generation goes by blocks)."""
+        total = len(self.prompt) + self.max_new_tokens
+        if self.block is None:
+            return total
+        return -(-total // len(self.block.tokens)) * len(self.block.tokens)
 
     @property
     def cached_tokens(self) -> int:
         """Tokens whose KV is (or must be restored to be) on device:
-        the prompt plus every generated token already fed back."""
+        the prompt plus every generated token already fed back; where
+        generation goes by blocks, the committed blocks."""
+        if self.block is not None:
+            return self.block.committed
         return len(self.prompt) + max(len(self.tokens_out) - 1, 0)
+
+    def cached_ids(self) -> List[int]:
+        """The ids of :attr:`cached_tokens`, in order."""
+        return (list(self.prompt) + self.tokens_out)[:self.cached_tokens]
+
+    def reopen_block(self, mask_id: int) -> None:
+        """Drop what the open block had denoised (its K and V went with
+        an eviction): it starts again from masks behind the last commit,
+        the prompt's partial last block standing in it as before."""
+        size = len(self.block.tokens)
+        carried = list(self.prompt[self.block.committed:])
+        self.block.tokens = carried + [mask_id] * (size - len(carried))
+        self.block.carried = len(carried)
+        self.block.passes = 0
+        self._drop_probes()
+
+    def wants_probe(self) -> bool:
+        """Whether the open block's next pass is asked for its probe."""
+        return bool(self.probe_blocks) and \
+            self.probe_blocks[0] <= self.block.ordinal and \
+            not self.block.probe_lost
+
+    def keep_probe(self, choice, commit: bool) -> None:
+        """A pass that :meth:`wants_probe` came back as ``choice``: keep
+        its probe; without one (another lane had the dispatch's turn) the
+        block's are dropped and the next block is asked. A commit with
+        every pass kept fulfils the wish."""
+        if choice.logits is None:
+            self.block.probe_lost = True
+            self._drop_probes()
+            return
+        self.probes.append(BlockProbe(
+            self.block.ordinal, self.cached_ids(), list(self.block.tokens),
+            choice.logits, choice.router_in))
+        if commit:
+            self.probe_blocks.pop(0)
+
+    def _drop_probes(self) -> None:
+        """The open block's probes, a part of a block: gone."""
+        while self.probes and self.probes[-1].ordinal == self.block.ordinal:
+            self.probes.pop()
 
     @property
     def remaining_tokens(self) -> int:

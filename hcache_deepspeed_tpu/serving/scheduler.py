@@ -35,7 +35,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..inference.scheduling import (BACKPRESSURE_ACTION, BackpressureAction,
-                                    SchedulingError, SchedulingResult)
+                                    BlockPass, SchedulingError,
+                                    SchedulingResult)
 from ..resilience.degradation import DegradationLadder, DegradationLevel
 from ..resilience.policy import ResiliencePolicy
 from ..resilience.retry import CircuitBreaker, Watchdog
@@ -44,7 +45,7 @@ from ..telemetry.flight import get_flight_recorder
 from ..telemetry.tracer import get_tracer
 from .clock import MonotonicClock
 from .crossover import RestoreCrossoverModel
-from .request import Request, RequestState
+from .request import OpenBlock, Request, RequestState
 from .spec import (SLODegradation, SLOModeConfig, SpeculationConfig,
                    lookup_draft, validate_slo_mode_config,
                    validate_speculation_config)
@@ -126,6 +127,14 @@ class StepReport:
     #: sequences holding a slot of the recurrent-state pools after this
     #: step (a hybrid trunk; 0 for a trunk with no recurrent layer)
     state_slots: int = 0
+    # -- generation by diffusion over blocks -------------------------- #
+    #: lanes that fed an open block this step (they are the step's
+    #: ``decode_lanes``), and those of them whose pass committed
+    block_lanes: int = 0
+    commit_lanes: int = 0
+    #: positions that denoise passes filled, and positions made final
+    tokens_unmasked: int = 0
+    tokens_committed: int = 0
 
     @property
     def work_done(self) -> bool:
@@ -158,7 +167,8 @@ class ContinuousBatchingScheduler:
                  restore_priority_barrier: bool = False,
                  speculation: SpeculationConfig = None,
                  slo_mode: SLOModeConfig = None,
-                 prefix_cache=None):
+                 prefix_cache=None, denoising_steps: int = 2,
+                 block_token_fn: Callable[[Request, int], None] = None):
         self.engine = engine
         #: fleet position of this scheduler (0 = standalone/replica 0);
         #: folded into the retry-jitter RNG key so N replicas retrying
@@ -238,6 +248,36 @@ class ContinuousBatchingScheduler:
                     "verifies drafts against greedy targets, so a "
                     "custom sample_fn would silently change the "
                     "stream — disable speculation or drop sample_fn")
+        #: a model that generates by diffusion over blocks: a DECODE
+        #: resident holds an open block (``Request.block``), a step is
+        #: a pass over it, and tokens come a block at a time, at commit:
+        #: chosen on the device, so ``sample_fn`` has no row to read;
+        #: ``block_token_fn(req, token)`` is told each token as it is
+        #: emitted. A denoise pass fills the ``ceil(block /
+        #: denoising_steps)`` masked positions of highest confidence
+        self._block_len = int(getattr(engine, "block_len", 1))
+        self._diffusion = self._block_len > 1
+        self.denoising_steps = int(denoising_steps)
+        self.block_token_fn = block_token_fn
+        if self._diffusion:
+            self._mask_id = int(engine.mask_token_id)
+            if self.denoising_steps < 1:
+                raise HDSConfigError(
+                    f"denoising_steps must be >= 1, got {denoising_steps}")
+            if sample_fn is not None and sample_fn is not greedy_sample:
+                raise HDSConfigError(
+                    "a model that generates by diffusion over blocks "
+                    "chooses its tokens on the device: sample_fn is "
+                    "never called; block_token_fn is told the tokens")
+            if speculation is not None and speculation.enabled:
+                raise HDSConfigError(
+                    "speculation drafts one-token decode steps; a model "
+                    "that generates by diffusion over blocks has none "
+                    "(engine.put_spec refuses it by name)")
+            if prefix_cache is not None:
+                raise HDSConfigError(
+                    "warm-prefix adoption is not supported for a model "
+                    "that generates by diffusion over blocks")
         #: current step's drafts: uid -> proposed tokens (rebuilt per
         #: step by _draft_pass; consulted by _next_feed so admission /
         #: pressure verdicts budget the full speculative feed)
@@ -324,6 +364,8 @@ class ContinuousBatchingScheduler:
                                      replica=self.replica_id,
                                      trace="" if req.trace is None
                                      else req.trace.trace_id)
+        if self._diffusion and req.block is None:
+            req.block = OpenBlock([self._mask_id] * self._block_len)
         self._event("queued", req.uid, f"prio={req.priority}")
         self.queue.append(req)
 
@@ -924,7 +966,7 @@ class ContinuousBatchingScheduler:
         """A recompute re-entry re-prefills the full cached prefix plus
         the pending fed token in ONE standalone forward — it must fit
         the per-forward token budget and the engine's verdict."""
-        tokens = req.cached_tokens + 1
+        tokens = self._reentry_tokens(req)
         sm = self.engine.config.state_manager
         per_fwd = min(tokens, sm.prefill_chunk) if sm.prefill_chunk \
             else tokens
@@ -932,6 +974,12 @@ class ContinuousBatchingScheduler:
             return False
         return self.engine.can_schedule([req.uid], [tokens]) == \
             SchedulingResult.Success
+
+    def _reentry_tokens(self, req: Request) -> int:
+        """Tokens a recompute re-entry forwards: the cached prefix and
+        the pending fed token; where generation goes by blocks the
+        committed blocks alone (the open block starts again)."""
+        return req.cached_tokens + (0 if self._diffusion else 1)
 
     def _recompute_reentry(self, req: Request, report: StepReport,
                            now: float) -> None:
@@ -947,7 +995,8 @@ class ContinuousBatchingScheduler:
             # re-entry span so attribution separates recompute compute
             # from restore-lane ship/replay time
             req.trace.relabel("recompute")
-        tokens = list(req.prompt) + req.tokens_out
+        tokens = req.cached_ids() if self._diffusion \
+            else list(req.prompt) + req.tokens_out
         with get_tracer().span("sched.recompute_issue", uid=req.uid,
                                sched_step=self.step_idx,
                                replica=self.replica_id,
@@ -958,7 +1007,9 @@ class ContinuousBatchingScheduler:
             saved = req.latents
             req.latents = None
             try:
-                logits, latents = self.engine.put([req.uid], [tokens])
+                # nothing committed yet: nothing to forward again
+                logits, latents = self.engine.put([req.uid], [tokens]) \
+                    if tokens else ([None], [None])
             except BaseException:
                 req.latents = saved
                 raise
@@ -969,6 +1020,12 @@ class ContinuousBatchingScheduler:
         report.recomputed.append(req.uid)
         self._event("restore", req.uid,
                     f"mode=recompute tokens={len(tokens)}")
+        if self._diffusion:
+            # the committed blocks are back; the open block (reopened
+            # at eviction) takes its passes with the residents
+            req.transition(RequestState.DECODE)
+            self.running[req.uid] = req
+            return
         tok = self.sample_fn(req, logits[0])
         req.tokens_out.append(tok)
         if len(req.tokens_out) >= req.max_new_tokens or (
@@ -1033,7 +1090,7 @@ class ContinuousBatchingScheduler:
                 # recompute re-entry is the only road back — re-prefill
                 # prompt + generated tokens when it fits, else wait
                 sm = self.engine.config.state_manager
-                tokens = req.cached_tokens + 1
+                tokens = self._reentry_tokens(req)
                 per_fwd = min(tokens, sm.prefill_chunk) \
                     if sm.prefill_chunk else tokens
                 if per_fwd > sm.max_ragged_batch_size:
@@ -1080,7 +1137,7 @@ class ContinuousBatchingScheduler:
                                    replica=self.replica_id,
                                    tokens=req.cached_tokens):
                 if self.latent_preemption:
-                    tokens = list(req.prompt) + req.tokens_out[:-1]
+                    tokens = req.cached_ids()
                     # only a hybrid trunk's engine takes ``states``: the
                     # simulator and the fabric's engines keep the
                     # three-argument form
@@ -1304,6 +1361,10 @@ class ContinuousBatchingScheduler:
         else:
             self.engine.suspend_sequence(req.uid)
             mode = "kv"
+        if self._diffusion:
+            # the open block's K and V went with the eviction: the
+            # request resumes from its last commit
+            req.reopen_block(self._mask_id)
         req.transition(RequestState.SUSPENDED)
         req.n_preemptions += 1
         req.suspended_in_step = self.step_idx
@@ -1319,10 +1380,18 @@ class ContinuousBatchingScheduler:
         mid-chunk PREFILL resident (scheduler-grain chunked
         prefill)."""
         if req.state == RequestState.PREFILL:
-            rest = len(req.prompt) - req.prefill_pos
+            rest = self._prompt_feed(req) - req.prefill_pos
             chunk = self._prefill_chunk_now
             return min(rest, chunk) if chunk else rest
+        if self._diffusion:
+            return self._block_len      # a pass over the open block
         return 1 + len(self._drafts.get(req.uid, ()))
+
+    def _prompt_feed(self, req: Request) -> int:
+        """Prompt tokens that prefill: all of them; where generation
+        goes by blocks the prompt's whole blocks (its partial last block
+        stands in the first open block)."""
+        return len(req.prompt) // self._block_len * self._block_len
 
     def _first_feed(self, req: Request) -> int:
         """Tokens an admission candidate would feed this step (its
@@ -1330,7 +1399,8 @@ class ContinuousBatchingScheduler:
         otherwise). Chunked admission budgets per slice — "fits
         eventually" is handled dynamically, like decode growth."""
         chunk = self._prefill_chunk_now
-        return min(len(req.prompt), chunk) if chunk else len(req.prompt)
+        feed = self._prompt_feed(req) or self._block_len
+        return min(feed, chunk) if chunk else feed
 
     def _trial_verdict(self, admits: List[Request],
                        cand: Optional[Request]) -> SchedulingResult:
@@ -1679,6 +1749,13 @@ class ContinuousBatchingScheduler:
                 if self.prefix_cache is not None and \
                         self.latent_preemption:
                     self._try_adopt_prefix(req, report)
+                if self._diffusion and not self._prompt_feed(req):
+                    # a prompt shorter than a block stands in the first
+                    # open block whole: nothing to prefill
+                    self._open_first_block(req)
+                    decodes.append(req)
+            admits = [r for r in admits
+                      if r.state == RequestState.PREFILL]
         spec_ok = False
         if spec_lanes:
             spec_ok = self._spec_dispatch(spec_lanes, report, now)
@@ -1692,7 +1769,16 @@ class ContinuousBatchingScheduler:
         with tracer.span("sched.batch_build", lanes=len(decodes),
                          slices=n_slices):
             slices: Dict[int, List[int]] = {}
-            toks: List = [[r.tokens_out[-1]] for r in decodes]
+            blocks = {}
+            if self._diffusion:
+                toks: List = [list(r.block.tokens) for r in decodes]
+                for r in decodes:
+                    blocks[r.uid] = BlockPass(
+                        commit=self._mask_id not in r.block.tokens,
+                        probe=r.wants_probe())
+                report.block_lanes = len(decodes)
+            else:
+                toks = [[r.tokens_out[-1]] for r in decodes]
             for req in chunking + admits:
                 n = self._next_feed(req)
                 slices[req.uid] = list(
@@ -1717,7 +1803,8 @@ class ContinuousBatchingScheduler:
                 overlapped_restores=report.overlapped_restores) as sp:
             try:
                 logits, latents = self.engine.put(
-                    [r.uid for r in step_reqs], toks)
+                    [r.uid for r in step_reqs], toks,
+                    **({"blocks": blocks} if self._diffusion else {}))
             except SchedulingError:
                 raise           # admission arithmetic bug — surface it
             except Exception as exc:
@@ -1766,6 +1853,10 @@ class ContinuousBatchingScheduler:
             for j, req in enumerate(step_reqs):
                 if req.uid in faulted:
                     continue
+                if self._diffusion:
+                    self._advance_block(req, logits[j],
+                                        slices.get(req.uid), report, now)
+                    continue
                 if req.state == RequestState.PREFILL:
                     req.prefill_pos += len(slices[req.uid])
                     if req.prefill_pos < len(req.prompt):
@@ -1788,6 +1879,62 @@ class ContinuousBatchingScheduler:
                     del self.running[req.uid]
                     self.engine.flush(req.uid)
                     self._close(req, report, now)
+
+    # ------------------------------------------------------------- #
+    # generation by diffusion over blocks
+    # ------------------------------------------------------------- #
+    def _open_first_block(self, req: Request) -> None:
+        """The prompt's whole blocks are in the cache: the request
+        becomes a DECODE resident holding its first open block, the
+        prompt's partial last block unmasked in it."""
+        req.block.committed = self._prompt_feed(req)
+        req.reopen_block(self._mask_id)
+        req.transition(RequestState.DECODE)
+        self.running[req.uid] = req
+
+    def _advance_block(self, req: Request, choice, prompt_slice,
+                       report: StepReport, now: float) -> None:
+        """What one step's dispatch did for ``req``: a prompt slice moves
+        its prefill on; a denoise pass fills the open block's masked
+        positions of highest confidence (``OpenBlock.unmask``); a commit
+        pass makes the block final and emits its tokens, in order
+        (``block_token_fn`` is told each), stopping at EOS or
+        ``max_new_tokens`` inside the block."""
+        if req.state == RequestState.PREFILL:
+            req.prefill_pos += len(prompt_slice)
+            req.block.committed = req.prefill_pos
+            self.running[req.uid] = req
+            if req.prefill_pos >= self._prompt_feed(req):
+                self._open_first_block(req)
+            return
+        block = req.block
+        commit = self._mask_id not in block.tokens
+        if req.wants_probe():
+            req.keep_probe(choice, commit)
+        if not commit:
+            report.tokens_unmasked += block.unmask(
+                choice.tokens, choice.confidence, self._mask_id,
+                -(-self._block_len // self.denoising_steps))
+            return
+        block.committed += self._block_len
+        block.ordinal += 1
+        block.probe_lost = False
+        report.commit_lanes += 1
+        report.tokens_committed += self._block_len
+        for tok in block.tokens[block.carried:]:
+            req.tokens_out.append(int(tok))
+            if self.block_token_fn is not None:
+                self.block_token_fn(req, int(tok))
+            if req.first_token_at is None:
+                req.first_token_at = now
+            if len(req.tokens_out) >= req.max_new_tokens or (
+                    req.eos_token_id is not None and
+                    tok == req.eos_token_id):
+                del self.running[req.uid]
+                self.engine.flush(req.uid)
+                self._close(req, report, now)
+                return
+        req.reopen_block(self._mask_id)
 
     def _quarantine_dispatch(self, exc: BaseException,
                              decodes: List[Request],

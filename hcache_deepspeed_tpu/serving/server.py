@@ -78,6 +78,10 @@ class ServerConfig:
     #: SLO-aware degradation mode (a :class:`~.spec.SLOModeConfig`;
     #: None = the fault-driven ladder alone)
     slo_mode: object = None
+    #: generation by diffusion over blocks: the denoise passes a whole
+    #: block takes (a pass fills ``ceil(block / denoising_steps)``
+    #: masked positions, those of highest confidence)
+    denoising_steps: int = 2
     # -- virtual-clock cost model (seconds) -------------------------- #
     step_overhead_s: float = 1e-3
     prefill_token_s: float = 1e-4
@@ -108,7 +112,7 @@ class ServingServer:
                  metrics: ServingMetrics = None, sample_fn=None,
                  monitor=None, emit_every_steps: int = 50,
                  crossover=None, resilience=None, replica_id: int = 0,
-                 prefix_cache=None):
+                 prefix_cache=None, block_token_fn=None):
         self.config = config or ServerConfig()
         self.clock = clock or MonotonicClock()
         self.virtual = isinstance(self.clock, VirtualClock)
@@ -127,7 +131,9 @@ class ServingServer:
             self.config.restore_priority_barrier,
             speculation=self.config.speculation,
             slo_mode=self.config.slo_mode,
-            prefix_cache=prefix_cache)
+            prefix_cache=prefix_cache,
+            denoising_steps=self.config.denoising_steps,
+            block_token_fn=block_token_fn)
         self.monitor = monitor
         self.emit_every_steps = emit_every_steps
         self._lock = make_lock("ServingServer._lock")

@@ -132,6 +132,11 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+#: the text each v5e program lowers to, before the compiler has it:
+#: ``(trunk, B, T) -> text`` (``test_causal_programs_lower_as_on_the_parent``)
+_LOWERED = {}
+
+
 class _ShapesOnly(PagedInferenceModel):
     """The model over ``ShapeDtypeStruct``s: a described device holds no
     array."""
@@ -193,6 +198,7 @@ def _v5e_program(one_chip, B, T, restore=False):
         else:
             lowered = model._fwd.lower(params, pool, pool,
                                        i32(B, lanes_width(T, 32)))
+            _LOWERED["mistral", B, T] = lowered.as_text()
         compiled = lowered.compile()
     finally:
         platform._platform = None
@@ -351,10 +357,11 @@ def _v5e_hybrid_program(one_chip, B, T):
             "state": on_chip((6, 65, 30, 96, 192), jnp.float32),
             "conv": on_chip((6, 65, 3 * cfg.conv_channels), jnp.bfloat16)}
         i32 = lambda *shape: on_chip(shape, jnp.int32)
-        compiled = model._fwd.lower(
+        lowered = model._fwd.lower(
             params, pools["kv"], pools["kv"], pools["state"],
-            pools["conv"],
-            i32(B, lanes_width(T, 128, slot=True))).compile()
+            pools["conv"], i32(B, lanes_width(T, 128, slot=True)))
+        _LOWERED["hybrid", B, T] = lowered.as_text()
+        compiled = lowered.compile()
     finally:
         platform._platform = None
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
@@ -395,3 +402,157 @@ def test_v5e_hybrid_program_holds_pools_and_weights_in_place(one_chip, B,
     # under a layer of any pool
     layer_bytes = int(np.prod(pools["state"].shape[1:])) * 4
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+# ------------------------------------------------------------------ #
+# generation by diffusion over blocks (SDAR-MoE): the block program, and
+# the causal programs it shares its trunk, kernels and lanes with
+# ------------------------------------------------------------------ #
+#: sha256 (first 16 digits) of the text the causal serving programs
+#: lower to for the described v5e, the Pallas kernels' serialized bodies
+#: left out (they carry the line numbers of ``ops/*.py``), and of the
+#: paged kernel's own jaxpr: recorded on the parent of PR 42 (PR 38's
+#: tree) by these same functions. A configuration without
+#: ``diffusion_block_length`` must go on lowering to exactly these: the
+#: block mask, the per-head norm, the layers' counts and the whole-stack
+#: expert products are statics that fold away. A later PR that changes
+#: the trunk on purpose records its own.
+_PARENT_LOWERED = {
+    ("mistral", 8, 1): "39bac33fc0ec6e21",
+    ("mistral", 1, 512): "636262a941e36f1f",
+    ("hybrid", 8, 1): "b98f521a7036b852",
+    ("hybrid", 1, 512): "dc2e86140bffcaae"}
+_PARENT_PAGED_KERNEL = {(8, 1): "6236461a2f0c60fe",
+                        (1, 512): "f8d844c932e6e2a1"}
+
+
+def _digest(text):
+    import hashlib
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("trunk,B,T", sorted(_PARENT_LOWERED))
+def test_causal_programs_lower_as_on_the_parent(one_chip, trunk, B, T):
+    build = _v5e_program if trunk == "mistral" else _v5e_hybrid_program
+    build(one_chip, B, T)
+    text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>",
+                  _LOWERED[trunk, B, T])
+    assert _digest(text) == _PARENT_LOWERED[trunk, B, T]
+
+
+@pytest.mark.parametrize("B,T", sorted(_PARENT_PAGED_KERNEL))
+def test_the_paged_kernel_at_block_one_is_the_causal_kernel(B, T):
+    from hcache_deepspeed_tpu.ops.paged_attention import \
+        pallas_paged_attention
+
+    def call(q, k, v, tables, start, kv_len):
+        return pallas_paged_attention(q, k, v, jnp.int32(1), tables, start,
+                                      kv_len, 64, interpret=True)
+
+    def kernel_of(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["jaxpr"]
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                found = kernel_of(inner)
+                if found is not None:
+                    return found
+
+    pool = jnp.zeros((2, 8, 64 * 64, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(call)(
+        jnp.zeros((B, T, 32, 128), jnp.bfloat16), pool, pool,
+        jnp.zeros((B, 32), jnp.int32), jnp.zeros((B,), jnp.int32),
+        jnp.ones((B,), jnp.int32))
+    assert _digest(str(kernel_of(jaxpr.jaxpr))) == \
+        _PARENT_PAGED_KERNEL[B, T]
+
+@functools.lru_cache(maxsize=None)
+def _v5e_sdar_program(one_chip, B, T, block=True):
+    """SDAR-30B-A3B widths (128 experts of 768, top-8, heads of 128 on a
+    hidden of 2048), 2 layers, the cell's pool (4096 blocks of 64),
+    compiled for the described chip: the block program over ``B`` lanes
+    of ``T`` positions, or (``block`` false) the prompt-slice program.
+    Returns ``(compiled, pool, params)``."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from hcache_deepspeed_tpu import platform
+    from hcache_deepspeed_tpu.inference.model import stack_layer_params
+    from hcache_deepspeed_tpu.inference.model_moe import PagedMoEModel
+    from hcache_deepspeed_tpu.models.sdar_moe import (SdarMoeConfig,
+                                                      SdarMoeForCausalLM)
+
+    class ShapesOnly(PagedMoEModel):
+        def load_params(self, params):
+            self.params = params
+
+    platform.set_platform("tpu")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = SdarMoeConfig(
+            vocab_size=151936, hidden_size=2048, intermediate_size=768,
+            n_layer=2, n_head=32, n_kv_head=4, head_width=128,
+            max_positions=2304, diffusion_block_length=4,
+            dtype="bfloat16")
+        tree = jax.eval_shape(lambda: SdarMoeForCausalLM(cfg).init(
+            jax.random.PRNGKey(0),
+            {"input_ids": np.zeros((1, 8), np.int32)},
+            train=False))["params"]
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, x: jax.ShapeDtypeStruct(
+                x.shape, jnp.float32 if PagedMoEModel._keep_fp32(path)
+                else jnp.bfloat16, sharding=one_chip),
+            {"embed": tree["embed_tokens"]["embedding"],
+             "norm": tree["norm"]["weight"],
+             "layers": jax.eval_shape(
+                 lambda p: stack_layer_params(p, cfg.n_layer), tree),
+             "lm_head": tree["lm_head"]["kernel"]})
+        model = ShapesOnly(cfg, params, block_size=64,
+                           max_blocks_per_seq=36)
+        pool = jax.ShapeDtypeStruct((2, 4, 4096 * 64, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+        program = model._fwd_block if block else model._fwd
+        lanes = jax.ShapeDtypeStruct(
+            (B, lanes_width(T, 36, slot=block)), jnp.int32,
+            sharding=one_chip)
+        compiled = program.lower(params, pool, pool, lanes).compile()
+    finally:
+        platform._platform = None
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    return compiled, pool, params
+
+
+@pytest.mark.parametrize("B,T,block", [(128, 4, True), (1, 512, False)],
+                         ids=["block", "slice"])
+def test_v5e_sdar_program_aliases_pools_and_copies_no_weight(one_chip, B, T,
+                                                             block):
+    """The block program (128 lanes of four positions, the choice made
+    on the device) and the prompt slice under the block mask: both pools
+    aliased input to output, nothing pool-sized copied, no stacked
+    weight leaf (the experts' three stacks among them) sliced out or
+    laid out again, the paged kernel in place."""
+    compiled, pool, params = _v5e_sdar_program(one_chip, B, T, block)
+    text = compiled.as_text()
+    assert "hds_paged_attention" in text
+    header = text.split("\n", 1)[0]
+    n_leaves = len(jax.tree.leaves(params))
+    for out, arg in ((0, n_leaves), (1, n_leaves + 1)):
+        assert f"{{{out}}}: ({arg}, {{}}" in header, header
+    assert pool_sized_copies(text, pool.shape) == []
+    kernels = [leaf.shape for leaf in jax.tree.leaves(params["layers"])
+               if leaf.ndim >= 3 and np.prod(leaf.shape[1:]) >= 1 << 20]
+    assert len(kernels) == 4 + 3            # q, k, v, o and w1, w2, w3
+    assert stacked_layer_copies(text, kernels) == []
+    # nor one layer's 128 experts without the leading 1, which is how
+    # ``lax.ragged_dot``'s custom call had them sliced out (403 MB a
+    # product): the grouped matmul reads the stack by a layer index
+    assert re.findall(r"= bf16\[128,(?:2048,768|768,2048)\]\S* "
+                      r"(?!bitcast|parameter)[\w\-]+\(", text) == []
+    assert text.count("hds_kernel=\"expert_gemm\"") >= 3
+    layer_bytes = int(np.prod(pool.shape[1:])) * 2
+    # the largest temporaries are the logits the choice is made from
+    # (128 x 4 x 151,936 float32, 311 MB: the head's result, and two
+    # passes of the softmax beside it), not a layer of the pool
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        (1e9 if block else layer_bytes)
