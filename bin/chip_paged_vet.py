@@ -1,16 +1,23 @@
 #!/usr/bin/env python3
-"""On-chip vet for the head-tiled paged-attention kernel: Mosaic
-lowering, parity vs the dense-gather oracle, and timing vs the
-single-head grid (head_tile=1 reproduces the old kernel's schedule).
+"""On-chip vet for the paged-attention kernel, program alone: Mosaic
+lowering, parity vs the dense-gather oracle, and device time a call at
+the shapes the benchmark's serve cells give it (lanes, heads, table
+width and contexts as their traced runs report them); the Mistral
+decode shape also at ``head_tile=1``, one head a grid step (0: the
+kernel's own pick). Heads of 128 only: the kernel's DMA out of the pool
+wants whole lane tiles, and the dispatcher hands narrower heads to the
+reference (``head_dim_misaligned``).
 
 Timing method: scan-stretch SLOPE — (t_256 - t_32)/224, medians of
 interleaved draws. A single timed dispatch carries a fixed cost that at
 32 iterations reads as phantom kernel time.
 
 Emits JSON lines, each naming the device; needs the chip (exits non-zero
-without one, and when any variant emitted an error row):
+without one, and when any variant emitted an error row). To read a
+parent beside a change, run this file from both checkouts in one call:
     python bin/chip_paged_vet.py
 """
+import functools
 import json
 import os
 import sys
@@ -18,6 +25,44 @@ import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+
+
+def _spread(live, lo, hi):
+    """``live`` context lengths spread evenly over ``lo..hi``."""
+    return [lo + (hi - lo) * i // max(live - 1, 1) for i in range(live)]
+
+
+def _lanes(contexts, bucket, T=1):
+    """``(start, kv_len)`` of a bucket of ``bucket`` lanes of ``T``
+    positions: a live lane a context (its ``T`` positions end it, on a
+    multiple of ``T``), the rest padding (no context, as the engine pads
+    a bucket)."""
+    ends = [c // T * T for c in contexts]
+    pad = [0] * (bucket - len(ends))
+    return [e - T for e in ends] + pad, ends + pad
+
+
+#: name: (B, T, Hq, KV, D, BS, NBLK, NB, mask_block, (start, kv_len),
+#: head tiles). The cells' shapes: PERF.md sections 5 and 6 (PR 43).
+SHAPES = {
+    # sdar-serve-block-denoise: 64 lanes of a block of 4, 48 live at
+    # 650-900 tokens and one near 2,000; and its prompt slice
+    "sdar-block": (64, 4, 32, 4, 128, 64, 4096, 36, 4,
+                   _lanes(_spread(47, 650, 900) + [2000], 64, 4), (0,)),
+    "sdar-slice": (1, 512, 32, 4, 128, 64, 4096, 36, 4,
+                   ([512], [1024]), (0,)),
+    # m7b-serve-chat-steady: 8 decode lanes, 2 live at 400-500 tokens
+    "m7b-decode": (8, 1, 32, 8, 128, 64, 2560, 32, 1,
+                   _lanes([420, 480], 8), (1, 0)),
+    "m7b-slice": (1, 512, 32, 8, 128, 64, 2560, 32, 1,
+                  ([0], [512]), (0,)),
+    # olmoh-serve-long-prompt: 8 decode lanes, 7 live at 3-4k tokens;
+    # and a 512-token slice at position 2048
+    "olmoh-decode": (8, 1, 30, 30, 128, 64, 1536, 128, 1,
+                     _lanes(_spread(7, 3000, 4000), 8), (0,)),
+    "olmoh-slice": (1, 512, 30, 30, 128, 64, 1536, 128, 1,
+                    ([2048], [2560]), (0,)),
+}
 
 
 def main():
@@ -34,26 +79,8 @@ def main():
 
     def emit(row):
         if "error" in row or row.get("ok") is False:
-            failed.append(row["phase"])
+            failed.append(row["shape"])
         print(json.dumps(dict(row, **device)), flush=True)
-
-    # 1B decode shape: 8 lanes, 32 heads, D=64, context 512
-    rng = np.random.default_rng(0)
-    B, T, Hq, KV, D, BS, NBLK, NB = 8, 1, 32, 32, 64, 64, 72, 8
-    q = jnp.asarray(rng.standard_normal((B, T, Hq, D)), jnp.bfloat16)
-    # a one-layer [L, KV, P, D] pool, read at layer 0
-    kp = jnp.asarray(rng.standard_normal((1, KV, NBLK * BS, D)),
-                     jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((1, KV, NBLK * BS, D)),
-                     jnp.bfloat16)
-    tables = rng.permutation(NBLK)[:B * NB].reshape(B, NB).astype(np.int32)
-    start = jnp.asarray([511, 300, 128, 64, 511, 17, 480, 2], jnp.int32)
-    kvl = start + 1
-
-    ref = np.asarray(reference_paged_attention(
-        q, kp, vp, 0, tables, start, kvl, BS), np.float32)
-
-    import functools
 
     def slope_ms(stretch, *operands, reps=5):
         """Per-iteration device time from interleaved 32/256-length
@@ -73,165 +100,64 @@ def main():
         s = (hi[reps // 2] - lo[reps // 2]) / 224 * 1000
         return round(s, 4) if s > 0 else None
 
-    for tile in (1, 8, 32):
-        try:
-            fn = jax.jit(lambda q, kp, vp, t=tile: pallas_paged_attention(
-                q, kp, vp, 0, tables, start, kvl, BS, interpret=False,
-                head_tile=t))
-            out = np.asarray(fn(q, kp, vp), np.float32)
-            err = float(np.max(np.abs(out - ref)))
+    for name, (B, T, Hq, KV, D, BS, NBLK, NB, MB, (start, kvl),
+               tiles) in SHAPES.items():
+        rng = np.random.default_rng(0)
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(keys[0], (B, T, Hq, D), jnp.bfloat16)
+        # a two-layer [L, KV, P, D] pool, read at layer 1
+        kp = jax.random.normal(keys[1], (2, KV, NBLK * BS, D), jnp.bfloat16)
+        vp = jax.random.normal(keys[2], (2, KV, NBLK * BS, D), jnp.bfloat16)
+        tables = rng.permutation(NBLK)[:B * NB].reshape(B, NB).astype(
+            np.int32)
+        blocks = -(-np.asarray(kvl) // BS)
+        # past a lane's own blocks a table is zero-padded, as the engine
+        # packs it
+        tables[np.arange(NB)[None, :] >= blocks[:, None]] = 0
+        start = jnp.asarray(start, jnp.int32)
+        kvl = jnp.asarray(kvl, jnp.int32)
+        shape_row = {
+            "phase": "paged-vet", "shape": name, "lanes": B, "rows": T,
+            "table_slots": B * NB, "blocks_walked": int(blocks.sum()),
+            # K and V of the lanes' exact contexts, once a call
+            "kv_mb": round(int(np.asarray(kvl).sum()) * 2 * KV * D * 2
+                           / 1e6, 3)}
+        # a padded lane is zeros from the kernel and a mean of V from
+        # the oracle's softmax over nothing: the live lanes are compared
+        live = np.asarray(kvl) > 0
+        ref = np.asarray(jax.jit(lambda q, kp, vp: reference_paged_attention(
+            q, kp, vp, 1, tables, start, kvl, BS, MB))(q, kp, vp),
+            np.float32)[live]
+        for tile in tiles:
+            try:
+                call = functools.partial(
+                    pallas_paged_attention, layer=1, tables=tables,
+                    start=start, kv_len=kvl, block_size=BS,
+                    interpret=False, head_tile=tile, mask_block=MB)
+                out = np.asarray(jax.jit(call)(q, kp, vp), np.float32)
+                err = float(np.max(np.abs(out[live] - ref)))
 
-            # device time: N kernel iterations inside ONE dispatch (a
-            # dispatch-per-call chain is enqueue-bound and reads the
-            # same for every variant). Loop-carried q
-            # perturbation keeps LICM from hoisting the kernel.
-            @functools.partial(jax.jit, static_argnums=(3,))
-            def stretch(q, kp, vp, n, t=tile):
-                def step(c, _):
-                    qq = q + (c * 1e-12).astype(q.dtype)
-                    o = pallas_paged_attention(
-                        qq, kp, vp, 0, tables, start, kvl, BS,
-                        interpret=False, head_tile=t)
-                    return c + jnp.abs(o).sum().astype(jnp.float32), ()
-                c, _ = jax.lax.scan(step, jnp.float32(0), None, length=n)
-                return c
+                # device time: N kernel iterations inside ONE dispatch (a
+                # dispatch-per-call chain is enqueue-bound and reads the
+                # same for every variant). Loop-carried q
+                # perturbation keeps LICM from hoisting the kernel.
+                @functools.partial(jax.jit, static_argnums=(3,))
+                def stretch(q, kp, vp, n, call=call):
+                    def step(c, _):
+                        qq = q + (c * 1e-12).astype(q.dtype)
+                        o = call(qq, kp, vp)
+                        return c + jnp.abs(o).sum().astype(jnp.float32), ()
+                    c, _ = jax.lax.scan(step, jnp.float32(0), None,
+                                        length=n)
+                    return c
 
-            ms = slope_ms(stretch, q, kp, vp)
-            emit({"phase": "paged-vet", "head_tile": tile,
-                  "max_abs_err": round(err, 5),
-                  "ok": err < 0.05, "device_ms_per_iter": ms})
-        except Exception as e:
-            emit({"phase": "paged-vet", "head_tile": tile,
-                  "error": str(e)[:300]})
-
-    # ---- experimental: block-major pool layout [NBLK, KV, BS, D].
-    # Hypothesis: the head-major pool makes every (head-tile, block) DMA
-    # KVT strided 16 KB segments; block-major makes it ONE contiguous
-    # KVT*BS*D segment — if this wins big, the engine layout flips.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    from hcache_deepspeed_tpu.ops.paged_attention import _NEG_INF
-
-    def block_major_attention(q, kp_bm, vp_bm, tables, start, kvl, BS,
-                              head_tile):
-        B, T, Hq, D = q.shape
-        NBLK, KV = kp_bm.shape[0], kp_bm.shape[1]
-        G = Hq // KV
-        NB = tables.shape[1]
-        KVT = head_tile
-        qg = q.reshape(B, T, KV, G, D).transpose(0, 2, 1, 3, 4).reshape(
-            B, KV, T * G, D)
-        TG = T * G
-        TGp = max(8, -(-TG // 8) * 8)
-        if TGp != TG:
-            qg = jnp.pad(qg, ((0, 0), (0, 0), (0, TGp - TG), (0, 0)))
-
-        def page_index(b, kh, nb, tables_ref, kvlen_ref, start_ref):
-            last = jnp.maximum(kvlen_ref[b] - 1, 0) // BS
-            return (tables_ref[b, jnp.minimum(nb, last)], kh, 0, 0)
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, KV // KVT, NB),
-            in_specs=[
-                pl.BlockSpec((1, KVT, TGp, D),
-                             lambda b, kh, nb, *refs: (b, kh, 0, 0)),
-                pl.BlockSpec((1, KVT, BS, D), page_index),
-                pl.BlockSpec((1, KVT, BS, D), page_index),
-            ],
-            out_specs=pl.BlockSpec((1, KVT, TGp, D),
-                                   lambda b, kh, nb, *refs: (b, kh, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((KVT, TGp, D), jnp.float32),
-                pltpu.VMEM((KVT, TGp, 128), jnp.float32),
-                pltpu.VMEM((KVT, TGp, 128), jnp.float32),
-            ],
-        )
-
-        def kern(tables_ref, kvlen_ref, start_ref, q_ref, k_ref, v_ref,
-                 o_ref, acc, m_s, l_s):
-            # same online softmax as _kernel, block-major tile indexing
-            b, nb = pl.program_id(0), pl.program_id(2)
-            nblocks = pl.num_programs(2)
-
-            @pl.when(nb == 0)
-            def _init():
-                acc[:] = jnp.zeros_like(acc)
-                m_s[:] = jnp.full_like(m_s, _NEG_INF)
-                l_s[:] = jnp.zeros_like(l_s)
-
-            kvlen = kvlen_ref[b]
-            st = start_ref[b]
-            run = nb * BS < kvlen
-
-            @pl.when(run)
-            def _body():
-                qq = q_ref[0]
-                k = k_ref[0].astype(qq.dtype)
-                s = jax.lax.dot_general(
-                    qq, k, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32) / np.sqrt(D)
-                rows = jax.lax.broadcasted_iota(jnp.int32, (TGp, BS), 0)
-                cols = nb * BS + jax.lax.broadcasted_iota(
-                    jnp.int32, (TGp, BS), 1)
-                ok = (cols <= st + rows // G) & (cols < kvlen)
-                s = jnp.where(ok[None], s, _NEG_INF)
-                m_prev = m_s[:, :, :1]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=2, keepdims=True))
-                p = jnp.exp(s - m_new)
-                corr = jnp.exp(m_prev - m_new)
-                l_s[:, :, :1] = corr * l_s[:, :, :1] + \
-                    jnp.sum(p, axis=2, keepdims=True)
-                m_s[:, :, :1] = m_new
-                v = v_ref[0]
-                acc[:] = acc[:] * corr + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
-
-            @pl.when(nb == nblocks - 1)
-            def _out():
-                l = l_s[:, :, :1]
-                l = jnp.where(l == 0.0, 1.0, l)
-                o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-
-        out = pl.pallas_call(
-            kern, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, KV, TGp, D), q.dtype),
-        )(tables, kvl, start, qg, kp_bm, vp_bm)
-        out = out[:, :, :TG].reshape(B, KV, T, G, D).transpose(
-            0, 2, 1, 3, 4)
-        return out.reshape(B, T, Hq, D)
-
-    kp_bm = jnp.asarray(np.asarray(kp[0]).reshape(KV, NBLK, BS, D)
-                        .transpose(1, 0, 2, 3))
-    vp_bm = jnp.asarray(np.asarray(vp[0]).reshape(KV, NBLK, BS, D)
-                        .transpose(1, 0, 2, 3))
-    for tile in (8, 32):
-        try:
-            fn = jax.jit(lambda q, kp_bm, vp_bm, t=tile:
-                         block_major_attention(q, kp_bm, vp_bm, tables,
-                                               start, kvl, BS, t))
-            out = np.asarray(fn(q, kp_bm, vp_bm), np.float32)
-            err = float(np.max(np.abs(out - ref)))
-
-            @functools.partial(jax.jit, static_argnums=(3,))
-            def stretch(q, kp_bm, vp_bm, n, t=tile):
-                def step(c, _):
-                    qq = q + (c * 1e-12).astype(q.dtype)
-                    o = block_major_attention(qq, kp_bm, vp_bm, tables,
-                                              start, kvl, BS, t)
-                    return c + jnp.abs(o).sum().astype(jnp.float32), ()
-                c, _ = jax.lax.scan(step, jnp.float32(0), None, length=n)
-                return c
-
-            ms = slope_ms(stretch, q, kp_bm, vp_bm)
-            emit({"phase": "paged-vet-blockmajor", "head_tile": tile,
-                  "max_abs_err": round(err, 5),
-                  "ok": err < 0.05, "device_ms_per_iter": ms})
-        except Exception as e:
-            emit({"phase": "paged-vet-blockmajor", "head_tile": tile,
-                  "error": str(e)[:300]})
+                ms = slope_ms(stretch, q, kp, vp)
+                emit(dict(shape_row, head_tile=tile,
+                          max_abs_err=round(err, 5), ok=err < 0.05,
+                          device_ms_per_iter=ms))
+            except Exception as e:
+                emit(dict(shape_row, head_tile=tile, error=str(e)[:300]))
+        del q, kp, vp
     return 1 if failed else 0
 
 

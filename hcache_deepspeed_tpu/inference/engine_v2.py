@@ -969,6 +969,18 @@ class InferenceEngineV2:
         handed over."""
         return dict(self.model.dispatch_stats)
 
+    def paged_walk_stats(self) -> Dict[str, int]:
+        """How much of the block tables the paged kernel's walk covers:
+        over the ``dispatches`` enqueued, ``table_slots`` (lanes of the
+        bucket x the table's width, padded lanes too: the steps of a
+        grid over the table) and ``blocks_walked`` (the sum over live
+        lanes of ``ceil((start + t_len) / block_size)``: the blocks the
+        kernel's loop fetches a head tile and row tile, at most).
+        Their ratio is the share of the table that holds a context.
+        Counted on the host from each dispatch's ``start`` and
+        ``t_len``."""
+        return dict(self.model.paged_walk_stats)
+
     # -------------------------------------------------------------- #
     # Serving loop (reference: the generate() surface the v1 engine
     # exposes via HF and hybrid_engine.py wraps; v2's counterpart is the

@@ -151,6 +151,12 @@ class PagedInferenceModel:
         #: (``_enqueue``)
         self.dispatch_stats = {"dispatches": 0, "h2d_arrays": 0,
                                "h2d_bytes": 0}
+        #: what the paged kernel's walk is spared: of the ``table_slots``
+        #: of the dispatches' buckets (lanes x table width, what a grid
+        #: over the table stepped through) the ``blocks_walked`` that
+        #: hold a lane's context (``_enqueue``)
+        self.paged_walk_stats = {"dispatches": 0, "table_slots": 0,
+                                 "blocks_walked": 0}
         self.topology = topology
         self.tp = topology.tensor_size if topology is not None else 1
         self.quantization = quantization if (
@@ -856,8 +862,15 @@ class PagedInferenceModel:
         ``pools``: the lanes packed into one host array and handed to the
         jitted call as they are, which makes the one transfer itself (a
         ``jnp.asarray`` in front would be ``device_put``'s Python on top
-        of it). Counted in ``dispatch_stats`` and ``kv_write_stats``."""
+        of it). Counted in ``dispatch_stats``, ``kv_write_stats`` and
+        ``paged_walk_stats``."""
         self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
+        walk = self.paged_walk_stats
+        walk["dispatches"] += 1
+        walk["table_slots"] += np.size(tables)
+        # a padded lane starts at 0 with nothing: no block
+        walk["blocks_walked"] += int(np.sum(
+            -(-np.add(start, t_len) // self.block_size)))
         lanes = pack_lanes(tokens, start, tables, t_len, slots)
         stats = self.dispatch_stats
         stats["dispatches"] += 1

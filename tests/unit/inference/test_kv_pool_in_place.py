@@ -135,6 +135,25 @@ def one_chip():
 #: the text each v5e program lowers to, before the compiler has it:
 #: ``(trunk, B, T) -> text`` (``test_causal_programs_lower_as_on_the_parent``)
 _LOWERED = {}
+#: and the static grid of the paged kernel's call in it
+#: (``test_v5e_paged_kernel_steps_over_no_table_slot``)
+_PAGED_GRID = {}
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(inner)
+
+
+def _paged_grid(jaxpr):
+    (grid,) = {eqn.params["grid_mapping"].grid
+               for eqn in _pallas_calls(jaxpr)
+               if "paged_attention" in str(eqn.params["name"])}
+    return grid
 
 
 class _ShapesOnly(PagedInferenceModel):
@@ -196,8 +215,10 @@ def _v5e_program(one_chip, B, T, restore=False):
                 params, pool, pool, i32(), latents, i32(B), i32(B, 32),
                 i32(B))
         else:
-            lowered = model._fwd.lower(params, pool, pool,
-                                       i32(B, lanes_width(T, 32)))
+            traced = model._fwd.trace(params, pool, pool,
+                                      i32(B, lanes_width(T, 32)))
+            _PAGED_GRID["mistral", B, T] = _paged_grid(traced.jaxpr)
+            lowered = traced.lower()
             _LOWERED["mistral", B, T] = lowered.as_text()
         compiled = lowered.compile()
     finally:
@@ -410,20 +431,22 @@ def test_v5e_hybrid_program_holds_pools_and_weights_in_place(one_chip, B,
 # ------------------------------------------------------------------ #
 #: sha256 (first 16 digits) of the text the causal serving programs
 #: lower to for the described v5e, the Pallas kernels' serialized bodies
-#: left out (they carry the line numbers of ``ops/*.py``), and of the
-#: paged kernel's own jaxpr: recorded on the parent of PR 42 (PR 38's
-#: tree) by these same functions. A configuration without
-#: ``diffusion_block_length`` must go on lowering to exactly these: the
-#: block mask, the per-head norm, the layers' counts and the whole-stack
-#: expert products are statics that fold away. A later PR that changes
-#: the trunk on purpose records its own.
+#: left out (they carry the line numbers of ``ops/*.py``): recorded on
+#: the parent of PR 42 (PR 38's tree) by these same functions. A
+#: configuration without ``diffusion_block_length`` must go on lowering
+#: to exactly these: the block mask, the per-head norm, the layers'
+#: counts and the whole-stack expert products are statics that fold
+#: away. A later PR that changes the trunk on purpose records its own.
+#: The paged kernel's own jaxpr at ``mask_block`` 1 is PR 43's, which
+#: gave the kernel its loop over a lane's own blocks (the programs'
+#: text around it did not move: the pools were whole operands before).
 _PARENT_LOWERED = {
     ("mistral", 8, 1): "39bac33fc0ec6e21",
     ("mistral", 1, 512): "636262a941e36f1f",
     ("hybrid", 8, 1): "b98f521a7036b852",
     ("hybrid", 1, 512): "dc2e86140bffcaae"}
-_PARENT_PAGED_KERNEL = {(8, 1): "6236461a2f0c60fe",
-                        (1, 512): "f8d844c932e6e2a1"}
+_PARENT_PAGED_KERNEL = {(8, 1): "d1b28c3e6e780a26",
+                        (1, 512): "b79c96fc96d9ca8f"}
 
 
 def _digest(text):
@@ -449,22 +472,13 @@ def test_the_paged_kernel_at_block_one_is_the_causal_kernel(B, T):
         return pallas_paged_attention(q, k, v, jnp.int32(1), tables, start,
                                       kv_len, 64, interpret=True)
 
-    def kernel_of(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                return eqn.params["jaxpr"]
-            for inner in jax.core.jaxprs_in_params(eqn.params):
-                found = kernel_of(inner)
-                if found is not None:
-                    return found
-
     pool = jnp.zeros((2, 8, 64 * 64, 128), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(call)(
         jnp.zeros((B, T, 32, 128), jnp.bfloat16), pool, pool,
         jnp.zeros((B, 32), jnp.int32), jnp.zeros((B,), jnp.int32),
         jnp.ones((B,), jnp.int32))
-    assert _digest(str(kernel_of(jaxpr.jaxpr))) == \
-        _PARENT_PAGED_KERNEL[B, T]
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    assert _digest(str(call.params["jaxpr"])) == _PARENT_PAGED_KERNEL[B, T]
 
 @functools.lru_cache(maxsize=None)
 def _v5e_sdar_program(one_chip, B, T, block=True):
@@ -515,7 +529,9 @@ def _v5e_sdar_program(one_chip, B, T, block=True):
         lanes = jax.ShapeDtypeStruct(
             (B, lanes_width(T, 36, slot=block)), jnp.int32,
             sharding=one_chip)
-        compiled = program.lower(params, pool, pool, lanes).compile()
+        traced = program.trace(params, pool, pool, lanes)
+        _PAGED_GRID["sdar", B, T] = _paged_grid(traced.jaxpr)
+        compiled = traced.lower().compile()
     finally:
         platform._platform = None
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
@@ -556,3 +572,29 @@ def test_v5e_sdar_program_aliases_pools_and_copies_no_weight(one_chip, B, T,
     # passes of the softmax beside it), not a layer of the pool
     assert compiled.memory_analysis().temp_size_in_bytes < \
         (1e9 if block else layer_bytes)
+
+
+@pytest.mark.parametrize("trunk,B,T,slots", [("sdar", 64, 4, 36),
+                                             ("mistral", 8, 1, 32)],
+                         ids=["sdar-block", "mistral-decode"])
+def test_v5e_paged_kernel_steps_over_no_table_slot(one_chip, trunk, B, T,
+                                                   slots):
+    """The block program at the sparse cell's shapes (64 lanes of 4, 4
+    KV heads of 128, a table of 36 slots, blocks of 64) and the Mistral
+    decode program compile for the described chip with the paged kernel
+    under both its names (``hds_kernel`` and the layer's block view),
+    and the kernel's grid is a step a lane: the table's width is no
+    factor of it, the walk over a lane's own blocks is the kernel's
+    loop."""
+    compiled, pool, _ = (_v5e_sdar_program if trunk == "sdar"
+                         else _v5e_program)(one_chip, B, T)
+    _, KV, P, D = pool.shape
+    calls = [text for text in re.split(r"\n(?=\s*(?:ROOT )?%)",
+                                       compiled.as_text())
+             if re.search(r"hds_kernel\W+paged_attention", text)]
+    assert calls and all(
+        f'hds_kv_layer_view="bf16[{KV},{P // 64},64,{D}]"' in text
+        for text in calls)
+    grid = _PAGED_GRID[trunk, B, T]
+    assert grid == (B, 1, 1), grid          # every head in one tile
+    assert all(steps % slots for steps in grid if steps > 1)

@@ -260,6 +260,43 @@ def test_scheduler_preempts_between_blocks_and_resumes_at_the_commit(
     assert engine.free_blocks == 11
 
 
+def test_paged_walk_stats_count_each_lanes_own_blocks(params):
+    """``engine.paged_walk_stats()`` over hand-built dispatches: a
+    prompt slice that starts inside its second block, a decode bucket
+    of eight with five padded lanes, a block pass of four lanes with
+    one padded. ``table_slots`` is what a grid over the table stepped
+    through, ``blocks_walked`` what the lanes hold."""
+    engine = make_engine(params)
+    model, cache, NB = engine.model, engine.cache, engine.max_blocks_per_seq
+    assert (NB, engine.block_size) == (8, 16)
+    assert engine.paged_walk_stats() == {
+        "dispatches": 0, "table_slots": 0, "blocks_walked": 0}
+    tables = np.arange(1, 1 + 8 * NB, dtype=np.int32).reshape(8, NB) % 32
+
+    def lanes(T, start, t_len):
+        start, t_len = np.asarray(start), np.asarray(t_len)
+        return (np.zeros((len(start), T), np.int32), start,
+                tables[:len(start)], t_len)
+
+    # positions 20..35: blocks 0, 1 and 2 of the lane
+    model.forward_chunk(cache, *lanes(16, [20], [16]))
+    assert engine.paged_walk_stats() == {
+        "dispatches": 1, "table_slots": NB, "blocks_walked": 3}
+    # contexts of 1, 16 and 17 tokens: 1 + 1 + 2 blocks; padding: none
+    model.forward_chunk(cache, *lanes(1, [0, 15, 16, 0, 0, 0, 0, 0],
+                                      [1, 1, 1, 0, 0, 0, 0, 0]))
+    assert engine.paged_walk_stats() == {
+        "dispatches": 2, "table_slots": 9 * NB, "blocks_walked": 7}
+    # blocks of four at 12 (ends on a block's edge), 16 and 124 (the
+    # table's last positions)
+    model.forward_block(cache, *lanes(4, [12, 16, 124, 0], [4, 4, 4, 0]),
+                        np.zeros((4,), np.int32))
+    assert engine.paged_walk_stats() == {
+        "dispatches": 3, "table_slots": 13 * NB,
+        "blocks_walked": 7 + 1 + 2 + 8}
+    assert engine.dispatch_stats()["dispatches"] == 3
+
+
 @pytest.mark.parametrize("call", [
     lambda e: e.put_spec([1], [[5, 6]]),
     lambda e: e.generate([[1, 2, 3, 4]], max_new_tokens=4),

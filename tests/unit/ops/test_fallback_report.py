@@ -41,14 +41,18 @@ def _flash():
     return pallas_attention(q, q, q, interpret=True)
 
 
-def _paged():
+def _paged(D=16, block=12):                     # block of 12: 12 % 8
     from hcache_deepspeed_tpu.ops.paged_attention import \
         _dispatch_paged_attention
-    q = jnp.ones((1, 1, 2, 16), jnp.float32)
-    pool = jnp.ones((1, 2, 48, 16), jnp.float32)  # block of 12: 12 % 8
+    q = jnp.ones((1, 1, 2, D), jnp.float32)
+    pool = jnp.ones((1, 2, 4 * block, D), jnp.float32)
     return _dispatch_paged_attention(
         q, pool, pool, 0, np.zeros((1, 4), np.int32),
-        jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), 12)
+        jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), block)
+
+
+def _paged_head():
+    return _paged(D=64, block=16)               # heads of 64: 64 % 128
 
 
 def _rms():
@@ -78,6 +82,7 @@ def _qmm():
 @pytest.mark.parametrize("call, op, reason", [
     (_flash, "flash_attention", "seq_not_block_multiple"),
     (_paged, "paged_attention", "block_misaligned"),
+    (_paged_head, "paged_attention", "head_dim_misaligned"),
     (_rms, "rms_norm", "rows_not_block_multiple"),
     (_quantize, "quantize", "groups_not_block_multiple"),
     (_quantize_fp8, "quantize_fp8", "groups_not_block_multiple"),

@@ -136,24 +136,90 @@ class TestPagedAttentionParity:
             atol=3e-2)
 
     def test_garbage_in_dead_table_slots_ignored(self):
-        # dead table slots point at blocks full of huge values; the
-        # clamped index_map + masking must never read them into the result
+        # past each lane's own count the table names blocks that are not
+        # in the pool at all, and the pool's last block is NaN: the walk
+        # must end at the lane's count, never forming their address (the
+        # TPU interpreter raises on a read out of bounds, starts every
+        # buffer as NaN and reports two copies that race)
+        from jax.experimental.pallas import tpu as pltpu
         rng = np.random.default_rng(4)
-        B, T, Hq, KV, D, BS, NBLK, NB = 1, 1, 2, 2, 32, 8, 16, 8
+        B, T, Hq, KV, D, BS, NBLK, NB = 4, 1, 8, 2, 32, 8, 64, 12
         q = jnp.asarray(rng.standard_normal((B, T, Hq, D)), jnp.float32)
-        kp = rng.standard_normal((KV, NBLK * BS, D)).astype(np.float32)
-        vp = rng.standard_normal((KV, NBLK * BS, D)).astype(np.float32)
-        kp[:, BS * 2:], vp[:, BS * 2:] = 1e9, 1e9  # poison all but blocks 0-1
-        tables = np.zeros((B, NB), np.int32)
-        tables[0, 0], tables[0, 1] = 0, 1
-        tables[0, 2:] = 9  # dead slots point at poison
-        start = jnp.asarray([11], jnp.int32)
-        kvl = jnp.asarray([12], jnp.int32)  # only blocks 0-1 valid
-        pal = pallas_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
-            0, tables, start, kvl, BS, interpret=True)
-        assert np.all(np.isfinite(np.asarray(pal)))
-        assert np.max(np.abs(np.asarray(pal))) < 1e3
+        kp = rng.standard_normal((1, KV, NBLK * BS, D)).astype(np.float32)
+        vp = rng.standard_normal((1, KV, NBLK * BS, D)).astype(np.float32)
+        kp[:, :, (NBLK - 1) * BS:] = np.nan
+        vp[:, :, (NBLK - 1) * BS:] = np.nan
+        lens = np.array([0, 3, 5 * BS + 1, NB * BS])
+        dead = np.arange(NB)[None] >= -(-lens // BS)[:, None]
+        live = rng.permutation(NBLK - 1)[:B * NB].reshape(B, NB)
+        # half the dead slots past the pool, half on the NaN block
+        poison = np.where(np.arange(NB)[None] % 2, NBLK + 100, NBLK - 1)
+        tables = np.where(dead, poison, live).astype(np.int32)
+        start = jnp.asarray(np.maximum(lens - 1, 0), jnp.int32)
+        kvl = jnp.asarray(lens, jnp.int32)
+        want = reference_paged_attention(
+            q, jnp.asarray(kp), jnp.asarray(vp), 0,
+            np.where(dead, 0, live).astype(np.int32), start, kvl, BS)
+        for interpret in (True, pltpu.InterpretParams(
+                detect_races=True, uninitialized_memory="nan",
+                out_of_bounds_reads="raise")):
+            pal = np.asarray(pallas_paged_attention(
+                q, jnp.asarray(kp), jnp.asarray(vp), 0, tables, start, kvl,
+                BS, interpret=interpret))
+            assert np.all(pal[0] == 0.0)        # the lane with no context
+            np.testing.assert_allclose(pal[1:], np.asarray(want)[1:],
+                                       atol=3e-5)
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+        assert not interpret_pallas_call.races.races_found
+
+
+# ------------------------------------------------------------------ #
+# ragged lanes in one dispatch: every lane walks its own blocks
+# ------------------------------------------------------------------ #
+_BS, _NB = 64, 18                          # a table of 1,152 positions
+
+
+def _ragged(shape, G):
+    """``(T, mask_block, start, kv_len)`` of one dispatch."""
+    whole = _NB * _BS
+    if shape == "decode":       # none, one, around a block's edge, all
+        lens = np.array([0, 1, _BS - 1, _BS, _BS + 1, whole])
+        return 1, 1, np.maximum(lens - 1, 0), lens
+    if shape == "block":        # a block of 4: inside a cache block, on
+        # its edge (the new block holds these 4 alone), ending on it,
+        # padding, the table's last positions
+        start = np.array([_BS + 8, 2 * _BS, _BS - 4, 0, whole - 4])
+        return 4, 4, start, np.where(start == 0, 0, start + 4)
+    # "tiles": 1,024 rows a kv head, two row tiles with frontiers of
+    # their own; the second lane's slice is half padding
+    T = 1024 // G
+    return T, 1, np.array([70, 0]), np.array([70 + T, T // 2 + 3])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("G", [1, 4, 8])
+@pytest.mark.parametrize("shape", ["decode", "block", "tiles"])
+def test_ragged_lanes_walk_their_own_blocks(shape, G, dtype):
+    T, MB, start, lens = _ragged(shape, G)
+    B, KV, D, NBLK = len(lens), 2, 32, 48
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.standard_normal((B, T, KV * G, D)), dtype)
+    kp = jnp.asarray(rng.standard_normal((2, KV, NBLK * _BS, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((2, KV, NBLK * _BS, D)), dtype)
+    # every lane's blocks its own, scattered over the pool
+    tables = np.stack([rng.permutation(NBLK)[:_NB]
+                       for _ in range(B)]).astype(np.int32)
+    args = (1, tables, jnp.asarray(start, jnp.int32),
+            jnp.asarray(lens, jnp.int32), _BS)
+    ref = np.asarray(reference_paged_attention(q, kp, vp, *args,
+                                               mask_block=MB), np.float32)
+    pal = np.asarray(pallas_paged_attention(
+        q, kp, vp, *args, interpret=True, mask_block=MB), np.float32)
+    live = lens > 0
+    assert live.sum() >= B - 1
+    np.testing.assert_allclose(
+        pal[live], ref[live], atol=3e-5 if dtype == "float32" else 3e-2)
+    assert np.all(pal[~live] == 0.0)       # a padded lane writes zeros
 
 
 class TestHeadTiling:
@@ -195,6 +261,27 @@ class TestHeadTiling:
         assert kvt * _step_bytes(tq, 128, 64, 2) <= _VMEM_BUDGET
         # ragged row counts pad up to whole tiles
         assert pick_tiles(2, 515, 64, 16, 4)[:2] == (512, 1024)
+
+    def test_pick_blocks(self):
+        """Blocks a loop iteration: from the shapes under the budget, at
+        the tiles ``pick_tiles`` chose; the serve cells' shapes."""
+        from hcache_deepspeed_tpu.ops.paged_attention import (
+            _MAX_COL_TILE, _VMEM_BUDGET, _step_bytes, pick_blocks,
+            pick_tiles)
+        # (KV, rows a head, D, BS, NB) -> blocks
+        for (KV, TG, D, BS, NB), want in {
+                (4, 4 * 8, 128, 64, 36): 8,        # sparse block pass
+                (8, 1 * 4, 128, 64, 32): 8,        # Mistral decode
+                (8, 512 * 4, 128, 64, 32): 4,      # Mistral slice
+                (30, 1, 128, 64, 128): 4,          # hybrid decode
+                (30, 512, 128, 64, 128): 1,        # hybrid slice
+                (2, 1, 128, 16, 4): 4,             # a table of 4 slots
+                (2, 1, 128, 1024, 8): 1}.items():  # a block a tile
+            TQ, _, KVT = pick_tiles(KV, TG, D, BS, 2)
+            P = pick_blocks(KVT, TQ, D, BS, NB, 2)
+            assert P == want, (KV, TG, D, BS, NB, P)
+            assert P <= NB and (P == 1 or P * BS <= _MAX_COL_TILE)
+            assert KVT * _step_bytes(TQ, D, P * BS, 2) <= _VMEM_BUDGET
 
     def test_over_budget_layout_is_a_typed_error(self):
         """One head at the row tile already over the VMEM budget is
