@@ -554,6 +554,109 @@ def hybrid_phase(hf, block_size=64, prefill_chunk=512):
 
 
 # ------------------------------------------------------------------ #
+# serve, a latent-attention trunk (a pool of compressed KV rows)
+# ------------------------------------------------------------------ #
+#: GLM-4.7-Flash at its published widths, its leading dense layer and
+#: one sparse layer (64 experts, one shared) under the whole vocabulary:
+#: 1.36 B parameters, 2.7 GB in bf16
+GLM_LATENT_PAIR = {
+    "model_type": "glm4_moe_lite", "vocab_size": 154880,
+    "hidden_size": 2048, "intermediate_size": 10240,
+    "moe_intermediate_size": 1536, "num_hidden_layers": 2,
+    "num_attention_heads": 20, "num_key_value_heads": 20,
+    "max_position_embeddings": 8192, "rms_norm_eps": 1e-5,
+    "rope_theta": 1000000, "rope_scaling": None, "n_routed_experts": 64,
+    "num_experts_per_tok": 4, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1.8,
+    "first_k_dense_replace": 1, "n_group": 1, "topk_group": 1,
+    "topk_method": "noaux_tc", "q_lora_rank": 768, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 192, "qk_rope_head_dim": 64, "v_head_dim": 256,
+    "attention_bias": False, "torch_dtype": "bfloat16"}
+#: the same pair at toy width in fp32, for the CPU test of the phase
+TINY_LATENT_PAIR = dict(
+    GLM_LATENT_PAIR, vocab_size=256, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_attention_heads=4,
+    max_position_embeddings=256, n_routed_experts=8, q_lora_rank=48,
+    kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=8,
+    v_head_dim=16, torch_dtype="float32")
+
+
+def latent_phase(hf, block_size=64, prefill_chunk=512, logit_tol=0.02):
+    """A prompt of three slices and a decode step through the latent
+    pool; then the sequence evicted to its host cache rows and brought
+    back through ``restore_kv`` (a ship and a write), its next logits
+    against the uninterrupted ones; and the chip's own programs read for
+    what they must not hold: a copy of either pool, a layer of a stacked
+    weight leaf."""
+    import jax
+
+    from hcache_deepspeed_tpu.inference import RaggedInferenceEngineConfig
+    from hcache_deepspeed_tpu.inference.factory import (MODEL_FAMILIES,
+                                                        build_hf_engine)
+    from hcache_deepspeed_tpu.inference.ragged.kv_cache import (
+        pool_sized_copies, stacked_layer_copies)
+    from hcache_deepspeed_tpu.models.glm4_moe_lite import seeded_params
+    cfg = MODEL_FAMILIES[hf["model_type"]](hf)
+    params = seeded_params(cfg, seed=0, dtype=hf["torch_dtype"])
+    n_prompt = 2 * prefill_chunk + prefill_chunk // 3
+    engine = build_hf_engine(hf, params, RaggedInferenceEngineConfig(
+        state_manager={"max_tracked_sequences": 8,
+                       "max_ragged_sequence_count": 8,
+                       "max_context": 4 * prefill_chunk,
+                       "prefill_chunk": prefill_chunk},
+        kv_cache={"block_size": block_size,
+                  "num_blocks": 2 + 8 * prefill_chunk // block_size,
+                  "cache_dtype": hf["torch_dtype"]}))
+    del params
+    rng = np.random.default_rng(0)
+    prompt = [int(t) for t in rng.integers(0, hf["vocab_size"], n_prompt)]
+    logits, rows = engine.put([0], [prompt])
+    fed = int(np.argmax(logits[0]))
+    uninterrupted, _ = engine.put([0], [[fed]])
+    rows = np.asarray(rows[0])
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    check(rows.shape == (cfg.n_layer, n_prompt, width),
+          f"what went to the host is the cache row of every layer "
+          f"({width} values a layer a token): {rows.shape}")
+    engine.flush(0)
+    before = dict(engine.restore_stats)
+    engine.restore_kv([0], [prompt], [rows])
+    restored, _ = engine.put([0], [[fed]])
+    shipped = engine.restore_stats["bytes_shipped"] - before["bytes_shipped"]
+    gap = _logit_gap(np.asarray(restored[0], np.float32),
+                     np.asarray(uninterrupted[0], np.float32))
+    check(np.all(np.isfinite(restored)) and gap <= logit_tol,
+          f"evicted to host cache rows and restored ({shipped} bytes "
+          f"shipped, nothing replayed): restored vs uninterrupted logits "
+          f"after {n_prompt + 1} tokens differ by {gap:.5f} of max |logit| "
+          f"(tolerance {logit_tol})")
+    check(engine.latent_stats()["saved_state"] == "cache_row" and
+          engine.restore_profile()["replay_flops_frac"] == 0.0,
+          "the engine names its saved state: the cache row")
+    model, cache = engine.model, engine.cache
+    for pool in (cache.k, cache.v):
+        _check_slice_program(engine, pool.shape, prefill_chunk)
+    text = _program_text(engine, 8, 1)
+    copies = pool_sized_copies(text, cache.k.shape) + \
+        pool_sized_copies(text, cache.v.shape)
+    check(not copies,
+          "the latent decode program neither copies nor slices the pool "
+          f"of c rows, the pool of r rows or a layer of either: {copies}")
+    copies = stacked_layer_copies(
+        text, [leaf.shape for stack in ("lead_layers", "layers")
+               for leaf in jax.tree.leaves(model.params[stack])
+               if leaf.ndim >= 3])
+    check(not copies,
+          "the latent decode program reads each layer's weights inside "
+          f"the product that uses them: {copies}")
+    engine.flush(0)
+    check(engine.free_blocks == cache.num_blocks - 1,
+          "the flush gave back every block of the latent pool")
+    print(f"  peak_bytes_in_use (process lifetime): {_peak_bytes()}",
+          flush=True)
+
+
+# ------------------------------------------------------------------ #
 # train
 # ------------------------------------------------------------------ #
 def train_phase(size, devices, zero_stage=0):
@@ -677,6 +780,7 @@ def main():
 
     one_chip = run("serve", serve_phase, size)
     run("serve hybrid", hybrid_phase, OLMO_HYBRID_PERIOD)
+    run("serve latent", latent_phase, GLM_LATENT_PAIR)
     losses = run("train", train_phase, size, jax.devices()[:1])["losses"]
     if device["count"] >= 4:
         four = jax.devices()[:4]

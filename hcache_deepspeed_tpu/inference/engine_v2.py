@@ -166,6 +166,10 @@ class InferenceEngineV2:
                                  "positions_masked": 0,
                                  "tokens_committed": 0}
         self._moe_stats = {"dispatches": 0, "picks": None, "touched": 0}
+        #: the sequences whose routers' inputs :meth:`router_inputs`
+        #: hands out (a check's probed requests; empty: nothing is kept)
+        self.router_probe_uids = set()
+        self._router_probes = {}
 
         self.state = StateManager(
             sm_cfg.max_tracked_sequences, num_blocks, self.block_size,
@@ -215,7 +219,11 @@ class InferenceEngineV2:
         from ..models.opt import OPTConfig
         from ..models.phi import PhiConfig
         model_cls = PagedInferenceModel
-        if isinstance(model_config, GPT2Config):
+        if getattr(model_config, "kv_lora_rank", 0):
+            # latent attention: a pool of compressed KV rows
+            from .model_latent import PagedLatentModel
+            model_cls = PagedLatentModel
+        elif isinstance(model_config, GPT2Config):
             from .model_gpt2 import PagedGPT2Model
             model_cls = PagedGPT2Model
         elif isinstance(model_config, OPTConfig):
@@ -256,9 +264,10 @@ class InferenceEngineV2:
                 conv_channels=model_config.conv_channels,
                 dtype=jnp.dtype(kv_cfg.cache_dtype))
         else:
+            kv_heads, k_width, v_width = self.model.pool_layout()
             self.cache = BlockedKVCache(
                 model_config.n_layer, num_blocks, self.block_size,
-                model_config.n_kv_head, model_config.head_dim,
+                kv_heads, k_width, v_head_dim=v_width,
                 dtype=jnp.dtype(kv_cfg.cache_dtype),
                 sharding=self.model.cache_sharding())
         #: recurrent-state evictions and returns (``snapshot_state``,
@@ -289,21 +298,17 @@ class InferenceEngineV2:
                  f"{self.max_context}", ranks=[0])
 
     def _check_paged_attention_fits(self, model_config):
-        """Refuse at construction a head layout / block size the paged
-        kernel cannot tile (``PagedAttentionBudgetError`` carries the
-        rows, bytes and limit) — otherwise the first dispatch dies
-        inside the Mosaic compiler. Query rows are tiled, so the
-        dispatch length (``max_ragged_batch_size``/``prefill_chunk``)
-        does not enter; only where the kernel is what will run."""
+        """Refuse at construction a head layout / block size the
+        attention kernel cannot tile (the model's ``attention_fits``
+        raises with the rows, bytes and limit) — otherwise the first
+        dispatch dies inside the Mosaic compiler. Query rows are tiled,
+        so the dispatch length (``max_ragged_batch_size``/
+        ``prefill_chunk``) does not enter; only where the kernel is what
+        will run."""
         from ..ops import get_op_impl
-        from ..ops.paged_attention import pick_tiles
-        if not get_op_impl("paged_attention").compatible():
-            return
-        kv_local = model_config.n_kv_head // self.model.tp
-        rows = self.config.state_manager.max_ragged_batch_size * \
-            (model_config.n_head // model_config.n_kv_head)
-        pick_tiles(kv_local, rows, model_config.head_dim, self.block_size,
-                   jnp.dtype(model_config.compute_dtype).itemsize)
+        if get_op_impl("paged_attention").compatible():
+            self.model.attention_fits(
+                self.config.state_manager.max_ragged_batch_size)
 
     @staticmethod
     def _size_cache_blocks(model_config, kv_cfg) -> int:
@@ -313,9 +318,12 @@ class InferenceEngineV2:
         layer_types = getattr(model_config, "layer_types", None)
         kv_layers = model_config.n_layer if layer_types is None else \
             sum(1 for kind in layer_types if kind == "full_attention")
+        k_width, v_width = getattr(
+            model_config, "cache_row_widths",
+            (model_config.head_dim, model_config.head_dim))
         per_token = BlockedKVCache.token_bytes(
-            kv_layers, model_config.n_kv_head,
-            model_config.head_dim, kv_cfg.cache_dtype)
+            kv_layers, model_config.n_kv_head, k_width,
+            kv_cfg.cache_dtype, v_head_dim=v_width)
         platform = get_platform()
         free = platform.available_memory()
         if free <= 0:
@@ -616,6 +624,11 @@ class InferenceEngineV2:
         out = self.model.forward_chunk(self.cache, *lanes)
         span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
                  h2d_bytes=stats["h2d_bytes"] - nbytes)
+        if self.router_probe_uids:
+            for j, i in enumerate(idx):
+                if uids[i] in self.router_probe_uids:
+                    self._router_probes[uids[i]] = (
+                        self.model.router_probe, j)
         return out
 
     def _run_decode(self, uids, tokens, idx, logits_out, latents_out,
@@ -842,6 +855,20 @@ class InferenceEngineV2:
         return {"dispatches": m["dispatches"], "touched": m["touched"],
                 "picks": None if m["picks"] is None else m["picks"].copy()}
 
+    def router_inputs(self, uid: int):
+        """What each sparse layer's router read for the last row that
+        ``uid`` fed in its latest forward, ``[L_sparse, hidden]`` on the
+        host, or ``None``: kept for the uids of ``router_probe_uids``
+        by a trunk whose forward hands it back (``model_latent.py``). A
+        check routes its reference's compared row by it, so that a near
+        tie falls the same way on both sides."""
+        kept = self._router_probes.get(uid)
+        if kept is None or kept[0] is None:
+            return None
+        # the whole array to the host, then the lane: a slice on the
+        # device would be a program of its own
+        return np.asarray(kept[0])[:, kept[1]]
+
     # -------------------------------------------------------------- #
     # Deferred latent landing (ragged/latents.py)
     # -------------------------------------------------------------- #
@@ -938,6 +965,7 @@ class InferenceEngineV2:
         link = self._latent_link
         pending = sum(p.unread_bytes for p in self._pending_parts())
         return {
+            "saved_state": self.model.saved_state,
             "captured_bytes": link.captured_bytes,
             "captured_tokens": link.captured_tokens,
             "landed_hidden_bytes": link.landed_hidden_bytes,
@@ -1857,6 +1885,19 @@ class InferenceEngineV2:
         chunks a restore costs (each chunk is one dispatch — the fixed
         overhead that makes recompute win at short prompts)."""
         cfg = self._model_config
+        latent_itemsize = jnp.dtype(self.model.latent_dtype).itemsize
+        if self.model.saved_state == "cache_row":
+            # the saved state is the cache row: a restore ships it and
+            # writes it, and replays nothing
+            return {
+                "n_layer": cfg.n_layer,
+                "saved_state": "cache_row",
+                "latent_bytes_per_token": self.model.saved_width
+                * latent_itemsize * self.model.n_latent_layers,
+                "replay_flops_frac": 0.0,
+                "restore_chunk_layers": self.model.restore_chunk_layers,
+                "restore_chunk_bytes": self.model.restore_chunk_bytes,
+            }
         H = cfg.hidden_size
         kvd = cfg.n_kv_head * cfg.head_dim
         qd = cfg.n_head * cfg.head_dim
@@ -1865,9 +1906,9 @@ class InferenceEngineV2:
         # forward adds the o-projection and the 3 SwiGLU matmuls
         replay = H * (qd + 2 * kvd)
         full = replay + H * qd + 3 * H * cfg.intermediate_size
-        latent_itemsize = jnp.dtype(self.model.latent_dtype).itemsize
         return {
             "n_layer": cfg.n_layer,
+            "saved_state": "hidden",
             "latent_bytes_per_token": cfg.hidden_size * latent_itemsize
             * self.model.n_latent_layers,
             "replay_flops_frac": replay / full,
@@ -2078,6 +2119,7 @@ class InferenceEngineV2:
         get_tracer().instant("serve.flush", uid=uid,
                              blocks=len(held))
         self.state.flush_sequence(uid)
+        self._router_probes.pop(uid, None)
         if self.prefix_caching and held:
             self._purge_freed_blocks(held)
 

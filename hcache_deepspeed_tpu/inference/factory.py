@@ -242,6 +242,59 @@ def _olmo_hybrid_like(hf: Dict[str, Any]):
     )
 
 
+def _glm4_moe_lite_like(hf: Dict[str, Any]):
+    """GLM-4-MoE-Lite: multi-head latent attention (low-rank q and kv
+    projections, a rotary part of ``qk_rope_head_dim`` channels a head
+    beside ``qk_nope_head_dim`` without), ``first_k_dense_replace`` dense
+    layers before sparse ones with a sigmoid router under a selection
+    bias (``noaux_tc``) and an ungated shared expert. What is not built
+    is refused by name; the release's multi-token-prediction module
+    (``num_nextn_predict_layers``) is no part of the main model's
+    logits and is not read."""
+    from ..models.glm4_moe_lite import Glm4MoeLiteConfig
+    refused = {
+        "rope_scaling": hf.get("rope_scaling") is not None,
+        "n_group > 1 (group-limited routing)": hf.get("n_group", 1) > 1,
+        "topk_group > 1 (group-limited routing)":
+            hf.get("topk_group", 1) > 1,
+        "attention_bias": bool(hf.get("attention_bias", False)),
+        f"topk_method {hf.get('topk_method')!r} (only noaux_tc)":
+            hf.get("topk_method", "noaux_tc") != "noaux_tc",
+        "partial_rotary_factor != 1":
+            hf.get("partial_rotary_factor", 1) != 1,
+    }
+    for feature, present in refused.items():
+        if present:
+            raise NotImplementedError(
+                f"glm4_moe_lite with {feature} is not supported: the "
+                "latent-attention trunk (inference/model_latent.py) "
+                "does not build it")
+    return Glm4MoeLiteConfig(
+        vocab_size=hf.get("vocab_size", 154880),
+        hidden_size=hf.get("hidden_size", 2048),
+        intermediate_size=hf.get("moe_intermediate_size", 1536),
+        dense_intermediate_size=hf.get("intermediate_size", 10240),
+        n_layer=hf.get("num_hidden_layers", 47),
+        n_head=hf.get("num_attention_heads", 20),
+        max_positions=hf.get("max_position_embeddings", 202752),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        rope_theta=hf.get("rope_theta", 1e6),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        num_experts=hf.get("n_routed_experts", 64),
+        top_k=hf.get("num_experts_per_tok", 4),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        q_lora_rank=hf.get("q_lora_rank", 768),
+        kv_lora_rank=hf.get("kv_lora_rank", 512),
+        qk_nope_head_dim=hf.get("qk_nope_head_dim", 192),
+        qk_rope_head_dim=hf.get("qk_rope_head_dim", 64),
+        v_head_dim=hf.get("v_head_dim", 256),
+        first_k_dense_replace=hf.get("first_k_dense_replace", 1),
+        n_shared_experts=hf.get("n_shared_experts", 1),
+        routed_scaling_factor=hf.get("routed_scaling_factor", 1.8),
+        dtype=hf.get("torch_dtype") or "bfloat16",
+    )
+
+
 #: model_type -> config adapter (reference: the policy map in
 #: engine_factory.py:69 — llama/mistral/qwen2/phi3 share the llama block
 #: layout; mixtral/qwen2_moe route through the MoE paged model
@@ -251,7 +304,9 @@ def _olmo_hybrid_like(hf: Dict[str, Any]):
 #: onto the llama trunk (_qwen_v1_like); olmo_hybrid is the hybrid trunk
 #: (model_hybrid.py: gated-delta-rule layers beside full attention);
 #: sdar_moe is the MoE paged model with a per-head q/k norm, an explicit
-#: head width and the block mask of generation by diffusion over blocks.
+#: head width and the block mask of generation by diffusion over blocks;
+#: glm4_moe_lite is the latent-attention trunk (model_latent.py: a pool
+#: of compressed KV rows, dense layers leading a sparse stack).
 MODEL_FAMILIES = {
     "llama": _llama_like,
     "mistral": _llama_like,
@@ -266,6 +321,7 @@ MODEL_FAMILIES = {
     "qwen2_moe": _qwen2_moe_like,
     "olmo_hybrid": _olmo_hybrid_like,
     "sdar_moe": _sdar_moe_like,
+    "glm4_moe_lite": _glm4_moe_lite_like,
 }
 
 

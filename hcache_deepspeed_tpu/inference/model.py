@@ -114,6 +114,19 @@ class PagedInferenceModel:
 
     #: a trunk with recurrent layers: its lanes carry a state slot each
     recurrent = False
+    #: the rotary step takes its angles from the lanes' positions
+    #: (``ops/rope.py rope_at``): no ``[max_positions, D/2]`` tables are
+    #: built, and no program carries them
+    rope_from_positions = False
+    #: what HCache saves of a layer and a token (``engine.latent_stats``,
+    #: ``engine.restore_profile``): ``hidden`` is the pre-attention
+    #: hidden state, put back by replaying the K/V projection;
+    #: ``cache_row`` the layer's cache row itself, put back by a write
+    saved_state = "hidden"
+    #: what each sparse layer's router read for every lane's last row in
+    #: the latest forward, on the device (``engine.router_inputs``);
+    #: ``None``: the trunk's forward does not hand it back
+    router_probe = None
 
     def __init__(self, cfg: LlamaConfig, params, *, block_size: int,
                  max_blocks_per_seq: int, capture_latents: bool = True,
@@ -172,7 +185,7 @@ class PagedInferenceModel:
         self.load_params(params)
         theta = getattr(cfg, "rope_theta", None)
         self.cos = self.sin = None
-        if theta is not None:
+        if theta is not None and not self.rope_from_positions:
             self.cos, self.sin = rope_frequencies(cfg.head_dim,
                                                   cfg.max_positions,
                                                   theta)
@@ -451,6 +464,20 @@ class PagedInferenceModel:
             self._param_spec_tree(params),
             is_leaf=lambda x: isinstance(x, PartitionSpec))
 
+    def pool_layout(self):
+        """``(kv heads, k width, v width)`` of the two block pools."""
+        return self.cfg.n_kv_head, self.cfg.head_dim, self.cfg.head_dim
+
+    def attention_fits(self, tokens):
+        """Raise (``PagedAttentionBudgetError``: the rows, bytes and
+        limit) where the attention kernel cannot tile a dispatch of
+        ``tokens`` positions at this head layout and block size."""
+        from ..ops.paged_attention import pick_tiles
+        cfg = self.cfg
+        pick_tiles(cfg.n_kv_head // self.tp,
+                   tokens * (cfg.n_head // cfg.n_kv_head), cfg.head_dim,
+                   self.block_size, jnp.dtype(cfg.compute_dtype).itemsize)
+
     def cache_sharding(self):
         """Sharding for the [L, KV, P, D] block pool: KV heads split over
         ``tensor``. None on single chip."""
@@ -638,6 +665,13 @@ class PagedInferenceModel:
                               cache_k.shape[2])
 
         scanned, whole = self._whole_layers(params["layers"])
+        # layers of another kind that lead the stack (a dense layer
+        # before sparse ones) run before the scan, at the first layers
+        # of the pools
+        x, cache_k, cache_v, lead = self._lead_layers(
+            params, x, cache_k, cache_v, tables, positions, flat_idx,
+            kv_len)
+        n_lead = len(lead)
 
         # the pools are carried, never scanned over: a scanned-over pool
         # is two buffers of the loop, every layer sliced out of one and
@@ -649,16 +683,27 @@ class PagedInferenceModel:
             if whole is not None:
                 lp = self._with_whole(lp, whole, layer)
             x, ck, cv, latent, stats = self._layer_step(
-                x, lp, ck, cv, layer, tables, positions, flat_idx, kv_len)
+                x, lp, ck, cv, layer + n_lead if n_lead else layer,
+                tables, positions, flat_idx, kv_len)
             # a layer with nothing to say adds nothing to the loop
             return (x, ck, cv), (latent, stats)
 
         (x, cache_k, cache_v), (latents, stats) = jax.lax.scan(
             step, (x, cache_k, cache_v),
-            (jnp.arange(cache_k.shape[0]), scanned))
+            (jnp.arange(cache_k.shape[0] - n_lead), scanned))
+        if n_lead:
+            latents = jnp.concatenate([jnp.stack(lead), latents])
 
         x = self._final_norm(params, x)
         return params, cache_k, cache_v, x, latents, stats
+
+    def _lead_layers(self, params, x, cache_k, cache_v, tables, positions,
+                     flat_idx, kv_len):
+        """The layers that run before the layer scan, unrolled: ``(x,
+        cache_k, cache_v, their latents)``. None here; the leading dense
+        layers of a trunk whose other layers are sparse
+        (``model_latent.py``)."""
+        return x, cache_k, cache_v, []
 
     def _whole_layers(self, layers):
         """``(scanned, whole)`` of the stacked layers: what the layer

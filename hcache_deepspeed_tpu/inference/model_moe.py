@@ -101,12 +101,20 @@ class PagedMoEModel(PagedInferenceModel):
         router read (a probed block lane's goes to the host with its
         logits rows)."""
         out, experts = self._routed(lp, h2)
-        valid = (flat_idx < pool_slots).reshape(-1, 1)       # [B * T, 1]
-        E = lp["mlp"]["moe"]["wg"].shape[-1]
-        picks = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
+        picks = self._picks(experts, flat_idx < pool_slots,
+                            lp["mlp"]["moe"]["wg"].shape[-1])
+        return out, {"picks": picks, "router_in": h2}
+
+    @staticmethod
+    def _picks(experts, valid, n_experts):
+        """The positions routed to each expert ``[E]``: ``experts``
+        ``[B * T, k]`` counted where ``valid`` ``[B, T]`` (the real
+        positions of the lanes)."""
+        valid = valid.reshape(-1, 1)                         # [B * T, 1]
+        return jnp.zeros((n_experts,), jnp.int32).at[
+            experts.reshape(-1)].add(
             jnp.broadcast_to(valid, experts.shape).reshape(-1)
             .astype(jnp.int32))
-        return out, {"picks": picks, "router_in": h2}
 
     def _routed(self, lp, h2):
         """``(output [B, T, d], experts picked [B * T, k])``."""

@@ -102,16 +102,20 @@ class BlockedKVCache:
 
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
-                 sharding=None):
+                 sharding=None, v_head_dim: Optional[int] = None):
+        """``v_head_dim``: the width of ``v``'s rows where it is not
+        ``k``'s (a latent-attention trunk: ``k`` holds the compressed KV
+        rows and ``v`` the narrower rotary keys, ``model_latent.py``)."""
         self.n_layers = n_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
+        self.v_head_dim = head_dim if v_head_dim is None else v_head_dim
         self.dtype = dtype
-        shape = (n_layers, n_kv_heads, num_blocks * block_size, head_dim)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
+        shape = (n_layers, n_kv_heads, num_blocks * block_size)
+        k = jnp.zeros(shape + (head_dim,), dtype)
+        v = jnp.zeros(shape + (self.v_head_dim,), dtype)
         if sharding is not None:
             k = jax.device_put(k, sharding)
             v = jax.device_put(v, sharding)
@@ -120,15 +124,16 @@ class BlockedKVCache:
 
     @staticmethod
     def token_bytes(n_layers: int, n_kv_heads: int, head_dim: int,
-                    dtype) -> int:
+                    dtype, v_head_dim: Optional[int] = None) -> int:
         """KV bytes per cached token (k + v across all layers)."""
-        return (2 * n_layers * n_kv_heads * head_dim *
+        v_head_dim = head_dim if v_head_dim is None else v_head_dim
+        return (n_layers * n_kv_heads * (head_dim + v_head_dim) *
                 jnp.dtype(dtype).itemsize)
 
     @property
     def per_token_bytes(self) -> int:
         return self.token_bytes(self.n_layers, self.n_kv_heads,
-                                self.head_dim, self.dtype)
+                                self.head_dim, self.dtype, self.v_head_dim)
 
     def replace(self, k, v):
         self.k, self.v = k, v

@@ -22,17 +22,34 @@ import jax.numpy as jnp
 from ..ops.grouped_gemm import grouped_matmul, grouped_matmul_stacked
 
 
-def dropless_route(logits, k, renormalize=True):
+def dropless_route(logits, k, renormalize=True, score="softmax",
+                   bias=None, scale=None):
     """Top-k routing without capacity: returns (probs [N,k], experts
     [N,k], aux load-balancing loss) — same aux formula as the capacity
     gate (fraction-mean * prob-mean * E). ``renormalize=False`` keeps
-    the raw softmax mass of the selected experts (qwen2-moe's
-    norm_topk_prob=False semantics)."""
+    the raw mass of the selected experts (qwen2-moe's
+    norm_topk_prob=False semantics). ``score``: the experts' scores are
+    the ``softmax`` of the logits over the experts, or each logit's
+    ``sigmoid``. ``bias`` [E] (a selection bias, ``noaux_tc``) is added
+    to the scores for the choice of experts alone: the weights are the
+    unbiased scores of the chosen. ``scale`` multiplies the weights
+    after the renormalisation (``routed_scaling_factor``)."""
     N, E = logits.shape
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score function {score!r}")
+    if bias is None:
+        topv, topi = jax.lax.top_k(probs, k)
+    else:
+        _, topi = jax.lax.top_k(probs + bias, k)
+        topv = jnp.take_along_axis(probs, topi, axis=-1)
     if renormalize:
         topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    if scale is not None:
+        topv = topv * scale
     # aux loss (reference: sharded_moe.py load-balancing)
     me = jnp.mean(probs, axis=0)
     ce = jnp.mean(
@@ -48,18 +65,20 @@ def dropless_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True):
 
 
 def routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True,
-                      layer=None):
+                      layer=None, **router):
     """The routed grouped-GEMM SwiGLU computation shared by the training
     layer below and the paged serving model (inference/model_moe.py).
     tokens: [N, d]; returns ([N, d], aux, experts picked [N, k]).
     ``layer``: ``w1``/``w3``/``w2`` are every layer's experts stacked
     ``[L, E, ...]`` and this is the layer to compute by; the stack is
-    read in place (``ops/grouped_gemm.py grouped_matmul_stacked``)."""
+    read in place (``ops/grouped_gemm.py grouped_matmul_stacked``).
+    ``router``: :func:`dropless_route`'s ``score``, ``bias`` and
+    ``scale``, from the configuration's published keys."""
     N, d = tokens.shape
     E = wg.shape[-1]
     dt = tokens.dtype
     logits = tokens.astype(jnp.float32) @ wg
-    probs, experts, aux = dropless_route(logits, k, renormalize)
+    probs, experts, aux = dropless_route(logits, k, renormalize, **router)
     flat_e = experts.reshape(-1)                     # [N*k]
     order = jnp.argsort(flat_e, stable=True)
     token_of = order // k

@@ -136,7 +136,7 @@ def _ensure_loaded():
     _loaded = True
     from . import (evoformer_attention, flash_attention,  # noqa: F401
                    fp_quantizer, gated_delta, grouped_gemm, kv_write,
-                   paged_attention,
+                   latent_attention, paged_attention,
                    quantized_matmul, quantizer, rms_norm, rope)
 
 

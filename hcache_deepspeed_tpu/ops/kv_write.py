@@ -18,7 +18,9 @@ p % BS``. Two granularities of the same write:
   pool, all KV heads at once. A frame the lane fills in part (a slice
   that starts inside a block, the last block of a prompt) is read into
   VMEM, takes the new rows under a row mask and goes back whole, so
-  every other slot keeps its bits. The pools are operands in place
+  every other slot keeps its bits. K's rows and V's may differ in width
+  (a latent pool: ``c`` rows beside narrower ``r`` rows). The pools are
+  operands in place
   (``input_output_aliases``): nothing pool-sized or layer-sized is
   formed, and a lane with nothing to write costs no DMA.
 """
@@ -137,13 +139,15 @@ def _kernel(tables_ref, start_ref, kvlen_ref, layer_ref,   # scalar prefetch
     @pl.when((hi > lo) & ((lo > 0) | (hi < BS)))
     def _ragged():
         copy((k_in.at[window], v_in.at[window]), (k_buf, v_buf))
-        row = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape, 1)
-        new = (row >= lo) & (row < hi)
         # the select in 32 bits: exact there and back for every narrower
         # dtype, and no packed mask for the compiler to lay out
         wide = jnp.float32 if k_buf.dtype.itemsize < 4 else k_buf.dtype
+        masks = {}      # a row mask a buffer shape: one, or K's and V's
         for frame, buf in ((kf_ref, k_buf), (vf_ref, v_buf)):
-            buf[...] = jnp.where(new, frame[0, 0].astype(wide),
+            if buf.shape not in masks:
+                row = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+                masks[buf.shape] = (row >= lo) & (row < hi)
+            buf[...] = jnp.where(masks[buf.shape], frame[0, 0].astype(wide),
                                  buf[...].astype(wide)).astype(buf.dtype)
         copy((k_buf, v_buf), (k_out.at[window], v_out.at[window]))
 
@@ -161,9 +165,10 @@ def pallas_kv_write(k_pool, v_pool, k, v, layer, tables, start, kv_len,
         from ..platform import get_platform
         interpret = not get_platform().supports_pallas()
     B, T, KV, D = k.shape
+    Dv = v.shape[-1]
     BS = block_size
     NJ = n_frames(T, BS)
-    KVT = head_tile(KV, BS, D, k_pool.dtype.itemsize)
+    KVT = head_tile(KV, BS, max(D, Dv), k_pool.dtype.itemsize)
     start = jnp.asarray(start, jnp.int32)
     kf = _frames(k.astype(k_pool.dtype), start, NJ, BS)
     vf = _frames(v.astype(v_pool.dtype), start, NJ, BS)
@@ -175,11 +180,11 @@ def pallas_kv_write(k_pool, v_pool, k, v, layer, tables, start, kv_len,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4, grid=(B, NJ, KV // KVT),
         in_specs=[pl.BlockSpec((1, 1, KVT, BS, D), frame),
-                  pl.BlockSpec((1, 1, KVT, BS, D), frame),
+                  pl.BlockSpec((1, 1, KVT, BS, Dv), frame),
                   in_place, in_place],
         out_specs=[in_place, in_place],
         scratch_shapes=[pltpu.VMEM((KVT, BS, D), k_pool.dtype),
-                        pltpu.VMEM((KVT, BS, D), v_pool.dtype),
+                        pltpu.VMEM((KVT, BS, Dv), v_pool.dtype),
                         pltpu.SemaphoreType.DMA((2,))])
     return pl.pallas_call(
         functools.partial(_kernel, BS=BS, KVT=KVT, NB=tables.shape[1]),

@@ -39,4 +39,18 @@ def apply_rope(x, cos, sin, positions=None):
     return out.astype(x.dtype)
 
 
+def rope_at(x, positions, theta):
+    """:func:`apply_rope` with the angles computed from ``positions``
+    [B, T] (float32), for a path whose contexts are too long for tables
+    built into every program. x: [B, T, H, D]."""
+    D = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (
+        jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [B,T,D/2]
+    c, s = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)
+
+
 register_op("rope", apply_rope)

@@ -598,3 +598,119 @@ def test_v5e_paged_kernel_steps_over_no_table_slot(one_chip, trunk, B, T,
     grid = _PAGED_GRID[trunk, B, T]
     assert grid == (B, 1, 1), grid          # every head in one tile
     assert all(steps % slots for steps in grid if steps > 1)
+
+
+# ------------------------------------------------------------------ #
+# latent attention (GLM-4-MoE-Lite): a pool of compressed KV rows, a
+# dense layer before the scan over the sparse stack
+# ------------------------------------------------------------------ #
+@functools.lru_cache(maxsize=None)
+def _v5e_latent_program(one_chip, B, T, restore=False):
+    """GLM-4.7-Flash widths (20 heads over rows of 512 + 64, 64 experts
+    of 1536, top-4, a dense layer of 10240 first), 1 + 2 layers, the
+    cell's pools (8192 blocks of 64) and table (512 slots: 32k
+    contexts), compiled for the described chip: the forward over ``B``
+    lanes of ``T`` positions, or (``restore``) the program that writes
+    all three layers' saved cache rows back. Returns ``(compiled, (c
+    pool, r pool), params)``."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from hcache_deepspeed_tpu import platform
+    from hcache_deepspeed_tpu.inference.model import stack_layer_params
+    from hcache_deepspeed_tpu.inference.model_latent import PagedLatentModel
+    from hcache_deepspeed_tpu.models.glm4_moe_lite import (
+        Glm4MoeLiteConfig, param_shapes)
+
+    class ShapesOnly(PagedLatentModel):
+        def load_params(self, params):
+            self.params = params
+
+    platform.set_platform("tpu")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = Glm4MoeLiteConfig(
+            vocab_size=154880, hidden_size=2048, intermediate_size=1536,
+            dense_intermediate_size=10240, n_layer=3, n_head=20,
+            max_positions=32768, num_experts=64, top_k=4, dtype="bfloat16")
+        tree = param_shapes(cfg)
+        model = ShapesOnly(cfg, None, block_size=64, max_blocks_per_seq=512)
+        stacks = {
+            "lead_layers": jax.eval_shape(
+                lambda p: stack_layer_params(p, 1), tree),
+            "layers": jax.eval_shape(lambda p: stack_layer_params(
+                {f"layers_{i - 1}": p[f"layers_{i}"] for i in (1, 2)}, 2),
+                tree)}
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, x: jax.ShapeDtypeStruct(
+                x.shape, jnp.float32 if PagedLatentModel._keep_fp32(path)
+                else jnp.bfloat16, sharding=one_chip),
+            {"embed": tree["embed_tokens"]["embedding"],
+             "norm": tree["norm"]["weight"],
+             "lm_head": tree["lm_head"]["kernel"],
+             **{k: jax.eval_shape(model._absorbed, v)
+                for k, v in stacks.items()}})
+        model.params = params
+        pools = tuple(jax.ShapeDtypeStruct(
+            (3, 1, 8192 * 64, width), jnp.bfloat16, sharding=one_chip)
+            for width in cfg.cache_row_widths)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                  sharding=one_chip)
+        if restore:
+            rows = jax.ShapeDtypeStruct((3, B, T, 512 + 64), jnp.bfloat16,
+                                        sharding=one_chip)
+            lowered = model._restore.lower(
+                params, *pools, i32(), rows, i32(B), i32(B, 512), i32(B))
+        else:
+            lowered = model._fwd.lower(
+                params, *pools, i32(B, lanes_width(T, 512)))
+        compiled = lowered.compile()
+    finally:
+        platform._platform = None
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    return compiled, pools, params
+
+
+@pytest.mark.parametrize("B,T,restore",
+                         [(16, 1, False), (1, 512, False), (1, 512, True)],
+                         ids=["decode", "slice", "restore"])
+def test_v5e_latent_program_holds_its_pools_in_place(one_chip, B, T,
+                                                     restore):
+    """The latent family's programs at the cell's real shapes: both
+    pools (the ``c`` rows and the ``r`` rows) aliased input to output,
+    nothing of either pool's or a layer's extent copied or sliced, no
+    stacked weight leaf (the absorbed halves of ``W_kvb`` and the
+    experts' stacks among them) sliced out or laid out again, the latent
+    kernel and the grouped products in place, and **no constant of
+    ``max_position_embeddings`` rows**: the rotary step takes its angles
+    from the lanes' positions."""
+    compiled, pools, params = _v5e_latent_program(one_chip, B, T, restore)
+    text = compiled.as_text()
+    header = text.split("\n", 1)[0]
+    # the restore reads no parameter: a write of what was shipped
+    first = 0 if restore else len(jax.tree.leaves(params))
+    for out, arg in ((0, first), (1, first + 1)):
+        assert f"{{{out}}}: ({arg}, {{}}" in header, header
+    for pool in pools:
+        assert pool_sized_copies(text, pool.shape) == []
+    assert not re.search(r"\[32768[,\]]", text)
+    layer_bytes = int(np.prod(pools[0].shape[1:])) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    if restore:
+        # a ship and a write: no product, no kernel but the block-run
+        # write
+        assert "hds_kv_write" in text and "hds_latent_attention" not in text
+        assert not re.search(r"= \w+\[[\d,]*\]\S* (dot|convolution)\(",
+                             text)
+        return
+    assert "hds_latent_attention" in text
+    assert text.count("hds_kernel=\"expert_gemm\"") >= 3
+    assert ("hds_kv_write" in text) == (T > 1)
+    kernels = [leaf.shape for leaf in jax.tree.leaves(params["layers"])
+               if leaf.ndim >= 3 and np.prod(leaf.shape[1:]) >= 1 << 20]
+    # q_a, q_b, kv_a, w_uk, w_uv, o; w1, w2, w3; the shared expert's 3
+    assert len(kernels) == 6 + 3 + 3
+    assert stacked_layer_copies(text, kernels) == []
+    assert re.findall(r"= bf16\[64,(?:2048,1536|1536,2048)\]\S* "
+                      r"(?!bitcast|parameter)[\w\-]+\(", text) == []

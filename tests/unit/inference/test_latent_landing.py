@@ -125,6 +125,7 @@ def test_the_next_put_lands_what_the_last_left_pending(tiny_model):
         store.append(chunk)
     first = sum(c.nbytes for c in lat)
     assert eng.latent_stats() == {
+        "saved_state": "hidden",
         "captured_bytes": first, "captured_tokens": 5 + 7 + 6,
         "landed_hidden_bytes": 0, "landed_forced_bytes": 0,
         "dropped_bytes": 0, "pending_bytes": first,
@@ -170,6 +171,7 @@ def test_chunks_nobody_keeps_are_dropped_unread(tiny_model):
     eng.flush(1)
     del lat
     assert eng.latent_stats() == {
+        "saved_state": "hidden",
         "captured_bytes": captured, "captured_tokens": 5 + 7,
         "landed_hidden_bytes": 0, "landed_forced_bytes": 0,
         "dropped_bytes": captured, "pending_bytes": 0,

@@ -46,6 +46,19 @@ def test_hybrid_smoke_phase_passes_at_tiny_size():
             published["linear_value_head_dim"]) == (3840, 11008, 96, 192)
 
 
+def test_latent_smoke_phase_passes_at_tiny_size():
+    """The latent-attention pair (a dense layer and a sparse one) at toy
+    width: the phase's checks (cache rows to the host, evict and restore
+    through the latent pool against the uninterrupted logits, nothing
+    pool-sized copied, blocks given back) hold on the CPU's program."""
+    chip_smoke.latent_phase(chip_smoke.TINY_LATENT_PAIR, block_size=8,
+                            prefill_chunk=16, logit_tol=1e-5)
+    published = chip_smoke.GLM_LATENT_PAIR
+    assert (published["hidden_size"], published["kv_lora_rank"],
+            published["qk_rope_head_dim"], published["n_routed_experts"],
+            published["vocab_size"]) == (2048, 512, 64, 64, 154880)
+
+
 def test_smoke_sizes_keep_the_full_mistral_7b_width():
     hf = chip_smoke.MISTRAL_7B.hf_config
     assert (hf["hidden_size"], hf["intermediate_size"],
