@@ -293,6 +293,12 @@ class InferenceEngineV2:
         self._latent_parts: deque = deque()
         self._latent_program_max = 0
         self._latent_pending_peak = 0
+        #: bytes of the latents of programs this put has enqueued and
+        #: not collected yet (``_dispatch``)
+        self._latent_launched = 0
+        #: programs enqueued behind one of the same put that had not
+        #: been collected: the device reached them without a host turn
+        self._chained = 0
         log_dist(f"InferenceEngineV2: {num_blocks} KV blocks x "
                  f"{self.block_size} tokens, max_context="
                  f"{self.max_context}", ranks=[0])
@@ -431,8 +437,9 @@ class InferenceEngineV2:
 
     def _put(self, batch_uids, batch_tokens, do_checks, defer_fetch,
              blocks):
-        """The body of :meth:`put`, in leaf spans: admit, then per
-        dispatch build / enqueue / wait / fetch / scatter."""
+        """The body of :meth:`put`, in leaf spans: admit; then build and
+        enqueue of every program of the step, back to back; then wait /
+        fetch / scatter of each, in that order (:meth:`_dispatch`)."""
         tracer = get_tracer()
         with tracer.span("serve.put.admit"):
             batch_tokens = [np.asarray(t, np.int32).reshape(-1)
@@ -523,8 +530,9 @@ class InferenceEngineV2:
                         seq.pre_forward(chunk)
                 part_l: List = [None] * len(batch_tokens)
                 part_t: List = [None] * len(batch_tokens)
-                self._run_prefill(batch_uids, heads, long_idx,
-                                  _bucket(chunk), part_l, part_t)
+                self._one_by_one([functools.partial(
+                    self._launch_prefill, batch_uids, heads, long_idx,
+                    _bucket(chunk), part_l, part_t)])
                 with tracer.span("serve.scatter"):
                     for i in long_idx:
                         self.state.get_sequence(
@@ -561,15 +569,20 @@ class InferenceEngineV2:
         logits_out: List = [None] * n
         latents_out: List = [None] * n
 
+        launches = []
         if decode_idx and self.diffusion:
-            self._run_blocks(batch_uids, batch_tokens, decode_idx, blocks,
-                             logits_out, latents_out)
+            launches.append(functools.partial(
+                self._launch_blocks, batch_uids, batch_tokens, decode_idx,
+                blocks, logits_out, latents_out))
         elif decode_idx:
-            self._run_decode(batch_uids, batch_tokens, decode_idx,
-                             logits_out, latents_out, defer=defer_fetch)
+            launches.append(functools.partial(
+                self._launch_decode, batch_uids, batch_tokens, decode_idx,
+                logits_out, latents_out, defer=defer_fetch))
         for T, idx in sorted(groups.items()):
-            self._run_prefill(batch_uids, batch_tokens, idx, T,
-                              logits_out, latents_out, defer=defer_fetch)
+            launches.append(functools.partial(
+                self._launch_prefill, batch_uids, batch_tokens, idx, T,
+                logits_out, latents_out, defer=defer_fetch))
+        self._dispatch(launches)
 
         with tracer.span("serve.scatter"):
             for uid in batch_uids:
@@ -591,6 +604,39 @@ class InferenceEngineV2:
                 return logits_out, latents_out
             return np.stack(logits_out), latents_out
 
+    def _dispatch(self, launches):
+        """The programs of one put: every one built and enqueued
+        (``launches``, each returning its collect, or ``None`` when it
+        leaves nothing to fetch), then each waited for, fetched and
+        scattered, in the same order. The device goes from a program to
+        the next with no host turn between them (the donated pools chain
+        them), and a program's results travel and are scattered while
+        the next runs. One program: the sequence of :meth:`_one_by_one`."""
+        self._latent_launched = 0   # a put that raised may have left some
+        flying = []
+        try:
+            for launch in launches:
+                collect = launch(chained=bool(flying))
+                if collect is not None:
+                    flying.append(collect)
+        except Exception:
+            # _one_by_one had collected these before it came to the
+            # launch that raised, and a fault of theirs came first
+            for collect in flying:
+                collect()
+            raise
+        for collect in flying:
+            collect()
+
+    def _one_by_one(self, launches):
+        """Each program collected before the next is built: the lead
+        rounds of a prompt longer than ``prefill_chunk``, and what
+        :meth:`_dispatch` is held to, result for result."""
+        for launch in launches:
+            collect = launch(chained=False)
+            if collect is not None:
+                collect()
+
     def _tables(self, idx, uids):
         return np.stack([
             self.state.block_table(self.state.get_sequence(uids[i]),
@@ -607,7 +653,15 @@ class InferenceEngineV2:
         tables[:, 0] = self._scratch_block
         return tok, start, t_len, tables
 
-    def _forward(self, span, tok, start, tables, t_len, uids, idx):
+    def _count_chained(self, span):
+        """The program enqueued inside ``span`` stands behind one of the
+        same put that has not been collected: the device reaches it
+        without a host turn."""
+        self._chained += 1
+        span.set(chained=1)
+
+    def _forward(self, span, tok, start, tables, t_len, uids, idx,
+                 chained):
         """One program over the built lanes, inside the enqueue span
         ``span``, which is told what the lanes' description cost in
         transfers. A trunk with recurrent layers also gets each lane's
@@ -624,6 +678,8 @@ class InferenceEngineV2:
         out = self.model.forward_chunk(self.cache, *lanes)
         span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
                  h2d_bytes=stats["h2d_bytes"] - nbytes)
+        if chained:
+            self._count_chained(span)
         if self.router_probe_uids:
             for j, i in enumerate(idx):
                 if uids[i] in self.router_probe_uids:
@@ -631,8 +687,12 @@ class InferenceEngineV2:
                         self.model.router_probe, j)
         return out
 
-    def _run_decode(self, uids, tokens, idx, logits_out, latents_out,
-                    defer=False):
+    def _launch_decode(self, uids, tokens, idx, logits_out, latents_out,
+                       defer=False, chained=False):
+        """Build and enqueue the decode program, its results' copies to
+        the host started. Returns its collect: wait, fetch and scatter
+        into ``logits_out`` / ``latents_out`` (``None`` under ``defer``:
+        the logits stay on the device)."""
         tracer = get_tracer()
         B = _bucket(len(idx))
         with tracer.span("serve.batch_build", bucket=B):
@@ -645,25 +705,28 @@ class InferenceEngineV2:
         with tracer.span("serve.decode_dispatch",
                          lanes=len(idx), bucket=B) as span:
             logits, latents = self._forward(span, tok, start, tables,
-                                            t_len, uids, idx)
+                                            t_len, uids, idx, chained)
             if not defer:
                 latents = self._start_copies(logits, latents)
         if defer:   # keep the device array whole (row slicing here would
             for j, i in enumerate(idx):   # dispatch an op per lane) —
                 logits_out[i] = (logits, j)   # every uid gets its lane
-            return
-        logits = self._fetch(logits, latents)
-        with tracer.span("serve.scatter"):
-            for j, i in enumerate(idx):
-                logits_out[i] = logits[j]
-                if latents is not None:                # [L, B, 1, H]
-                    latents_out[i] = self._hand_out(latents, j, 1)
+            return None
 
-    def _run_prefill(self, uids, tokens, idx, T, logits_out, latents_out,
-                     defer=False):
-        """One batched dispatch for all prefills in a length bucket;
-        padded rows (t_len=0) write to the scratch block like padded
-        decode lanes."""
+        def collect():
+            rows = self._fetch(logits, latents)
+            with tracer.span("serve.scatter"):
+                for j, i in enumerate(idx):
+                    logits_out[i] = rows[j]
+                    if latents is not None:                # [L, B, 1, H]
+                        latents_out[i] = self._hand_out(latents, j, 1)
+        return collect
+
+    def _launch_prefill(self, uids, tokens, idx, T, logits_out,
+                        latents_out, defer=False, chained=False):
+        """As :meth:`_launch_decode`, one batched dispatch for all
+        prefills in a length bucket; padded rows (t_len=0) write to the
+        scratch block like padded decode lanes."""
         tracer = get_tracer()
         B = _bucket(len(idx), minimum=1)
         with tracer.span("serve.batch_build", bucket=B):
@@ -679,23 +742,26 @@ class InferenceEngineV2:
                          tokens=_token_count(tokens[i] for i in idx)
                          if tracer.enabled else 0) as span:
             logits, latents = self._forward(span, tok, start, tables,
-                                            t_len, uids, idx)
+                                            t_len, uids, idx, chained)
             if not defer:
                 latents = self._start_copies(logits, latents,
                                              fetch=not self.diffusion)
         if defer:
             for j, i in enumerate(idx):
                 logits_out[i] = (logits, j)
-            return
-        # a prompt slice of a diffusion model predicts nothing: its
-        # first block starts from masks, and no logits row is fetched
-        logits = self._fetch(logits, latents, fetch=not self.diffusion)
-        with tracer.span("serve.scatter"):
-            for j, i in enumerate(idx):
-                logits_out[i] = None if self.diffusion else logits[j]
-                if latents is not None:            # [L, B, T, H]
-                    latents_out[i] = self._hand_out(
-                        latents, j, len(tokens[i]))
+            return None
+
+        def collect():
+            # a prompt slice of a diffusion model predicts nothing: its
+            # first block starts from masks, and no logits row is fetched
+            rows = self._fetch(logits, latents, fetch=not self.diffusion)
+            with tracer.span("serve.scatter"):
+                for j, i in enumerate(idx):
+                    logits_out[i] = None if self.diffusion else rows[j]
+                    if latents is not None:            # [L, B, T, H]
+                        latents_out[i] = self._hand_out(
+                            latents, j, len(tokens[i]))
+        return collect
 
     def _start_copies(self, logits, latents, fetch=True):
         """Part of a dispatch: start the copies of its results to the
@@ -707,7 +773,9 @@ class InferenceEngineV2:
             logits.copy_to_host_async()
         if not self.config.hcache.enable_latents or latents is None:
             return None
-        return LatentProgram(latents, self._latent_link)
+        program = LatentProgram(latents, self._latent_link)
+        self._latent_launched += program.nbytes
+        return program
 
     def _fetch(self, logits, program, fetch=True):
         """A dispatch's logits on the host. In the time the device
@@ -718,6 +786,8 @@ class InferenceEngineV2:
         false). The latents stay where they are: ``program`` is only
         told when its copy could start."""
         tracer = get_tracer()
+        if program is not None:
+            self._latent_launched -= program.nbytes
         self._land_pending(logits, program)
         with tracer.span("serve.device_wait"):
             logits.block_until_ready()
@@ -759,9 +829,11 @@ class InferenceEngineV2:
                     f"uid {uid}: {len(toks)} tokens from position {seen} "
                     f"are not whole blocks of {B}")
 
-    def _run_blocks(self, uids, tokens, idx, blocks, out, latents_out):
+    def _launch_blocks(self, uids, tokens, idx, blocks, out, latents_out,
+                       chained=False):
         """One pass over every open block: the decode dispatch with
-        lanes of ``block_len`` positions. The device chooses, greedy;
+        lanes of ``block_len`` positions, launched and collected as
+        :meth:`_launch_decode`. The device chooses, greedy;
         what comes back is a token and a confidence a position and the
         experts' counts, in one array, plus the logits rows and the
         routers' inputs of the one lane that asked. Latents go to the host for the committing lanes only."""
@@ -791,6 +863,8 @@ class InferenceEngineV2:
                 self.cache, tok, start, tables, t_len, flags)
             span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
                      h2d_bytes=stats["h2d_bytes"] - nbytes)
+            if chained:
+                self._count_chained(span)
             if probe is not None:
                 for a in probed:
                     a.copy_to_host_async()
@@ -800,27 +874,31 @@ class InferenceEngineV2:
             latents = self._start_copies(
                 packed, latents[buckets.index(min(B, _bucket(len(commits))))]
                 if commits else None)
-        packed = self._fetch(packed, latents)
-        if probe is not None:
-            # the one fetch of logits rows there is: told apart by ``probe``
-            with tracer.span("serve.fetch", probe=1,
-                             bytes=_nbytes(*probed)):
-                rows, router_in = (np.asarray(a) for a in probed)
-        with tracer.span("serve.scatter"):
-            chosen = packed[:B * T].reshape(B, T)
-            confidence = packed[B * T:2 * B * T].view(np.float32) \
-                .reshape(B, T)
-            for j, i in enumerate(idx):
-                out[i] = BlockChoice(chosen[j], confidence[j]) \
-                    if j != probe else \
-                    BlockChoice(chosen[j], confidence[j], rows, router_in)
-                seq = self.state.get_sequence(uids[i])
-                if not passes[j].commit:
-                    seq.in_flight_tokens = 0    # provisional: not seen
-                elif latents is not None:
-                    latents_out[i] = self._hand_out(latents, rank[j], T)
-            self._count_blocks(len(idx), masked, len(commits),
-                               packed[2 * B * T:])
+
+        def collect():
+            choice = self._fetch(packed, latents)
+            if probe is not None:
+                # the one fetch of logits rows there is: told apart by ``probe``
+                with tracer.span("serve.fetch", probe=1,
+                                 bytes=_nbytes(*probed)):
+                    rows, router_in = (np.asarray(a) for a in probed)
+            with tracer.span("serve.scatter"):
+                chosen = choice[:B * T].reshape(B, T)
+                confidence = choice[B * T:2 * B * T].view(np.float32) \
+                    .reshape(B, T)
+                for j, i in enumerate(idx):
+                    out[i] = BlockChoice(chosen[j], confidence[j]) \
+                        if j != probe else \
+                        BlockChoice(chosen[j], confidence[j], rows,
+                                    router_in)
+                    seq = self.state.get_sequence(uids[i])
+                    if not passes[j].commit:
+                        seq.in_flight_tokens = 0    # provisional: not seen
+                    elif latents is not None:
+                        latents_out[i] = self._hand_out(latents, rank[j], T)
+                self._count_blocks(len(idx), masked, len(commits),
+                                   choice[2 * B * T:])
+        return collect
 
     def _count_blocks(self, lanes, masked, commits, counts) -> None:
         d, T = self._diffusion_stats, self.block_len
@@ -896,14 +974,18 @@ class InferenceEngineV2:
         latents still on the device, ``new_program``'s among them, hold
         more than ``_PENDING_PROGRAMS`` of the largest program: then
         the oldest copies are waited for, as every dispatch did before
-        landing was deferred."""
+        landing was deferred. Programs of this put enqueued behind
+        ``new_program`` count in the peak from their launch and against
+        that bound when their own collect comes, so nothing is waited
+        for that was not before launches were chained."""
         held = 0
         if new_program is not None:
             held = new_program.nbytes
             self._latent_program_max = max(self._latent_program_max, held)
+        behind = self._latent_launched
         if not self._latent_parts:
             self._latent_pending_peak = max(self._latent_pending_peak,
-                                            held)
+                                            held + behind)
             return
         with get_tracer().span("serve.latents.land") as span:
             parts, last = self._pending_parts(), None
@@ -912,7 +994,7 @@ class InferenceEngineV2:
                     last = part.program
                     held += last.device_bytes
             self._latent_pending_peak = max(self._latent_pending_peak,
-                                            held)
+                                            held + behind)
             over = held - self._PENDING_PROGRAMS * self._latent_program_max
             if new_program is not None and over > 0:
                 self._force_pending(parts, over)
@@ -994,8 +1076,11 @@ class InferenceEngineV2:
         host-to-device transfer each, and their ``h2d_bytes``. One
         packed array a dispatch (``ragged/lanes.py``), so ``h2d_arrays
         == dispatches``. Counted on the host where the arrays are
-        handed over."""
-        return dict(self.model.dispatch_stats)
+        handed over. ``chained``: those of :meth:`put`'s that were
+        enqueued while an earlier program of the same put had not been
+        collected (:meth:`_dispatch`), which the device reaches without
+        a host turn; over ``dispatches``, their share."""
+        return dict(self.model.dispatch_stats, chained=self._chained)
 
     def paged_walk_stats(self) -> Dict[str, int]:
         """How much of the block tables the paged kernel's walk covers:

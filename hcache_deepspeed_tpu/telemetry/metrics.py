@@ -175,8 +175,11 @@ def serve_step_breakdown(events) -> "OrderedDict":
     that *end* in the step, a turn being the stretch from a
     ``serve.device_wait``'s end to the end of the next enqueue span
     (:data:`ENQUEUE_SPANS`): what the host does between two programs
-    while the device has none. ``benchmarks/reducers/idle_cut.py``
-    reads the same two edges off a profiler trace."""
+    while the device has none. A put that enqueues its programs back to
+    back and then waits for each opens a turn at its last wait only
+    (after the others the device still has a program).
+    ``benchmarks/reducers/idle_cut.py`` reads the same two edges off a
+    profiler trace."""
     steps = [ev for ev in events
              if ev.get("ph") == "X" and ev["name"] == SERVE_STEP_SPAN]
     out = OrderedDict()

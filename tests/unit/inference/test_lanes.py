@@ -91,7 +91,7 @@ def test_every_dispatch_hands_over_one_array(trunk, request):
     slot = engine.recurrent
     n_blocks = engine.max_blocks_per_seq
     assert engine.dispatch_stats() == {"dispatches": 0, "h2d_arrays": 0,
-                                       "h2d_bytes": 0}
+                                       "h2d_bytes": 0, "chained": 0}
     rng = np.random.default_rng(0)
     tracer = get_tracer()
     tracer.clear()

@@ -16,8 +16,9 @@ import pytest
 from hcache_deepspeed_tpu.inference import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
 from hcache_deepspeed_tpu.inference import engine_v2
-from hcache_deepspeed_tpu.serving import (Request, ServerConfig,
-                                          ServingServer, VirtualClock)
+from hcache_deepspeed_tpu.serving import (Request, RequestState,
+                                          ServerConfig, ServingServer,
+                                          VirtualClock)
 from hcache_deepspeed_tpu.telemetry.metrics import (ENQUEUE_SPANS,
                                                     serve_step_breakdown,
                                                     serving_step_summary)
@@ -177,9 +178,11 @@ def test_leaf_spans_cover_their_parent(recorded, parent):
 
 def test_latents_land_between_the_dispatch_and_the_wait(recorded):
     """``serve.latents.land`` is a leaf on the loop's thread, after the
-    put's program is enqueued and before the wait for it; the
-    scheduler's absorb pass still opens every dispatching step (it
-    records the chunks; the bytes land here)."""
+    put's last program is enqueued (a step's programs are enqueued back
+    to back, then collected one by one) and directly before the wait for
+    the program being collected; the scheduler's absorb pass still opens
+    every dispatching step (it records the chunks; the bytes land
+    here)."""
     lands = [e for e in recorded if e["name"] == "serve.latents.land"]
     puts = [e for e in recorded if e["name"] == "hds.serve.put"]
     assert len(lands) >= len(puts) - 1          # all but the first put
@@ -188,17 +191,95 @@ def test_latents_land_between_the_dispatch_and_the_wait(recorded):
                        for e in recorded), "not a leaf"
         put = next(p for p in puts if inside(land, p))
         mine = [e for e in recorded if e is not put and inside(e, put)]
-        before = [e for e in mine if e["ts"] + e["dur"] <= land["ts"]]
+        enqueues = [e for e in mine if e["name"] in ENQUEUE_SPANS]
         after = [e for e in mine if e["ts"] >= land["ts"] + land["dur"]]
-        assert max(before, key=lambda e: e["ts"])["name"] in (
-            "serve.decode_dispatch", "serve.prefill_dispatch")
+        assert enqueues and max(e["ts"] + e["dur"] for e in enqueues) <= \
+            land["ts"] + 1e-3
         assert min(after, key=lambda e: e["ts"])["name"] == \
             "serve.device_wait"
     assert sum(e["args"]["bytes"] for e in lands) > 0
+    # a put of two programs (a prompt slice beside decode lanes) is in
+    # the trace: two landing passes, one before each wait
+    assert any(sum(1 for land in lands if inside(land, p)) == 2
+               for p in puts)
     steps = [e for e in recorded if e["name"] == "sched.decode_dispatch"
              and any(inside(p, e) for p in puts)]
     absorbs = [e for e in recorded if e["name"] == "sched.absorb_latents"]
     assert len(absorbs) == len(steps)
+
+
+def test_chaining_forces_no_landing_the_order_before_did_not(tiny):
+    """The seeded trace with a step's programs enqueued back to back and
+    with each collected before the next is built (``_one_by_one``):
+    the same tokens, no more bytes landed while the caller waited, and
+    at most one more program's latents pending on the device at the
+    peak."""
+    cfg, build = tiny
+    stats, tokens = [], []
+    for one_by_one in (False, True):
+        srv = virtual_server(build, landing=True)
+        engine = srv.scheduler.engine
+        if one_by_one:
+            engine._dispatch = engine._one_by_one
+        reqs = seeded_trace(cfg)
+        srv.run_trace(reqs)
+        tokens.append([r.tokens_out for r in reqs])
+        stats.append(dict(engine.latent_stats(),
+                          program=engine._latent_program_max,
+                          **engine.dispatch_stats()))
+    chained, plain = stats
+    assert tokens[0] == tokens[1]
+    assert chained["chained"] >= 1 and plain["chained"] == 0
+    assert chained["dispatches"] == plain["dispatches"]
+    assert chained["landed_forced_bytes"] <= plain["landed_forced_bytes"]
+    assert chained["captured_bytes"] == plain["captured_bytes"]
+    assert plain["pending_peak_bytes"] <= chained["pending_peak_bytes"] \
+        <= plain["pending_peak_bytes"] + plain["program"]
+
+
+@pytest.mark.parametrize("fault", ["second-launch", "first-collect"])
+def test_a_fault_in_a_two_program_step_and_the_next_step_runs(tiny, fault):
+    """An exception out of the slice's enqueue (the decode program is
+    launched and not yet collected) or out of the decode program's
+    fetch (the slice's is launched behind it) fails the step's batch
+    as any engine fault does (``_quarantine_dispatch``); the blocks come
+    back and the requests that follow are served."""
+    cfg, build = tiny
+    srv = virtual_server(build)
+    engine = srv.scheduler.engine
+    free = engine.free_blocks
+    first = Request(uid=0, prompt=list(range(1, 13)), max_new_tokens=8)
+    srv.scheduler.submit(first)
+    while len(first.tokens_out) < 2:
+        srv.scheduler.step()
+    name = "_enqueue" if fault == "second-launch" else "_fetch"
+    owner = engine.model if fault == "second-launch" else engine
+    real, calls = getattr(owner, name), []
+
+    def faulty(*args, **kwargs):
+        calls.append(1)
+        if len(calls) == (2 if fault == "second-launch" else 1):
+            raise RuntimeError(fault)
+        return real(*args, **kwargs)
+
+    setattr(owner, name, faulty)
+    second = Request(uid=1, prompt=list(range(20, 40)), max_new_tokens=4)
+    srv.scheduler.submit(second)
+    dispatched = engine.dispatch_stats()["dispatches"]
+    srv.scheduler.step()            # decode lane + prompt slice: faults
+    assert engine.dispatch_stats()["dispatches"] - dispatched == \
+        (1 if fault == "second-launch" else 2)
+    assert first.state == second.state == RequestState.FAILED
+    assert first.error.startswith("engine_fault")
+    assert engine.free_blocks == free
+    assert engine.state.n_tracked_sequences == 0
+    third = Request(uid=2, prompt=list(range(3, 30)), max_new_tokens=5)
+    srv.scheduler.submit(third)
+    while not third.finished:
+        srv.scheduler.step()
+    assert len(third.tokens_out) == 5 and \
+        third.state == RequestState.DONE
+    assert engine.free_blocks == free
 
 
 def test_device_wait_holds_only_the_wait(tiny, tracing):
