@@ -69,26 +69,33 @@ class _Window:
     events: deque = field(default_factory=deque)   # (t, good)
     total: int = 0
     total_bad: int = 0
+    #: bad events among ``events``: raised as one enters, lowered as
+    #: one leaves, so a read costs the same however many the window
+    #: holds (the scheduler reads every step)
+    bad: int = 0
 
     def observe(self, t: float, good: bool, max_events: int) -> None:
         self.events.append((t, bool(good)))
         self.total += 1
         self.total_bad += not good
+        self.bad += not good
         while len(self.events) > max_events:
-            self.events.popleft()
+            self._drop()
         self.evict(t)
+
+    def _drop(self) -> None:
+        self.bad -= not self.events.popleft()[1]
 
     def evict(self, now: float) -> None:
         w = self.objective.window_s
         while self.events and now - self.events[0][0] > w:
-            self.events.popleft()
+            self._drop()
 
     def bad_fraction(self, now: float) -> float:
         self.evict(now)
         if not self.events:
             return 0.0
-        bad = sum(1 for _, good in self.events if not good)
-        return bad / len(self.events)
+        return self.bad / len(self.events)
 
     def burn_rate(self, now: float) -> float:
         return self.bad_fraction(now) / (1.0 - self.objective.target)
@@ -116,6 +123,8 @@ class SLOTracker:
         self._windows = {o.name: _Window(o) for o in self.objectives}
         #: degradation-context window: (t, level) — same sliding bound
         self._degradation = deque()
+        #: its entries with a level above 0, kept as they come and go
+        self._degraded = 0
         self._degradation_window_s = max(
             (o.window_s for o in self.objectives), default=60.0)
         self.last_t = 0.0
@@ -150,12 +159,17 @@ class SLOTracker:
 
     def note_degradation(self, t: float, level: int) -> None:
         self.last_t = max(self.last_t, t)
-        self._degradation.append((t, int(level)))
+        level = int(level)
+        self._degradation.append((t, level))
+        self._degraded += level > 0
         w = self._degradation_window_s
         while self._degradation and t - self._degradation[0][0] > w:
-            self._degradation.popleft()
+            self._drop_degradation()
         while len(self._degradation) > self.max_events:
-            self._degradation.popleft()
+            self._drop_degradation()
+
+    def _drop_degradation(self) -> None:
+        self._degraded -= self._degradation.popleft()[1] > 0
 
     # ------------------------------------------------------------- #
     def burn_rates(self, now: Optional[float] = None) -> Dict[str, float]:
@@ -165,12 +179,25 @@ class SLOTracker:
                 for name, w in self._windows.items()}
 
     def degraded_fraction(self, now: Optional[float] = None) -> float:
+        """Share of the steps in the window that ends at ``now`` spent
+        degraded. On the step's own clock nothing in the deque is older
+        than the window (``note_degradation`` has dropped it) and the
+        answer is the running count over the length. A reader whose
+        clock is past the last step's counts the steps that have left
+        its window out, from the old end, and drops none: a later read
+        at an earlier ``now`` (``summary(now)``, then ``summary()``)
+        still sees them. Steps are noted on a clock that does not run
+        backwards, so what has left is the head of the deque."""
         now = self.last_t if now is None else now
         w = self._degradation_window_s
-        recent = [lvl for t, lvl in self._degradation if now - t <= w]
-        if not recent:
-            return 0.0
-        return sum(1 for lvl in recent if lvl > 0) / len(recent)
+        n, degraded = len(self._degradation), self._degraded
+        if n and now - self._degradation[0][0] > w:
+            for t, lvl in self._degradation:
+                if now - t <= w:
+                    break
+                n -= 1
+                degraded -= lvl > 0
+        return degraded / n if n else 0.0
 
     def gauges(self, now: Optional[float] = None) -> Dict[str, float]:
         """The flat gauge dict the serving metrics/monitor path emits:
