@@ -299,6 +299,23 @@ class InferenceEngineV2:
         #: programs enqueued behind one of the same put that had not
         #: been collected: the device reached them without a host turn
         self._chained = 0
+        #: programs that carried a step's decode lanes and its prompt
+        #: slice together (``_launch_step``)
+        self._fused = 0
+        #: the slice shape such a program carries: a full
+        #: ``prefill_chunk`` (or a tail in its bucket), one lane. 0: no
+        #: step of this engine is one program (no chunked prefill; a
+        #: model that generates by diffusion over blocks, whose block
+        #: lanes stay a program of their own; prefix caching, whose
+        #: waves are puts of their own)
+        chunk = sm_cfg.prefill_chunk
+        self._step_T = _bucket(chunk) if chunk and not (
+            self.diffusion or self.prefix_caching) else 0
+        #: the decode lanes such a program carries: the narrowest decode
+        #: bucket. A step with more keeps its decode program and chains
+        #: the step program behind it on blank decode lanes (blank lanes
+        #: cost rows, a program costs set-up: one step program an engine)
+        self._step_B = _bucket(1)
         log_dist(f"InferenceEngineV2: {num_blocks} KV blocks x "
                  f"{self.block_size} tokens, max_context="
                  f"{self.max_context}", ranks=[0])
@@ -530,9 +547,15 @@ class InferenceEngineV2:
                         seq.pre_forward(chunk)
                 part_l: List = [None] * len(batch_tokens)
                 part_t: List = [None] * len(batch_tokens)
-                self._one_by_one([functools.partial(
-                    self._launch_prefill, batch_uids, heads, long_idx,
-                    _bucket(chunk), part_l, part_t)])
+                if self._rides_decode({_bucket(chunk): long_idx}):
+                    launch = functools.partial(
+                        self._launch_step, batch_uids, heads, [], long_idx,
+                        part_l, part_t)
+                else:
+                    launch = functools.partial(
+                        self._launch_prefill, batch_uids, heads, long_idx,
+                        _bucket(chunk), part_l, part_t)
+                self._one_by_one([launch])
                 with tracer.span("serve.scatter"):
                     for i in long_idx:
                         self.state.get_sequence(
@@ -570,14 +593,23 @@ class InferenceEngineV2:
         latents_out: List = [None] * n
 
         launches = []
+        rides = self._rides_decode(groups)
+        # more decode lanes than the step program holds: they keep their
+        # program, and the slice takes its own on blank decode lanes
+        riders = decode_idx if rides and \
+            len(decode_idx) <= self._step_B else []
         if decode_idx and self.diffusion:
             launches.append(functools.partial(
                 self._launch_blocks, batch_uids, batch_tokens, decode_idx,
                 blocks, logits_out, latents_out))
-        elif decode_idx:
+        elif decode_idx and not riders:
             launches.append(functools.partial(
                 self._launch_decode, batch_uids, batch_tokens, decode_idx,
                 logits_out, latents_out, defer=defer_fetch))
+        if rides:
+            launches.append(functools.partial(
+                self._launch_step, batch_uids, batch_tokens, riders,
+                groups.pop(self._step_T), logits_out, latents_out))
         for T, idx in sorted(groups.items()):
             launches.append(functools.partial(
                 self._launch_prefill, batch_uids, batch_tokens, idx, T,
@@ -666,13 +698,8 @@ class InferenceEngineV2:
         ``span``, which is told what the lanes' description cost in
         transfers. A trunk with recurrent layers also gets each lane's
         state slot, blank lanes the spare one."""
-        lanes = (tok, start, tables, t_len)
-        if self.recurrent:
-            slots = np.full((len(t_len),), self.state.state_slots,
-                            np.int32)
-            for j, i in enumerate(idx):
-                slots[j] = self.state.get_sequence(uids[i]).state_slot
-            lanes += (slots,)
+        lanes = (tok, start, tables, t_len) + self._slots(uids, idx,
+                                                           len(t_len))
         stats = self.model.dispatch_stats
         arrays, nbytes = stats["h2d_arrays"], stats["h2d_bytes"]
         out = self.model.forward_chunk(self.cache, *lanes)
@@ -680,12 +707,117 @@ class InferenceEngineV2:
                  h2d_bytes=stats["h2d_bytes"] - nbytes)
         if chained:
             self._count_chained(span)
+        self._keep_router_probes(uids, idx)
+        return out
+
+    def _keep_router_probes(self, uids, idx, first=0):
+        """Remember, for the uids that asked, the latest forward's
+        router inputs and each one's lane in them (``idx`` are lanes
+        ``first`` onwards)."""
         if self.router_probe_uids:
             for j, i in enumerate(idx):
                 if uids[i] in self.router_probe_uids:
                     self._router_probes[uids[i]] = (
-                        self.model.router_probe, j)
-        return out
+                        self.model.router_probe, first + j)
+
+    def _decode_lanes(self, uids, tokens, idx, B):
+        """The lanes of a decode dispatch of bucket ``B``."""
+        tok, start, t_len, tables = self._blank_lanes(B)
+        if idx:
+            tables[:len(idx)] = self._tables(idx, uids)
+        for j, i in enumerate(idx):
+            tok[j, 0] = tokens[i][0]
+            start[j] = self.state.get_sequence(uids[i]).seen_tokens
+            t_len[j] = 1
+        return tok, start, tables, t_len
+
+    def _slice_lanes(self, uids, tokens, idx, B, T):
+        """The lanes of a prefill dispatch of ``B`` lanes of ``T``."""
+        tok, start, t_len, tables = self._blank_lanes(B, T)
+        tables[:len(idx)] = self._tables(idx, uids)
+        for j, i in enumerate(idx):
+            seq = self.state.get_sequence(uids[i])
+            tok[j, :len(tokens[i])] = tokens[i]
+            start[j] = seq.seen_tokens
+            t_len[j] = len(tokens[i])
+        return tok, start, tables, t_len
+
+    def _slots(self, uids, idx, B):
+        """``((slots [B],),)`` of a recurrent trunk's lanes, the blank
+        ones at the spare slot; ``()`` for any other trunk."""
+        if not self.recurrent:
+            return ()
+        slots = np.full((B,), self.state.state_slots, np.int32)
+        for j, i in enumerate(idx):
+            slots[j] = self.state.get_sequence(uids[i]).state_slot
+        return (slots,)
+
+    # -------------------------------------------------------------- #
+    # A step that holds decode lanes and a prompt slice: one program
+    # -------------------------------------------------------------- #
+    def _rides_decode(self, groups) -> bool:
+        """Whether the put's prefill ``groups`` hold one lane of the
+        slice shape that rides the decode lanes' program
+        (``_step_T``): decided by the step's shape, and by one slice
+        shape, so that an engine has one such program and not one a pair
+        of buckets. Such a slice with no decode lane beside it (or with
+        more than ``_step_B``, which keep their own program in front)
+        takes the step program too, on blank decode lanes (eight rows
+        more): that shape has one program wherever a put meets it, which
+        a warm-up of each shape alone builds. Every other group (a short
+        tail, two prompts' slices; ``defer_fetch``, which chunked
+        prefill refuses) keeps a program of its own, chained behind."""
+        return self._step_T and len(groups.get(self._step_T, ())) == 1
+
+    def _launch_step(self, uids, tokens, d_idx, s_idx, logits_out,
+                     latents_out, chained=False):
+        """:meth:`_launch_decode` and :meth:`_launch_prefill` of one
+        slice lane as one program (``model.forward_step``): the weights
+        are read once for the rows of both groups. One enqueue span, the
+        slice's, with ``decode_lanes`` and, where there are any,
+        ``fused=1``; one wait and one fetch, the decode lanes' logits
+        rows first. The put's first program unless its decode lanes were
+        too many to ride (``chained`` then); other prefill groups of the
+        put are chained behind it."""
+        tracer = get_tracer()
+        B, T = self._step_B, self._step_T
+        fused = {"fused": 1} if d_idx else {}
+        with tracer.span("serve.batch_build", bucket=B):
+            decode = self._decode_lanes(uids, tokens, d_idx, B) + \
+                self._slots(uids, d_idx, B)
+            slice_ = self._slice_lanes(uids, tokens, s_idx, 1, T) + \
+                self._slots(uids, s_idx, 1)
+        with tracer.span("serve.prefill_dispatch", lanes=len(s_idx),
+                         bucket=1, bucket_T=T, decode_lanes=len(d_idx),
+                         **fused, tokens=_token_count(tokens[i] for i in s_idx)
+                         if tracer.enabled else 0) as span:
+            stats = self.model.dispatch_stats
+            arrays, nbytes = stats["h2d_arrays"], stats["h2d_bytes"]
+            logits, (lat_d, lat_s) = self.model.forward_step(
+                self.cache, decode, slice_)
+            span.set(h2d_arrays=stats["h2d_arrays"] - arrays,
+                     h2d_bytes=stats["h2d_bytes"] - nbytes)
+            self._fused += bool(d_idx)
+            if chained:
+                self._count_chained(span)
+            self._keep_router_probes(uids, d_idx)
+            self._keep_router_probes(uids, s_idx, first=B)
+            lat_d = self._start_copies(logits, lat_d)
+            lat_s = self._start_copies(logits, lat_s, fetch=False)
+
+        def collect():
+            rows = self._fetch(logits, lat_d, beside=lat_s)
+            with tracer.span("serve.scatter"):
+                for j, i in enumerate(d_idx):
+                    logits_out[i] = rows[j]
+                    if lat_d is not None:                  # [L, B, 1, H]
+                        latents_out[i] = self._hand_out(lat_d, j, 1)
+                for j, i in enumerate(s_idx):
+                    logits_out[i] = rows[B + j]
+                    if lat_s is not None:                  # [L, 1, T, H]
+                        latents_out[i] = self._hand_out(
+                            lat_s, j, len(tokens[i]))
+        return collect
 
     def _launch_decode(self, uids, tokens, idx, logits_out, latents_out,
                        defer=False, chained=False):
@@ -696,12 +828,8 @@ class InferenceEngineV2:
         tracer = get_tracer()
         B = _bucket(len(idx))
         with tracer.span("serve.batch_build", bucket=B):
-            tok, start, t_len, tables = self._blank_lanes(B)
-            tables[:len(idx)] = self._tables(idx, uids)
-            for j, i in enumerate(idx):
-                tok[j, 0] = tokens[i][0]
-                start[j] = self.state.get_sequence(uids[i]).seen_tokens
-                t_len[j] = 1
+            tok, start, tables, t_len = self._decode_lanes(uids, tokens,
+                                                           idx, B)
         with tracer.span("serve.decode_dispatch",
                          lanes=len(idx), bucket=B) as span:
             logits, latents = self._forward(span, tok, start, tables,
@@ -730,13 +858,8 @@ class InferenceEngineV2:
         tracer = get_tracer()
         B = _bucket(len(idx), minimum=1)
         with tracer.span("serve.batch_build", bucket=B):
-            tok, start, t_len, tables = self._blank_lanes(B, T)
-            tables[:len(idx)] = self._tables(idx, uids)
-            for j, i in enumerate(idx):
-                seq = self.state.get_sequence(uids[i])
-                tok[j, :len(tokens[i])] = tokens[i]
-                start[j] = seq.seen_tokens
-                t_len[j] = len(tokens[i])
+            tok, start, tables, t_len = self._slice_lanes(uids, tokens,
+                                                          idx, B, T)
         with tracer.span("serve.prefill_dispatch",
                          lanes=len(idx), bucket=B, bucket_T=T,
                          tokens=_token_count(tokens[i] for i in idx)
@@ -777,22 +900,28 @@ class InferenceEngineV2:
         self._latent_launched += program.nbytes
         return program
 
-    def _fetch(self, logits, program, fetch=True):
+    def _fetch(self, logits, program, fetch=True, beside=None):
         """A dispatch's logits on the host. In the time the device
         needs for the program, what earlier programs left pending is
         landed (``serve.latents.land``); then the wait for the device
         (``serve.device_wait``) and the logits' copy
         (``serve.fetch``; none, and ``None`` back, when ``fetch`` is
-        false). The latents stay where they are: ``program`` is only
-        told when its copy could start."""
+        false). The latents stay where they are: ``program`` (and
+        ``beside``, the second array of a dispatch of two lane groups)
+        is only told when its copy could start."""
         tracer = get_tracer()
+        held = None
         if program is not None:
-            self._latent_launched -= program.nbytes
-        self._land_pending(logits, program)
+            held = program.nbytes + (
+                0 if beside is None else beside.nbytes)
+            self._latent_launched -= held
+        self._land_pending(logits, held)
         with tracer.span("serve.device_wait"):
             logits.block_until_ready()
         if program is not None:     # two assignments: the parent's time
             self._latent_link.enqueue(program, time.perf_counter())
+            if beside is not None:
+                self._latent_link.enqueue(beside, time.perf_counter())
         if not fetch:
             return None
         with tracer.span("serve.fetch",
@@ -965,22 +1094,23 @@ class InferenceEngineV2:
         self._latent_parts = deque(ref for ref, _ in live)
         return [part for _, part in live]
 
-    def _land_pending(self, in_flight, new_program) -> None:
+    def _land_pending(self, in_flight, new_bytes) -> None:
         """Copy what earlier programs left pending into the stores that
         adopted it, oldest first, while ``in_flight`` (this dispatch's
         logits) is not ready; what is left waits for the next dispatch.
         A program whose copy the link's model does not expect yet is
         left alone, and so is everything behind it — unless the
-        latents still on the device, ``new_program``'s among them, hold
+        latents still on the device, the ``new_bytes`` of this
+        dispatch's program among them (``None``: it captured none), hold
         more than ``_PENDING_PROGRAMS`` of the largest program: then
         the oldest copies are waited for, as every dispatch did before
         landing was deferred. Programs of this put enqueued behind
-        ``new_program`` count in the peak from their launch and against
+        this one count in the peak from their launch and against
         that bound when their own collect comes, so nothing is waited
         for that was not before launches were chained."""
         held = 0
-        if new_program is not None:
-            held = new_program.nbytes
+        if new_bytes is not None:
+            held = new_bytes
             self._latent_program_max = max(self._latent_program_max, held)
         behind = self._latent_launched
         if not self._latent_parts:
@@ -996,7 +1126,7 @@ class InferenceEngineV2:
             self._latent_pending_peak = max(self._latent_pending_peak,
                                             held + behind)
             over = held - self._PENDING_PROGRAMS * self._latent_program_max
-            if new_program is not None and over > 0:
+            if new_bytes is not None and over > 0:
                 self._force_pending(parts, over)
             link, now = self._latent_link, time.perf_counter()
             nbytes = chunks = 0
@@ -1079,8 +1209,12 @@ class InferenceEngineV2:
         handed over. ``chained``: those of :meth:`put`'s that were
         enqueued while an earlier program of the same put had not been
         collected (:meth:`_dispatch`), which the device reaches without
-        a host turn; over ``dispatches``, their share."""
-        return dict(self.model.dispatch_stats, chained=self._chained)
+        a host turn; over ``dispatches``, their share. ``fused``: the
+        programs that carried a step's decode lanes and its prompt slice
+        together (:meth:`_launch_step`): over ``fused + chained``, the
+        share of two-group steps that took one program."""
+        return dict(self.model.dispatch_stats, chained=self._chained,
+                    fused=self._fused)
 
     def paged_walk_stats(self) -> Dict[str, int]:
         """How much of the block tables the paged kernel's walk covers:

@@ -43,13 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import LlamaConfig
-from ..ops.kv_write import flat_slots, kv_write, write_rows
+from ..ops.kv_write import kv_write, write_rows
 from ..ops.paged_attention import paged_attention
 from ..ops.rms_norm import reference_rms_norm, rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.topology import TENSOR_AXIS
 from ..telemetry.tracer import get_tracer
-from .ragged.lanes import pack_lanes, unpack_lanes
+from .ragged.lanes import (Lanes, pack_lanes, pack_step, unpack_lanes,
+                           unpack_step)
 
 
 def join_path(path):
@@ -192,7 +193,10 @@ class PagedInferenceModel:
         #: the forward over separate lane arrays, for the fused loops'
         #: bodies; a dispatch goes through ``_fwd``, over packed lanes
         self._fwd_inner = self._manual_tp(self._forward_chunk, 4, 2)
-        self._fwd = self._lane_program(self._forward_chunk, 2)
+        self._fwd = self._chunk_program()
+        #: the programs of a step's lane groups, by the groups' shapes
+        #: (``forward_step``)
+        self._fwd_step_cache = {}
         self._restore = jax.jit(
             self._manual_tp(self._restore_chunk, 5, 0),
             donate_argnums=(1, 2))
@@ -506,21 +510,36 @@ class PagedInferenceModel:
             check_vma=False)
 
     def _lane_program(self, fwd, results: int, pools: int = 2,
-                      column=None):
+                      column=None, shapes=None):
         """The jitted program of one dispatch: ``fwd(params, *pools,
         tokens, start, tables, t_len[, column])`` with the lanes as one
         packed operand (``ragged/lanes.py``), cut inside the program;
         the pools donated. ``column``: the lanes carry a fifth column (a
         recurrent trunk's state slots, a block dispatch's flags);
-        default: as the trunk is. Named after ``fwd`` in a profile."""
+        default: as the trunk is. ``shapes``: the operand holds several
+        lane groups of these ``(B, T)`` (``pack_step``) and ``fwd`` takes
+        every group's columns, one group after the other. Named after
+        ``fwd`` in a profile."""
         column = self.recurrent if column is None else column
+        blocks = self.max_blocks_per_seq
 
         def program(params, *operands):
-            return fwd(params, *operands[:pools], *unpack_lanes(
-                operands[pools], self.max_blocks_per_seq, column))
+            lanes = operands[pools]
+            columns = unpack_lanes(lanes, blocks, column) \
+                if shapes is None else [c for group in unpack_step(
+                    lanes, shapes, blocks, column) for c in group]
+            return fwd(params, *operands[:pools], *columns)
         program.__name__ = fwd.__name__
         return jax.jit(self._manual_tp(program, 1, results),
                        donate_argnums=tuple(range(1, 1 + pools)))
+
+    def _chunk_program(self, shapes=None):
+        """The program of a forward over one lane group, or over the
+        groups ``shapes`` of a step: ``(pools..., logits, latents a
+        group)``."""
+        groups = 1 if shapes is None else len(shapes)
+        return self._lane_program(self._forward_chunk, 1 + groups,
+                                  shapes=shapes)
 
     # -------------------------------------------------------------- #
     # Layer math (mirrors models/llama.py LlamaBlock exactly)
@@ -566,39 +585,47 @@ class PagedInferenceModel:
         k = apply_rope(k, self.cos, self.sin, positions)
         return q, k, v
 
-    def _scatter_kv(self, ck, cv, layer, k, v, flat_idx, tables, start,
-                    kv_len):
-        """ck/cv: the whole [L, KV, P, D] pools; k/v: [B, T, KV, D] of
-        ``layer``, the rows of positions ``start + [0, T)`` of each lane;
-        those before ``kv_len`` are written to their slots by ``tables``
+    def _scatter_kv(self, ck, cv, layer, k, v, lanes):
+        """ck/cv: the whole [L, KV, P, D] pools; k/v: the rows of
+        ``layer`` of all of ``lanes``; a group's are [B, T, KV, D], the
+        rows of positions ``start + [0, T)`` of each lane: those before
+        ``kv_len`` are written to their slots by ``tables``
         (``flat_idx`` [B, T]: the same slots, reckoned once a program),
-        the rest (padding) are not. One write at the granularity its
-        shape wants (``ops/kv_write.py``): a lane of a decode program is
+        the rest (padding) are not. One write a group at the granularity
+        its shape wants (``ops/kv_write.py``): a lane of decode lanes is
         one row in a block of its own, a row an update; a lane of ``T``
         positions is runs of consecutive slots, a block an update."""
-        if k.shape[1] > 1:
-            return kv_write(ck, cv, k, v, layer, tables, start, kv_len,
-                            self.block_size)
-        return write_rows(ck, cv, layer, k, v, flat_idx)
+        for g, kg, vg in zip(lanes.groups, lanes.split(k), lanes.split(v)):
+            start = g.positions[:, 0]   # consecutive positions a lane
+            if kg.shape[1] > 1:
+                ck, cv = lanes.shared(kv_write, (8,))(
+                    ck, cv, kg, vg, layer, g.tables, start, g.kv_len,
+                    self.block_size)
+            else:
+                ck, cv = write_rows(ck, cv, layer, kg, vg, g.flat_idx)
+        return ck, cv
 
-    def _paged_attention(self, q, ck, cv, layer, tables, q_positions,
-                         kv_len):
-        """q: [B, T, Hq, D]; ck/cv: the whole [L, KV, P, D] pools, read at
-        ``layer``; tables: [B, NB]; q_positions: [B, T] absolute; kv_len:
-        [B] valid cache length. Returns [B, T, Hq*D].
+    def _paged_attention(self, q, ck, cv, layer, lanes):
+        """q: the rows of all of ``lanes``, a group's [B, T, Hq, D];
+        ck/cv: the whole [L, KV, P, D] pools, read at ``layer`` by each
+        group's tables [B, NB], absolute positions [B, T] and valid
+        cache length [B]. Returns the rows' [.., Hq*D].
 
         Dispatches to the Pallas ragged paged-attention kernel
-        (``ops/paged_attention.py`` — the blocked_flash analog): block-
-        table-indexed flash over valid blocks only, no dense [B, S_max]
-        gather, no GQA repeat."""
-        B, T, Hq, D = q.shape
-        start = q_positions[:, 0]  # chunk rows are consecutive positions
-        out = paged_attention(q, ck, cv, layer, tables, start, kv_len,
-                              self.block_size, self.mask_block)
-        return out.reshape(B, T, Hq * D)
+        (``ops/paged_attention.py`` — the blocked_flash analog), a call
+        a group at the group's shape: block-table-indexed flash over
+        valid blocks only, no dense [B, S_max] gather, no GQA repeat."""
+        outs = []
+        for g, qg in zip(lanes.groups, lanes.split(q)):
+            B, T, Hq, D = qg.shape
+            start = g.positions[:, 0]
+            out = lanes.shared(paged_attention, (7, 8))(
+                qg, ck, cv, layer, g.tables, start, g.kv_len,
+                self.block_size, self.mask_block)
+            outs.append(out.reshape(B, T, Hq * D))
+        return lanes.join(outs)
 
-    def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
-                    flat_idx, kv_len):
+    def _layer_step(self, x, lp, ck, cv, layer, lanes):
         cfg = self.cfg
         # fp32 norm weights promote under standard dtype rules — pin the
         # residual stream to the compute dtype
@@ -607,25 +634,23 @@ class PagedInferenceModel:
         latent = h.astype(self.latent_dtype) \
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
-        q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
-                                  positions[:, 0], kv_len)
-        attn = self._paged_attention(q, ck, cv, layer, tables, positions,
-                                     kv_len)
+        q, k, v = self._qkv(lp, h, lanes.positions)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, lanes)
+        attn = self._paged_attention(q, ck, cv, layer, lanes)
         proj = self._mm(attn, lp["self_attn"]["o_proj"]["kernel"])
         if self.tp > 1:   # row-parallel partial sum (reference :160)
             proj = jax.lax.psum(proj, TENSOR_AXIS)
         x = x + proj
         h2 = rms_norm(x, lp["post_attention_layernorm"]["weight"],
                       eps=cfg.rms_norm_eps).astype(cfg.compute_dtype)
-        mlp, stats = self._mlp(lp, h2, flat_idx, ck.shape[2])
+        mlp, stats = self._mlp(lp, h2, lanes, ck.shape[2])
         x = x + mlp
         return x.astype(cfg.compute_dtype), ck, cv, latent, stats
 
-    def _mlp(self, lp, h2, flat_idx, pool_slots):
+    def _mlp(self, lp, h2, lanes, pool_slots):
         """:meth:`_mlp_out` and what the layer has to say of itself, a
         dict of arrays that the layer scan stacks for
-        :meth:`forward_block` (``flat_idx`` [B, T] below ``pool_slots``
+        :meth:`forward_block` (``lanes.flat_idx`` below ``pool_slots``
         are the real positions; the rest is padding): nothing here; the
         router's input and the picks an expert in the MoE family
         (``model_moe.py``)."""
@@ -645,32 +670,29 @@ class PagedInferenceModel:
     # -------------------------------------------------------------- #
     # forward_chunk: the one compiled family (prefill & ragged decode)
     # -------------------------------------------------------------- #
-    def _trunk(self, params, cache_k, cache_v, tokens, start, tables,
-               t_len):
+    def _trunk(self, params, cache_k, cache_v, *columns):
         """Embed → layer scan → final norm: the shared body of the
-        chunk forwards. Returns (params', cache_k', cache_v',
-        x [B, T, H] normed hidden states, latents)."""
+        chunk forwards, over the lanes ``columns`` (tokens, start,
+        tables, t_len of one group, or of each group of a step). Returns
+        (params', cache_k', cache_v', x [B, T, H] normed hidden states,
+        latents, the layers' stats, the lanes): of several groups ``x``
+        is ``[1, N, H]`` and the latents ``[L, 1, N, H]``, all rows, to
+        be cut by the :class:`~.ragged.lanes.Lanes`."""
         from ..ops.quantizer import dequantize_tree
         # non-layer leaves (head) dequantize here; the stacked layers stay
         # int8 and dequantize ONE layer at a time inside the scan step —
         # resident HBM holds int8 weights + one bf16 layer, not L of them
         params = {k: (v if k == "layers" else dequantize_tree(v))
                   for k, v in params.items()}
-        T = tokens.shape[1]
-        positions = start[:, None] + jnp.arange(T)[None, :]     # [B, T]
-        x = self._embed_lookup(params["embed"], tokens) + \
-            self._embed_extra(params, positions)
-        kv_len = start + t_len
-        flat_idx = flat_slots(tables, start, t_len, T, self.block_size,
-                              cache_k.shape[2])
+        lanes = Lanes.of(columns)
+        x = self._embed_lanes(params, lanes, cache_k.shape[2])
 
         scanned, whole = self._whole_layers(params["layers"])
         # layers of another kind that lead the stack (a dense layer
         # before sparse ones) run before the scan, at the first layers
         # of the pools
         x, cache_k, cache_v, lead = self._lead_layers(
-            params, x, cache_k, cache_v, tables, positions, flat_idx,
-            kv_len)
+            params, x, cache_k, cache_v, lanes)
         n_lead = len(lead)
 
         # the pools are carried, never scanned over: a scanned-over pool
@@ -683,8 +705,7 @@ class PagedInferenceModel:
             if whole is not None:
                 lp = self._with_whole(lp, whole, layer)
             x, ck, cv, latent, stats = self._layer_step(
-                x, lp, ck, cv, layer + n_lead if n_lead else layer,
-                tables, positions, flat_idx, kv_len)
+                x, lp, ck, cv, layer + n_lead if n_lead else layer, lanes)
             # a layer with nothing to say adds nothing to the loop
             return (x, ck, cv), (latent, stats)
 
@@ -695,10 +716,22 @@ class PagedInferenceModel:
             latents = jnp.concatenate([jnp.stack(lead), latents])
 
         x = self._final_norm(params, x)
-        return params, cache_k, cache_v, x, latents, stats
+        return params, cache_k, cache_v, x, latents, stats, lanes
 
-    def _lead_layers(self, params, x, cache_k, cache_v, tables, positions,
-                     flat_idx, kv_len):
+    def _embed_lanes(self, params, lanes, pool_slots):
+        """The embedded rows of ``lanes`` ([B, T, H]; [1, N, H] of
+        several groups), and on every group and on ``lanes`` (all rows)
+        what the layers read of them: ``positions``, ``kv_len``,
+        ``flat_idx``."""
+        lanes.place_positions()
+        x = self._embed_lookup(params["embed"], lanes.rows("tokens"))
+        extra = self._embed_extra(params, lanes.positions)
+        if extra is not None:
+            x = x + extra
+        lanes.place_slots(self.block_size, pool_slots)
+        return x
+
+    def _lead_layers(self, params, x, cache_k, cache_v, lanes):
         """The layers that run before the layer scan, unrolled: ``(x,
         cache_k, cache_v, their latents)``. None here; the leading dense
         layers of a trunk whose other layers are sparse
@@ -718,23 +751,26 @@ class PagedInferenceModel:
         index put where the layer looks for them."""
         raise NotImplementedError
 
-    def _forward_chunk(self, params, cache_k, cache_v, tokens, start,
-                       tables, t_len):
-        """tokens: [B, T] int32; start: [B] first absolute position;
-        tables: [B, NB]; t_len: [B] valid new tokens (≤ T).
-        Returns (cache_k', cache_v', logits [B, V], latents [L, B, T, H])."""
-        params, cache_k, cache_v, x, latents, _ = self._trunk(
-            params, cache_k, cache_v, tokens, start, tables, t_len)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(t_len - 1, 0)[:, None, None], axis=1)[:, 0]
-        logits = self._head_logits(params, last)
+    def _forward_chunk(self, params, cache_k, cache_v, *columns):
+        """``columns``: tokens [B, T] int32; start [B] first absolute
+        position; tables [B, NB]; t_len [B] valid new tokens (≤ T).
+        Returns (cache_k', cache_v', logits [B, V], latents [L, B, T, H]).
+
+        The same four columns once more a further lane group (a step's
+        decode lanes ``[B_d, 1]``, then its prompt slice ``[B_s, T]``):
+        one pass over the weights for all rows, each group's write and
+        attention at its own shape; the logits are the groups' lanes one
+        after the other, the latents an array a group."""
+        params, cache_k, cache_v, x, latents, _, lanes = self._trunk(
+            params, cache_k, cache_v, *columns)
+        logits = self._head_logits(params, lanes.last_rows(x))
         if self.tp > 1:
             # vocab is sharded either way (tied: rows of the table;
             # untied: head columns) — gather the full logits row
             # (reference: allgather logits if tp>1, llama_v2/model.py:181)
             logits = jax.lax.all_gather(logits, TENSOR_AXIS, axis=1,
                                         tiled=True)
-        return cache_k, cache_v, logits, latents
+        return (cache_k, cache_v, logits, *lanes.split(latents, lead=1))
 
     def _forward_chunk_tail(self, params, cache_k, cache_v, tokens,
                             start, tables, t_len, tail):
@@ -745,7 +781,7 @@ class PagedInferenceModel:
         (cache_k', cache_v', logits [B, tail, V]); positions before a
         short sequence's first valid slot clamp to 0 and the caller
         masks by its own accept arithmetic."""
-        params, cache_k, cache_v, x, _latents, _ = self._trunk(
+        params, cache_k, cache_v, x, *_ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         idx = jnp.maximum(
             t_len[:, None] - tail + jnp.arange(tail)[None, :], 0)  # [B,tail]
@@ -765,7 +801,7 @@ class PagedInferenceModel:
         and discards the rolled-back tail. A separate compiled family
         (``_fwd_tail_lat_cache``): engines running exact-KV suspension
         never pay for the latent output."""
-        params, cache_k, cache_v, x, latents, _ = self._trunk(
+        params, cache_k, cache_v, x, latents, *_ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         idx = jnp.maximum(
             t_len[:, None] - tail + jnp.arange(tail)[None, :], 0)
@@ -798,7 +834,7 @@ class PagedInferenceModel:
         lanes first, as a tuple of the first 8, 16, ... B lanes ``[L, n,
         T, H]``, so that the caller sends the host the bucket that holds
         its committing lanes and no second program cuts it."""
-        params, cache_k, cache_v, x, latents, stats = self._trunk(
+        params, cache_k, cache_v, x, latents, stats, _ = self._trunk(
             params, cache_k, cache_v, tokens, start, tables, t_len)
         B, T, H = x.shape
         # positions as rows from here on: a [B, T, V] result with T = 4
@@ -870,7 +906,7 @@ class PagedInferenceModel:
 
     def _embed_extra(self, params, positions):
         """Additive embedding term (learned positions in the gpt2/opt
-        trunk); rope families add nothing here."""
+        trunk); rope families add nothing here (``None``: no term)."""
         return jnp.zeros((), self.cfg.compute_dtype)
 
     def _embed_lookup(self, table, tokens):
@@ -909,6 +945,13 @@ class PagedInferenceModel:
         ``jnp.asarray`` in front would be ``device_put``'s Python on top
         of it). Counted in ``dispatch_stats``, ``kv_write_stats`` and
         ``paged_walk_stats``."""
+        self._count_lanes(tokens, start, tables, t_len)
+        return self._hand_over(program, pools, pack_lanes(
+            tokens, start, tables, t_len, slots))
+
+    def _count_lanes(self, tokens, start, tables, t_len, *_):
+        """One lane group of a dispatch in ``kv_write_stats`` and
+        ``paged_walk_stats``."""
         self._count_kv_write(np.shape(tokens)[1], np.sum(t_len))
         walk = self.paged_walk_stats
         walk["dispatches"] += 1
@@ -916,7 +959,10 @@ class PagedInferenceModel:
         # a padded lane starts at 0 with nothing: no block
         walk["blocks_walked"] += int(np.sum(
             -(-np.add(start, t_len) // self.block_size)))
-        lanes = pack_lanes(tokens, start, tables, t_len, slots)
+
+    def _hand_over(self, program, pools, lanes):
+        """``program`` over ``pools`` and the packed host array
+        ``lanes``, counted in ``dispatch_stats``."""
         stats = self.dispatch_stats
         stats["dispatches"] += 1
         stats["h2d_arrays"] += 1
@@ -926,6 +972,39 @@ class PagedInferenceModel:
     def forward_chunk(self, cache, tokens, start, tables, t_len):
         ck, cv, logits, latents = self._enqueue(
             self._fwd, (cache.k, cache.v), tokens, start, tables, t_len)
+        cache.replace(ck, cv)
+        return logits, latents
+
+    # -------------------------------------------------------------- #
+    # A step's lane groups in one program
+    # -------------------------------------------------------------- #
+    def step_program(self, shapes):
+        """The program over lane groups of ``shapes`` ``((B, T), ...)``:
+        built once a tuple of shapes."""
+        program = self._fwd_step_cache.get(shapes)
+        if program is None:
+            program = self._fwd_step_cache[shapes] = \
+                self._chunk_program(shapes)
+        return program
+
+    def _enqueue_step(self, pools, groups):
+        """One dispatch of :meth:`step_program` over ``groups`` (each
+        the lane arrays of :meth:`forward_chunk`), packed into one host
+        array (``ragged/lanes.py pack_step``)."""
+        program = self.step_program(tuple(
+            np.shape(group[0]) for group in groups))
+        lanes = pack_step(groups)
+        for group in groups:
+            if np.any(group[3]):    # a group of blank lanes wrote nothing
+                self._count_lanes(*group)
+        return self._hand_over(program, pools, lanes)
+
+    def forward_step(self, cache, *groups):
+        """:meth:`forward_chunk` over several lane groups in one
+        program. Returns ``(logits [lanes of all groups, V], [latents
+        [L, B, T, H] a group])``."""
+        ck, cv, logits, *latents = self._enqueue_step(
+            (cache.k, cache.v), groups)
         cache.replace(ck, cv)
         return logits, latents
 
@@ -1001,13 +1080,19 @@ class PagedInferenceModel:
         # ever materialized full-precision
         lp = jax.tree.map(lambda p: p[layer], params["layers"])
         lp = dequantize_tree(lp)
-        positions = start[:, None] + jnp.arange(latent.shape[1])[None, :]
+        lanes = self._restore_lanes(latent, start, tables, t_len,
+                                    cache_k.shape[2])
         _, k, v = self._qkv(lp, latent.astype(self.cfg.compute_dtype),
-                            positions)
-        flat_idx = flat_slots(tables, start, t_len, latent.shape[1],
-                              self.block_size, cache_k.shape[2])
-        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx,
-                                tables, start, start + t_len)
+                            lanes.positions)
+        return self._scatter_kv(cache_k, cache_v, layer, k, v, lanes)
+
+    def _restore_lanes(self, latent, start, tables, t_len, pool_slots):
+        """The lanes whose saved ``latent`` [B, T, ...] a restore
+        replays, as a layer's write takes them."""
+        lanes = Lanes.of((latent, start, tables, t_len))
+        lanes.place_positions()
+        lanes.place_slots(self.block_size, pool_slots)
+        return lanes
 
     # -------------------------------------------------------------- #
     # Fused decode loop: N greedy steps in ONE device program

@@ -67,8 +67,7 @@ class PagedFalconModel(PagedInferenceModel):
     def _final_norm(self, params, x):
         return self._ln(x, params["norm"], self.cfg.layer_norm_epsilon)
 
-    def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
-                    flat_idx, kv_len):
+    def _layer_step(self, x, lp, ck, cv, layer, lanes):
         """Parallel residual (falcon-7b): x + attn(h) + mlp(h) with ONE
         shared input LayerNorm h."""
         cfg = self.cfg
@@ -76,11 +75,9 @@ class PagedFalconModel(PagedInferenceModel):
         latent = h.astype(self.latent_dtype) \
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
-        q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
-                                  positions[:, 0], kv_len)
-        attn = self._paged_attention(q, ck, cv, layer, tables, positions,
-                                     kv_len)
+        q, k, v = self._qkv(lp, h, lanes.positions)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, lanes)
+        attn = self._paged_attention(q, ck, cv, layer, lanes)
         attn = self._mm(attn, lp["self_attn"]["o_proj"]["kernel"])
         up = self._mm(h, lp["dense_h_to_4h"]["kernel"])
         mlp = self._mm(jax.nn.gelu(up), lp["dense_4h_to_h"]["kernel"])

@@ -105,19 +105,16 @@ class PagedGPT2Model(PagedInferenceModel):
                          m["c_fc"]["bias"], approximate=True)
         return self._mm(ff, m["c_proj"]["kernel"]), m["c_proj"]["bias"]
 
-    def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
-                    flat_idx, kv_len):
+    def _layer_step(self, x, lp, ck, cv, layer, lanes):
         cfg = self.cfg
         eps = cfg.layer_norm_epsilon
         h = self._ln(x, lp["ln_1"], eps)
         latent = h.astype(self.latent_dtype) \
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
-        q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
-                                  positions[:, 0], kv_len)
-        attn = self._paged_attention(q, ck, cv, layer, tables, positions,
-                                     kv_len)
+        q, k, v = self._qkv(lp, h, lanes.positions)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, lanes)
+        attn = self._paged_attention(q, ck, cv, layer, lanes)
         ap, ab = self._attn_out_parts(lp, attn)
         if self.tp > 1:
             ap = jax.lax.psum(ap, TENSOR_AXIS)

@@ -41,9 +41,9 @@ import jax.numpy as jnp
 
 from ..models.olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
 from ..ops.gated_delta import gated_delta_rule
-from ..ops.kv_write import flat_slots
 from ..ops.rms_norm import reference_rms_norm, rms_norm
 from .model import PagedInferenceModel, stack_layer_params
+from .ragged.lanes import Lanes
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -106,7 +106,6 @@ class PagedHybridModel(PagedInferenceModel):
         super().__init__(cfg, params, topology=None, quantization=None,
                          **kw)
         self.n_latent_layers = self.n_periods * self.full_per
-        self._fwd = self._lane_program(self._forward_chunk, 2, pools=4)
 
     # -------------------------------------------------------------- #
     def load_params(self, params):
@@ -128,17 +127,14 @@ class PagedHybridModel(PagedInferenceModel):
         return x + rms_norm(y, weight, eps=self.cfg.rms_norm_eps) \
             .astype(self.cfg.compute_dtype)
 
-    def _full_step(self, x, lp, ck, cv, layer, tables, positions,
-                   flat_idx, kv_len):
+    def _full_step(self, x, lp, ck, cv, layer, lanes):
         cfg = self.cfg
         attn = lp["self_attn"]
         latent = x.astype(self.latent_dtype) if self.capture_latents \
             else jnp.zeros((x.shape[0], x.shape[1], 0), x.dtype)
         q, k, v = self._full_qkv(attn, x)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
-                                  positions[:, 0], kv_len)
-        y = self._paged_attention(q, ck, cv, layer, tables, positions,
-                                  kv_len)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, lanes)
+        y = self._paged_attention(q, ck, cv, layer, lanes)
         x = self._post(x, self._mm(y, attn["o_proj"]["kernel"]),
                        lp["post_attention_layernorm"]["weight"])
         x = self._post(x, self._mlp_out(lp, x),
@@ -158,21 +154,14 @@ class PagedHybridModel(PagedInferenceModel):
         return tuple(y.astype(cfg.compute_dtype).reshape(
             *y.shape[:-1], y.shape[-1] // D, D) for y in (q, k, v))
 
-    def _linear_step(self, x, lp, state, conv, layer, slots, start,
-                     t_len):
-        cfg = self.cfg
-        la = lp["linear_attn"]
-        B, T, _ = x.shape
-        H = cfg.linear_num_key_heads
-        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-        K = cfg.linear_conv_kernel_dim
+    def _causal_conv(self, la, pre, conv, layer, g):
+        """The causal depthwise convolution of one lane group's ``pre``
+        [B, T, C] over (tail | slice) by the taps of ``la``, and the
+        pool of tails with the group's new ones."""
+        B, T, _ = pre.shape
+        K = self.cfg.linear_conv_kernel_dim
         f32 = jnp.float32
-        *pre, gate = jax.lax.optimization_barrier(tuple(
-            self._mm(x, la[n]["kernel"])
-            for n in ("q_proj", "k_proj", "v_proj", "g_proj")))
-        pre = jnp.concatenate(pre, axis=-1)                 # [B, T, C]
-        # causal depthwise convolution over (tail | slice)
-        tail = jnp.where((start == 0)[:, None], 0, conv[layer, slots]) \
+        tail = jnp.where((g.start == 0)[:, None], 0, conv[layer, g.slots]) \
             .astype(pre.dtype).reshape(B, K - 1, -1)
         xx = jnp.concatenate([tail, pre], axis=1)           # [B,T+K-1,C]
         taps = jnp.concatenate(
@@ -182,9 +171,26 @@ class PagedHybridModel(PagedInferenceModel):
                             for j in range(K)))
         # the last K-1 real inputs: pads leave the tail where it was
         new_tail = jax.vmap(lambda a, n: jax.lax.dynamic_slice_in_dim(
-            a, n, K - 1, axis=0))(xx, t_len)
-        conv = conv.at[layer, slots].set(
+            a, n, K - 1, axis=0))(xx, g.t_len)
+        return y, conv.at[layer, g.slots].set(
             new_tail.astype(conv.dtype).reshape(B, -1))
+
+    def _linear_step(self, x, lp, state, conv, layer, lanes):
+        cfg = self.cfg
+        la = lp["linear_attn"]
+        B, T, _ = x.shape
+        H = cfg.linear_num_key_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        f32 = jnp.float32
+        *pre, gate = jax.lax.optimization_barrier(tuple(
+            self._mm(x, la[n]["kernel"])
+            for n in ("q_proj", "k_proj", "v_proj", "g_proj")))
+        pre = jnp.concatenate(pre, axis=-1)                 # [B, T, C]
+        ys = []
+        for g, pre_g in zip(lanes.groups, lanes.split(pre)):
+            y, conv = self._causal_conv(la, pre_g, conv, layer, g)
+            ys.append(y)
+        y = lanes.join(ys)
 
         def heads(a, d):
             return a.reshape(B, T, H, d)
@@ -203,8 +209,13 @@ class PagedHybridModel(PagedInferenceModel):
         g = -jnp.exp(la["A_log"].astype(f32)) * jax.nn.softplus(
             jnp.dot(x, la["a_proj"]["kernel"], preferred_element_type=f32)
             + la["dt_bias"].astype(f32))
-        o, state = gated_delta_rule(q, k, v, g, beta, state, layer, slots,
-                                    start, t_len)
+        rule, os = lanes.shared(gated_delta_rule), []
+        for grp, *qkvgb in zip(lanes.groups, *(
+                lanes.split(a) for a in (q, k, v, g, beta))):
+            o, state = rule(*qkvgb, state, layer, grp.slots, grp.start,
+                            grp.t_len)
+            os.append(o)
+        o = lanes.join(os)
         o = reference_rms_norm(o, la["o_norm"]["weight"],
                                cfg.rms_norm_eps)
         o = o * heads(jax.nn.silu(gate.astype(f32)), dv)
@@ -216,14 +227,12 @@ class PagedHybridModel(PagedInferenceModel):
         return x.astype(cfg.compute_dtype), state, conv
 
     # -------------------------------------------------------------- #
-    def _trunk(self, params, cache_k, cache_v, state, conv, tokens, start,
-               tables, t_len, slots):
-        T = tokens.shape[1]
-        positions = start[:, None] + jnp.arange(T)[None, :]
-        x = self._embed_lookup(params["embed"], tokens)
-        kv_len = start + t_len
-        flat_idx = flat_slots(tables, start, t_len, T, self.block_size,
-                              cache_k.shape[2])
+    def _embed_extra(self, params, positions):
+        return None
+
+    def _trunk(self, params, cache_k, cache_v, state, conv, *columns):
+        lanes = Lanes.of(columns, slot=True)
+        x = self._embed_lanes(params, lanes, cache_k.shape[2])
 
         # every pool is carried: a scanned-over pool is two buffers of
         # the loop (inference/model.py _trunk)
@@ -243,13 +252,13 @@ class PagedHybridModel(PagedInferenceModel):
                     layer = period * self.lin_per + jl
                     x, st, cn = self._linear_step(
                         x, layer_of(params["lin_layers"], layer), st, cn,
-                        layer, slots, start, t_len)
+                        layer, lanes)
                     jl += 1
                 else:
                     layer = period * self.full_per + jf
                     x, ck, cv, latent = self._full_step(
                         x, layer_of(params["full_layers"], layer), ck, cv,
-                        layer, tables, positions, flat_idx, kv_len)
+                        layer, lanes)
                     latents.append(latent)
                     jf += 1
             return (x, ck, cv, st, cn), jnp.stack(latents)
@@ -259,27 +268,38 @@ class PagedHybridModel(PagedInferenceModel):
             jnp.arange(self.n_periods))
         latents = latents.reshape(-1, *latents.shape[2:])   # [L_full, ...]
         x = self._final_norm(params, x)
-        return cache_k, cache_v, state, conv, x, latents
+        return cache_k, cache_v, state, conv, x, latents, lanes
 
-    def _forward_chunk(self, params, cache_k, cache_v, state, conv, tokens,
-                       start, tables, t_len, slots):
+    def _chunk_program(self, shapes=None):
+        groups = 1 if shapes is None else len(shapes)
+        return self._lane_program(self._forward_chunk, 1 + groups, pools=4,
+                                  shapes=shapes)
+
+    def _forward_chunk(self, params, cache_k, cache_v, state, conv,
+                       *columns):
         """``_forward_chunk`` of the base model with the two slot pools
-        and ``slots`` [B]. Returns ``(cache_k', cache_v', state', conv',
-        logits [B, V], latents [L_full, B, T, H])``."""
-        cache_k, cache_v, state, conv, x, latents = self._trunk(
-            params, cache_k, cache_v, state, conv, tokens, start, tables,
-            t_len, slots)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(t_len - 1, 0)[:, None, None], axis=1)[:, 0]
-        return (cache_k, cache_v, state, conv,
-                self._head_logits(params, last), latents)
+        and a fifth column, ``slots`` [B], a lane group. Returns
+        ``(cache_k', cache_v', state', conv', logits [B, V], latents
+        [L_full, B, T, H] a group)``."""
+        cache_k, cache_v, state, conv, x, latents, lanes = self._trunk(
+            params, cache_k, cache_v, state, conv, *columns)
+        logits = self._head_logits(params, lanes.last_rows(x))
+        return (cache_k, cache_v, state, conv, logits,
+                *lanes.split(latents, lead=1))
 
-    def forward_chunk(self, cache, tokens, start, tables, t_len, slots):
-        ck, cv, state, conv, logits, latents = self._enqueue(
-            self._fwd, (cache.k, cache.v, cache.state, cache.conv),
-            tokens, start, tables, t_len, slots)
+    def _keep_pools(self, cache, ck, cv, state, conv, *out):
         cache.replace(ck, cv)
         cache.replace_state(state, conv)
+        return out
+
+    def forward_chunk(self, cache, tokens, start, tables, t_len, slots):
+        return self._keep_pools(cache, *self._enqueue(
+            self._fwd, (cache.k, cache.v, cache.state, cache.conv),
+            tokens, start, tables, t_len, slots))
+
+    def forward_step(self, cache, *groups):
+        logits, *latents = self._keep_pools(cache, *self._enqueue_step(
+            (cache.k, cache.v, cache.state, cache.conv), groups))
         return logits, latents
 
     # -------------------------------------------------------------- #
@@ -291,10 +311,9 @@ class PagedHybridModel(PagedInferenceModel):
                             params["full_layers"]["self_attn"])
         _, k, v = self._full_qkv(attn,
                                  latent.astype(self.cfg.compute_dtype))
-        flat_idx = flat_slots(tables, start, t_len, latent.shape[1],
-                              self.block_size, cache_k.shape[2])
-        return self._scatter_kv(cache_k, cache_v, layer, k, v, flat_idx,
-                                tables, start, start + t_len)
+        return self._scatter_kv(
+            cache_k, cache_v, layer, k, v, self._restore_lanes(
+                latent, start, tables, t_len, cache_k.shape[2]))
 
     # -------------------------------------------------------------- #
     # What needs a state snapshot at a block boundary refuses by name

@@ -44,7 +44,6 @@ import numpy as np
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..moe.dropless import routed_expert_ffn
-from ..ops.kv_write import flat_slots
 from ..ops.latent_attention import latent_attention
 from ..ops.rms_norm import reference_rms_norm, rms_norm
 from ..ops.rope import rope_at
@@ -92,7 +91,6 @@ class PagedLatentModel(PagedMoEModel):
         self.r_width = cfg.qk_rope_head_dim
         self.n_lead = cfg.first_k_dense_replace
         super().__init__(cfg, params, **kw)
-        self._fwd = self._lane_program(self._forward_chunk_probed, 3)
 
     def pool_layout(self):
         """``(kv heads, k width, v width)`` of the two pools."""
@@ -194,16 +192,23 @@ class PagedLatentModel(PagedMoEModel):
                         h.dtype)
         return jnp.concatenate([q_abs, q_rope, pad], axis=-1), c, r
 
-    def _write_rows(self, ck, cv, layer, c, r, flat_idx, tables, start,
-                    kv_len):
+    def _write_rows(self, ck, cv, layer, c, r, lanes):
         """``c`` [B, T, C] and ``r`` [B, T, R] into the two pools."""
         r = jnp.pad(r, ((0, 0), (0, 0), (0, self.r_pool_width -
                                           self.r_width)))
         return self._scatter_kv(ck, cv, layer, c[:, :, None], r[:, :, None],
-                                flat_idx, tables, start, kv_len)
+                                lanes)
 
-    def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
-                    flat_idx, kv_len):
+    def _latent_attention(self, q, ck, cv, layer, lanes):
+        """The latent kernel over the rows ``q`` of all of ``lanes``, a
+        call a group at the group's shape."""
+        kernel = lanes.shared(latent_attention, (7, 8))
+        return lanes.join([kernel(
+            qg, ck, cv, layer, g.tables, g.positions[:, 0], g.kv_len,
+            self.block_size, 1.0 / np.sqrt(self.cfg.head_dim))
+            for g, qg in zip(lanes.groups, lanes.split(q))])
+
+    def _layer_step(self, x, lp, ck, cv, layer, lanes):
         cfg = self.cfg
         attn = lp["self_attn"]
         B, T, _ = x.shape
@@ -214,33 +219,28 @@ class PagedLatentModel(PagedMoEModel):
         # attribute and not the scope
         with jax.named_scope("latent_attn"), \
                 set_xla_metadata(hds_layer="latent_attn"):
-            q, c, r = self._latent_qcr(attn, h, positions)
+            q, c, r = self._latent_qcr(attn, h, lanes.positions)
             latent = jnp.concatenate([c, r], axis=-1).astype(
                 self.latent_dtype) if self.capture_latents else jnp.zeros(
                 (B, T, 0), h.dtype)
-            ck, cv = self._write_rows(ck, cv, layer, c, r, flat_idx, tables,
-                                      positions[:, 0], kv_len)
-            u = latent_attention(q, ck, cv, layer, tables, positions[:, 0],
-                                 kv_len, self.block_size,
-                                 1.0 / np.sqrt(cfg.head_dim))
+            ck, cv = self._write_rows(ck, cv, layer, c, r, lanes)
+            u = self._latent_attention(q, ck, cv, layer, lanes)
             o = jnp.einsum("bthc,hcd->bthd", u, attn["w_uv"])
             proj = self._mm(o.reshape(B, T, cfg.n_head * cfg.v_head_dim),
                             attn["o_proj"]["kernel"])
         x = x + proj
         h2 = rms_norm(x, lp["post_attention_layernorm"]["weight"],
                       eps=cfg.rms_norm_eps).astype(cfg.compute_dtype)
-        mlp, stats = self._mlp(lp, h2, flat_idx, ck.shape[2])
+        mlp, stats = self._mlp(lp, h2, lanes, ck.shape[2])
         x = x + mlp
         return x.astype(cfg.compute_dtype), ck, cv, latent, stats
 
-    def _lead_layers(self, params, x, cache_k, cache_v, tables, positions,
-                     flat_idx, kv_len):
+    def _lead_layers(self, params, x, cache_k, cache_v, lanes):
         latents = []
         for i in range(self.n_lead):
             lp = jax.tree.map(lambda p: p[i], params["lead_layers"])
             x, cache_k, cache_v, latent, _ = self._layer_step(
-                x, lp, cache_k, cache_v, jnp.int32(i), tables, positions,
-                flat_idx, kv_len)
+                x, lp, cache_k, cache_v, jnp.int32(i), lanes)
             latents.append(latent)
         return x, cache_k, cache_v, latents
 
@@ -250,17 +250,14 @@ class PagedLatentModel(PagedMoEModel):
         up = self._mm(h2, p["up_proj"]["kernel"])
         return self._mm(jax.nn.silu(gate) * up, p["down_proj"]["kernel"])
 
-    def _mlp(self, lp, h2, flat_idx, pool_slots):
+    def _mlp(self, lp, h2, lanes, pool_slots):
         if "experts" not in lp["mlp"]:          # a leading dense layer
             return self._swiglu(lp["mlp"], h2), {}
         out, experts = self._routed(lp, h2)
-        valid = flat_idx < pool_slots                          # [B, T]
-        picks = self._picks(experts, valid,
+        picks = self._picks(experts, lanes.flat_idx < pool_slots,
                             lp["mlp"]["gate"]["weight"].shape[-1])
         # what the router read for each lane's last real row [B, H]
-        last = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0)
-        router_in = jnp.take_along_axis(h2, last[:, None, None], axis=1)
-        return out, {"picks": picks, "router_in": router_in[:, 0]}
+        return out, {"picks": picks, "router_in": lanes.last_rows(h2)}
 
     def _routed(self, lp, h2):
         mlp = lp["mlp"]
@@ -280,21 +277,30 @@ class PagedLatentModel(PagedMoEModel):
             mlp["shared_experts"], h2), experts
 
     # -------------------------------------------------------------- #
-    def _forward_chunk_probed(self, params, cache_k, cache_v, tokens,
-                              start, tables, t_len):
-        """``_forward_chunk`` that also returns what each sparse layer's
-        router read for every lane's last row ``[L_sparse, B, H]``: a
-        check routes its reference's compared row by it."""
-        params, cache_k, cache_v, x, latents, stats = self._trunk(
-            params, cache_k, cache_v, tokens, start, tables, t_len)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(t_len - 1, 0)[:, None, None], axis=1)[:, 0]
-        return (cache_k, cache_v, self._head_logits(params, last), latents,
+    def _chunk_program(self, shapes=None):
+        groups = 1 if shapes is None else len(shapes)
+        return self._lane_program(self._forward_chunk_probed, 2 + groups,
+                                  shapes=shapes)
+
+    def _forward_chunk_probed(self, params, cache_k, cache_v, *columns):
+        """``_forward_chunk`` that also returns, last, what each sparse
+        layer's router read for every lane's last row ``[L_sparse, B,
+        H]``: a check routes its reference's compared row by it."""
+        params, cache_k, cache_v, x, latents, stats, lanes = self._trunk(
+            params, cache_k, cache_v, *columns)
+        logits = self._head_logits(params, lanes.last_rows(x))
+        return (cache_k, cache_v, logits, *lanes.split(latents, lead=1),
                 stats["router_in"])
 
     def forward_chunk(self, cache, tokens, start, tables, t_len):
         ck, cv, logits, latents, self.router_probe = self._enqueue(
             self._fwd, (cache.k, cache.v), tokens, start, tables, t_len)
+        cache.replace(ck, cv)
+        return logits, latents
+
+    def forward_step(self, cache, *groups):
+        ck, cv, logits, *latents, self.router_probe = self._enqueue_step(
+            (cache.k, cache.v), groups)
         cache.replace(ck, cv)
         return logits, latents
 
@@ -311,10 +317,9 @@ class PagedLatentModel(PagedMoEModel):
                        start, tables, t_len):
         """Put one layer's saved cache rows ``[B, T, C + R]`` back into
         the pools: a write, nothing replayed."""
-        flat_idx = flat_slots(tables, start, t_len, latent.shape[1],
-                              self.block_size, cache_k.shape[2])
+        lanes = self._restore_lanes(latent, start, tables, t_len,
+                                    cache_k.shape[2])
         latent = latent.astype(cache_k.dtype)
         return self._write_rows(
             cache_k, cache_v, layer, latent[..., :self.c_width],
-            latent[..., self.c_width:], flat_idx, tables, start,
-            start + t_len)
+            latent[..., self.c_width:], lanes)

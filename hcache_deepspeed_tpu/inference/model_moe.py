@@ -95,13 +95,13 @@ class PagedMoEModel(PagedInferenceModel):
     def _mlp_out(self, lp, h2):
         return self._routed(lp, h2)[0]
 
-    def _mlp(self, lp, h2, flat_idx, pool_slots):
+    def _mlp(self, lp, h2, lanes, pool_slots):
         """The expert layer; ``picks`` an expert took over the real
         positions (``engine.moe_stats()``) and ``router_in``, what the
         router read (a probed block lane's goes to the host with its
         logits rows)."""
         out, experts = self._routed(lp, h2)
-        picks = self._picks(experts, flat_idx < pool_slots,
+        picks = self._picks(experts, lanes.flat_idx < pool_slots,
                             lp["mlp"]["moe"]["wg"].shape[-1])
         return out, {"picks": picks, "router_in": h2}
 

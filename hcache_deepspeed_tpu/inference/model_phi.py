@@ -64,18 +64,15 @@ class PagedPhiModel(PagedFalconModel):
                          rotary_dim=cfg.rotary_dim)
         return q, k, v
 
-    def _layer_step(self, x, lp, ck, cv, layer, tables, positions,
-                    flat_idx, kv_len):
+    def _layer_step(self, x, lp, ck, cv, layer, lanes):
         cfg = self.cfg
         h = self._ln(x, lp["input_layernorm"], cfg.layer_norm_epsilon)
         latent = h.astype(self.latent_dtype) \
             if self.capture_latents else jnp.zeros(
             (x.shape[0], x.shape[1], 0), h.dtype)
-        q, k, v = self._qkv(lp, h, positions)
-        ck, cv = self._scatter_kv(ck, cv, layer, k, v, flat_idx, tables,
-                                  positions[:, 0], kv_len)
-        attn = self._paged_attention(q, ck, cv, layer, tables, positions,
-                                     kv_len)
+        q, k, v = self._qkv(lp, h, lanes.positions)
+        ck, cv = self._scatter_kv(ck, cv, layer, k, v, lanes)
+        attn = self._paged_attention(q, ck, cv, layer, lanes)
         d = lp["self_attn"]["dense"]
         attn = self._mm(attn, d["kernel"])
         up = self._mm(h, lp["fc1"]["kernel"]) + lp["fc1"]["bias"]

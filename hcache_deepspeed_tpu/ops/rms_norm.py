@@ -31,12 +31,23 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
                 w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
+def _block_rows(n, most=256):
+    """Rows a grid step: ``most`` or all ``n`` where that divides them,
+    else the most sublane tiles (8 rows) that do (the 520 rows of a
+    step's decode lanes and prompt slice: 104); ``most`` where nothing
+    does, which the caller refuses."""
+    if n % min(most, n) == 0:
+        return min(most, n)
+    return next((rows for rows in range(most - most % 8, 0, -8)
+                 if n % rows == 0), most)
+
+
 def _rms_fwd_pallas(x, weight, eps, interpret):
     orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
-    block_rows = min(256, n)
+    block_rows = _block_rows(n)
     if n % block_rows:
         note_fallback("rms_norm", "rows_not_block_multiple",
                       f"rows={n} block_rows={block_rows}")

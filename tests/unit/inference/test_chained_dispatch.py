@@ -4,7 +4,12 @@ diffusion trunks, a put that holds decode (or block) lanes and a prompt
 slice against the same put with each program collected before the next
 is built (``_one_by_one``, the order before launches were chained):
 results bit for bit, the order of the spans, the ``chained`` counter and
-attribute, and what a fault leaves behind."""
+attribute, and what a fault leaves behind.
+
+The slice of a causal trunk's put here is a short tail (5 tokens beside
+a ``prefill_chunk`` of 16), which stays a program of its own; a slice in
+the chunk's bucket rides the decode lanes' program
+(``test_fused_step.py``)."""
 
 import functools
 
@@ -56,6 +61,19 @@ def _builder(trunk):
                 jax.random.PRNGKey(1),
                 {"input_ids": np.zeros((1, 16), np.int32)})["params"], 200)
         return lambda: hybrid.make_engine(weights)
+    if trunk == "moe":
+        from hcache_deepspeed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                         mixtral_tiny)
+        cfg = mixtral_tiny(max_positions=128, use_flash=False,
+                           dropless=True)
+        weights = MixtralForCausalLM(cfg).init(
+            jax.random.PRNGKey(0),
+            {"input_ids": np.zeros((1, 8), np.int32)},
+            train=False)["params"]
+        return lambda: llama.make_engine(cfg, weights, state_manager={
+            "max_tracked_sequences": 8, "max_ragged_batch_size": 128,
+            "max_ragged_sequence_count": 4, "max_context": 128,
+            "prefill_chunk": 16})
     if trunk == "latent":
         from hcache_deepspeed_tpu.models.glm4_moe_lite import seeded_params
         weights = _norms_off_one(seeded_params(
@@ -90,11 +108,19 @@ def warm_up(engine):
     return [engine.put([1, 2], [_tokens(5, 1), _tokens(12, 2)])]
 
 
-def mixed_put(engine, done):
+#: a causal trunk's slice under half a ``prefill_chunk`` (16 in every
+#: builder): its bucket is not the chunk's, so it stays a program
+SHORT_TAIL = 5
+
+
+def mixed_put(engine, done, slice_len=None):
     """The put of a serving step that moves a prompt forward: the two
     sequences' decode lanes (their open blocks, of which one commits) and
-    a 16-token slice of a third: two programs."""
-    slice_ = _tokens(16, 3)
+    a slice of a third (default: 16 tokens of a model that generates by
+    diffusion, else ``SHORT_TAIL``): two programs."""
+    if slice_len is None:
+        slice_len = 16 if engine.diffusion else SHORT_TAIL
+    slice_ = _tokens(slice_len, 3)
     if engine.diffusion:
         blocks = {1: BlockPass(commit=False, probe=True),
                   2: BlockPass(commit=True, probe=False)}
@@ -121,23 +147,28 @@ def pools(engine):
     return [np.asarray(getattr(cache, name)) for name in names]
 
 
-def assert_same_results(got, want):
-    """Two puts' ``(logits, latents)``, bit for bit."""
+def assert_same_results(got, want, atol=0.0):
+    """Two puts' ``(logits, latents)``, bit for bit (``atol`` 0)."""
+    def same(a, b):
+        if atol and b is not None:
+            np.testing.assert_allclose(a, b, rtol=0.0, atol=atol)
+        else:
+            np.testing.assert_array_equal(a, b)
+
     for mine, theirs in zip(got[0], want[0]):
         if theirs is None or isinstance(theirs, np.ndarray):
-            np.testing.assert_array_equal(mine, theirs)
+            same(mine, theirs)
             continue
         for field in ("tokens", "confidence", "logits", "router_in"):
             a, b = getattr(mine, field), getattr(theirs, field)
             assert (a is None) == (b is None)
             if b is not None:
-                np.testing.assert_array_equal(a, b)
+                same(a, b)
     assert len(got[1]) == len(want[1])
     for mine, theirs in zip(got[1], want[1]):
         assert (mine is None) == (theirs is None)
         if theirs is not None:
-            np.testing.assert_array_equal(np.asarray(mine),
-                                          np.asarray(theirs))
+            same(np.asarray(mine), np.asarray(theirs))
 
 
 @pytest.mark.parametrize("trunk", TRUNKS)

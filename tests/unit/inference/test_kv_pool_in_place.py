@@ -164,12 +164,21 @@ class _ShapesOnly(PagedInferenceModel):
         self.params = params
 
 
+def _step_lanes(B, T, n_blocks, slot=False):
+    """Length of the flat operand of a step program over ``B`` decode
+    lanes and one slice lane of ``T`` (``ragged/lanes.py pack_step``)."""
+    return B * lanes_width(1, n_blocks, slot) + lanes_width(T, n_blocks,
+                                                            slot)
+
+
 @functools.lru_cache(maxsize=None)
-def _v5e_program(one_chip, B, T, restore=False):
+def _v5e_program(one_chip, B, T, restore=False, step=False):
     """Mistral-7B widths, 2 layers, the serve cell's block size and pool
     (2560 blocks of 64), compiled for the described chip: ``(compiled,
     pool, params)``. ``restore``: the program that replays both layers'
-    K and V from ``[2, B, T, H]`` latents, in place of the forward."""
+    K and V from ``[2, B, T, H]`` latents, in place of the forward.
+    ``step``: the program of a step of ``B`` decode lanes and one slice
+    lane of ``T``."""
     from jax.experimental.compilation_cache import compilation_cache
     from hcache_deepspeed_tpu import platform
     from hcache_deepspeed_tpu.inference.model import stack_layer_params
@@ -214,6 +223,9 @@ def _v5e_program(one_chip, B, T, restore=False):
             lowered = model._restore.lower(
                 params, pool, pool, i32(), latents, i32(B), i32(B, 32),
                 i32(B))
+        elif step:
+            lowered = model.step_program(((B, 1), (1, T))).lower(
+                params, pool, pool, i32(_step_lanes(B, T, 32)))
         else:
             traced = model._fwd.trace(params, pool, pool,
                                       i32(B, lanes_width(T, 32)))
@@ -334,10 +346,11 @@ def test_v5e_program_names_the_paged_kernel_to_its_finders(one_chip, B, T,
 # attention): here, beside the serving programs' other compiled cases
 # ------------------------------------------------------------------ #
 @functools.lru_cache(maxsize=None)
-def _v5e_hybrid_program(one_chip, B, T):
+def _v5e_hybrid_program(one_chip, B, T, step=False):
     """Olmo-Hybrid-7B widths, two periods (6 linear layers, 2 full), the
     cell's pools (1536 blocks of 64; 64 state slots and the spare),
-    compiled for the described chip: ``(compiled, pools, params)``."""
+    compiled for the described chip: ``(compiled, pools, params)``.
+    ``step``: as :func:`_v5e_program`'s."""
     from jax.experimental.compilation_cache import compilation_cache
     from hcache_deepspeed_tpu import platform
     from hcache_deepspeed_tpu.inference.model_hybrid import (
@@ -378,10 +391,15 @@ def _v5e_hybrid_program(one_chip, B, T):
             "state": on_chip((6, 65, 30, 96, 192), jnp.float32),
             "conv": on_chip((6, 65, 3 * cfg.conv_channels), jnp.bfloat16)}
         i32 = lambda *shape: on_chip(shape, jnp.int32)
-        lowered = model._fwd.lower(
-            params, pools["kv"], pools["kv"], pools["state"],
-            pools["conv"], i32(B, lanes_width(T, 128, slot=True)))
-        _LOWERED["hybrid", B, T] = lowered.as_text()
+        if step:
+            lowered = model.step_program(((B, 1), (1, T))).lower(
+                params, pools["kv"], pools["kv"], pools["state"],
+                pools["conv"], i32(_step_lanes(B, T, 128, slot=True)))
+        else:
+            lowered = model._fwd.lower(
+                params, pools["kv"], pools["kv"], pools["state"],
+                pools["conv"], i32(B, lanes_width(T, 128, slot=True)))
+            _LOWERED["hybrid", B, T] = lowered.as_text()
         compiled = lowered.compile()
     finally:
         platform._platform = None
@@ -440,11 +458,29 @@ def test_v5e_hybrid_program_holds_pools_and_weights_in_place(one_chip, B,
 #: The paged kernel's own jaxpr at ``mask_block`` 1 is PR 43's, which
 #: gave the kernel its loop over a lane's own blocks (the programs'
 #: text around it did not move: the pools were whole operands before).
+#:
+#: The sparse cell's two programs (the block program over 32 lanes of 4
+#: and its prompt slice) were recorded on the parent of PR 53 (58af4b9),
+#: before anything else of that PR was written: it gave the trunk a
+#: second lane group for a causal step's decode lanes and prompt slice,
+#: and a model that generates by diffusion over blocks has to run the
+#: programs it ran before, digest for digest. The four causal ones came
+#: through that PR unchanged. The latent trunk's two did not (the
+#: parent's: da0f3e89de7fd0ed, f6ffa78c32fbedd0) and are PR 53's own: a
+#: lane's last row, for the head and for the routers' probe, is taken at
+#: ``t_len - 1`` by ``Lanes.last_rows`` where the probe counted the
+#: lane's valid slots, the same row of any lane that has its blocks
+#: (``test_latent_family.py
+#: test_routing_the_reference_from_the_served_routers_input``).
 _PARENT_LOWERED = {
     ("mistral", 8, 1): "39bac33fc0ec6e21",
     ("mistral", 1, 512): "636262a941e36f1f",
     ("hybrid", 8, 1): "b98f521a7036b852",
-    ("hybrid", 1, 512): "dc2e86140bffcaae"}
+    ("hybrid", 1, 512): "dc2e86140bffcaae",
+    ("sdar-block", 32, 4): "5ad7297a255dd8b2",
+    ("sdar-slice", 1, 512): "2833aa14efcc9752",
+    ("latent", 16, 1): "5a169b0acd803f24",
+    ("latent", 1, 512): "12338aa0eb5c380e"}
 _PARENT_PAGED_KERNEL = {(8, 1): "d1b28c3e6e780a26",
                         (1, 512): "b79c96fc96d9ca8f"}
 
@@ -456,8 +492,12 @@ def _digest(text):
 
 @pytest.mark.parametrize("trunk,B,T", sorted(_PARENT_LOWERED))
 def test_causal_programs_lower_as_on_the_parent(one_chip, trunk, B, T):
-    build = _v5e_program if trunk == "mistral" else _v5e_hybrid_program
-    build(one_chip, B, T)
+    if trunk.startswith("sdar"):
+        _v5e_sdar_program(one_chip, B, T, block=trunk == "sdar-block")
+    else:
+        build = {"mistral": _v5e_program, "hybrid": _v5e_hybrid_program,
+                 "latent": _v5e_latent_program}[trunk]
+        build(one_chip, B, T)
     text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>",
                   _LOWERED[trunk, B, T])
     assert _digest(text) == _PARENT_LOWERED[trunk, B, T]
@@ -531,7 +571,10 @@ def _v5e_sdar_program(one_chip, B, T, block=True):
             sharding=one_chip)
         traced = program.trace(params, pool, pool, lanes)
         _PAGED_GRID["sdar", B, T] = _paged_grid(traced.jaxpr)
-        compiled = traced.lower().compile()
+        lowered = traced.lower()
+        _LOWERED["sdar-block" if block else "sdar-slice", B, T] = \
+            lowered.as_text()
+        compiled = lowered.compile()
     finally:
         platform._platform = None
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
@@ -605,7 +648,7 @@ def test_v5e_paged_kernel_steps_over_no_table_slot(one_chip, trunk, B, T,
 # dense layer before the scan over the sparse stack
 # ------------------------------------------------------------------ #
 @functools.lru_cache(maxsize=None)
-def _v5e_latent_program(one_chip, B, T, restore=False):
+def _v5e_latent_program(one_chip, B, T, restore=False, step=False):
     """GLM-4.7-Flash widths (20 heads over rows of 512 + 64, 64 experts
     of 1536, top-4, a dense layer of 10240 first), 1 + 2 layers, the
     cell's pools (8192 blocks of 64) and table (512 slots: 32k
@@ -661,9 +704,13 @@ def _v5e_latent_program(one_chip, B, T, restore=False):
                                         sharding=one_chip)
             lowered = model._restore.lower(
                 params, *pools, i32(), rows, i32(B), i32(B, 512), i32(B))
+        elif step:
+            lowered = model.step_program(((B, 1), (1, T))).lower(
+                params, *pools, i32(_step_lanes(B, T, 512)))
         else:
             lowered = model._fwd.lower(
                 params, *pools, i32(B, lanes_width(T, 512)))
+            _LOWERED["latent", B, T] = lowered.as_text()
         compiled = lowered.compile()
     finally:
         platform._platform = None
@@ -714,3 +761,81 @@ def test_v5e_latent_program_holds_its_pools_in_place(one_chip, B, T,
     assert stacked_layer_copies(text, kernels) == []
     assert re.findall(r"= bf16\[64,(?:2048,1536|1536,2048)\]\S* "
                       r"(?!bitcast|parameter)[\w\-]+\(", text) == []
+
+
+def _v5e_step_program(one_chip, trunk):
+    """The program of a step of 8 decode lanes and a 512-token slice at
+    the serve cell's sizes of ``trunk``: ``(compiled, pools, params)``."""
+    if trunk == "mistral":
+        compiled, pool, params = _v5e_program(one_chip, 8, 512, step=True)
+        return compiled, {"kv": pool}, params
+    if trunk == "hybrid":
+        return _v5e_hybrid_program(one_chip, 8, 512, step=True)
+    compiled, pools, params = _v5e_latent_program(one_chip, 8, 512,
+                                                  step=True)
+    return compiled, dict(zip(("c", "r"), pools)), params
+
+
+def _instructions(text, kernel):
+    """The instructions of a compiled program that the benchmark's
+    finders take for ``kernel``: one instruction's text at a time, as a
+    trace holds it, searched for ``hds_kernel`` and the name."""
+    return [ins for ins in re.split(r"\n(?=\s*(?:ROOT )?%)", text)
+            if re.search(rf"hds_kernel\W+{kernel}", ins)
+            and "custom_call_target" in ins]
+
+
+@pytest.mark.parametrize("trunk", ["mistral", "hybrid", "latent"])
+def test_v5e_step_program_holds_pools_and_names_both_shapes(one_chip, trunk):
+    """The program of a step's 8 decode lanes and 512-token slice at the
+    serve cells' sizes: every pool in one buffer (nothing of a pool's or
+    a layer's extent copied; the only scatters into a KV pool are the
+    decode lanes' rows, the slice goes by block runs), no layer of a
+    stacked weight copied, no kernel given up for its reference, and
+    each per-lane kernel called at both its shapes under the name the
+    benchmark's finders look for: the paged kernel twice a full layer,
+    the gated-delta step and chunk kernels once a linear layer each,
+    the latent kernel twice a layer. The matrix products run once over
+    all 520 rows."""
+    from hcache_deepspeed_tpu import ops
+    ops.reset_fallback_report()
+    compiled, pools, params = _v5e_step_program(one_chip, trunk)
+    assert ops.fallback_report() == {}
+    text = compiled.as_text()
+    for name, pool in pools.items():
+        copies = pool_sized_copies(text, pool.shape)
+        if name == "conv":      # laid out once, as the two programs do
+            copies = [c for c in copies if c.startswith("copy")]
+        assert copies == [], name
+    kv = [pool for name, pool in pools.items() if name in ("kv", "c", "r")]
+    for pool in kv:
+        scatters = pool_scatters(text, pool.shape)
+        assert len(scatters) == 2, scatters     # the decode lanes' rows
+    assert "hds_kv_write" in text
+    stacks = [k for k in params if k.endswith("layers")]
+    kernels = [leaf.shape for stack in stacks
+               for leaf in jax.tree.leaves(params[stack])
+               if leaf.ndim >= 3 and np.prod(leaf.shape[1:]) >= 1 << 20]
+    assert stacked_layer_copies(text, kernels) == []
+    # the layer loop's body holds one layer (the hybrid's: one period,
+    # three linear layers and a full one; the latent trunk's dense lead
+    # layer stands unrolled before it)
+    want = {"mistral": {"paged_attention": [(8, 1), (1, 512)]},
+            "hybrid": {"paged_attention": [(8, 1), (1, 512)],
+                       "gated_delta_step": [(8, 1)] * 3,
+                       "gated_delta_chunk": [(1, 512)] * 3},
+            "latent": {"latent_attention": [(8, 1), (1, 512)] * 2}}[trunk]
+    for kernel, shapes in want.items():
+        calls = _instructions(text, kernel)
+        assert len(calls) == len(shapes), (kernel, len(calls))
+        found = sorted(
+            (8, 1) if re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[8,", ins)
+            else (1, 512) for ins in calls)
+        assert found == sorted(shapes), (kernel, found)
+    layer_bytes = min(int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
+                      for pool in pools.values() if pool.ndim >= 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    # one pass over the weights: the MLP's products take all rows
+    rows = {"mistral": "520,14336", "hybrid": "520,11008",
+            "latent": "520,10240"}[trunk]
+    assert f"bf16[{rows}]" in text

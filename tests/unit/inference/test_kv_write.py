@@ -176,13 +176,26 @@ def test_the_forward_picks_the_write_by_its_shape(monkeypatch):
                         lambda *a: taken.append("runs") or a[:2])
     monkeypatch.setattr(model_mod, "write_rows",
                         lambda *a: taken.append("rows") or a[:2])
+    from hcache_deepspeed_tpu.inference.ragged.lanes import LaneGroup, Lanes
     self = type("M", (), {"block_size": 16})()
     pool = jnp.zeros((1, 2, 64, 8))
+
+    def group(B, T):
+        g = LaneGroup(jnp.zeros((B, T), jnp.int32), None, None, None)
+        g.positions = jnp.zeros((B, T), jnp.int32)
+        return g
+
     for T in (1, 8, 1, 512):
         k = jnp.zeros((2, T, 2, 8))
-        PagedInferenceModel._scatter_kv(self, pool, pool, 0, k, k, None,
-                                        None, None, None)
+        PagedInferenceModel._scatter_kv(self, pool, pool, 0, k, k,
+                                        Lanes([group(2, T)]))
     assert taken == ["rows", "runs", "rows", "runs"]
+    # a step's two lane groups: each its own write, in the groups' order
+    del taken[:]
+    k = jnp.zeros((1, 8 + 512, 2, 8))
+    PagedInferenceModel._scatter_kv(self, pool, pool, 0, k, k,
+                                    Lanes([group(8, 1), group(1, 512)]))
+    assert taken == ["rows", "runs"]
 
 
 def test_the_engine_counts_dispatches_and_rows_by_the_write_they_took():

@@ -9,9 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hcache_deepspeed_tpu.inference.ragged.lanes import (lanes_width,
+from hcache_deepspeed_tpu.inference.ragged.lanes import (LaneGroup, Lanes,
+                                                         lanes_width,
                                                          pack_lanes,
-                                                         unpack_lanes)
+                                                         pack_step,
+                                                         unpack_lanes,
+                                                         unpack_step)
 from hcache_deepspeed_tpu.telemetry.tracer import get_tracer
 
 from . import test_hybrid_engine as hybrid
@@ -60,6 +63,69 @@ def test_pack_and_unpack_round_trip(B, T, slot):
         np.testing.assert_array_equal(np.asarray(cut), given)
 
 
+@pytest.mark.parametrize("slot", [False, True], ids=["kv", "kv+slot"])
+@pytest.mark.parametrize("shapes", [((8, 1), (1, 512)), ((16, 1), (1, 16)),
+                                    ((8, 1),)])
+def test_a_steps_groups_pack_flat_and_unpack_by_their_shapes(shapes, slot):
+    """``pack_step``: the groups' packed lanes flat, one after the other,
+    one host array; ``unpack_step`` cuts every group's columns out again
+    by the static shapes, on the host and inside a program, and refuses
+    an array that is not of those shapes."""
+    n_blocks = 32
+    groups = [_lanes(B, T, n_blocks, slot, seed=i)
+              for i, (B, T) in enumerate(shapes)]
+    flat = pack_step(groups)
+    assert flat.dtype == np.int32 and flat.ndim == 1
+    assert flat.size == sum(B * lanes_width(T, n_blocks, slot)
+                            for B, T in shapes)
+    np.testing.assert_array_equal(flat, np.concatenate(
+        [pack_lanes(*g).ravel() for g in groups]))
+    on_host = unpack_step(flat, shapes, n_blocks, slot)
+    in_program = jax.jit(
+        lambda x: unpack_step(x, shapes, n_blocks, slot))(flat)
+    for given, views, cuts in zip(groups, on_host, in_program):
+        assert len(given) == len(views) == len(cuts)
+        for a, view, cut in zip(given, views, cuts):
+            np.testing.assert_array_equal(view, a)
+            np.testing.assert_array_equal(np.asarray(cut), a)
+    with pytest.raises(ValueError, match="not lane groups"):
+        unpack_step(flat[:-1], shapes, n_blocks, slot)
+
+
+def test_lanes_cut_rows_into_groups_and_put_them_together_again():
+    """``Lanes`` over two groups: ``split`` cuts ``[.., 1, N, ..]`` rows
+    into each group's ``[.., B, T, ..]``, ``join`` is its inverse,
+    ``rows`` lays a per-group attribute over all rows and ``last_rows``
+    takes each lane's last valid position; over one group every one of
+    them hands back what it got."""
+    decode = LaneGroup(*(jnp.asarray(a) for a in _lanes(8, 1, 4, False)))
+    slice_ = LaneGroup(*(jnp.asarray(a) for a in _lanes(2, 16, 4, False, 1)))
+    both = Lanes([decode, slice_])
+    assert (decode.shape, slice_.shape) == ((8, 1), (2, 16))
+    x = jnp.arange(3 * 40 * 5, dtype=jnp.float32).reshape(3, 1, 40, 5)
+    a, b = both.split(x, lead=1)
+    assert (a.shape, b.shape) == ((3, 8, 1, 5), (3, 2, 16, 5))
+    np.testing.assert_array_equal(a[:, :, 0], x[:, 0, :8])
+    np.testing.assert_array_equal(b[:, 1], x[:, 0, 24:])
+    np.testing.assert_array_equal(both.join([a, b], lead=1), x)
+    tokens = both.rows("tokens")
+    assert tokens.shape == (1, 40)
+    np.testing.assert_array_equal(tokens[0, :8], decode.tokens[:, 0])
+    np.testing.assert_array_equal(tokens[0, 8:], slice_.tokens.reshape(-1))
+    last = both.last_rows(x[0])
+    assert last.shape == (10, 5)
+    np.testing.assert_array_equal(last[:8], x[0, 0, :8])
+    for j in range(2):
+        at = max(int(slice_.t_len[j]) - 1, 0)
+        np.testing.assert_array_equal(last[8 + j], x[0, 0, 8 + 16 * j + at])
+    one = Lanes([slice_])
+    y = x[0, 0, :32].reshape(2, 16, 5)
+    assert one.split(y)[0] is y and one.join([y]) is y
+    assert one.rows("tokens") is slice_.tokens
+    assert len(Lanes.of(list(_lanes(8, 1, 4, True)) +
+                        list(_lanes(1, 16, 4, True)), slot=True).groups) == 2
+
+
 def test_pack_casts_what_callers_hand_it():
     """Lists, int64 arrays and device arrays pack as ``int32``, as the
     ``jnp.asarray(x, jnp.int32)`` they replace did."""
@@ -76,8 +142,9 @@ def test_pack_casts_what_callers_hand_it():
 @pytest.mark.parametrize("trunk", ["llama", "hybrid"])
 def test_every_dispatch_hands_over_one_array(trunk, request):
     """``engine.dispatch_stats()`` after a chunked prompt beside decode
-    lanes: as many host arrays as dispatches, of the packed lanes'
-    bytes; the two enqueue spans carry the same as attributes."""
+    lanes, and a full slice beside them (one program for both groups):
+    as many host arrays as dispatches, of the packed lanes' bytes; the
+    two enqueue spans carry the same as attributes."""
     if trunk == "hybrid":
         engine = hybrid.make_engine(request.getfixturevalue("params"))
         vocab = hybrid.HF["vocab_size"]
@@ -91,7 +158,8 @@ def test_every_dispatch_hands_over_one_array(trunk, request):
     slot = engine.recurrent
     n_blocks = engine.max_blocks_per_seq
     assert engine.dispatch_stats() == {"dispatches": 0, "h2d_arrays": 0,
-                                       "h2d_bytes": 0, "chained": 0}
+                                       "h2d_bytes": 0, "chained": 0,
+                                       "fused": 0}
     rng = np.random.default_rng(0)
     tracer = get_tracer()
     tracer.clear()
@@ -104,13 +172,16 @@ def test_every_dispatch_hands_over_one_array(trunk, request):
                                [int(np.argmax(logits[1]))],
                                rng.integers(0, vocab, 37)])
         engine.put([1, 2, 3], [[5], [6], [7]])
+        # a full slice beside three decode lanes: one program
+        engine.put([1, 2, 3, 4], [[5], [6], [7], rng.integers(0, vocab, 16)])
     finally:
         tracer.configure(enabled=False)
     stats = engine.dispatch_stats()
     writes = engine.kv_write_stats()
-    assert stats["dispatches"] == \
+    assert stats["fused"] == 1      # which wrote rows and runs
+    assert stats["dispatches"] + stats["fused"] == \
         writes["run_dispatches"] + writes["row_dispatches"]
-    assert writes["row_dispatches"] == 2 and writes["run_dispatches"] >= 4
+    assert writes["row_dispatches"] == 3 and writes["run_dispatches"] >= 5
     assert stats["h2d_arrays"] == stats["dispatches"]
     spans = [e for e in tracer.events()
              if e["name"] in ("serve.decode_dispatch",
@@ -122,8 +193,13 @@ def test_every_dispatch_hands_over_one_array(trunk, request):
         args = event["args"]
         assert args["h2d_arrays"] == 1
         T = args.get("bucket_T", 1)
+        # a step program's span (``decode_lanes``: a chunk-bucket slice
+        # with the decode lanes, or alone on blank ones) carries both
+        # groups' bytes
+        decode = 8 * lanes_width(1, n_blocks, slot) \
+            if "decode_lanes" in args else 0
         assert args["h2d_bytes"] == \
-            4 * args["bucket"] * lanes_width(T, n_blocks, slot)
+            4 * (decode + args["bucket"] * lanes_width(T, n_blocks, slot))
     assert stats["h2d_bytes"] == sum(e["args"]["h2d_bytes"] for e in spans)
     tracer.clear()
 

@@ -53,6 +53,16 @@ def record_programs(eng):
         return logits, latents
 
     eng.model.forward_chunk = recording
+    step = eng.model.forward_step
+
+    def recording_step(*args, **kwargs):
+        # a slice of the chunk's bucket takes the step program (alone:
+        # on blank decode lanes); its latents are the last group's
+        logits, latents = step(*args, **kwargs)
+        seen.append(latents[-1])
+        return logits, latents
+
+    eng.model.forward_step = recording_step
     return seen
 
 

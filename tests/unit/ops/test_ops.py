@@ -114,6 +114,28 @@ class TestRMSNorm:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("rows,block", [(520, 104), (528, 176),
+                                            (544, 136), (576, 192),
+                                            (512, 256), (8, 8), (300, 256)])
+    def test_rows_of_a_fused_step_run_the_kernel(self, rows, block):
+        """The rows of a step's decode lanes and prompt slice together
+        (8, 16, 32 or 64 lanes and 512 positions) are no multiple of
+        256: the kernel takes the most whole sublane tiles that divide
+        them, and only rows that nothing divides go to the reference."""
+        from hcache_deepspeed_tpu.ops import (fallback_report,
+                                              reset_fallback_report)
+        from hcache_deepspeed_tpu.ops.rms_norm import _block_rows
+        assert _block_rows(rows) == block
+        x = jax.random.normal(jax.random.PRNGKey(0), (1, rows, 128))
+        w = jax.random.normal(jax.random.PRNGKey(1), (128,)) + 1.0
+        reset_fallback_report()
+        got = pallas_rms_norm(x, w, interpret=True)
+        assert ("rms_norm" in fallback_report()) == bool(rows % block)
+        reset_fallback_report()
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(reference_rms_norm(x, w)),
+                                   rtol=1e-5, atol=1e-5)
+
     def test_bwd(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
         w = jax.random.normal(jax.random.PRNGKey(1), (128,)) + 1.0
