@@ -16,6 +16,10 @@ Emits JSON lines, each naming the device; needs the chip (exits non-zero
 without one, and when any variant emitted an error row). To read a
 parent beside a change, run this file from both checkouts in one call:
     python bin/chip_paged_vet.py
+Arguments: prefixes of the shapes to run (default: all), as in
+``python bin/chip_paged_vet.py cmda`` for the windowed cell's table: the
+kernel under its window and without it at contexts of 2k, 8k, 16k and
+24k, decode and slice shapes.
 """
 import functools
 import json
@@ -43,7 +47,8 @@ def _lanes(contexts, bucket, T=1):
 
 
 #: name: (B, T, Hq, KV, D, BS, NBLK, NB, mask_block, (start, kv_len),
-#: head tiles). The cells' shapes: PERF.md sections 5 and 6 (PR 43).
+#: head tiles[, window]). The cells' shapes: PERF.md sections 5 and 6
+#: (PR 43; the ``cmda-`` ones PR 56).
 SHAPES = {
     # sdar-serve-block-denoise: 64 lanes of a block of 4, 48 live at
     # 650-900 tokens and one near 2,000; and its prompt slice
@@ -63,6 +68,19 @@ SHAPES = {
     "olmoh-slice": (1, 512, 30, 30, 128, 64, 1536, 128, 1,
                     ([2048], [2560]), (0,)),
 }
+# cmdaplus-serve-mixed-length: 128 query heads over 8 KV heads of 128, a
+# table of 512 slots; 16 decode lanes, 4 live at each context, and a
+# 512-token slice ending at it; under the window (the window layers'
+# pool, 2,336 blocks) and without (the global layer's, 6,144)
+for _ctx in (2048, 8192, 16384, 24576):
+    for _window, _blocks in ((4096, 2336), (None, 6144)):
+        _tag = f"{_ctx // 1024}k-" + ("window" if _window else "global")
+        SHAPES[f"cmda-decode-{_tag}"] = (
+            16, 1, 128, 8, 128, 64, _blocks, 512, 1,
+            _lanes([_ctx - 40 * i for i in range(4)], 16), (0,), _window)
+        SHAPES[f"cmda-slice-{_tag}"] = (
+            1, 512, 128, 8, 128, 64, _blocks, 512, 1,
+            ([_ctx - 512], [_ctx]), (0,), _window)
 
 
 def main():
@@ -100,42 +118,63 @@ def main():
         s = (hi[reps // 2] - lo[reps // 2]) / 224 * 1000
         return round(s, 4) if s > 0 else None
 
-    for name, (B, T, Hq, KV, D, BS, NBLK, NB, MB, (start, kvl),
-               tiles) in SHAPES.items():
+    wanted = tuple(sys.argv[1:])
+    for name, (B, T, Hq, KV, D, BS, NBLK, NB, MB, (start, kvl), tiles,
+               *window) in SHAPES.items():
+        if wanted and not name.startswith(wanted):
+            continue
+        (window,) = window or (None,)
         rng = np.random.default_rng(0)
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(keys[0], (B, T, Hq, D), jnp.bfloat16)
         # a two-layer [L, KV, P, D] pool, read at layer 1
         kp = jax.random.normal(keys[1], (2, KV, NBLK * BS, D), jnp.bfloat16)
         vp = jax.random.normal(keys[2], (2, KV, NBLK * BS, D), jnp.bfloat16)
-        tables = rng.permutation(NBLK)[:B * NB].reshape(B, NB).astype(
-            np.int32)
         blocks = -(-np.asarray(kvl) // BS)
+        # a lane's blocks anywhere in the pool; under a window the pool
+        # holds a window a lane and the table's entries behind it are
+        # never read
+        held = blocks if window is None else np.minimum(
+            blocks, -(-(window + T) // BS) + 1)
+        tables = np.zeros((B, NB), np.int32)
+        free = list(rng.permutation(NBLK))
+        for b in range(B):
+            for slot in range(blocks[b] - held[b], blocks[b]):
+                tables[b, slot] = free.pop()
         # past a lane's own blocks a table is zero-padded, as the engine
         # packs it
-        tables[np.arange(NB)[None, :] >= blocks[:, None]] = 0
         start = jnp.asarray(start, jnp.int32)
         kvl = jnp.asarray(kvl, jnp.int32)
         shape_row = {
             "phase": "paged-vet", "shape": name, "lanes": B, "rows": T,
-            "table_slots": B * NB, "blocks_walked": int(blocks.sum()),
-            # K and V of the lanes' exact contexts, once a call
-            "kv_mb": round(int(np.asarray(kvl).sum()) * 2 * KV * D * 2
-                           / 1e6, 3)}
+            "table_slots": B * NB, "blocks_walked": int(held.sum()),
+            # K and V of the lanes' exact contexts (under a window: the
+            # positions their rows see), once a call
+            "kv_mb": round(int(np.minimum(
+                np.asarray(kvl), np.asarray(kvl) if window is None
+                else window + T - 1).sum()) * 2 * KV * D * 2 / 1e6, 3)}
         # a padded lane is zeros from the kernel and a mean of V from
         # the oracle's softmax over nothing: the live lanes are compared
         live = np.asarray(kvl) > 0
-        ref = np.asarray(jax.jit(lambda q, kp, vp: reference_paged_attention(
-            q, kp, vp, 1, tables, start, kvl, BS, MB))(q, kp, vp),
-            np.float32)[live]
+        extra = {} if window is None else {"window": window}
+        # the dense oracle where its scores fit (a slice of 128 heads
+        # over a 32k table is 8.6 GB of them)
+        ref = None
+        if B * T * Hq * NB * BS * 4 < 2 << 30:
+            ref = np.asarray(jax.jit(
+                lambda q, kp, vp: reference_paged_attention(
+                    q, kp, vp, 1, tables, start, kvl, BS, MB, **extra))(
+                        q, kp, vp), np.float32)[live]
         for tile in tiles:
             try:
                 call = functools.partial(
                     pallas_paged_attention, layer=1, tables=tables,
                     start=start, kv_len=kvl, block_size=BS,
-                    interpret=False, head_tile=tile, mask_block=MB)
+                    interpret=False, head_tile=tile, mask_block=MB,
+                    **extra)
                 out = np.asarray(jax.jit(call)(q, kp, vp), np.float32)
-                err = float(np.max(np.abs(out[live] - ref)))
+                err = None if ref is None else \
+                    float(np.max(np.abs(out[live] - ref)))
 
                 # device time: N kernel iterations inside ONE dispatch (a
                 # dispatch-per-call chain is enqueue-bound and reads the
@@ -152,8 +191,11 @@ def main():
                     return c
 
                 ms = slope_ms(stretch, q, kp, vp)
-                emit(dict(shape_row, head_tile=tile,
-                          max_abs_err=round(err, 5), ok=err < 0.05,
+                emit(dict(shape_row, head_tile=tile, window=window,
+                          max_abs_err=None if err is None
+                          else round(err, 5),
+                          ok=bool(np.isfinite(out[live]).all()) and
+                          (err is None or err < 0.05),
                           device_ms_per_iter=ms))
             except Exception as e:
                 emit(dict(shape_row, head_tile=tile, error=str(e)[:300]))

@@ -657,6 +657,106 @@ def latent_phase(hf, block_size=64, prefill_chunk=512, logit_tol=0.02):
 
 
 # ------------------------------------------------------------------ #
+# serve, a trunk with window and global layers (two block pools)
+# ------------------------------------------------------------------ #
+#: Command A+ at its published widths, one period (three window layers
+#: and a global one), 4 of the 128 routed experts held beside the four
+#: shared ones, an eighth of the vocabulary: 2.3 B parameters, 4.6 GB in
+#: bf16 (the engine holds them twice while it stacks them)
+COMMAND_A_PERIOD = {
+    "model_type": "cohere2_moe", "vocab_size": 32768, "hidden_size": 4096,
+    "intermediate_size": 4096, "num_hidden_layers": 4,
+    "num_attention_heads": 128, "num_key_value_heads": 8, "head_dim": 128,
+    "max_position_embeddings": 8192, "layer_norm_eps": 1e-5,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "sliding_window": 4096, "rope_theta": 50000, "num_experts": 128,
+    "num_experts_per_tok": 8, "num_shared_experts": 4,
+    "norm_topk_prob": True, "experts_held": [0, 4], "logit_scale": 1,
+    "tie_word_embeddings": True, "torch_dtype": "bfloat16"}
+#: the same period at toy width in fp32, for the CPU test of the phase
+TINY_WINDOW_PERIOD = dict(
+    COMMAND_A_PERIOD, vocab_size=256, hidden_size=64, intermediate_size=32,
+    num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+    max_position_embeddings=512, sliding_window=64, num_experts=8,
+    num_experts_per_tok=2, num_shared_experts=2, experts_held=[2, 4],
+    torch_dtype="float32")
+
+
+def window_phase(hf, block_size=64, prefill_chunk=512, logit_tol=0.02):
+    """A prompt longer than the window through both pools (the window
+    layers' blocks behind the window freed on the way) and a decode
+    step; then the sequence evicted to its host K/V rows and brought
+    back through ``restore_kv`` into both pools (a ship and a write, of
+    the window layers only the rows inside the window), its next logits
+    against the uninterrupted ones."""
+    from hcache_deepspeed_tpu.inference import RaggedInferenceEngineConfig
+    from hcache_deepspeed_tpu.inference.factory import (MODEL_FAMILIES,
+                                                        build_hf_engine)
+    from hcache_deepspeed_tpu.models.cohere2_moe import seeded_params
+    cfg = MODEL_FAMILIES[hf["model_type"]](hf)
+    params = seeded_params(cfg, seed=0, dtype=hf["torch_dtype"])
+    window = cfg.sliding_window
+    n_prompt = window + 2 * prefill_chunk + prefill_chunk // 3
+    per_seq = -(-(window + prefill_chunk) // block_size) + 1
+    engine = build_hf_engine(hf, params, RaggedInferenceEngineConfig(
+        state_manager={"max_tracked_sequences": 8,
+                       "max_ragged_sequence_count": 8,
+                       "max_context": -(-(n_prompt + 8) // block_size)
+                       * block_size,
+                       "prefill_chunk": prefill_chunk},
+        kv_cache={"block_size": block_size,
+                  "num_blocks": 2 + 2 * -(-n_prompt // block_size),
+                  "num_window_blocks": 2 + 2 * per_seq,
+                  "cache_dtype": hf["torch_dtype"]}))
+    del params
+    rng = np.random.default_rng(0)
+    prompt = [int(t) for t in rng.integers(0, hf["vocab_size"], n_prompt)]
+    logits, rows = engine.put([0], [prompt])
+    fed = int(np.argmax(logits[0]))
+    uninterrupted, _ = engine.put([0], [[fed]])
+    rows = np.asarray(rows[0])
+    width = 2 * cfg.n_kv_head * cfg.head_dim
+    check(rows.shape == (cfg.n_layer, n_prompt, width),
+          f"what went to the host is the K and V rows of every layer "
+          f"({width} values a layer a token): {rows.shape}")
+    pools = engine.kv_pool_stats()
+    check(pools["window"]["released"] >= (n_prompt - window) // block_size
+          - 1 and pools["window"]["peak_in_use"] - 1 <= per_seq,
+          f"the window layers' blocks behind the window went back while "
+          f"the sequence lived, and it never held more than {per_seq}: "
+          f"{pools['window']}")
+    engine.flush(0)
+    before = dict(engine.restore_stats)
+    engine.restore_kv([0], [prompt], [rows])
+    restored, _ = engine.put([0], [[fed]])
+    shipped = engine.restore_stats["bytes_shipped"] - before["bytes_shipped"]
+    gap = _logit_gap(np.asarray(restored[0], np.float32),
+                     np.asarray(uninterrupted[0], np.float32))
+    check(np.all(np.isfinite(restored)) and gap <= logit_tol,
+          f"evicted to host K/V rows and restored into both pools "
+          f"({shipped} bytes shipped, nothing replayed): restored vs "
+          f"uninterrupted logits after {n_prompt + 1} tokens differ by "
+          f"{gap:.5f} of max |logit| (tolerance {logit_tol})")
+    check(shipped < rows.nbytes,
+          f"of the window layers only the rows inside the window were "
+          f"shipped: {shipped} of {rows.nbytes} bytes")
+    check(engine.latent_stats()["saved_state"] == "cache_row" and
+          engine.restore_profile()["replay_flops_frac"] == 0.0,
+          "the engine names its saved state: the cache row")
+    moe = engine.moe_stats()
+    first, count = cfg.held
+    check(0 < moe["picks_held"] < moe["picks"].sum(),
+          f"picks over all {cfg.num_experts} experts, {moe['picks_held']} "
+          f"of {int(moe['picks'].sum())} on the {count} held")
+    engine.flush(0)
+    pools = engine.kv_pool_stats()
+    check(pools["global"]["in_use"] == pools["window"]["in_use"] == 1,
+          f"the flush gave back every block of both pools: {pools}")
+    print(f"  peak_bytes_in_use (process lifetime): {_peak_bytes()}",
+          flush=True)
+
+
+# ------------------------------------------------------------------ #
 # train
 # ------------------------------------------------------------------ #
 def train_phase(size, devices, zero_stage=0):
@@ -781,6 +881,7 @@ def main():
     one_chip = run("serve", serve_phase, size)
     run("serve hybrid", hybrid_phase, OLMO_HYBRID_PERIOD)
     run("serve latent", latent_phase, GLM_LATENT_PAIR)
+    run("serve window", window_phase, COMMAND_A_PERIOD)
     losses = run("train", train_phase, size, jax.devices()[:1])["losses"]
     if device["count"] >= 4:
         four = jax.devices()[:4]

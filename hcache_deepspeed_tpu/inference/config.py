@@ -20,6 +20,17 @@ class KVCacheConfig(HDSConfigModel):
      'reserve' (fraction of free HBM) or explicit block count."""
     block_size: int = 64              # tokens per KV block (ref: KV_BLOCK)
     num_blocks: Optional[int] = None  # explicit pool size
+    #: the memory budget of the window layers' pool of a trunk that has
+    #: window layers beside global ones (``ragged/kv_cache.py
+    #: WindowedKVCache``), in blocks, as ``num_blocks`` is the global
+    #: layers'. None: the worst case, every tracked sequence past the
+    #: window with a slice in flight (``ceil((window + slice) /
+    #: block_size) + 1`` blocks each, and the scratch block), under
+    #: which the window pool never refuses an admission. A deployment
+    #: that tracks more sequences than it expects past the window sets
+    #: less, and admission waits on whichever pool is short
+    #: (``StateManager.has_room``)
+    num_window_blocks: Optional[int] = None
     memory_fraction: float = 0.8      # used when num_blocks is None (TPU:
     #                                   sized from platform free-memory)
     cache_dtype: str = "bfloat16"

@@ -30,7 +30,8 @@ from ..utils.compile_cache import ensure_compile_cache
 from ..utils.logging import log_dist
 from .config import RaggedInferenceEngineConfig
 from .model import PagedInferenceModel
-from .ragged.kv_cache import BlockedKVCache, HybridCache, StateManager
+from .ragged.kv_cache import (BlockedKVCache, HybridCache, StateManager,
+                              WindowedKVCache, window_of)
 from .ragged.latents import HostLink, LatentProgram, PendingLatents
 from .scheduling import (BlockChoice, BlockPass, SchedulingError,
                          SchedulingResult)
@@ -144,6 +145,10 @@ class InferenceEngineV2:
             num_blocks = min(self._size_cache_blocks(model_config, kv_cfg),
                              cap)
         self._model_config = model_config
+        #: a trunk with window layers beside global ones keeps two block
+        #: pools with two block lifetimes (``ragged/kv_cache.py
+        #: WindowedKVCache``); read off its shape. 0: one pool
+        self.window = window_of(model_config)
         from ..models.olmo_hybrid import OlmoHybridConfig
         #: a trunk with recurrent layers keeps a state slot a sequence
         #: beside its KV blocks (``ragged/kv_cache.py HybridCache``)
@@ -171,18 +176,38 @@ class InferenceEngineV2:
         self.router_probe_uids = set()
         self._router_probes = {}
 
+        num_window_blocks = 0
+        if self.window:
+            # what a sequence holds at most: the window, the slice in
+            # flight and a block of misalignment
+            most = -(-(self.window + (sm_cfg.prefill_chunk or
+                                      sm_cfg.max_ragged_batch_size))
+                     // self.block_size) + 1
+            num_window_blocks = kv_cfg.num_window_blocks or min(
+                num_blocks, sm_cfg.max_tracked_sequences * most + 1)
         self.state = StateManager(
             sm_cfg.max_tracked_sequences, num_blocks, self.block_size,
             self.max_context,
             state_slots=sm_cfg.max_tracked_sequences
-            if self.recurrent else 0)
+            if self.recurrent else 0,
+            window_blocks=num_window_blocks, window=self.window)
         # block 0 is reserved scratch: padded decode lanes write there
         self._scratch_block = self.state.allocator.allocate(1)[0]
+        if self.window:
+            self._window_scratch = \
+                self.state.window_allocator.allocate(1)[0]
 
         self.prefix_caching = sm_cfg.prefix_caching
         if self.prefix_caching and self.recurrent:
             from .model_hybrid import refuse
             raise refuse("prefix_caching (shared prefixes)")
+        if self.prefix_caching and self.window:
+            from .model_window import refuse
+            raise refuse(
+                "prefix_caching (shared prefixes)",
+                "a window layer's blocks behind the window, which have "
+                "gone back to their allocator: a shared prefix longer "
+                "than the window cannot be attached to them")
         if self.prefix_caching:
             self._refuse_diffusion(
                 "prefix_caching (a shared block's K and V depend on the "
@@ -219,7 +244,10 @@ class InferenceEngineV2:
         from ..models.opt import OPTConfig
         from ..models.phi import PhiConfig
         model_cls = PagedInferenceModel
-        if getattr(model_config, "kv_lora_rank", 0):
+        if self.window:
+            from .model_window import PagedWindowModel
+            model_cls = PagedWindowModel
+        elif getattr(model_config, "kv_lora_rank", 0):
             # latent attention: a pool of compressed KV rows
             from .model_latent import PagedLatentModel
             model_cls = PagedLatentModel
@@ -263,6 +291,12 @@ class InferenceEngineV2:
                 conv_taps=model_config.linear_conv_kernel_dim,
                 conv_channels=model_config.conv_channels,
                 dtype=jnp.dtype(kv_cfg.cache_dtype))
+        elif self.window:
+            self.cache = WindowedKVCache(
+                self.model.pool_layers["global"], num_blocks,
+                self.model.pool_layers["window"], num_window_blocks,
+                self.block_size, model_config.n_kv_head,
+                model_config.head_dim, dtype=jnp.dtype(kv_cfg.cache_dtype))
         else:
             kv_heads, k_width, v_width = self.model.pool_layout()
             self.cache = BlockedKVCache(
@@ -316,6 +350,10 @@ class InferenceEngineV2:
         #: the step program behind it on blank decode lanes (blank lanes
         #: cost rows, a program costs set-up: one step program an engine)
         self._step_B = _bucket(1)
+        if self.window:
+            log_dist(f"InferenceEngineV2: {num_window_blocks} blocks in "
+                     f"the window layers' pool (window {self.window})",
+                     ranks=[0])
         log_dist(f"InferenceEngineV2: {num_blocks} KV blocks x "
                  f"{self.block_size} tokens, max_context="
                  f"{self.max_context}", ranks=[0])
@@ -374,6 +412,8 @@ class InferenceEngineV2:
                 not self.state.free_state_slots:
             return 0, 0         # no recurrent-state slot to start in
         blocks = self.state.blocks_needed(seq, max_tokens)
+        if self.window and not self._has_room([(seq, max_tokens)]):
+            return 0, 0         # the window layers' pool is short
         return max_tokens, min(blocks, max_request_blocks)
 
     @property
@@ -402,16 +442,21 @@ class InferenceEngineV2:
                    for n in lengths]
         if sum(per_fwd) > sm.max_ragged_batch_size:
             return SchedulingResult.BatchTokenLimitExceeded
-        blocks = 0
+        asks = []
         for uid, n in zip(uids, lengths):
             seq = self.state.get_sequence(uid)
             seen = seq.seen_tokens if seq else 0
             if seen + n > self.max_context:
                 return SchedulingResult.SequenceTokenLimitExceeded
-            blocks += self.state.blocks_needed(seq, n)
-        if blocks > self.state.free_blocks:
+            asks.append((seq, n))
+        if not self._has_room(asks):
             return SchedulingResult.KVCacheLimitExceeded
         return SchedulingResult.Success
+
+    def _has_room(self, asks, behind: bool = True) -> bool:
+        """``StateManager.has_room`` at this engine's prefill slice."""
+        return self.state.has_room(
+            asks, behind, self.config.state_manager.prefill_chunk)
 
     # -------------------------------------------------------------- #
     # put (reference: engine_v2.py:131)
@@ -564,6 +609,8 @@ class InferenceEngineV2:
                             lead_latents.setdefault(i, []).append(
                                 part_t[i])
                         batch_tokens[i] = batch_tokens[i][chunk:]
+                    self._release_behind_windows(batch_uids[i]
+                                                 for i in long_idx)
 
         with tracer.span("serve.put.admit"):
             for uid, tokens in zip(batch_uids, batch_tokens):
@@ -619,6 +666,7 @@ class InferenceEngineV2:
         with tracer.span("serve.scatter"):
             for uid in batch_uids:
                 self.state.get_sequence(uid).post_forward()
+            self._release_behind_windows(batch_uids)
 
             if self.prefix_caching:
                 for uid, toks in zip(batch_uids, processed):
@@ -669,6 +717,18 @@ class InferenceEngineV2:
             if collect is not None:
                 collect()
 
+    def _release_behind_windows(self, uids) -> None:
+        """After a step: the window blocks of ``uids`` that lie wholly
+        behind their windows go back to the window pool's allocator (a
+        leaf span of its own inside ``serve.scatter``,
+        ``serve.window_free``; nothing for a trunk with one pool)."""
+        if not self.window:
+            return
+        with get_tracer().span("serve.window_free") as span:
+            span.set(blocks=sum(
+                self.state.release_behind_window(
+                    self.state.get_sequence(uid)) for uid in uids))
+
     def _tables(self, idx, uids):
         return np.stack([
             self.state.block_table(self.state.get_sequence(uids[i]),
@@ -681,8 +741,10 @@ class InferenceEngineV2:
         tok = np.zeros((B, T), np.int32)
         start = np.zeros((B,), np.int32)
         t_len = np.zeros((B,), np.int32)
-        tables = np.zeros((B, self.max_blocks_per_seq), np.int32)
+        tables = np.zeros((B, self.model.table_width), np.int32)
         tables[:, 0] = self._scratch_block
+        if self.window:     # the window pool's table follows
+            tables[:, self.max_blocks_per_seq] = self._window_scratch
         return tok, start, t_len, tables
 
     def _count_chained(self, span):
@@ -1057,10 +1119,31 @@ class InferenceEngineV2:
         ``[E]`` the positions routed to each expert, all layers summed
         (``None`` before the first pass); ``touched`` the experts with
         any pick, layer by layer and pass by pass; ``dispatches`` the
-        passes counted."""
+        passes counted. A causal trunk whose forwards keep their counts
+        on the device (``model.take_picks``) answers over all the
+        experts its router scores and, beside them, for the experts its
+        parameter tree holds: ``picks_held``, ``held_log`` ``[forwards,
+        2]`` (a forward's rows on held experts and the held experts
+        those touched, all layers summed), and ``touched`` of the held
+        experts alone."""
         m = self._moe_stats
+        if hasattr(self.model, "take_picks"):
+            picks = self.model.take_picks()
+            first, count = self._model_config.held
+            log = self.model.held_log
+            return {"dispatches": self.model.moe_dispatches,
+                    "touched": int(log[:, 1].sum()), "picks": picks,
+                    "picks_held": int(picks[first:first + count].sum()),
+                    "held_log": log.copy()}
         return {"dispatches": m["dispatches"], "touched": m["touched"],
                 "picks": None if m["picks"] is None else m["picks"].copy()}
+
+    def kv_pool_stats(self) -> Dict[str, Dict[str, int]]:
+        """Each block pool's ``blocks``, ``in_use`` and ``peak_in_use``
+        (the scratch block counted), and for the window layers' pool of
+        a trunk that has one the blocks ``released`` behind windows
+        while their sequences lived."""
+        return self.state.pool_stats()
 
     def router_inputs(self, uid: int):
         """What each sparse layer's router read for the last row that
@@ -1379,6 +1462,10 @@ class InferenceEngineV2:
         a returning sequence can be HCache-restored from them after a
         flush."""
         self._refuse_recurrent("generate_fused (the fused decode loop)")
+        self._refuse_windowed(
+            "generate_fused (the fused decode loop)",
+            "both pools and both tables carried through the loop's "
+            "program, and blocks freed inside it")
         self._refuse_diffusion("generate_fused (the fused decode loop)")
         if not 0.0 < top_p <= 1.0:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
@@ -1483,6 +1570,14 @@ class InferenceEngineV2:
                                    for l in logprobs]
         return outs, latents
 
+    def _refuse_windowed(self, feature: str, needs: str) -> None:
+        """Raise for a trunk with window layers (two pools, the window
+        pool's blocks freed behind the window): what would read or
+        rewrite a block that has gone back."""
+        if self.window:
+            from .model_window import refuse
+            raise refuse(feature, needs)
+
     def _refuse_recurrent(self, feature: str) -> None:
         """Raise for a call that would roll a recurrent state back or
         carry it through a program that does not hold it."""
@@ -1536,6 +1631,9 @@ class InferenceEngineV2:
         ``stats = {drafted, accepted, dispatches, tokens}``.
         """
         self._refuse_recurrent("generate_lookup (speculative rollback)")
+        self._refuse_windowed(
+            "generate_lookup (speculative rollback)",
+            "a rollback across a window block that has gone back")
         self._refuse_diffusion("generate_lookup (prompt-lookup drafts)")
         if self.prefix_caching:
             raise ValueError(
@@ -1658,6 +1756,10 @@ class InferenceEngineV2:
         batch)."""
         self._refuse_recurrent("generate_lookup_fused (speculative "
                                "rollback in a fused loop)")
+        self._refuse_windowed(
+            "generate_lookup_fused (the fused speculative decode loop)",
+            "both pools carried through the loop's program and a "
+            "rollback across a freed window block")
         self._refuse_diffusion("generate_lookup_fused (the fused lookup "
                                "loop)")
         if self.prefix_caching:
@@ -1774,6 +1876,10 @@ class InferenceEngineV2:
         ``prefix_caching`` stays unsupported (rolled-back KV must
         never register as a sharable prefix)."""
         self._refuse_recurrent("put_spec (rollback of rejected drafts)")
+        self._refuse_windowed(
+            "put_spec (rollback of rejected drafts)",
+            "a rollback across a window block that has gone back to "
+            "its allocator")
         self._refuse_diffusion("put_spec (speculative verification)")
         capture = bool(self.config.hcache.enable_latents)
         if self.prefix_caching:
@@ -1935,15 +2041,16 @@ class InferenceEngineV2:
                 self.config.state_manager.max_tracked_sequences:
             raise SchedulingError(
                 SchedulingResult.EngineSequenceLimitExceeded)
-        need = 0
+        asks = []
         for uid, tokens, _ in items:
             seq = self.state.get_sequence(uid)
             seen = seq.seen_tokens if seq else 0
             if seen + len(tokens) > self.max_context:
                 raise SchedulingError(
                     SchedulingResult.SequenceTokenLimitExceeded)
-            need += self.state.blocks_needed(seq, len(tokens))
-        if need > self.state.free_blocks:
+            asks.append((seq, len(tokens)))
+        # a restore writes a window layer's rows still inside the window
+        if not self._has_room(asks, behind=False):
             raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
         groups: Dict[int, List] = {}
         for item in items:
@@ -2113,6 +2220,11 @@ class InferenceEngineV2:
                 "saved_state": "cache_row",
                 "latent_bytes_per_token": self.model.saved_width
                 * latent_itemsize * self.model.n_latent_layers,
+                # of a window layer a restore ships the rows still
+                # inside the window (0: the trunk has no such layer)
+                "window_layers": self.model.pool_layers["window"]
+                if self.window else 0,
+                "window": self.window,
                 "replay_flops_frac": 0.0,
                 "restore_chunk_layers": self.model.restore_chunk_layers,
                 "restore_chunk_bytes": self.model.restore_chunk_bytes,
@@ -2155,7 +2267,7 @@ class InferenceEngineV2:
         seqs = []
         for j, (uid, tokens, latents) in enumerate(group):
             seq = self.state.get_or_create_sequence(uid)
-            self.state.maybe_allocate_kv(seq, len(tokens))
+            self.state.maybe_allocate_kv(seq, len(tokens), behind=False)
             seq.pre_forward(len(tokens))
             lat[:, j, :len(tokens)] = latents
             start[j] = seq.seen_tokens
@@ -2379,6 +2491,10 @@ class InferenceEngineV2:
         """Copy the sequence's KV to host memory and free its pool
         blocks. The sequence stays tracked; ``resume_sequence`` swaps it
         back in (possibly into different blocks)."""
+        self._refuse_windowed(
+            "suspend_sequence (a host copy of the exact KV)",
+            "both pools' blocks copied out and back; eviction to saved "
+            "rows (latent preemption) serves this trunk")
         seq = self.state.get_sequence(uid)
         if seq is None:
             raise KeyError(f"unknown sequence {uid}")

@@ -295,6 +295,75 @@ def _glm4_moe_lite_like(hf: Dict[str, Any]):
     )
 
 
+def _cohere2_moe_like(hf: Dict[str, Any]):
+    """Cohere2-MoE (Command A+): a parallel block with one bias-free
+    LayerNorm a layer over window (``sliding_attention``: rotary,
+    interleaved pairing) and global (``full_attention``: no positional
+    step) layers in the period ``layer_types`` gives, sigmoid-routed
+    experts beside averaged shared ones, a tied head.
+    ``experts_held = [first, count]``: the routed experts this parameter
+    tree holds (default all). What is not built is refused by name; the
+    vision tower is no part of the language model's ``config`` and is
+    not read."""
+    from ..models.cohere2_moe import Cohere2MoeConfig
+    rope = hf.get("rope_parameters") or {}
+    refused = {
+        "use_qk_norm": bool(hf.get("use_qk_norm", False)),
+        "attention_bias": bool(hf.get("attention_bias", False)),
+        "first_k_dense_replace > 0 (leading dense layers)":
+            hf.get("first_k_dense_replace", 0) > 0,
+        f"rope_type {rope.get('rope_type')!r} (only default)":
+            rope.get("rope_type", "default") != "default",
+        "rope_scaling": hf.get("rope_scaling") is not None,
+        "rotary_pct != 1": hf.get("rotary_pct", 1) != 1,
+        "use_parallel_block false (a sequential block)":
+            not hf.get("use_parallel_block", True),
+        f"position_embedding_type "
+        f"{hf.get('position_embedding_type')!r} (only rope_gptj)":
+            hf.get("position_embedding_type", "rope_gptj") != "rope_gptj",
+        f"expert_selection_fn {hf.get('expert_selection_fn')!r} "
+        "(only sigmoid)":
+            hf.get("expert_selection_fn", "sigmoid") != "sigmoid",
+        f"shared_expert_combination_strategy "
+        f"{hf.get('shared_expert_combination_strategy')!r} (only "
+        "average)":
+            hf.get("shared_expert_combination_strategy",
+                   "average") != "average",
+        "use_gated_activation false": not hf.get("use_gated_activation",
+                                                 True),
+        "tie_word_embeddings false (an untied head)":
+            not hf.get("tie_word_embeddings", True),
+    }
+    for feature, present in refused.items():
+        if present:
+            raise NotImplementedError(
+                f"cohere2_moe with {feature} is not supported: the "
+                "windowed trunk (inference/model_window.py) does not "
+                "build it")
+    held = hf.get("experts_held")
+    return Cohere2MoeConfig(
+        vocab_size=hf.get("vocab_size", 262144),
+        hidden_size=hf.get("hidden_size", 4096),
+        intermediate_size=hf.get("intermediate_size", 4096),
+        n_layer=hf.get("num_hidden_layers", 32),
+        n_head=hf.get("num_attention_heads", 128),
+        n_kv_head=hf.get("num_key_value_heads", 8),
+        head_width=hf.get("head_dim", 128),
+        max_positions=hf.get("max_position_embeddings", 200000),
+        layer_norm_eps=hf.get("layer_norm_eps", 1e-5),
+        rope_theta=hf.get("rope_theta", rope.get("rope_theta", 50000)),
+        layer_types=tuple(hf["layer_types"]),
+        sliding_window=hf.get("sliding_window", 4096),
+        num_experts=hf.get("num_experts", 128),
+        top_k=hf.get("num_experts_per_tok", 8),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        num_shared_experts=hf.get("num_shared_experts", 4),
+        logit_scale=hf.get("logit_scale", 1.0),
+        experts_held=tuple(held) if held else None,
+        dtype=hf.get("torch_dtype") or "bfloat16",
+    )
+
+
 #: model_type -> config adapter (reference: the policy map in
 #: engine_factory.py:69 — llama/mistral/qwen2/phi3 share the llama block
 #: layout; mixtral/qwen2_moe route through the MoE paged model
@@ -306,7 +375,10 @@ def _glm4_moe_lite_like(hf: Dict[str, Any]):
 #: sdar_moe is the MoE paged model with a per-head q/k norm, an explicit
 #: head width and the block mask of generation by diffusion over blocks;
 #: glm4_moe_lite is the latent-attention trunk (model_latent.py: a pool
-#: of compressed KV rows, dense layers leading a sparse stack).
+#: of compressed KV rows, dense layers leading a sparse stack);
+#: cohere2_moe is the windowed trunk (model_window.py: window and global
+#: layers each with a block pool, a parallel block, an expert layer that
+#: may hold a share of its experts).
 MODEL_FAMILIES = {
     "llama": _llama_like,
     "mistral": _llama_like,
@@ -322,6 +394,7 @@ MODEL_FAMILIES = {
     "olmo_hybrid": _olmo_hybrid_like,
     "sdar_moe": _sdar_moe_like,
     "glm4_moe_lite": _glm4_moe_lite_like,
+    "cohere2_moe": _cohere2_moe_like,
 }
 
 

@@ -107,6 +107,47 @@ def stack_layer_params(params: Dict[str, Any], n_layers: int,
     return jax.tree.map(stack, *layers)
 
 
+def scan_periods(period, n_periods, x, pools, steps):
+    """The layer loop of a trunk whose layers are of several kinds in a
+    repeating ``period`` (a tuple of kinds): a scan over the periods,
+    each step the period's layers one after the other.
+    ``steps[kind](x, pools, index)`` runs one layer of that kind, the
+    ``index``-th of its kind in the trunk, and returns ``(x, pools,
+    out)``; ``out`` is what the layer hands back (a latent, a tree of
+    arrays) or ``None``. ``pools`` (any tree) is **carried, never
+    scanned over**: a scanned-over pool is two buffers of the loop, and
+    each layer's weights are read where they lie, at a dynamic index of
+    their kind's stack (a period's slab handed over as the scan's ``xs``
+    is sliced out of the stack and copied first). Returns ``(x, pools,
+    outs)``, ``outs`` stacked over the layers that gave one, in layer
+    order."""
+    per = {kind: period.count(kind) for kind in period}
+
+    def step(carry, p):
+        x, pools = carry
+        outs, seen = [], dict.fromkeys(per, 0)
+        for kind in period:
+            x, pools, out = steps[kind](
+                x, pools, p * per[kind] + seen[kind])
+            seen[kind] += 1
+            if out is not None:
+                outs.append(out)
+        return (x, pools), jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+    (x, pools), outs = jax.lax.scan(step, (x, pools),
+                                    jnp.arange(n_periods))
+    return x, pools, jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), outs)
+
+
+def layer_of(stack, index):
+    """One layer's parameters out of a stacked tree ``[L, ...]`` at a
+    (traced) ``index``, each leaf read where it lies."""
+    return jax.tree.map(
+        lambda p: jax.lax.dynamic_index_in_dim(
+            p, index, axis=0, keepdims=False), stack)
+
+
 class PagedInferenceModel:
     """Functional paged-attention transformer consuming *training* params
     from ``models.llama.LlamaForCausalLM`` (same names/shapes — a trained
@@ -468,6 +509,12 @@ class PagedInferenceModel:
             self._param_spec_tree(params),
             is_leaf=lambda x: isinstance(x, PartitionSpec))
 
+    @property
+    def table_width(self):
+        """Entries of a lane's block table as a dispatch carries it: a
+        table a block pool, side by side."""
+        return self.max_blocks_per_seq
+
     def pool_layout(self):
         """``(kv heads, k width, v width)`` of the two block pools."""
         return self.cfg.n_kv_head, self.cfg.head_dim, self.cfg.head_dim
@@ -521,7 +568,7 @@ class PagedInferenceModel:
         every group's columns, one group after the other. Named after
         ``fwd`` in a profile."""
         column = self.recurrent if column is None else column
-        blocks = self.max_blocks_per_seq
+        blocks = self.table_width
 
         def program(params, *operands):
             lanes = operands[pools]
@@ -605,7 +652,7 @@ class PagedInferenceModel:
                 ck, cv = write_rows(ck, cv, layer, kg, vg, g.flat_idx)
         return ck, cv
 
-    def _paged_attention(self, q, ck, cv, layer, lanes):
+    def _paged_attention(self, q, ck, cv, layer, lanes, window=None):
         """q: the rows of all of ``lanes``, a group's [B, T, Hq, D];
         ck/cv: the whole [L, KV, P, D] pools, read at ``layer`` by each
         group's tables [B, NB], absolute positions [B, T] and valid
@@ -614,14 +661,17 @@ class PagedInferenceModel:
         Dispatches to the Pallas ragged paged-attention kernel
         (``ops/paged_attention.py`` — the blocked_flash analog), a call
         a group at the group's shape: block-table-indexed flash over
-        valid blocks only, no dense [B, S_max] gather, no GQA repeat."""
+        valid blocks only, no dense [B, S_max] gather, no GQA repeat.
+        ``window``: the layer's sliding window (``None``: none)."""
         outs = []
+        extra = () if window is None else (window,)
+        static = (7, 8) + ((9,) if extra else ())
         for g, qg in zip(lanes.groups, lanes.split(q)):
             B, T, Hq, D = qg.shape
             start = g.positions[:, 0]
-            out = lanes.shared(paged_attention, (7, 8))(
+            out = lanes.shared(paged_attention, static)(
                 qg, ck, cv, layer, g.tables, start, g.kv_len,
-                self.block_size, self.mask_block)
+                self.block_size, self.mask_block, *extra)
             outs.append(out.reshape(B, T, Hq * D))
         return lanes.join(outs)
 

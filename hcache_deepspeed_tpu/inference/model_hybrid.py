@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from ..models.olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
 from ..ops.gated_delta import gated_delta_rule
 from ..ops.rms_norm import reference_rms_norm, rms_norm
-from .model import PagedInferenceModel, stack_layer_params
+from .model import (PagedInferenceModel, layer_of, scan_periods,
+                    stack_layer_params)
 from .ragged.lanes import Lanes
 
 
@@ -234,39 +235,23 @@ class PagedHybridModel(PagedInferenceModel):
         lanes = Lanes.of(columns, slot=True)
         x = self._embed_lanes(params, lanes, cache_k.shape[2])
 
-        # every pool is carried: a scanned-over pool is two buffers of
-        # the loop (inference/model.py _trunk)
-        # and each layer's weights are read where they lie, at a dynamic
-        # index of their stack: a period's slab handed over as the scan's
-        # ``xs`` is sliced out of the stack and copied first
-        def layer_of(stack, index):
-            return jax.tree.map(
-                lambda p: jax.lax.dynamic_index_in_dim(
-                    p, index, axis=0, keepdims=False), stack)
+        def linear(x, pools, layer):
+            ck, cv, st, cn = pools
+            x, st, cn = self._linear_step(
+                x, layer_of(params["lin_layers"], layer), st, cn, layer,
+                lanes)
+            return x, (ck, cv, st, cn), None
 
-        def step(carry, period):
-            x, ck, cv, st, cn = carry
-            latents, jl, jf = [], 0, 0
-            for kind in self.period:
-                if kind == LINEAR:
-                    layer = period * self.lin_per + jl
-                    x, st, cn = self._linear_step(
-                        x, layer_of(params["lin_layers"], layer), st, cn,
-                        layer, lanes)
-                    jl += 1
-                else:
-                    layer = period * self.full_per + jf
-                    x, ck, cv, latent = self._full_step(
-                        x, layer_of(params["full_layers"], layer), ck, cv,
-                        layer, lanes)
-                    latents.append(latent)
-                    jf += 1
-            return (x, ck, cv, st, cn), jnp.stack(latents)
+        def full(x, pools, layer):
+            ck, cv, st, cn = pools
+            x, ck, cv, latent = self._full_step(
+                x, layer_of(params["full_layers"], layer), ck, cv, layer,
+                lanes)
+            return x, (ck, cv, st, cn), latent
 
-        (x, cache_k, cache_v, state, conv), latents = jax.lax.scan(
-            step, (x, cache_k, cache_v, state, conv),
-            jnp.arange(self.n_periods))
-        latents = latents.reshape(-1, *latents.shape[2:])   # [L_full, ...]
+        x, (cache_k, cache_v, state, conv), latents = scan_periods(
+            self.period, self.n_periods, x,
+            (cache_k, cache_v, state, conv), {LINEAR: linear, FULL: full})
         x = self._final_norm(params, x)
         return cache_k, cache_v, state, conv, x, latents, lanes
 

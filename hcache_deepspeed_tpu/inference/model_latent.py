@@ -245,11 +245,6 @@ class PagedLatentModel(PagedMoEModel):
         return x, cache_k, cache_v, latents
 
     # -------------------------------------------------------------- #
-    def _swiglu(self, p, h2):
-        gate = self._mm(h2, p["gate_proj"]["kernel"])
-        up = self._mm(h2, p["up_proj"]["kernel"])
-        return self._mm(jax.nn.silu(gate) * up, p["down_proj"]["kernel"])
-
     def _mlp(self, lp, h2, lanes, pool_slots):
         if "experts" not in lp["mlp"]:          # a leading dense layer
             return self._swiglu(lp["mlp"], h2), {}

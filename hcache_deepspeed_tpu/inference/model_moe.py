@@ -95,6 +95,13 @@ class PagedMoEModel(PagedInferenceModel):
     def _mlp_out(self, lp, h2):
         return self._routed(lp, h2)[0]
 
+    def _swiglu(self, p, h2):
+        """A dense SwiGLU of ``gate_proj``/``up_proj``/``down_proj``: a
+        shared expert, or a dense layer among sparse ones."""
+        gate = self._mm(h2, p["gate_proj"]["kernel"])
+        up = self._mm(h2, p["up_proj"]["kernel"])
+        return self._mm(jax.nn.silu(gate) * up, p["down_proj"]["kernel"])
+
     def _mlp(self, lp, h2, lanes, pool_slots):
         """The expert layer; ``picks`` an expert took over the real
         positions (``engine.moe_stats()``) and ``router_in``, what the

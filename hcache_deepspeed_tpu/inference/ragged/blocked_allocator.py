@@ -23,6 +23,8 @@ class BlockedAllocator:
         self._num_blocks = num_blocks
         self._free: List[int] = list(range(num_blocks))
         self._refs: Dict[int, int] = {}
+        #: the most blocks that were out at once
+        self.peak_in_use = 0
 
     @property
     def free_blocks(self) -> int:
@@ -50,6 +52,8 @@ class BlockedAllocator:
                 f"cannot allocate {num_blocks} blocks, only "
                 f"{len(self._free)} free")
         out, self._free = self._free[:num_blocks], self._free[num_blocks:]
+        self.peak_in_use = max(self.peak_in_use,
+                               self._num_blocks - len(self._free))
         for b in out:
             self._refs[b] = 1
         return out

@@ -174,13 +174,58 @@ class HybridCache(BlockedKVCache):
         self.state, self.conv = state, conv
 
 
+class WindowedKVCache(BlockedKVCache):
+    """Two block pools for a trunk whose layers are of two kinds: the
+    global (full-attention) layers' K and V in ``k``/``v`` ``[L_global,
+    KV, P_g, D]`` and the window layers' in ``wk``/``wv`` ``[L_window,
+    KV, P_w, D]``, each with its own allocator and block lifetime
+    (``StateManager``): a window layer's blocks go back while the
+    sequence lives, so ``P_w`` is sized by the window and not by the
+    context. All four arrays are donated to every forward and replaced
+    by its results."""
+
+    def __init__(self, n_global_layers: int, num_blocks: int,
+                 n_window_layers: int, num_window_blocks: int,
+                 block_size: int, n_kv_heads: int, head_dim: int,
+                 dtype=jnp.bfloat16):
+        super().__init__(n_global_layers, num_blocks, block_size,
+                         n_kv_heads, head_dim, dtype=dtype)
+        self.n_window_layers = n_window_layers
+        self.num_window_blocks = num_window_blocks
+        shape = (n_window_layers, n_kv_heads,
+                 num_window_blocks * block_size, head_dim)
+        self.wk = jnp.zeros(shape, dtype)
+        self.wv = jnp.zeros(shape, dtype)
+
+    def replace_window(self, wk, wv):
+        self.wk, self.wv = wk, wv
+
+
+def window_of(model_config) -> int:
+    """The sliding window of a trunk that has window
+    (``sliding_attention``) layers beside global ones and so keeps two
+    block pools, read off its shape; 0 for any other."""
+    types = getattr(model_config, "layer_types", None) or ()
+    window = getattr(model_config, "sliding_window", None)
+    return int(window) if window and "sliding_attention" in types else 0
+
+
 class StateManager:
     """uid → SequenceDescriptor tracking + block budget arithmetic, and
     for a trunk with recurrent layers the slots of its state pools
-    (``state_slots`` of them; 0: no such layer, nothing is kept)."""
+    (``state_slots`` of them; 0: no such layer, nothing is kept).
+
+    ``window_blocks`` > 0: the trunk's window layers keep their K and V
+    in a second pool of that many blocks (``WindowedKVCache``), with its
+    own allocator and a block lifetime of its own: after a step, a
+    sequence's window blocks that lie wholly behind ``seen_tokens -
+    window`` return to the allocator (:meth:`release_behind_window`),
+    while its global blocks stay until the flush. Every budget question
+    (:meth:`has_room`) is asked of both pools."""
 
     def __init__(self, max_tracked_sequences: int, num_blocks: int,
-                 block_size: int, max_seq_len: int, state_slots: int = 0):
+                 block_size: int, max_seq_len: int, state_slots: int = 0,
+                 window_blocks: int = 0, window: int = 0):
         self.max_tracked_sequences = max_tracked_sequences
         self.block_size = block_size
         self.max_seq_len = max_seq_len
@@ -188,6 +233,11 @@ class StateManager:
         self._seqs: Dict[int, SequenceDescriptor] = {}
         self.state_slots = state_slots
         self._free_slots: List[int] = list(range(state_slots))
+        self.window = window
+        self.window_allocator = BlockedAllocator(window_blocks) \
+            if window_blocks else None
+        #: window blocks given back while their sequence lived
+        self.window_blocks_released = 0
 
     @property
     def free_state_slots(self) -> int:
@@ -231,11 +281,104 @@ class StateManager:
         need = -(-total // self.block_size)  # ceil
         return max(need - have, 0)
 
+    # ---- the window layers' pool ---------------------------------- #
+    @property
+    def free_window_blocks(self) -> int:
+        return self.window_allocator.free_blocks \
+            if self.window_allocator else 0
+
+    def _first_live(self, seen: int) -> int:
+        """The first logical block a query at ``seen`` or later can
+        see under the window."""
+        return max(seen - self.window, 0) // self.block_size
+
+    def window_blocks_needed(self, seq: Optional[SequenceDescriptor],
+                             new_tokens: int, behind: bool = True) -> int:
+        """Blocks of the window pool a forward of ``new_tokens`` more
+        positions takes: the logical blocks up to its end that the
+        sequence does not hold, at most ``ceil((window + new_tokens) /
+        block_size) + 1`` once the blocks behind the window have gone
+        back. ``behind`` false: the forward writes only the rows still
+        inside the window at its end (a restore), and a sequence that
+        holds nothing starts at the first block those lie in."""
+        if self.window_allocator is None:
+            return 0
+        seen = seq.seen_tokens if seq else 0
+        have = len(seq.window_blocks) if seq else 0
+        need = -(-(seen + new_tokens) // self.block_size)
+        return max(need - self._window_first(seq, new_tokens, behind)
+                   - have, 0)
+
+    def _window_first(self, seq, new_tokens: int, behind: bool) -> int:
+        """The logical block ``seq``'s window table starts at for a
+        forward of ``new_tokens``: where it stands, or (``behind``
+        false, nothing held yet) the first block a query past the
+        forward's end still sees."""
+        first = seq.window_first if seq else 0
+        if behind or (seq and seq.window_blocks):
+            return first
+        seen = seq.seen_tokens if seq else 0
+        return max(first, self._first_live(seen + new_tokens))
+
+    def has_room(self, asks, behind: bool = True,
+                 slice_tokens: int = 0) -> bool:
+        """Whether both pools hold the blocks that ``asks``, pairs of
+        (sequence or ``None``, new tokens), need together.
+        ``slice_tokens`` > 0: a forward holds at most that many tokens
+        of a sequence (chunked prefill) and the blocks behind the window
+        go back between forwards, so the window pool is asked for a
+        slice."""
+        asks = list(asks)
+        if sum(self.blocks_needed(seq, n) for seq, n in asks) > \
+                self.free_blocks:
+            return False
+        if self.window_allocator is None:
+            return True
+
+        def tokens(n):
+            return min(n, slice_tokens) if slice_tokens and behind else n
+        return sum(self.window_blocks_needed(seq, tokens(n), behind)
+                   for seq, n in asks) <= self.free_window_blocks
+
+    def release_behind_window(self, seq: SequenceDescriptor) -> int:
+        """Give back ``seq``'s window blocks that lie wholly behind
+        ``seen_tokens - window``: no later query reaches them, and the
+        kernel's walk starts past their table entries. Returns how
+        many."""
+        if self.window_allocator is None:
+            return 0
+        gone = min(self._first_live(seq.seen_tokens) - seq.window_first,
+                   len(seq.window_blocks))
+        if gone <= 0:
+            return 0
+        self.window_allocator.free(seq.window_blocks[:gone])
+        del seq.window_blocks[:gone]
+        seq.window_first += gone
+        self.window_blocks_released += gone
+        return gone
+
+    def pool_stats(self) -> Dict[str, Dict[str, int]]:
+        """Blocks, blocks in use and the most in use of each pool, and
+        the window blocks given back behind windows."""
+        def of(alloc):
+            return {"blocks": alloc.num_blocks,
+                    "in_use": alloc.num_blocks - alloc.free_blocks,
+                    "peak_in_use": alloc.peak_in_use}
+        out = {"global": of(self.allocator)}
+        if self.window_allocator is not None:
+            out["window"] = dict(of(self.window_allocator),
+                                 released=self.window_blocks_released)
+        return out
+
     def maybe_allocate_kv(self, seq: SequenceDescriptor,
-                          new_tokens: int) -> None:
+                          new_tokens: int, behind: bool = True) -> None:
         need = self.blocks_needed(seq, new_tokens)
         if need:
             seq.extend_blocks(self.allocator.allocate(need))
+        need = self.window_blocks_needed(seq, new_tokens, behind)
+        if need:
+            seq.window_first = self._window_first(seq, new_tokens, behind)
+            seq.window_blocks.extend(self.window_allocator.allocate(need))
 
     def flush_sequence(self, uid: int) -> None:
         seq = self._seqs.pop(uid, None)
@@ -243,6 +386,9 @@ class StateManager:
             return
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if seq.window_blocks:
+            self.window_allocator.free(seq.window_blocks)
+            seq.window_blocks = []
         if seq.state_slot >= 0:
             self._free_slots.append(seq.state_slot)
             seq.state_slot = -1
@@ -250,8 +396,16 @@ class StateManager:
     def block_table(self, seq: SequenceDescriptor,
                     max_blocks: int) -> np.ndarray:
         """Padded int32 block table; unused entries point at block 0 but are
-        never read/written thanks to length masks."""
-        table = np.zeros((max_blocks,), np.int32)
+        never read/written thanks to length masks. With a window pool
+        the window table follows the global one, ``max_blocks`` entries
+        each by logical block: the entries behind the window, whose
+        blocks have gone back, are never read either."""
+        table = np.zeros((max_blocks * (1 + bool(self.window_allocator)),),
+                         np.int32)
         n = min(len(seq.blocks), max_blocks)
         table[:n] = seq.blocks[:n]
+        if self.window_allocator is not None:
+            at = max_blocks + seq.window_first
+            held = seq.window_blocks[:2 * max_blocks - at]
+            table[at:at + len(held)] = held
         return table

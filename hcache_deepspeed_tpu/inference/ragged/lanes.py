@@ -133,6 +133,13 @@ class Lanes:
         return cls(LaneGroup(*columns[i:i + n])
                    for i in range(0, len(columns), n))
 
+    def over_tables(self, lo: int, hi: int):
+        """These lanes over columns ``[lo, hi)`` of their tables alone:
+        a trunk with two block pools carries each lane's two tables side
+        by side, and every pool's writes and reads go by its own."""
+        return Lanes(LaneGroup(g.tokens, g.start, g.tables[:, lo:hi],
+                               g.t_len, g.slots) for g in self.groups)
+
     def place_positions(self):
         """Reckon every group's ``positions`` ``[B, T]`` (a lane's are
         consecutive from its ``start``) and those of all rows."""

@@ -16,6 +16,13 @@ class SequenceDescriptor:
         self.seen_tokens = 0            # tokens whose KV is materialized
         self.in_flight_tokens = 0       # tokens in the current forward
         self.blocks: List[int] = []     # KV pool block ids, in order
+        #: a trunk with window layers keeps their K and V in a pool of
+        #: its own (``StateManager``): the blocks of that pool this
+        #: sequence holds, for its logical blocks ``window_first``
+        #: onwards; those before went back to the allocator when they
+        #: fell wholly behind the window
+        self.window_blocks: List[int] = []
+        self.window_first = 0
         #: slot of the recurrent-state pools this sequence holds from its
         #: first scheduling to its flush (-1: the model has no recurrent
         #: layer, see ``StateManager``)

@@ -65,7 +65,7 @@ def dropless_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True):
 
 
 def routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True,
-                      layer=None, **router):
+                      layer=None, held=None, **router):
     """The routed grouped-GEMM SwiGLU computation shared by the training
     layer below and the paged serving model (inference/model_moe.py).
     tokens: [N, d]; returns ([N, d], aux, experts picked [N, k]).
@@ -73,16 +73,30 @@ def routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True,
     ``[L, E, ...]`` and this is the layer to compute by; the stack is
     read in place (``ops/grouped_gemm.py grouped_matmul_stacked``).
     ``router``: :func:`dropless_route`'s ``score``, ``bias`` and
-    ``scale``, from the configuration's published keys."""
+    ``scale``, from the configuration's published keys.
+    ``held`` ``(first, count)``: the stacks hold only experts ``[first,
+    first + count)`` of the ``E`` the router scores (a layer whose
+    experts are spread over several chips, this chip's share). Scores,
+    picks and weights are over all ``E``; picks that fall elsewhere sort
+    into a tail that belongs to no group, so no product is computed for
+    them and no weight read, and what those experts would have added is
+    left out of the result."""
     N, d = tokens.shape
     E = wg.shape[-1]
     dt = tokens.dtype
     logits = tokens.astype(jnp.float32) @ wg
     probs, experts, aux = dropless_route(logits, k, renormalize, **router)
     flat_e = experts.reshape(-1)                     # [N*k]
+    here = None
+    if held is not None and tuple(held) != (0, E):
+        first, count = held
+        here = (flat_e >= first) & (flat_e < first + count)
+        flat_e = jnp.where(here, flat_e - first, count)  # the tail: count
+        E = count
     order = jnp.argsort(flat_e, stable=True)
     token_of = order // k
     xs = tokens[token_of]
+    # the tail's rows lie behind every group's: bincount leaves them out
     group_sizes = jnp.bincount(flat_e, length=E)
     if layer is None:
         def product(x, w):
@@ -94,8 +108,11 @@ def routed_expert_ffn(tokens, wg, w1, w3, w2, k, renormalize=True,
     h = jax.nn.silu(product(xs, w1)) * product(xs, w3)
     ys = product(h, w2)                                  # [N*k, d]
     gate = probs.reshape(-1)[order].astype(dt)
-    out = jax.ops.segment_sum(ys * gate[:, None], token_of,
-                              num_segments=N)
+    ys = ys * gate[:, None]
+    if here is not None:
+        # a row of no group was never written by the kernel
+        ys = jnp.where(here[order][:, None], ys, 0)
+    out = jax.ops.segment_sum(ys, token_of, num_segments=N)
     return out, aux, experts
 
 

@@ -923,10 +923,14 @@ class ContinuousBatchingScheduler:
         sequence), KV blocks for the full cached span plus a decode
         headroom of one block per resident (residents crossing a block
         boundary next step must not be starved by the restore — the
-        anti-thrash guard), and a free decode lane next step.
+        anti-thrash guard), and a free decode lane next step. A trunk
+        with window layers is asked of both its pools: the window
+        layers' for the rows still inside the window.
         """
         sm = self.engine.config.state_manager
-        free = self.engine.state.free_blocks
+        state = self.engine.state
+        free = state.free_blocks
+        free_window = state.free_window_blocks
         headroom = len(self.running)
         # open lanes become decode lanes when they complete — budget
         # them now so completions can't overflow the ragged batch
@@ -942,16 +946,22 @@ class ContinuousBatchingScheduler:
                 break
             if self.latent_preemption:
                 need = -(-req.cached_tokens // self.engine.block_size)
+                need_window = state.window_blocks_needed(
+                    None, req.cached_tokens, behind=False)
                 if tracked + 1 > sm.max_tracked_sequences:
                     break
             else:
                 seq = self.engine.state.get_sequence(req.uid)
                 need = self.engine.state.blocks_needed(seq, 0)
-            if need > free - headroom:
+                need_window = 0
+            if need > free - headroom or (
+                    need_window and
+                    need_window > free_window - headroom):
                 if self.restore_priority_barrier:
                     break     # head-of-line: nobody leapfrogs
                 continue      # smaller suspendees may still fit
             free -= need
+            free_window -= need_window
             lanes += 1
             tracked += 1
             out.append(req)

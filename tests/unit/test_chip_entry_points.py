@@ -59,6 +59,22 @@ def test_latent_smoke_phase_passes_at_tiny_size():
             published["vocab_size"]) == (2048, 512, 64, 64, 154880)
 
 
+def test_window_smoke_phase_passes_at_tiny_size():
+    """The windowed period (three window layers and a global one, a
+    share of the experts held) at toy width: the phase's checks (K/V
+    rows to the host, window blocks back while the sequence lives, evict
+    and restore through both pools against the uninterrupted logits,
+    every block given back) hold on the CPU's program."""
+    chip_smoke.window_phase(chip_smoke.TINY_WINDOW_PERIOD, block_size=8,
+                            prefill_chunk=16, logit_tol=1e-5)
+    published = chip_smoke.COMMAND_A_PERIOD
+    assert (published["hidden_size"], published["num_attention_heads"],
+            published["num_key_value_heads"], published["head_dim"],
+            published["sliding_window"], published["num_experts"],
+            published["intermediate_size"]) == \
+        (4096, 128, 8, 128, 4096, 128, 4096)
+
+
 def test_smoke_sizes_keep_the_full_mistral_7b_width():
     hf = chip_smoke.MISTRAL_7B.hf_config
     assert (hf["hidden_size"], hf["intermediate_size"],
